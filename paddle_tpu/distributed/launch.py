@@ -66,7 +66,11 @@ def parse_args(argv=None):
     p.add_argument("--node_ip", default="127.0.0.1")
     p.add_argument("--started_port", type=int, default=6170)
     p.add_argument("--nproc_per_node", type=int, default=None,
-                   help="processes per node (default: local device count)")
+                   help="processes per node (default: 1).  On a TPU host "
+                        "the supported shape is ONE process driving all "
+                        "local chips: more than one plain-mode process "
+                        "is refused unless JAX_PLATFORMS pins the "
+                        "children off the TPU")
     p.add_argument("--selected_devices", default=None,
                    help="comma list overriding nproc_per_node")
     p.add_argument("--log_dir", default=None)
@@ -477,6 +481,14 @@ def _supervise_pack(args, nproc, devices, attempt, prev_nproc,
     return None
 
 
+def _children_may_claim_tpu():
+    """True unless JAX_PLATFORMS pins the children off the TPU.  Read
+    from the environment, never from JAX: a launcher that initialised a
+    backend would hold the chips its children need."""
+    platforms = os.environ.get("JAX_PLATFORMS", "").replace(" ", "")
+    return not platforms or "tpu" in platforms.split(",")
+
+
 def launch(args):
     if args.selected_devices:
         devices = [d for d in args.selected_devices.split(",") if d]
@@ -484,6 +496,17 @@ def launch(args):
     else:
         nproc = args.nproc_per_node or 1
         devices = [str(i) for i in range(nproc)]
+    if nproc > 1 and not args.coordinator and _children_may_claim_tpu():
+        # nothing restricts a child's chip visibility (FLAGS_selected_tpus
+        # is advisory), and a chip belongs to one process at a time: N
+        # children would each try to own every local chip
+        sys.stderr.write(
+            "plain mode with %d processes per host is refused: on a TPU "
+            "host ONE process drives all local chips (CompiledProgram."
+            "with_data_parallel or GradAllReduce(nranks=N) inside it).  "
+            "For a CPU pack set JAX_PLATFORMS=cpu, or use --coordinator "
+            "(single-device CPU processes).\n" % nproc)
+        return 2
     if args.elastic_min_nproc is not None and \
             args.elastic_min_nproc > nproc:
         # a floor above the launched world would GROW the pack on

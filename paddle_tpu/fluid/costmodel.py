@@ -214,6 +214,31 @@ def top_ops(attribution, n=6):
 
 
 # ---------------------------------------------------------------------------
+# Device peaks
+# ---------------------------------------------------------------------------
+
+# Published per-chip peaks keyed by ``jax.Device.device_kind`` (Google
+# Cloud documentation, "TPU v5e"; the key is the string the chip reports).
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9,
+                    "hbm_bytes": 16e9},
+}
+
+
+def device_peaks(device_kind):
+    """Peaks of one chip of ``device_kind``.  A device that is not in the
+    table is an error, never a default: a utilization computed against
+    another chip's peak is not a measurement."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            "no published peaks for device_kind %r (known: %s) — add its "
+            "row to costmodel.DEVICE_PEAKS with the source"
+            % (device_kind, sorted(DEVICE_PEAKS))) from None
+
+
+# ---------------------------------------------------------------------------
 # Record building
 # ---------------------------------------------------------------------------
 

@@ -86,39 +86,13 @@ def parallel_env_from_env():
     return coord, nproc, rank, local_ids
 
 
-def cpu_collectives_supported():
-    """True when this jax build exposes the CPU cross-process collective
-    transport knob (gloo/mpi).  The 2-process CI suites skip cleanly
-    when it is absent (tests/test_multihost.py)."""
-    try:
-        import jax
-        if "jax_cpu_collectives_implementation" in jax.config.values:
-            return True
-        jax.config.jax_cpu_collectives_implementation  # noqa: B018
-        return True
-    except Exception:
-        return False
-
-
-def ensure_cpu_collectives(implementation="gloo", warn=True):
+def ensure_cpu_collectives(implementation="gloo"):
     """Route CPU cross-process collectives through ``implementation``
     (gloo by default).  Must run before the CPU backend initializes;
-    idempotent; returns True on success.  Non-CPU backends are
-    unaffected — the knob only matters when the computation actually
-    lands on the CPU platform (``warn=False`` silences the
-    knob-missing warning where CPU is merely a possibility)."""
-    try:
-        import jax
-        jax.config.update("jax_cpu_collectives_implementation",
-                          implementation)
-        return True
-    except Exception as e:
-        if warn:
-            warnings.warn(
-                "CPU cross-process collectives unavailable (%s: %s) — "
-                "a multi-process CPU run will fail inside the first "
-                "collective" % (type(e).__name__, e), stacklevel=2)
-        return False
+    idempotent.  Non-CPU backends are unaffected — the knob only matters
+    when the computation actually lands on the CPU platform."""
+    import jax
+    jax.config.update("jax_cpu_collectives_implementation", implementation)
 
 
 def init(coordinator_address=None, num_processes=None, process_id=None,
@@ -168,14 +142,11 @@ def init(coordinator_address=None, num_processes=None, process_id=None,
     import jax
 
     # CPU pods (CI, laptops, manual two-terminal runs) need the gloo
-    # transport picked BEFORE the backend spins up; TPU/GPU backends
-    # ignore the knob, so ALWAYS attempt it — warn about a missing knob
-    # only when the environment positively says the backend is CPU
-    # (probing the backend here would initialize it, which is exactly
-    # what must not happen before jax.distributed.initialize)
-    cpu_hinted = (os.environ.get("JAX_PLATFORMS", "").strip() == "cpu" or
-                  bool(os.environ.get("PADDLE_MULTIHOST_CPU")))
-    ensure_cpu_collectives(warn=cpu_hinted)
+    # transport picked BEFORE the backend spins up; TPU backends ignore
+    # the knob, so ALWAYS set it (probing the backend here would
+    # initialize it, which is exactly what must not happen before
+    # jax.distributed.initialize)
+    ensure_cpu_collectives()
 
     kwargs = {}
     if local_device_ids is not None:
@@ -204,17 +175,17 @@ def shutdown():
     reshard-restore.
 
     Disconnects from the coordinator (``jax.distributed.shutdown``),
-    drops the cached device backend so the next backend initialization
-    sees the new world's devices, resets this module's identity state,
-    and clears the telemetry process label.  A world of one (never
-    connected) just resets local state.  Idempotent.
+    resets this module's identity state, and clears the telemetry
+    process label.  A world of one (never connected) just resets local
+    state.  Idempotent.
 
-    Best-effort by design: jax's in-process re-initialization support
-    varies by version, so the PRODUCTION resize path is a process
-    restart — ``distributed/launch.py`` relaunches the pack at the
-    survivor count (``--max_restarts`` / ``--elastic_min_nproc``) and
-    the fresh processes init cleanly.  In-process re-init is for
-    worlds of one changing sharding degree and for tests."""
+    A process that WAS connected keeps the old world's device list
+    cached, so joining a new multi-process world in the same process is
+    unsupported: the resize path is a process restart —
+    ``distributed/launch.py`` relaunches the pack at the survivor count
+    (``--max_restarts`` / ``--elastic_min_nproc``) and the fresh
+    processes init cleanly.  In-process re-init is for worlds of one
+    changing sharding degree and for tests."""
     # fence: join any in-flight async checkpoint upload BEFORE the
     # world goes away.  The async commit protocol is storage-only (no
     # collective), so waiting here cannot deadlock against peers that
@@ -245,16 +216,6 @@ def shutdown():
             "jax.distributed.shutdown failed (%s: %s) — continuing; a "
             "fresh process is the reliable way to rejoin a new world"
             % (type(e).__name__, e), stacklevel=2)
-    try:
-        # deprecated-but-present in the 0.4.x line; without it the old
-        # world's device list stays cached and a re-init would keep
-        # dispatching onto dead peers
-        import warnings as _w
-        with _w.catch_warnings():
-            _w.simplefilter("ignore")
-            jax.clear_backends()
-    except Exception:        # noqa: BLE001 — best-effort cache drop
-        pass
 
 
 def process_index():

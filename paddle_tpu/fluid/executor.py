@@ -14,6 +14,7 @@ as device arrays; each run threads them through the compiled function with
 buffer donation, so in-place optimizer updates stay in-place on device.
 """
 
+import os
 import time
 import contextlib
 import warnings
@@ -105,33 +106,32 @@ _m_bucket_overlap = telemetry.gauge(
 
 
 # ---------------------------------------------------------------------------
-# Persistent XLA compilation cache (FLAGS_compile_cache_dir)
+# Persistent XLA compilation cache
 # ---------------------------------------------------------------------------
 
-_compile_cache_applied = [False]
+# <checkout>/.jax_cache: the cache key includes the directory, so the path
+# is fixed (never a tempdir, pid or timestamp) and git-ignored
+_DEFAULT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
-def maybe_enable_compile_cache():
-    """Point JAX's persistent compilation cache at FLAGS_compile_cache_dir
-    (idempotent; called from Executor.__init__).  Repeated processes
-    compiling the same (program, feed signature) step then deserialize the
-    XLA executable from disk instead of re-running the compiler — the
-    process-level analogue of the in-process executable cache."""
-    if _compile_cache_applied[0]:
+def maybe_enable_compile_cache(device):
+    """Give an executor on a TPU a persistent compilation cache, so a
+    second process compiling the same step deserializes the executable
+    instead of re-running XLA (minutes on a real model).
+
+    ``JAX_COMPILATION_CACHE_DIR`` in the environment wins: JAX reads it
+    itself and no directory is set in code.  Without it a TPU executor
+    uses the fixed ``<checkout>/.jax_cache``; a CPU executor gets no
+    persistent cache (the test suite neither slows nor grows the tree).
+    Launcher children inherit the environment, so one cache serves a
+    pack.  Idempotent; called from ``Executor.__init__``."""
+    if "JAX_COMPILATION_CACHE_DIR" in os.environ or \
+            device.platform != "tpu" or \
+            jax.config.jax_compilation_cache_dir:
         return
-    cache_dir = flags.get_flag("compile_cache_dir")
-    if not cache_dir:
-        return
-    _compile_cache_applied[0] = True
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # our steps are small on CPU test backends; cache everything
-        # rather than only long compiles
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception as e:  # older jaxlib without the knobs
-        warnings.warn("FLAGS_compile_cache_dir ignored: %s" % (e,),
-                      stacklevel=2)
+    jax.config.update("jax_compilation_cache_dir", _DEFAULT_COMPILE_CACHE)
 
 
 # ---------------------------------------------------------------------------
@@ -166,18 +166,28 @@ CUDAPlace = TPUPlace
 
 
 def _device_for_place(place):
+    """The device a Place names.  ``None`` is the default backend's first
+    local device (what JAX itself would pick); ``CPUPlace`` a CPU device;
+    an explicit ``TPUPlace`` resolves to a TPU or raises — it never
+    continues on a CPU device."""
     # under jax.distributed, jax.devices() is the GLOBAL list — computation
     # placed on another process's device is not addressable here, so pick
     # from this process's devices only (mesh_utils.local_devices is THE
     # resolver every placement site shares; meshes alone span the globe)
     from .mesh_utils import local_devices as local
 
+    if place is None:
+        return local()[0]
     if isinstance(place, CPUPlace):
-        return local("cpu")[0] if jax.default_backend() != "cpu" \
-            else local()[0]
-    devs = [d for d in local() if d.platform != "cpu"]
-    if not devs:
-        devs = local()
+        return local("cpu")[0]
+    try:
+        devs = local("tpu")
+    except RuntimeError as e:
+        raise RuntimeError(
+            "%r needs a TPU, but JAX found none: the default backend is "
+            "%r with devices %s.  Use CPUPlace() (or Executor() for the "
+            "default backend's device) to run on this machine."
+            % (place, jax.default_backend(), jax.devices())) from e
     return devs[place.device_id % len(devs)]
 
 
@@ -1034,8 +1044,11 @@ class Executor:
     """Compile-and-run executor for one place (executor.py:294 contract)."""
 
     def __init__(self, place=None):
-        self.place = place if place is not None else TPUPlace()
-        self._device = _device_for_place(self.place)
+        self._device = _device_for_place(place)
+        if place is None:
+            place = TPUPlace() if self._device.platform == "tpu" \
+                else CPUPlace()
+        self.place = place
         self._cache = {}
         # dispatch-plan cache: steady-state run() is one lookup here plus
         # the jitted call (no per-step sorting/coercion/key hashing)
@@ -1049,7 +1062,7 @@ class Executor:
         # producers read its feed shardings so feeds land already
         # sharded (GSPMD) / on the right device ahead of the next pull
         self._last_compiled = None
-        maybe_enable_compile_cache()
+        maybe_enable_compile_cache(self._device)
         # FLAGS_pe_profile_fname (parallel_executor.cc:38 gperftools
         # hook): whole-process host profile, dumped at exit
         profiler.maybe_start_pe_profile()
@@ -1478,8 +1491,8 @@ class Executor:
         compile_s = None
         if fresh:
             # the first call of a fresh executable carries trace + XLA
-            # compile — its host wall time IS the compile cost (with
-            # FLAGS_compile_cache_dir warm it collapses to deserialize)
+            # compile — its host wall time IS the compile cost (with a
+            # warm persistent cache it collapses to deserialize)
             compiled._fresh = False
             compile_s = (t1 - t0) / 1e9
             _m_compile_s.observe(compile_s, kind="dispatch")
@@ -1925,12 +1938,12 @@ class Executor:
     def _compile(self, program, feed_names, feed_shapes, fetch_names,
                  in_shardings=None, steps_per_run=None):
         self._compile_count += 1
-        # build count by persistent-cache state: with FLAGS_compile_cache_
-        # dir set, the XLA compile riding the first dispatch deserializes
-        # from disk when warm — compare executor_compile_seconds between
-        # the two labels to see the cache-dir hit rate's effect
+        # build count by persistent-cache state: with a cache directory
+        # the XLA compile riding the first dispatch deserializes from
+        # disk when warm — compare executor_compile_seconds between the
+        # two labels to see the cache's effect
         _m_compiles.inc(persistent_cache=(
-            "on" if flags.get_flag("compile_cache_dir") else "off"))
+            "on" if jax.config.jax_compilation_cache_dir else "off"))
         windowed = steps_per_run is not None
         K = int(steps_per_run) if windowed else 1
         if windowed:

@@ -82,9 +82,6 @@ _DEFS = {
                                      # FeedRing); 0 = legacy synchronous
                                      # one-batch lookahead (A/B control,
                                      # bit-exact same losses)
-    "compile_cache_dir": "",         # JAX persistent compilation cache:
-                                     # repeated processes skip XLA
-                                     # recompiles of identical steps
     "checkpoint_async": True,        # CheckpointManager: serialize+commit
                                      # on a background thread (snapshot
                                      # stays synchronous)
@@ -244,8 +241,8 @@ def apply_prng_impl():
     fills) through the TPU's hardware RngBitGenerator — the analogue of the
     reference's curand-backed dropout (operators/dropout_op.cu) and, like
     curand, stable only per (backend, compiler) rather than across them.
-    Measured +30% BERT-base pretrain step throughput vs threefry at batch
-    64 x seq 128 (PROFILE.md).  ``FLAGS_prng_impl=threefry`` restores jax's
+    Builder-measured +30% BERT-base pretrain step throughput vs threefry at
+    batch 64 x seq 128 (round 3; not reproduced on the current code).  ``FLAGS_prng_impl=threefry`` restores jax's
     cross-backend-reproducible counter-based PRNG.
     """
     import jax

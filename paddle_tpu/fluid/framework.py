@@ -656,15 +656,12 @@ def current_name_scope():
 
 
 def is_compiled_with_cuda():
-    """True when an accelerator backend is attached: the canonical
-    reference idiom ``CUDAPlace(0) if is_compiled_with_cuda() else
-    CPUPlace()`` must route onto the TPU (CUDAPlace aliases TPUPlace,
-    executor.py) rather than silently pinning host CPU."""
+    """True when the default backend is a TPU: the canonical reference
+    idiom ``CUDAPlace(0) if is_compiled_with_cuda() else CPUPlace()``
+    must route onto the TPU (CUDAPlace aliases TPUPlace, executor.py)
+    rather than silently pinning host CPU."""
     import jax as _jax
-    try:
-        return _jax.default_backend() != "cpu"
-    except Exception:
-        return False
+    return _jax.default_backend() == "tpu"
 
 
 def cpu_places(device_count=None):

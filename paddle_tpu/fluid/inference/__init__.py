@@ -29,7 +29,9 @@ class Config:
         self.model_dir = model_dir
         self.prog_file = prog_file
         self.params_file = params_file
-        self._use_tpu = True
+        # None = the default backend's first device, until the script
+        # asks for one (enable_use_gpu / disable_gpu)
+        self._use_tpu = None
         self._ir_optim = True
         self._passes = list(DEFAULT_INFERENCE_PASSES)
 
@@ -89,8 +91,9 @@ class AnalysisPredictor:
             (self._program, self._feed_names, self._fetch_vars,
              self._scope, self._exe) = _shared
             return
-        place = TPUPlace() if config.use_tpu() else CPUPlace()
-        self._exe = Executor(place)
+        use_tpu = config.use_tpu()
+        self._exe = Executor(None if use_tpu is None else
+                             TPUPlace() if use_tpu else CPUPlace())
         self._scope = Scope()
         with scope_guard(self._scope):
             program, feed_names, fetch_vars = fluid_io.load_inference_model(

@@ -11,14 +11,10 @@ from . import (Program, program_guard, unique_name, Scope, scope_guard,
 def run_check(use_device=None):
     """Train one step of a tiny model; raises on failure, prints success.
 
-    ``use_device``: None (auto: TPU if visible, else CPU), "cpu", "tpu".
+    ``use_device``: None (the default backend's first device), "cpu",
+    "tpu" (raises when JAX found no TPU).
     """
-    import jax
-    if use_device is None:
-        platforms = {d.platform for d in jax.devices()}
-        place = TPUPlace() if platforms - {"cpu"} else CPUPlace()
-    else:
-        place = CPUPlace() if use_device == "cpu" else TPUPlace()
+    place = {None: None, "cpu": CPUPlace(), "tpu": TPUPlace()}[use_device]
 
     main, startup = Program(), Program()
     with program_guard(main, startup):
@@ -39,5 +35,5 @@ def run_check(use_device=None):
     val = float(np.asarray(lv).reshape(-1)[0])
     if not np.isfinite(val):
         raise RuntimeError("install check produced a non-finite loss")
-    print("Your paddle_tpu works on %r! loss = %.4f" % (place, val))
+    print("Your paddle_tpu works on %r! loss = %.4f" % (exe.place, val))
     return True

@@ -27,31 +27,16 @@ from jax.sharding import Mesh
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma=None,
               axis_names=None):
-    """``jax.shard_map`` across jax versions — the ONE resolver every
-    shard_map call site routes through.  Newer jax exposes it top-level
-    with the ``check_vma`` / ``axis_names`` kwargs; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map`` where the same knobs are
-    named ``check_rep`` and (inverted: the set of NON-manual axes)
-    ``auto`` (on 0.4.x this container, ``jax.shard_map`` raises the
-    deprecation AttributeError — the seed's collective/pipeline tests
-    failed on exactly that)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        kwargs = {}
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        if axis_names is not None:
-            kwargs["axis_names"] = axis_names
-        return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kwargs)
-    from jax.experimental.shard_map import shard_map as legacy
+    """``jax.shard_map`` — the ONE call every shard_map site routes
+    through; ``check_vma`` / ``axis_names`` left at None keep jax's
+    defaults."""
     kwargs = {}
     if check_vma is not None:
-        kwargs["check_rep"] = check_vma
+        kwargs["check_vma"] = check_vma
     if axis_names is not None:
-        kwargs["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kwargs)
+        kwargs["axis_names"] = axis_names
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 def ordered_devices(platform=None, devices=None):
@@ -123,34 +108,27 @@ def build_mesh(axis_names, axis_sizes=None, devices=None, platform=None):
         # a node (collectives would cross DCN on the wrong axis).
         _check_dcn_granules(devices, sizes[0], axis_names)
 
-    arr = None
     if devices and devices[0].platform == "tpu":
-        try:
-            from jax.experimental import mesh_utils as jmu
-            n_slices = len({d.process_index for d in devices})
-            if axis_names[0] == "dcn" and n_slices > 1 and sizes[0] > 1:
-                # process_is_granule: 'dcn' means node/process boundary
-                # here (the hierarchical-allreduce contract), not TPU
-                # slice boundary — a multi-host single-slice pod still
-                # groups by host
-                # same-rank contract: per-axis within-granule sizes x
-                # across-granule sizes; 'dcn' spans granules, the rest
-                # live inside one
-                arr = jmu.create_hybrid_device_mesh(
-                    (1,) + tuple(sizes[1:]),
-                    (sizes[0],) + (1,) * (len(sizes) - 1),
-                    devices=devices, process_is_granule=True)
-                arr = arr.reshape(sizes)
-            else:
-                arr = jmu.create_device_mesh(tuple(sizes), devices=devices)
-        except Exception as e:
-            import warnings
-            warnings.warn(
-                "topology-aware mesh layout failed (%s: %s); falling back "
-                "to device-enumeration order — collectives may cross more "
-                "ICI hops than necessary" % (type(e).__name__, e))
-            arr = None
-    if arr is None:
+        # a layout the topology cannot hold raises: on one host that means
+        # the mesh request was wrong, and enumeration order would hide it
+        from jax.experimental import mesh_utils as jmu
+        n_slices = len({d.process_index for d in devices})
+        if axis_names[0] == "dcn" and n_slices > 1 and sizes[0] > 1:
+            # process_is_granule: 'dcn' means node/process boundary
+            # here (the hierarchical-allreduce contract), not TPU
+            # slice boundary — a multi-host single-slice pod still
+            # groups by host
+            # same-rank contract: per-axis within-granule sizes x
+            # across-granule sizes; 'dcn' spans granules, the rest
+            # live inside one
+            arr = jmu.create_hybrid_device_mesh(
+                (1,) + tuple(sizes[1:]),
+                (sizes[0],) + (1,) * (len(sizes) - 1),
+                devices=devices, process_is_granule=True)
+            arr = arr.reshape(sizes)
+        else:
+            arr = jmu.create_device_mesh(tuple(sizes), devices=devices)
+    else:
         arr = np.array(devices).reshape(sizes)
     return Mesh(arr, axis_names)
 

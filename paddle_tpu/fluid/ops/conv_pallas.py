@@ -1,9 +1,10 @@
-"""Pallas implicit-GEMM conv kernel — the r3-verdict conv-ceiling attack.
+"""Pallas implicit-GEMM conv kernel — the ROADMAP S2 conv-ceiling experiment.
 
-Why this shape of kernel: PROFILE.md attributed ResNet-50's ~16% MFU to
-XLA's conv efficiency at ResNet's channel counts — a native conv
-contracts over C (64..512), underfilling the 128-wide MXU contraction at
-the early layers, while the HBM-materialized im2col alternative
+Why this shape of kernel: the hypothesis (builder-measured in round 3, not
+reproduced since) is that ResNet-50's step is bound by XLA's conv
+efficiency at ResNet's channel counts — a native conv contracts over C
+(64..512), underfilling the 128-wide MXU contraction at the early
+layers, while the HBM-materialized im2col alternative
 (FLAGS_conv_im2col) pays kh*kw x activation bandwidth.  This kernel does
 the third thing: build the im2col patch matrix **in VMEM** per row-block
 (9 slices, one concat) and run a single [bh*W, 9C] x [9C, O] MXU matmul
@@ -28,6 +29,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .pallas_ops import _pallas_call
 
 
 def _conv3x3_kernel(x_ref, w_ref, scale_ref, shift_ref, o_ref, *,
@@ -74,10 +77,9 @@ def conv3x3_bn_relu(x, w, scale=None, shift=None, relu=True):
         bh -= 1
     xp = jnp.pad(x, ((0, 0), (1, 1), (1, 1), (0, 0)))
     wm = w.reshape(9 * C, O)
-    interpret = jax.default_backend() != "tpu"
     kern = functools.partial(_conv3x3_kernel, bh=bh, W=W, C=C, O=O,
                              relu=relu)
-    return pl.pallas_call(
+    return _pallas_call(
         kern,
         grid=(N, H // bh),
         in_specs=[
@@ -88,5 +90,4 @@ def conv3x3_bn_relu(x, w, scale=None, shift=None, relu=True):
         ],
         out_specs=pl.BlockSpec((1, bh, W, O), lambda n, i: (n, i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((N, H, W, O), x.dtype),
-        interpret=interpret,
     )(xp, wm, scale, shift)

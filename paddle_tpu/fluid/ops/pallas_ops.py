@@ -12,8 +12,20 @@ dq/dk/dv.  Bias gradients are exact too, via a separate tiled pass whose
 non-trainable mask XLA dead-code-eliminates that pass.  Non-tileable
 shapes fall back to differentiating the identical XLA composition.
 
-Off-TPU (CPU tests, virtual meshes) the kernel runs in Pallas interpret
-mode so behavior is identical everywhere.
+Every kernel goes through ``_pallas_call``: Mosaic compiles it when the
+computation is lowered for a TPU, and Pallas interpret mode runs it on any
+other platform (CPU tests, virtual meshes), so behavior is identical
+everywhere.
+
+VMEM: a grid cell holds one 128-row block of its own operand and the WHOLE
+sequence of the other side — K and V in the forward, dQ and dbias passes, Q
+and dO (plus a [S_q, 128] bias tile) in the dK/dV pass, a [128, S_kv] tile
+in the dbias pass — and no ``vmem_limit_bytes`` is set, so the compiler's
+16 MiB scoped default is the ceiling.  Compiled for a v5e at D=64, bf16
+(libtpu 0.0.34): without a bias, forward and backward fit through S=8192
+and stop at S=16384 (the forward asks for 21.5 MiB); with a bias the
+backward stops at S=8192 (17.5 MiB) and fits at S=4096.  Longer sequences
+need K/V streamed block by block through the grid, not a higher limit.
 """
 
 import functools
@@ -26,6 +38,31 @@ from jax.experimental import pallas as pl
 from ..registry import register_op
 
 _NEG = -1e30
+
+
+def _pallas_call(kernel, **kwargs):
+    """``pl.pallas_call`` whose mode follows the platform the computation
+    is LOWERED for, not the process's default backend: compiled by Mosaic
+    for a TPU, interpreted for anything else (a ``CPUPlace`` executor on a
+    TPU host included).  ``lax.platform_dependent`` lowers only the chosen
+    branch, so a TPU executable never holds an interpreted kernel.
+
+    Inside a ``shard_map`` the outputs vary over every mesh axis an input
+    varies over; saying so in ``out_shape`` lets the kernels run under
+    ``check_vma=True``."""
+    out_shape = kwargs.pop("out_shape")
+
+    def call(*args):
+        vma = frozenset().union(*(jax.typeof(a).vma for a in args))
+        shapes = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, vma=vma),
+            out_shape)
+        return jax.lax.platform_dependent(
+            *args,
+            tpu=pl.pallas_call(kernel, out_shape=shapes, **kwargs),
+            default=pl.pallas_call(kernel, out_shape=shapes,
+                                   interpret=True, **kwargs))
+    return call
 
 
 def _reference_attention(q, k, v, bias, scale, causal=False):
@@ -45,8 +82,8 @@ def _attention_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
                       scale, block_k, causal=False):
     # dots run in the INPUT dtype (bf16 under pure-bf16 AMP — a single
     # fast MXU pass) and accumulate fp32 via preferred_element_type;
-    # casting inputs to fp32 first forces multi-pass fp32 MXU emulation,
-    # measured ~2x slower end-to-end at S=512 (PROFILE.md)
+    # casting inputs to fp32 first forces multi-pass fp32 MXU emulation
+    # (builder-measured ~2x slower end-to-end at S=512 in round 3)
     q = q_ref[0]                                  # [bq, D], native dtype
     S = k_ref.shape[1]
     bq, D = q.shape
@@ -90,9 +127,9 @@ def _attention_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
     # logsumexp per row — the statistic the tiled backward replays
     # against; inference (with_lse=False) omits the output entirely so
     # it pays neither the in-kernel log nor the fp32 per-row HBM write
-    # (pallas outputs are not DCE'd — ADVICE r3)
+    # (an unused output of a pallas_call is still computed)
     if lse_ref is not None:
-        lse_ref[0] = (m + jnp.log(l)).reshape(bq)
+        lse_ref[0] = m + jnp.log(l)
 
 
 def _bias_block(bias_ref, rows, row_len, cols, col_len):
@@ -118,8 +155,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
     p is recomputed from the saved LSE — no [S, S] materialization."""
     q = q_ref[0]                                   # [bq, D]
     do = do_ref[0].astype(jnp.float32)             # [bq, D]
-    lse = lse_ref[0].astype(jnp.float32)           # [bq]
-    delta = delta_ref[0].astype(jnp.float32)       # [bq]
+    lse = lse_ref[0]                               # [bq, 1] fp32
+    delta = delta_ref[0]                           # [bq, 1] fp32
     S = k_ref.shape[1]
     bq, D = q.shape
     pid = pl.program_id(1)
@@ -134,10 +171,10 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
             s = s + _bias_block(bias_ref, 0, bq, kb * block_k, block_k)
             if causal:
                 s = _causal_mask(s, pid * bq, kb * block_k)
-            p = jnp.exp(s - lse[:, None])
+            p = jnp.exp(s - lse)
             dp = jnp.dot(do.astype(q.dtype), vs.T,
                          preferred_element_type=jnp.float32)
-            ds = p * (dp - delta[:, None]) * scale
+            ds = p * (dp - delta) * scale
             return acc + jnp.dot(ds.astype(q.dtype), ks,
                                  preferred_element_type=jnp.float32)
 
@@ -162,10 +199,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
     for qb in range(S // block_q):
         q = q_ref[0, qb * block_q:(qb + 1) * block_q, :]
         do = do_ref[0, qb * block_q:(qb + 1) * block_q, :]
-        lse = lse_ref[0, qb * block_q:(qb + 1) * block_q] \
-            .astype(jnp.float32)
-        delta = delta_ref[0, qb * block_q:(qb + 1) * block_q] \
-            .astype(jnp.float32)
+        lse = lse_ref[0, qb * block_q:(qb + 1) * block_q, :]     # [bq, 1]
+        delta = delta_ref[0, qb * block_q:(qb + 1) * block_q, :]
 
         def blk(carry, q=q, do=do, lse=lse, delta=delta, qb=qb):
             dk, dv = carry
@@ -174,11 +209,11 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
             s = s + _bias_block(bias_ref, qb * block_q, block_q, 0, bk)
             if causal:
                 s = _causal_mask(s, qb * block_q, pid * bk)
-            p = jnp.exp(s - lse[:, None])          # [bq, bk]
+            p = jnp.exp(s - lse)                   # [bq, bk]
             pc = p.astype(q.dtype)
             dv = dv + jnp.dot(pc.T, do, preferred_element_type=jnp.float32)
             dp = jnp.dot(do, vs.T, preferred_element_type=jnp.float32)
-            ds = p * (dp - delta[:, None]) * scale
+            ds = p * (dp - delta) * scale
             dk = dk + jnp.dot(ds.astype(q.dtype).T, q,
                               preferred_element_type=jnp.float32)
             return dk, dv
@@ -200,8 +235,8 @@ def _dbias_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
     so XLA drops the whole pass when the bias is not trainable."""
     q = q_ref[0]
     do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0].astype(jnp.float32)
-    delta = delta_ref[0].astype(jnp.float32)
+    lse = lse_ref[0]
+    delta = delta_ref[0]
     S = k_ref.shape[1]
     bq, D = q.shape
     pid = pl.program_id(1)
@@ -215,10 +250,10 @@ def _dbias_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
             s = s + _bias_block(bias_ref, 0, bq, kb * block_k, block_k)
             if causal:
                 s = _causal_mask(s, pid * bq, kb * block_k)
-            p = jnp.exp(s - lse[:, None])
+            p = jnp.exp(s - lse)
             dp = jnp.dot(do.astype(q.dtype), vs.T,
                          preferred_element_type=jnp.float32)
-            return p * (dp - delta[:, None])
+            return p * (dp - delta)
 
         if causal:
             live = (pid + 1) * bq > kb * block_k
@@ -229,6 +264,15 @@ def _dbias_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
             ds = blk()
         db_ref[0, :, kb * block_k:(kb + 1) * block_k] = \
             ds.astype(db_ref.dtype)
+
+
+def _row_stat_spec(block_q):
+    """Block of a per-row statistic (logsumexp, delta), held as
+    [BH, S_q, 1]: rows on sublanes, so the kernels broadcast it against a
+    [bq, bk] score tile without a relayout.  (A [BH, S_q] array cut into
+    (1, block_q) blocks is refused by the Mosaic lowering: the
+    second-to-last block dim must be a multiple of 8 or the whole dim.)"""
+    return pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0))
 
 
 def _tileable(S_q, S_kv):
@@ -259,7 +303,6 @@ def _flash_forward(q, k, v, bias, scale, *, with_lse=False,
         # tileability check, so this fallback never computes an LSE)
         raise AssertionError("with_lse requested for a non-tileable "
                              "shape — caller bug")
-    interpret = jax.default_backend() != "tpu"
     grid = (BH, S_q // block_q)
     in_specs = [
         pl.BlockSpec((1, block_q, D), lambda i, j: (i, j, 0)),
@@ -284,15 +327,14 @@ def _flash_forward(q, k, v, bias, scale, *, with_lse=False,
     out_specs = [pl.BlockSpec((1, block_q, D), lambda i, j: (i, j, 0))]
     out_shape = [jax.ShapeDtypeStruct((BH, S_q, D), q.dtype)]
     if with_lse:
-        out_specs.append(pl.BlockSpec((1, block_q), lambda i, j: (i, j)))
-        out_shape.append(jax.ShapeDtypeStruct((BH, S_q), jnp.float32))
-    res = pl.pallas_call(
+        out_specs.append(_row_stat_spec(block_q))
+        out_shape.append(jax.ShapeDtypeStruct((BH, S_q, 1), jnp.float32))
+    res = _pallas_call(
         kern,
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        interpret=interpret,
     )(*args)
     return (res[0], res[1]) if with_lse else res[0]
 
@@ -303,9 +345,8 @@ def _flash_backward(q, k, v, bias, scale, out, lse, g, causal=False):
     BH, S_q, D = q.shape
     S_kv = k.shape[1]
     _, block_q, block_k = _tileable(S_q, S_kv)
-    interpret = jax.default_backend() != "tpu"
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)                       # [BH, S_q]
+                    axis=-1, keepdims=True)        # [BH, S_q, 1]
 
     # dQ pass: grid over q blocks
     dq_specs = [
@@ -328,16 +369,15 @@ def _flash_backward(q, k, v, bias, scale, out, lse, g, causal=False):
                        causal=causal)
     dq_specs += [
         pl.BlockSpec((1, block_q, D), lambda i, j: (i, j, 0)),  # dO
-        pl.BlockSpec((1, block_q), lambda i, j: (i, j)),        # lse
-        pl.BlockSpec((1, block_q), lambda i, j: (i, j)),        # delta
+        _row_stat_spec(block_q),                                # lse
+        _row_stat_spec(block_q),                                # delta
     ]
-    dq = pl.pallas_call(
+    dq = _pallas_call(
         dq_kern,
         grid=(BH, S_q // block_q),
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, block_q, D), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S_q, D), q.dtype),
-        interpret=interpret,
     )(*dq_args, g, lse, delta)
 
     # dK/dV pass: grid over k blocks
@@ -361,10 +401,10 @@ def _flash_backward(q, k, v, bias, scale, out, lse, g, causal=False):
                         block_q=block_q, causal=causal)
     dkv_specs += [
         pl.BlockSpec((1, S_q, D), lambda i, j: (i, 0, 0)),      # dO
-        pl.BlockSpec((1, S_q), lambda i, j: (i, 0)),            # lse
-        pl.BlockSpec((1, S_q), lambda i, j: (i, 0)),            # delta
+        pl.BlockSpec((1, S_q, 1), lambda i, j: (i, 0, 0)),      # lse
+        pl.BlockSpec((1, S_q, 1), lambda i, j: (i, 0, 0)),      # delta
     ]
-    dk, dv = pl.pallas_call(
+    dk, dv = _pallas_call(
         dkv_kern,
         grid=(BH, S_kv // block_k),
         in_specs=dkv_specs,
@@ -372,7 +412,6 @@ def _flash_backward(q, k, v, bias, scale, out, lse, g, causal=False):
                    pl.BlockSpec((1, block_k, D), lambda i, j: (i, j, 0))],
         out_shape=[jax.ShapeDtypeStruct((BH, S_kv, D), k.dtype),
                    jax.ShapeDtypeStruct((BH, S_kv, D), v.dtype)],
-        interpret=interpret,
     )(*dkv_args, g, lse, delta)
 
     dbias = None
@@ -383,10 +422,10 @@ def _flash_backward(q, k, v, bias, scale, out, lse, g, causal=False):
             pl.BlockSpec((1, S_kv, D), lambda i, j: (i, 0, 0)),     # v
             bias_spec_q,                                            # bias
             pl.BlockSpec((1, block_q, D), lambda i, j: (i, j, 0)),  # dO
-            pl.BlockSpec((1, block_q), lambda i, j: (i, j)),        # lse
-            pl.BlockSpec((1, block_q), lambda i, j: (i, j)),        # delta
+            _row_stat_spec(block_q),                                # lse
+            _row_stat_spec(block_q),                                # delta
         ]
-        dbias = pl.pallas_call(
+        dbias = _pallas_call(
             functools.partial(_dbias_kernel, scale=scale,
                               block_k=block_k, causal=causal),
             grid=(BH, S_q // block_q),
@@ -394,7 +433,6 @@ def _flash_backward(q, k, v, bias, scale, out, lse, g, causal=False):
             out_specs=pl.BlockSpec((1, block_q, S_kv),
                                    lambda i, j: (i, j, 0)),
             out_shape=jax.ShapeDtypeStruct((BH, S_q, S_kv), bias.dtype),
-            interpret=interpret,
         )(q, k, v, bias, g, lse, delta)
     return dq, dk, dv, dbias
 
@@ -483,18 +521,9 @@ def _sp_attention(q, k, v, mesh, axis, mode, scale, causal, bias=None):
 def _axis_is_auto(mesh, name):
     """True when ``name`` is a GSPMD (auto) axis of ``mesh`` — inside a
     manual shard_map region (the pipeline), axes like 'dp'/'pp' are
-    Manual and an inner island must not mention them in its specs.
-    jax 0.4.x meshes predate AxisType entirely (every top-level axis is
-    auto there) — treat absence of the API like absence of the
-    attribute."""
-    types = getattr(mesh, "axis_types", None)
-    if types is None:
-        return True
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return True
-    d = dict(zip(mesh.axis_names, tuple(types)))
+    Manual and an inner island must not mention them in its specs."""
+    from jax.sharding import AxisType
+    d = dict(zip(mesh.axis_names, mesh.axis_types))
     return d.get(name, AxisType.Auto) == AxisType.Auto
 
 
@@ -605,9 +634,9 @@ def _sp_gather_attention(q, k, v, mesh, axis, scale, causal, bias,
         return _attn_core_remat(scale, causal, dropout, rng_axes)(
             qb, kb, vb, bb, q_off, kloc)
 
-    # check_vma=False: the flash fast path is a pallas_call, whose output
-    # abstract value carries no varying-mesh-axes annotation — the check
-    # would reject it inside the manual region
+    # check_vma=False: off a TPU the flash fast path is an INTERPRETED
+    # pallas_call, whose grid loop slices varying blocks at unvarying
+    # indices — jax's varying-axes check rejects that lowering
     from ..mesh_utils import shard_map
     return shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
                      out_specs=spec_q, check_vma=False)(*args)
@@ -722,8 +751,7 @@ def _pallas_layer_norm(x2d, scale, bias, eps):
     block_m = 128
     while M % block_m and block_m > 1:
         block_m //= 2
-    interpret = jax.default_backend() != "tpu"
-    return pl.pallas_call(
+    return _pallas_call(
         functools.partial(_layer_norm_kernel, eps=eps),
         grid=(M // block_m,),
         in_specs=[pl.BlockSpec((block_m, D), lambda i: (i, 0)),
@@ -731,7 +759,6 @@ def _pallas_layer_norm(x2d, scale, bias, eps):
                   pl.BlockSpec((D,), lambda i: (0,))],
         out_specs=pl.BlockSpec((block_m, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((M, D), x2d.dtype),
-        interpret=interpret,
     )(x2d, scale, bias)
 
 

@@ -9,7 +9,7 @@ a loss name, call run(fetch_list, feed) — on top of it.
 
 from . import framework
 from .compiler import CompiledProgram
-from .executor import Executor, TPUPlace, global_scope
+from .executor import CPUPlace, Executor, TPUPlace, global_scope
 
 __all__ = ["ParallelExecutor"]
 
@@ -24,7 +24,7 @@ class ParallelExecutor:
             loss_name=loss_name, build_strategy=build_strategy,
             exec_strategy=exec_strategy,
             share_vars_from=getattr(share_vars_from, "_compiled", None))
-        self._exe = Executor(TPUPlace())
+        self._exe = Executor(TPUPlace() if use_cuda else CPUPlace())
         self._scope = scope
 
     def run(self, fetch_list, feed=None, feed_dict=None,

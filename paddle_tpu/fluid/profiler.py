@@ -142,7 +142,8 @@ _device_profile = {"remaining": None, "active": False, "dir": None}
 def device_profile_begin():
     """Start the FLAGS_device_profile trace before the first profiled
     dispatch.  No-op (one dict read) when the flag is 0 or the budget is
-    spent; trace failures disable the capture rather than the job."""
+    spent.  A trace that cannot start raises: a requested capture that
+    silently does not exist would be read as "nothing ran"."""
     st = _device_profile
     rem = st["remaining"]
     if rem is None:
@@ -154,14 +155,11 @@ def device_profile_begin():
     from . import flags
     out = flags.get_flag("device_profile_dir") or \
         os.path.join(os.getcwd(), "device_profile")
-    try:
-        import jax
-        os.makedirs(out, exist_ok=True)
-        jax.profiler.start_trace(out)
-        st["active"] = True
-        st["dir"] = out
-    except Exception:
-        st["remaining"] = 0
+    import jax
+    os.makedirs(out, exist_ok=True)
+    jax.profiler.start_trace(out)
+    st["active"] = True
+    st["dir"] = out
 
 
 def device_profile_end(k=1):
@@ -174,11 +172,8 @@ def device_profile_end(k=1):
     if st["remaining"] <= 0:
         st["remaining"] = 0
         st["active"] = False
-        try:
-            import jax
-            jax.profiler.stop_trace()
-        except Exception:
-            pass
+        import jax
+        jax.profiler.stop_trace()
 
 
 def device_profile_reset():

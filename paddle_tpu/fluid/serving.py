@@ -12,8 +12,8 @@ already exists:
   ``max_batch``), each bucket compiles exactly once (the PR 2 dispatch-
   plan cache makes the steady-state dispatch one dict lookup), all
   buckets are eagerly compiled by :meth:`ServingExecutor.warmup`, and
-  the compiled artifacts persist across processes through
-  ``FLAGS_compile_cache_dir``.  ``serving_recompiles_total`` pins the
+  on a TPU the compiled artifacts persist across processes in JAX's
+  compilation cache (``executor.maybe_enable_compile_cache``).  ``serving_recompiles_total`` pins the
   contract: after warmup it must stay 0 forever.
 - **Continuous batching** — a scheduler thread (the FeedRing
   producer/consumer pattern from reader.py, generalized to a request
@@ -234,7 +234,7 @@ class ServingExecutor:
     def __init__(self, program, feed_specs=None, fetch_list=None,
                  scope=None, place=None, max_batch=64, buckets=None,
                  max_wait_ms=None, max_queue=None, executor=None):
-        from .executor import (Executor, TPUPlace, global_scope)
+        from .executor import Executor, global_scope
 
         if not feed_specs:
             raise ServingError(
@@ -250,7 +250,7 @@ class ServingExecutor:
         self._fetch_list = list(fetch_list)
         self._scope = scope if scope is not None else global_scope()
         self._exe = executor if executor is not None else \
-            Executor(place if place is not None else TPUPlace())
+            Executor(place)
         self.buckets = bucket_ladder(max_batch, buckets)
         self._max_wait_s = (flags.get_flag("serving_max_wait_ms")
                             if max_wait_ms is None else
@@ -294,9 +294,9 @@ class ServingExecutor:
         be the batch dim), and ``feed_names`` follows the saved
         manifest's feed order — the positional-request contract."""
         from . import io as fluid_io
-        from .executor import Executor, Scope, TPUPlace, scope_guard
+        from .executor import Executor, Scope, scope_guard
 
-        exe = Executor(place if place is not None else TPUPlace())
+        exe = Executor(place)
         scope = Scope()
         with scope_guard(scope):
             program, feed_names, fetch_vars = \
@@ -430,8 +430,8 @@ class ServingExecutor:
     def warmup(self, ledger=False):
         """Eagerly compile every bucket (zero-filled feeds, outputs
         discarded) so steady-state traffic never pays a compile on the
-        latency path.  With ``FLAGS_compile_cache_dir`` set, later
-        processes warm from the persistent cache instead of recompiling.
+        latency path.  On a TPU, later processes warm from JAX's persistent
+        compilation cache instead of recompiling.
         Returns ``{bucket: seconds}`` (first-process entries ARE the
         XLA compile times).  Call before serving traffic — warmup
         dispatches on the caller's thread and does not count toward

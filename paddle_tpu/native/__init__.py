@@ -1,5 +1,7 @@
-"""Native runtime loader: compiles native.cc once via the system toolchain
-and binds it through ctypes.
+"""Native runtime loader: compiles native.cc via the system toolchain and
+binds it through ctypes.  The library's file name carries a hash of
+native.cc, so the one loaded is always the one the source in this tree
+builds (an mtime means nothing after a copy or a checkout).
 
 The reference's runtime-critical components are C++ (SURVEY.md §2: "everything
 runtime-critical is C++"); this package is their TPU-framework equivalent —
@@ -10,29 +12,35 @@ call's duration).  ``available()`` is False when no toolchain exists; callers
 """
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "native.cc")
-_LIB_PATH = os.path.join(_HERE, "libpaddle_tpu_native.so")
 
 _lib = None
 _tried = False
 _lock = threading.Lock()
 
 
-def _build():
+def _lib_path():
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_HERE, "libpaddle_tpu_native.%s.so" % digest)
+
+
+def _build(lib_path):
     # compile to a private temp path, then atomic-rename into place:
     # concurrent processes (subprocess tests, multi-worker launch) must
     # never dlopen a half-written .so
-    tmp = "%s.tmp.%d" % (_LIB_PATH, os.getpid())
+    tmp = "%s.tmp.%d" % (lib_path, os.getpid())
     cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC",
            "-fvisibility=hidden", _SRC, "-o", tmp, "-lz", "-lpthread"]
     try:
         subprocess.run(cmd, check=True, capture_output=True)
-        os.replace(tmp, _LIB_PATH)
+        os.replace(tmp, lib_path)
     finally:
         if os.path.exists(tmp):
             os.unlink(tmp)
@@ -94,10 +102,10 @@ def get_lib():
             return _lib
         _tried = True
         try:
-            if (not os.path.exists(_LIB_PATH) or
-                    os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC)):
-                _build()
-            _lib = _bind(ctypes.CDLL(_LIB_PATH))
+            lib_path = _lib_path()
+            if not os.path.exists(lib_path):
+                _build(lib_path)
+            _lib = _bind(ctypes.CDLL(lib_path))
         except (OSError, subprocess.CalledProcessError):
             _lib = None
     return _lib

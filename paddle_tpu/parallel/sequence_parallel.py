@@ -31,13 +31,6 @@ import jax.numpy as jnp
 from jax import lax
 
 
-def _axis_size(axis_name):
-    """Static size of a mapped axis — ``lax.axis_size`` where it
-    exists (newer jax), else the psum-of-1 constant fold 0.4.x
-    supports."""
-    fn = getattr(lax, "axis_size", None)
-    return fn(axis_name) if fn is not None else lax.psum(1, axis_name)
-
 NEG_INF = -1e30
 
 
@@ -83,7 +76,7 @@ def _flash_block(q, kb, vb, scale):
 
 
 def _ring_flash_fwd_impl(q, k, v, axis_name, scale):
-    P = _axis_size(axis_name)
+    P = lax.axis_size(axis_name)
     B, Tl, H, D = q.shape
     perm = [(j, (j + 1) % P) for j in range(P)]
     kb, vb = k, v
@@ -138,12 +131,12 @@ def _ring_flash_bwd(axis_name, scale, res, g):
     from paddle_tpu.fluid.ops.pallas_ops import _flash_backward
 
     q, k, v, out, lse = res
-    P = _axis_size(axis_name)
+    P = lax.axis_size(axis_name)
     B, Tl, H, D = q.shape
     perm = [(j, (j + 1) % P) for j in range(P)]
     qf, gf = _bhsd(q), _bhsd(g.astype(q.dtype))
     outf = _bhsd(out)
-    lsef = lse.reshape(B * H, Tl)
+    lsef = lse.reshape(B * H, Tl, 1)
     kb, vb = k, v
     dq = jnp.zeros((B * H, Tl, D), jnp.float32)
     dkb = jnp.zeros_like(k, dtype=jnp.float32)
@@ -217,21 +210,27 @@ def ring_attention(q, k, v, axis_name="sp", causal=False, scale=None,
             raise ValueError(
                 "use_flash=True needs a static (Python float) scale, "
                 "got a traced value — omit use_flash or pass a constant")
-    if use_flash is None:
-        # default on only where it pays: real TPU (interpret-mode pallas
-        # on CPU is strictly slower emulation), tileable, static scale
-        use_flash = (not causal) and bias is None and tileable and \
-            static_scale is not None and jax.default_backend() == "tpu"
-    if use_flash:
+    def einsum(q, k, v):
+        return _ring_attention_einsum(q, k, v, axis_name, causal, scale,
+                                      bias=bias)
+
+    def flash(q, k, v):
         return _ring_attention_flash(q, k, v, axis_name, static_scale)
-    return _ring_attention_einsum(q, k, v, axis_name, causal, scale,
-                                  bias=bias)
+
+    if use_flash is None and (not causal) and bias is None and tileable \
+            and static_scale is not None:
+        # default: the kernel where it pays — a computation lowered for
+        # a TPU (interpret-mode pallas anywhere else is strictly slower
+        # emulation).  The lowering's target platform decides, not the
+        # process's default backend.
+        return lax.platform_dependent(q, k, v, tpu=flash, default=einsum)
+    return flash(q, k, v) if use_flash else einsum(q, k, v)
 
 
 def _ring_attention_einsum(q, k, v, axis_name, causal, scale, bias=None):
     """The masked-einsum ring (blockwise online softmax); also the
     autodiff path behind the flash forward."""
-    P = _axis_size(axis_name)
+    P = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     B, Tl, H, D = q.shape
     q32 = q.astype(jnp.float32)
@@ -298,7 +297,7 @@ def ulysses_attention(q, k, v, axis_name="sp", causal=False, scale=None,
     all kv columns).  A per-head bias rides the same all-to-all as q (head
     shard in, q rows gathered); a broadcast (HB=1) bias is all-gathered
     on the q dim."""
-    P = _axis_size(axis_name)
+    P = lax.axis_size(axis_name)
     H = q.shape[2]
     if H % P:
         raise ValueError("ulysses needs heads %% axis size == 0 "
@@ -352,10 +351,17 @@ def ulysses_attention(q, k, v, axis_name="sp", causal=False, scale=None,
             raise ValueError("flash ulysses needs a static scale and a "
                              "128-tileable full sequence")
         attn = flash_attn
-    elif attn is None:
-        attn = flash_attn if (flash_ok and
-                              jax.default_backend() == "tpu") \
-            else local_attention
     kw = {"bias": bias} if bias is not None else {}
-    out = attn(qf, kf, vf, causal=causal, scale=scale, **kw)
+
+    def run(attn):
+        return lambda q_, k_, v_: attn(q_, k_, v_, causal=causal,
+                                       scale=scale, **kw)
+
+    if attn is None and flash_ok:
+        # default: the flash kernel when lowered for a TPU, the einsum
+        # oracle anywhere else (same rule as ring_attention)
+        out = lax.platform_dependent(qf, kf, vf, tpu=run(flash_attn),
+                                     default=run(local_attention))
+    else:
+        out = run(attn or local_attention)(qf, kf, vf)
     return rev(out)

@@ -14,7 +14,7 @@ assertions:
    real BERT-base embedding + attention geometry, depth-trimmed for CPU
    time), finite and decreasing.
 
-On-chip BERT-base steps/s is bench.py's job (BENCH_LAST_GOOD sidecar).
+On-chip BERT-base at full width is chip_smoke.py's and bench.py's job.
 """
 
 import numpy as np

@@ -1,0 +1,99 @@
+"""chip_smoke.py and the rules it stands on, as far as a CPU can check them:
+the smoke refuses a CPU, its dry run passes, an explicit TPUPlace never
+lands on a CPU device, importing the entry modules initialises no backend
+(a launcher parent must not hold the chip), and the compile cache is placed
+from outside."""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+import paddle_tpu.fluid as fluid
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _run(args, **env):
+    return subprocess.run([sys.executable] + args, cwd=REPO,
+                          env=dict(os.environ, **env), capture_output=True,
+                          text=True, timeout=300)
+
+
+def test_smoke_refuses_a_cpu_and_prints_no_result():
+    proc = _run(["chip_smoke.py"], JAX_PLATFORMS="cpu")
+    assert proc.returncode != 0
+    assert "needs a TPU" in proc.stderr and "platform='cpu'" in proc.stderr
+    assert not any(ln.startswith("{") for ln in proc.stdout.splitlines()), \
+        proc.stdout
+
+
+def test_smoke_dry_run_passes_at_tiny_sizes(capsys):
+    import chip_smoke
+
+    chip_smoke.main(["--dry-run-cpu"])
+    out = capsys.readouterr().out
+    assert "DRY RUN" in out
+    doc = json.loads(out.strip().splitlines()[-1])
+    assert doc["ok"] is True and doc["dry_run"] is True
+    assert doc["device"]["platform"] == "cpu"
+    assert doc["phases"] == {"device": "pass", "resnet50": "pass",
+                             "bert": "pass", "kernels": "pass"}
+
+
+def test_explicit_tpu_place_never_resolves_to_a_cpu():
+    with pytest.raises(RuntimeError, match="needs a TPU.*'cpu'"):
+        fluid.Executor(fluid.TPUPlace())
+    # no place = the default backend's first device, what JAX would pick
+    assert fluid.Executor()._device.platform == "cpu"
+
+
+def test_importing_entry_modules_initialises_no_backend():
+    code = (
+        "import paddle_tpu, paddle_tpu.fluid, paddle_tpu.distributed.launch\n"
+        "import bench, chip_smoke\n"
+        "import jax._src.xla_bridge as xb\n"
+        "assert not xb._backends, list(xb._backends)\n")
+    proc = _run(["-c", code])
+    assert proc.returncode == 0, proc.stderr
+
+
+def test_compile_cache_dir_comes_from_the_environment(monkeypatch):
+    import jax
+    from paddle_tpu.fluid import executor
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    fluid.Executor(fluid.CPUPlace())
+    assert jax.config.jax_compilation_cache_dir == before
+
+    # unset: only a TPU executor gets the fixed <checkout>/.jax_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
+
+    class Dev:
+        platform = "cpu"
+    executor.maybe_enable_compile_cache(Dev)
+    assert updates == []
+    Dev.platform = "tpu"
+    executor.maybe_enable_compile_cache(Dev)
+    assert updates == [("jax_compilation_cache_dir",
+                        os.path.join(REPO, ".jax_cache"))]
+
+
+def test_launcher_refuses_several_plain_processes_on_a_tpu_host(
+        monkeypatch, capsys):
+    from paddle_tpu.distributed import launch
+
+    args = launch.parse_args(["--nproc_per_node", "4", "train.py"])
+    for platforms in ("", "tpu", "tpu,cpu"):
+        monkeypatch.setenv("JAX_PLATFORMS", platforms)
+        assert launch.launch(args) == 2
+        assert "ONE process drives all local chips" in \
+            capsys.readouterr().err
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert not launch._children_may_claim_tpu()

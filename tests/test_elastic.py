@@ -49,12 +49,6 @@ import dist_multihost_worker as worker_mod
 REPO = mh.REPO
 _WORKER = mh.WORKER
 
-requires_gloo = pytest.mark.skipif(
-    not dist.cpu_collectives_supported(),
-    reason="this jax build has no CPU cross-process collective "
-           "transport (gloo) — multi-process CPU SPMD unavailable")
-
-
 # ---------------------------------------------------------------------------
 # Shared world: one tiny WUS job, several sharding degrees.  Programs
 # and executors are built once per module (compiles dominate cost);
@@ -646,7 +640,6 @@ def test_elastic_smoke_shrink_expand_bit_exact_in_process(W, tmp_path):
     assert got == control, (got, control)
 
 
-@requires_gloo
 @pytest.mark.slow
 def test_two_process_elastic_shrink_then_expand_bit_exact(tmp_path):
     """ISSUE 14 acceptance: a real 2-process gloo pack saves a degree-2
@@ -757,7 +750,6 @@ def test_two_process_elastic_shrink_then_expand_bit_exact(tmp_path):
     assert rec_b[0]["recovery_s"] > 0
 
 
-@requires_gloo
 def test_inspect_cli_on_pack_checkpoint_dirs(pack):
     """The operator pre-flight on REAL pod artifacts: both the sync
     (wus) and the async (asyncpod) checkpoint dirs of the shared pack

@@ -57,12 +57,6 @@ import dist_multihost_worker as worker_mod
 
 REPO = mh.REPO
 
-requires_gloo = pytest.mark.skipif(
-    not dist.cpu_collectives_supported(),
-    reason="this jax build has no CPU cross-process collective "
-           "transport (gloo) — multi-process CPU SPMD unavailable")
-
-
 # ---------------------------------------------------------------------------
 # Single-process oracles (same builders as the worker — no drift)
 # ---------------------------------------------------------------------------
@@ -97,7 +91,6 @@ def _single_process_run(precision="fp32", steps=8, windows=2):
 # test needs its own signal-able pack
 # ---------------------------------------------------------------------------
 
-@requires_gloo
 def test_two_process_dp_parity_bit_exact_k1_and_k4(pack):
     """THE acceptance pin: a real 2-process jax.distributed CPU run
     trains the dp model to BIT-EXACT loss parity with the
@@ -126,7 +119,6 @@ def test_two_process_dp_parity_bit_exact_k1_and_k4(pack):
         assert out["prometheus_has_process_label"], out
 
 
-@requires_gloo
 def test_two_process_compiled_cost_and_memory_introspection(pack):
     """Device-cost ledger satellite: ``compiled_cost``/
     ``compiled_memory`` work on the MULTIHOST ``_lowered_executable``
@@ -147,7 +139,6 @@ def test_two_process_compiled_cost_and_memory_introspection(pack):
     assert figures[0] == figures[1], figures
 
 
-@requires_gloo
 def test_two_process_metrics_jsonl_streams_merge_with_skew(pack):
     """Telemetry satellite: each process writes its own
     ``<path>.p<idx>`` JSONL stream (no interleaving), records carry
@@ -197,7 +188,6 @@ def _single_process_int8_step_bytes(steps=6):
         return int(m.value()) - b0
 
 
-@requires_gloo
 def test_two_process_int8_allreduce_bytes_sum_across_processes(pack):
     """PR 10's quantized allreduce on real inter-process wire: losses
     identical shard-for-shard to the single-process int8 run, and the
@@ -230,7 +220,6 @@ def test_two_process_int8_allreduce_bytes_sum_across_processes(pack):
     assert total == 2 * control
 
 
-@requires_gloo
 def test_two_process_weight_update_sharding_ckpt_round_trip(pack):
     """PR 11's ZeRO-sharded optimizer state lives SPLIT ACROSS
     PROCESSES; the multi-host checkpoint writes each process's shard
@@ -254,7 +243,6 @@ def test_two_process_weight_update_sharding_ckpt_round_trip(pack):
     assert procs == {0, 1}                       # both processes wrote
 
 
-@requires_gloo
 def test_sigterm_to_one_process_drains_both_exit_zero(tmp_path):
     """Preemption consensus: SIGTERM delivered to exactly ONE process
     of the pack — the stop propagates through the per-boundary
@@ -928,7 +916,6 @@ def test_gc_spares_young_markerless_prefix_reaps_aged(tmp_path):
 # ISSUE 18 on the REAL pack (asyncpod section of the shared run)
 # ---------------------------------------------------------------------------
 
-@requires_gloo
 def test_two_process_async_pod_save_commits_and_overlaps(pack):
     """The acceptance pin on real collectives: the async pod save's
     upload provably OVERLAPS training dispatches (rank 1's upload span
@@ -975,7 +962,6 @@ def test_two_process_async_pod_save_commits_and_overlaps(pack):
     assert read_manifest(path)["multihost"]["process_count"] == 2
 
 
-@requires_gloo
 @pytest.mark.slow
 def test_two_process_chief_killed_mid_async_save_survivor_resumes(
         tmp_path):

@@ -307,7 +307,6 @@ def test_checked_in_tier1_baseline_loads():
 # The live 2-process pack: merged trace + straggler + axis split
 # ---------------------------------------------------------------------------
 
-@mh.requires_gloo
 def test_trace_pack_straggler_and_axis_split(tmp_path):
     """ISSUE 16 acceptance: a genuine 2-process (× 2 virtual devices)
     hierarchical run produces ONE merged Chrome trace with per-rank

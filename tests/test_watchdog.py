@@ -55,12 +55,6 @@ import mh_harness as mh
 REPO = mh.REPO
 _WORKER = mh.WORKER
 
-requires_gloo = pytest.mark.skipif(
-    not dist.cpu_collectives_supported(),
-    reason="this jax build has no CPU cross-process collective "
-           "transport (gloo) — multi-process CPU SPMD unavailable")
-
-
 @pytest.fixture(autouse=True)
 def _disarmed():
     """Every test starts and ends disarmed — a leaked watchdog thread
@@ -530,7 +524,6 @@ def _child_env(out_dir, jsonl):
     })
 
 
-@requires_gloo
 def test_pack_async_save_under_armed_watchdog(pack):
     """ISSUE 18 × ISSUE 15: the shared pack's asyncpod segment ran its
     save + commit-wait under a 30s-armed watchdog on both ranks — no
@@ -545,7 +538,6 @@ def test_pack_async_save_under_armed_watchdog(pack):
         assert seg["save_returned_s"] < seg["total_s"]
 
 
-@requires_gloo
 @pytest.mark.slow
 def test_two_process_hung_rank_detected_relaunched_continues(tmp_path):
     """ISSUE 15 acceptance: a real 2-process gloo pack trains 3 steps
