@@ -51,8 +51,8 @@ def bench_resnet(batch, steps, amp):
                 learning_rate=0.1, momentum=0.9,
                 regularization=fluid.regularizer.L2Decay(1e-4))
             if amp:
-                # pure-bf16 activations: +24% step throughput vs
-                # fp32-round-trip AMP (PROFILE.md)
+                # pure-bf16 activations: no fp32 round trip of the
+                # activations through HBM
                 opt = fluid.contrib.mixed_precision.decorate(
                     opt, use_pure_bf16=True)
             opt.minimize(loss)

@@ -133,6 +133,10 @@ class CompiledProgram(_CompiledProgramProxy):
                 out.add(n)
         return out
 
+    def _zero_sharded(self):
+        return bool(getattr(self._build_strategy,
+                            "zero_shard_optimizer_state", False))
+
     # -- execution (called from Executor.run) ------------------------------
     def _mesh(self, exe):
         if self._places:
@@ -187,8 +191,7 @@ class CompiledProgram(_CompiledProgramProxy):
         (mirrors Executor._run_resolved)."""
         program = self._program
         feed = feed or {}
-        zero = bool(getattr(self._build_strategy, "zero_shard_optimizer_state",
-                            False))
+        zero = self._zero_sharded()
         if flags.get_flag("dispatch_plan"):
             # same dispatch-plan hot path as Executor.run (executor.py):
             # steady state is one dict lookup + the jitted call
@@ -221,8 +224,7 @@ class CompiledProgram(_CompiledProgramProxy):
         scope = scope or global_scope()
         feed = feed or {}
         K = int(steps_per_run)
-        zero = bool(getattr(self._build_strategy, "zero_shard_optimizer_state",
-                            False))
+        zero = self._zero_sharded()
         if flags.get_flag("dispatch_plan"):
             pkey = exe._plan_key(program, feed, fetch_list)
             if pkey is not None:
@@ -238,6 +240,18 @@ class CompiledProgram(_CompiledProgramProxy):
                                                     steps_per_run=K)
         feed_vals = compiled.globalize_feeds(feed_vals)
         return exe._dispatch(compiled, scope, feed_vals, return_numpy)
+
+    def _lookup_executable(self, exe, feed, fetch_list, scope,
+                           steps_per_run=None):
+        """(compiled block, coerced feeds) that ``_run`` / ``_run_window``
+        dispatch — what ``Executor.compiled_hlo`` / ``compiled_cost`` /
+        ``compiled_memory`` read when handed this CompiledProgram."""
+        if not self._is_data_parallel:
+            return exe._resolve_compiled(self._program, feed, fetch_list,
+                                         scope, steps_per_run)
+        zero = self._zero_sharded()
+        return self._lookup_compiled(exe, feed, fetch_list, scope, zero,
+                                     steps_per_run=steps_per_run)
 
     def _lookup_compiled(self, exe, feed, fetch_list, scope, zero,
                          steps_per_run=None):

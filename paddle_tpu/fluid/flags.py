@@ -241,9 +241,11 @@ def apply_prng_impl():
     fills) through the TPU's hardware RngBitGenerator — the analogue of the
     reference's curand-backed dropout (operators/dropout_op.cu) and, like
     curand, stable only per (backend, compiler) rather than across them.
-    Builder-measured +30% BERT-base pretrain step throughput vs threefry at
-    batch 64 x seq 128 (round 3; not reproduced on the current code).  ``FLAGS_prng_impl=threefry`` restores jax's
-    cross-backend-reproducible counter-based PRNG.
+    Dropout masks then cost one hardware instruction per tile, where
+    threefry spends vector-unit integer work per element (the speed
+    difference is not measured on the current code).
+    ``FLAGS_prng_impl=threefry`` restores jax's cross-backend-reproducible
+    counter-based PRNG.
     """
     import jax
 

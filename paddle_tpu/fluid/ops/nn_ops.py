@@ -257,10 +257,9 @@ def _batch_norm(ctx, op):
     else:
         # single-pass statistics: E[x] and E[x^2] reduce in the SAME read
         # of x (XLA fuses both into one loop), where jnp.var's two-pass
-        # mean((x-mean)^2) costs an extra full pass over the activation —
-        # measured ~1/3 of the BN-stats HBM traffic of a ResNet step
-        # (PROFILE.md r3).  Accumulation is fp32 (cancellation-safe the
-        # same way cuDNN/TPU fused BN does it); clamp for safety.
+        # mean((x-mean)^2) costs an extra full pass over the activation.
+        # Accumulation is fp32 (cancellation-safe the same way cuDNN/TPU
+        # fused BN does it); clamp for safety.
         xm = x.astype(cdt)
         use_mean = jnp.mean(xm, axis=axes)
         use_var = jnp.maximum(

@@ -83,7 +83,6 @@ def _attention_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
     # dots run in the INPUT dtype (bf16 under pure-bf16 AMP — a single
     # fast MXU pass) and accumulate fp32 via preferred_element_type;
     # casting inputs to fp32 first forces multi-pass fp32 MXU emulation
-    # (builder-measured ~2x slower end-to-end at S=512 in round 3)
     q = q_ref[0]                                  # [bq, D], native dtype
     S = k_ref.shape[1]
     bq, D = q.shape

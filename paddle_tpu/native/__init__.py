@@ -12,6 +12,7 @@ call's duration).  ``available()`` is False when no toolchain exists; callers
 """
 
 import ctypes
+import glob
 import hashlib
 import os
 import subprocess
@@ -44,6 +45,14 @@ def _build(lib_path):
     finally:
         if os.path.exists(tmp):
             os.unlink(tmp)
+    # libraries built from earlier versions of native.cc: nothing can load
+    # them by name any more (unlinking one a live process has mapped is safe)
+    for stale in glob.glob(os.path.join(_HERE, "libpaddle_tpu_native*.so")):
+        if stale != lib_path:
+            try:
+                os.unlink(stale)
+            except FileNotFoundError:   # a concurrent builder got there first
+                pass
 
 
 def _bind(lib):

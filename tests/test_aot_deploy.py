@@ -3,11 +3,7 @@
 export_aot_model writes an HLO module + manifest; pjrt_demo.cc compiles
 and runs it through the XLA native runtime in libtensorflow_cc with NO
 libpython linked — the reference's pure-C++ deployment contract
-(train/demo/demo_trainer.cc, inference/api/demo_ci).
-
-The two tests that compile C++ against TensorFlow's bundled XLA (~40 s
-each) sit behind ``slow`` (ROADMAP D10): tier-1 runs against an 870 s wall.
-Run them with ``pytest tests/test_aot_deploy.py -m slow``."""
+(train/demo/demo_trainer.cc, inference/api/demo_ci)."""
 
 import os
 import subprocess
@@ -41,7 +37,6 @@ def _build_demo(exe_path):
     assert cp.returncode == 0, cp.stderr[-3000:]
 
 
-@pytest.mark.slow
 @pytest.mark.skipif(not os.path.isdir(_TF), reason="no tensorflow libs")
 def test_aot_export_and_cpp_run():
     main, startup = fluid.Program(), fluid.Program()
@@ -98,7 +93,6 @@ def test_export_requires_initialized_scope():
                                      exe, main_program=main)
 
 
-@pytest.mark.slow
 @pytest.mark.skipif(not os.path.isdir(_TF), reason="no tensorflow libs")
 def test_aot_train_cpp_loop():
     """The exported TRAIN step iterated from C++ (demo_trainer.cc

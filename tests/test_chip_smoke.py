@@ -38,9 +38,13 @@ def test_smoke_dry_run_passes_at_tiny_sizes(capsys):
     assert "DRY RUN" in out
     doc = json.loads(out.strip().splitlines()[-1])
     assert doc["ok"] is True and doc["dry_run"] is True
-    assert doc["device"]["platform"] == "cpu"
+    assert doc["device"]["platform"] == "cpu" and doc["native"] is True
     assert doc["phases"] == {"device": "pass", "resnet50": "pass",
                              "bert": "pass", "kernels": "pass"}
+    # a program-bound loader moving from host batches to staged ones
+    # compiles nothing again, in the executor or under it in jit
+    assert doc["informational"]["resnet50"][
+        "xla_compiles_after_first_step"] == 0
 
 
 def test_explicit_tpu_place_never_resolves_to_a_cpu():

@@ -293,15 +293,19 @@ def test_window_ep_collectives_match_k1():
     _assert_no_host_transfers(hlo)
 
 
-def test_gspmd_dp_loader_feeds_arrive_sharded_zero_reshard():
-    """GSPMD dp + program-bound DataLoader: after the first dispatch
-    binds the plan's feed shardings back to the loader, the producer
-    thread stages batches ALREADY SHARDED across the 8-device mesh —
-    steady-state dispatches perform zero implicit device-to-device
-    reshard transfers (pinned with jax's transfer guard, which trips on
-    exactly the replicated-then-resharded layout this fix removes)."""
+@pytest.mark.parametrize("path", ["gspmd", "collective"])
+def test_dp_loader_feeds_arrive_sharded_zero_reshard(path):
+    """Data parallel + program-bound DataLoader, through GSPMD
+    (CompiledProgram) and through the program's own collectives
+    (GradAllReduce): after the first dispatch binds the plan's feed
+    shardings back to the loader, the producer thread stages batches
+    ALREADY SHARDED across the 8-device mesh — steady-state dispatches
+    perform zero implicit device-to-device reshard transfers (pinned with
+    jax's transfer guard, which trips on exactly the
+    whole-batch-on-one-device-then-resharded layout this removes)."""
     import jax
     from jax.sharding import NamedSharding
+    from paddle_tpu.fluid.transpiler import GradAllReduce
 
     main, startup = fluid.Program(), fluid.Program()
     main.random_seed = startup.random_seed = 1
@@ -319,8 +323,13 @@ def test_gspmd_dp_loader_feeds_arrive_sharded_zero_reshard():
             yield {"x": rng.normal(0, 1, (16, 16)).astype(np.float32)}
 
     loader.set_batch_generator(gen)
-    compiled = fluid.CompiledProgram(main).with_data_parallel(
-        loss_name=loss.name)
+    if path == "gspmd":
+        compiled = fluid.CompiledProgram(main).with_data_parallel(
+            loss_name=loss.name)
+    else:
+        GradAllReduce().transpile(startup_program=startup, main_program=main,
+                                  rank=0, endpoints=[], nranks=8)
+        compiled = main
     from paddle_tpu.fluid import telemetry
     reputs = telemetry.registry().counter("executor_feed_reputs_total")
     scope = fluid.Scope()
@@ -356,6 +365,10 @@ def test_gspmd_dp_loader_feeds_arrive_sharded_zero_reshard():
                     exe.run(compiled, feed=loader.next_feed(),
                             fetch_list=[loss], return_numpy=False)
             assert reputs.value() == r0, "steady-state feeds resharded"
+            # introspection reads the executable that ran, whichever
+            # kind of program it was handed
+            hlo = exe.compiled_hlo(compiled, feed=feed, fetch_list=[loss])
+            assert _counts(hlo)["all-reduce"] >= 1
         finally:
             loader.reset()
 

@@ -16,6 +16,30 @@ def test_native_builds():
     assert native.available(), "g++ toolchain present: native must build"
 
 
+def test_build_removes_libraries_of_earlier_sources(tmp_path, monkeypatch):
+    """The library's name carries a hash of native.cc, so every edit leaves
+    the previous build behind: a successful build sweeps them out."""
+    import subprocess
+
+    stale = [tmp_path / "libpaddle_tpu_native.0123456789abcdef.so",
+             tmp_path / "libpaddle_tpu_native.so"]
+    for f in stale:
+        f.write_bytes(b"old")
+    keep = tmp_path / "native.cc"
+    keep.write_text("// source")
+    monkeypatch.setattr(native, "_HERE", str(tmp_path))
+
+    def fake_gpp(cmd, **kw):
+        with open(cmd[cmd.index("-o") + 1], "wb") as f:
+            f.write(b"new")
+    monkeypatch.setattr(subprocess, "run", fake_gpp)
+    lib = tmp_path / "libpaddle_tpu_native.fedcba9876543210.so"
+    native._build(str(lib))
+    assert sorted(p.name for p in tmp_path.iterdir()) == \
+        sorted([lib.name, keep.name])
+    assert lib.read_bytes() == b"new"
+
+
 def test_recordio_roundtrip_native(tmp_path):
     path = str(tmp_path / "data.recordio")
     records = [b"hello", b"", b"x" * 100000, pickle.dumps({"a": 1})]
