@@ -37,9 +37,12 @@ executor's device(s); no compile after the first training step; in steady
 state the loader hands every batch over already on the device — one shard
 per chip — and the dispatch moves none of it again.
 
-The timings printed are informational: this script records no metric.  The
-last line of stdout is one JSON object, printed only when every phase
-passed.
+The timings printed are informational: this script records no metric.  When
+every phase has passed, a ``summary:`` line carries the per-phase outcome and
+those timings, and the last line of stdout is the result, exactly
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}`` with
+the device as JAX reports it.  A dry run prints ``dry-run summary:`` and no
+result line.
 """
 
 import argparse
@@ -612,12 +615,15 @@ def main(argv=None):
         "jax_compilation_cache_dir=%r"
         % ("set" if "JAX_COMPILATION_CACHE_DIR" in os.environ else "unset",
            jax.config.jax_compilation_cache_dir))
-    result = {"ok": True, "device": device, "native": True,
-              "phases": phases, "informational": timings,
-              "wall_s": round(time.perf_counter() - t_start, 1)}
+    summary = {"device": device, "native": True, "phases": phases,
+               "informational": timings,
+               "wall_s": round(time.perf_counter() - t_start, 1)}
     if args.dry_run_cpu:
-        result["dry_run"] = True
-    print(json.dumps(result), flush=True)
+        # no result line: a result comes only from a chip
+        log("dry-run summary: " + json.dumps(summary))
+        return
+    log("summary: " + json.dumps(summary))
+    print(json.dumps({"ok": True, "device": device}), flush=True)
 
 
 if __name__ == "__main__":

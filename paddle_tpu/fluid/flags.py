@@ -190,7 +190,7 @@ _DEFS = {
                                      # traces ("" = ./device_profile)
     "roofline_peak_flops": 197e12,   # roofline model peak FLOP/s used for
                                      # estimated_step_s (default: v5e
-                                     # bf16 peak, bench.PEAK_BF16_FLOPS)
+                                     # bf16 peak, costmodel.DEVICE_PEAKS)
     "roofline_peak_bytes_per_s": 819e9,  # roofline model peak memory
                                      # bandwidth (default: v5e HBM ~819
                                      # GB/s); estimated_step_s =

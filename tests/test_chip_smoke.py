@@ -36,8 +36,12 @@ def test_smoke_dry_run_passes_at_tiny_sizes(capsys):
     chip_smoke.main(["--dry-run-cpu"])
     out = capsys.readouterr().out
     assert "DRY RUN" in out
-    doc = json.loads(out.strip().splitlines()[-1])
-    assert doc["ok"] is True and doc["dry_run"] is True
+    # a dry run ends with its summary and prints no result line: the
+    # driver reads `{"ok": ..., "device": ...}` only from a chip run
+    last = out.strip().splitlines()[-1]
+    assert last.startswith("dry-run summary: ")
+    assert not any(ln.startswith("{") for ln in out.splitlines()), out
+    doc = json.loads(last[len("dry-run summary: "):])
     assert doc["device"]["platform"] == "cpu" and doc["native"] is True
     assert doc["phases"] == {"device": "pass", "resnet50": "pass",
                              "bert": "pass", "kernels": "pass"}
@@ -45,6 +49,26 @@ def test_smoke_dry_run_passes_at_tiny_sizes(capsys):
     # compiles nothing again, in the executor or under it in jit
     assert doc["informational"]["resnet50"][
         "xla_compiles_after_first_step"] == 0
+
+
+def test_smoke_result_line_is_exactly_ok_and_device(monkeypatch, capsys):
+    """The driver reads the last stdout line of a chip run and takes
+    nothing but `ok` and the device as JAX reports it; the per-phase
+    detail is on the `summary:` line before it."""
+    import chip_smoke
+
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    monkeypatch.setattr(chip_smoke, "phase_device",
+                        lambda dry_run, chips: (device, [None]))
+    for name in ("phase_resnet", "phase_bert", "phase_kernels"):
+        monkeypatch.setattr(chip_smoke, name, lambda *a: {})
+    chip_smoke.main([])
+    lines = capsys.readouterr().out.strip().splitlines()
+    assert json.loads(lines[-1]) == {"ok": True, "device": device}
+    assert lines[-2].startswith("summary: ")
+    assert json.loads(lines[-2][len("summary: "):])["phases"] == {
+        "device": "pass", "resnet50": "pass", "bert": "pass",
+        "kernels": "pass"}
 
 
 def test_explicit_tpu_place_never_resolves_to_a_cpu():
