@@ -166,8 +166,8 @@ class CompiledProgram(_CompiledProgramProxy):
 
     def _run(self, exe, feed, fetch_list, scope, return_numpy):
         if not self._is_data_parallel:
-            return exe.run(self._program, feed=feed, fetch_list=fetch_list,
-                           scope=scope, return_numpy=return_numpy)
+            return exe._run(self._program, feed, fetch_list, scope,
+                            return_numpy)
         program = self._program
         scope = scope or global_scope()
         if not feed and getattr(program, "_loader", None) is not None:
@@ -178,7 +178,7 @@ class CompiledProgram(_CompiledProgramProxy):
             # Dispatch through _run_resolved, never back through _run
             # (an empty pulled feed would re-enter this branch)
             return exe._loader_fed_run(
-                program._loader,
+                program._loader, scope,
                 lambda f: self._run_resolved(exe, f, fetch_list, scope,
                                              return_numpy),
                 lambda f, k: self._run_window(exe, f, fetch_list, scope,
@@ -216,10 +216,8 @@ class CompiledProgram(_CompiledProgramProxy):
         dispatch — the collective layout inside the scan body is exactly
         the K=1 step's (GSPMD partitions the body once)."""
         if not self._is_data_parallel:
-            return exe.run_window(self._program, feed=feed,
-                                  fetch_list=fetch_list, scope=scope,
-                                  steps_per_run=steps_per_run,
-                                  return_numpy=return_numpy)
+            return exe._run_window(self._program, feed, fetch_list, scope,
+                                   int(steps_per_run), return_numpy)
         program = self._program
         scope = scope or global_scope()
         feed = feed or {}

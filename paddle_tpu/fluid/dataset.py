@@ -38,8 +38,6 @@ from . import telemetry
 from .data_types import np_dtype
 
 # dataset-tier telemetry (docs/observability.md)
-_m_ds_batches = telemetry.counter(
-    "dataset_batches_total", "batches assembled by the Dataset tier")
 _m_flushes = telemetry.counter(
     "window_flushes_total",
     "stacked K-step windows emitted, by reason "
@@ -431,7 +429,6 @@ class DatasetBase:
     def _batchify(self, insts, spec):
         """instances → feed dict; variable slots pad to the batch max and
         emit a ``<name>@len`` companion (padded+lengths replaces LoD)."""
-        _m_ds_batches.inc()
         feed = {}
         for name, dtype, fixed in spec:
             vals = [np.asarray(i[name], dtype=dtype) for i in insts]

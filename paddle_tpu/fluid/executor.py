@@ -14,7 +14,9 @@ as device arrays; each run threads them through the compiled function with
 buffer donation, so in-place optimizer updates stay in-place on device.
 """
 
+import itertools
 import os
+import threading
 import time
 import contextlib
 import warnings
@@ -82,15 +84,19 @@ _m_comm_bytes = telemetry.counter(
     "quantized_collectives.allreduce_wire_bytes; a hierarchical "
     "two-level ring splits per member axis, 'ici' vs 'dcn', totals "
     "preserved — ExecState.record_comm)")
-_m_device_mem = telemetry.gauge(
-    "device_memory_bytes",
-    "device-resident array bytes sampled at dispatch boundaries "
-    "(FLAGS_metrics_device_memory): kind=live is the jax.live_arrays() "
-    "sum right after state writeback (attribute reads, no sync), "
-    "kind=peak the high-water mark of those samples — the HBM-headroom "
-    "signal; Executor.compiled_memory gives the complementary "
-    "per-executable XLA estimate")
-_mem_peak = [0]
+_m_xla_compile_s = telemetry.counter(
+    "xla_compile_seconds_total",
+    "seconds jit spent inside the executor's calls, by phase (trace: "
+    "Fluid program -> jaxpr; lower: jaxpr -> MLIR; backend: XLA/Mosaic "
+    "compile or persistent-cache load) and why (dispatch: a step's "
+    "call; introspection: compiled_hlo/_cost/_memory)")
+_m_xla_compiles = telemetry.counter(
+    "xla_backend_compiles_total",
+    "XLA backend compiles (cache loads included) inside the executor's "
+    "calls, by why: dispatch = the first call of a fresh executable, "
+    "introspection = compiled_hlo/_cost/_memory, recompile = jit "
+    "compiling a step AGAIN for changed argument shardings or "
+    "commitment (compile_count() cannot see these)")
 _m_opt_state_bytes = telemetry.gauge(
     "optimizer_state_bytes",
     "per-device bytes of optimizer state (accumulators / moments) of "
@@ -103,6 +109,62 @@ _m_bucket_overlap = telemetry.gauge(
     "exchange is emitted at its last-producer position with no "
     "cross-bucket data dependence, so all but the final bucket's wire "
     "time can hide under remaining backward compute")
+
+
+# ---------------------------------------------------------------------------
+# What jit compiles inside the executor's calls (jax.monitoring)
+# ---------------------------------------------------------------------------
+
+_COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+# why the calling thread is inside jit right now: None (not in an
+# executor call: the event is someone else's), "dispatch", "recompile"
+# (the call of an executable that has run before) or "introspection".
+# jax reports an event on the thread that compiled.
+_compiling = threading.local()
+_compile_listener = []
+
+
+def _on_compile_event(event, duration_secs, **_):
+    phase = _COMPILE_PHASES.get(event)
+    why = getattr(_compiling, "why", None)
+    if phase is None or why is None:
+        return
+    _m_xla_compile_s.inc(
+        duration_secs, phase=phase,
+        why="introspection" if why == "introspection" else "dispatch")
+    if phase == "backend":
+        _m_xla_compiles.inc(why=why)
+
+
+def _listen_for_compiles():
+    """Register the ONE listener behind ``xla_compile_seconds_total`` /
+    ``xla_backend_compiles_total``; idempotent (``Executor.__init__``)."""
+    if not _compile_listener:
+        import jax.monitoring
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_compile_event)
+        _compile_listener.append(_on_compile_event)
+
+
+class _compiling_for:
+    """What jit compiles on this thread inside the block is counted under
+    ``why`` (a class, not a generator: it sits on the dispatch path)."""
+
+    __slots__ = ("why",)
+
+    def __init__(self, why):
+        self.why = why
+
+    def __enter__(self):
+        _compiling.why = self.why
+
+    def __exit__(self, *exc):
+        _compiling.why = None
+        return False
 
 
 # ---------------------------------------------------------------------------
@@ -126,7 +188,22 @@ def maybe_enable_compile_cache(device):
     uses the fixed ``<checkout>/.jax_cache``; a CPU executor gets no
     persistent cache (the test suite neither slows nor grows the tree).
     Launcher children inherit the environment, so one cache serves a
-    pack.  Idempotent; called from ``Executor.__init__``."""
+    pack.  Idempotent; called from ``Executor.__init__``.
+
+    Whatever directory serves, its key covers the instructions' metadata:
+    JAX leaves ``op_name`` out of the key by default, and a cache warmed
+    by a checkout whose lowering named its scopes differently then hands
+    back an executable with THAT checkout's names (seen on the CPU and
+    on the v5e, PR 26) — every reader of ``fluid_<op>`` / ``role_*``
+    scopes would attribute device time by another program's map.  The
+    metadata is an instruction's scopes and the line of its lowering rule,
+    NOT the Python stack that called the step: with JAX's default of ten
+    frames a location, ``exe.compiled_hlo`` misses the entry the dispatch
+    of the same step wrote (31 s of ``checks`` against 5.5 s in the
+    four-chip cell's cold set-up, PR 26) and an edit that moves the
+    caller's ``exe.run`` line recompiles everything."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
     if "JAX_COMPILATION_CACHE_DIR" in os.environ or \
             device.platform != "tpu" or \
             jax.config.jax_compilation_cache_dir:
@@ -443,19 +520,37 @@ def prefetch_ahead(put, batches, depth=None, stop_when=None):
     return _prefetch_ahead_sync(put, batches)
 
 
+def feed_nbytes(feed):
+    """Host bytes of one feed dict (attribute reads)."""
+    return int(sum(getattr(v, "nbytes", 0) for v in feed.values())) \
+        if isinstance(feed, dict) else 0
+
+
 def _prefetch_ahead_sync(put, batches):
-    """The depth-0 legacy path of ``prefetch_ahead`` (see there)."""
+    """The depth-0 legacy path of ``prefetch_ahead`` (see there).  Each
+    batch is drawn from the source and ``put`` inside one
+    ``fluid.feed_stage`` span on the calling thread (a program-bound
+    loader's worker), numbered as the consumer's ``fluid.feed_wait``
+    numbers the batch it is handed."""
     it = iter(batches)
+    done = object()
+
+    def stage(batch):
+        with telemetry.span("feed_stage", batch=batch) as staging:
+            host = next(it, done)
+            if host is done:
+                return done
+            staging.label(bytes=feed_nbytes(host))
+            return put(host)
+
     try:
-        try:
-            ahead = put(next(it))
-        except StopIteration:
-            return
-        for nxt in it:
-            nxt = put(nxt)   # transfer overlaps consumer's compute
+        ahead = stage(0)
+        for batch in itertools.count(1):
+            if ahead is done:
+                return
+            nxt = stage(batch)   # transfer overlaps consumer's compute
             yield ahead
             ahead = nxt
-        yield ahead
     finally:
         # generator .close() / GC must release the source too (its own
         # finally blocks may hold reader threads or open shards)
@@ -1077,6 +1172,7 @@ class Executor:
         # sharded (GSPMD) / on the right device ahead of the next pull
         self._last_compiled = None
         maybe_enable_compile_cache(self._device)
+        _listen_for_compiles()
         # FLAGS_pe_profile_fname (parallel_executor.cc:38 gperftools
         # hook): whole-process host profile, dumped at exit
         profiler.maybe_start_pe_profile()
@@ -1172,16 +1268,23 @@ class Executor:
                     "HLO introspection is unavailable for this program: "
                     "its execution path does not expose one jitted step "
                     "function")
-            lowered = jitted.lower(mut, ro, tuple(feed_vals),
-                                   np.int32(scope.step_counter))
             # cached on the block so compiled_hlo + compiled_cost on the
             # same (program, feeds, fetches, state avals) pay ONE XLA
             # compile
-            t0 = time.perf_counter()
-            executable = lowered.compile()
+            with _compiling_for("introspection"):
+                lowered = jitted.lower(mut, ro, tuple(feed_vals),
+                                       np.int32(scope.step_counter))
+                t0 = time.perf_counter()
+                with telemetry.span(
+                        "compile", why="introspection",
+                        sig=costmodel.signature(
+                            compiled.program_fingerprint,
+                            k=compiled.steps_per_run)):
+                    executable = lowered.compile()
             _m_compile_s.observe(time.perf_counter() - t0,
                                  kind="introspection")
             compiled._xla_executables[aval_key] = executable
+        profiler.note_step_executable(executable)
         return executable
 
     def compiled_hlo(self, program=None, feed=None, fetch_list=None,
@@ -1269,6 +1372,28 @@ class Executor:
     def run(self, program=None, feed=None, fetch_list=None, feed_var_name="feed",
             fetch_var_name="fetch", scope=None, return_numpy=True,
             use_program_cache=True):
+        return self._as_step(scope, 1, self._run, program, feed, fetch_list,
+                             scope, return_numpy)
+
+    def _as_step(self, scope, k, body, *args):
+        """One call of ``run`` / ``run_window``, as a reader of a trace
+        sees it: ``FLAGS_device_profile``'s bracket, then the
+        ``fluid.step`` span (the loader pull, the dispatch and the
+        executor's own Python are its children), then ``body(*args)``.
+        ``run`` and ``run_window`` enter here and nothing below them
+        does, so a step is never nested in a step."""
+        # FLAGS_device_profile=N: bracket the next N dispatched steps in
+        # a jax.profiler trace (profiler.py) — one cached-int read when
+        # the flag is 0
+        profiler.device_profile_begin()
+        step = (scope or global_scope()).step_counter
+        with telemetry.span("step", step_num=int(step), k=k):
+            out = body(*args)
+        last = self._last_compiled
+        profiler.device_profile_end(last.steps_per_run if last else k)
+        return out
+
+    def _run(self, program, feed, fetch_list, scope, return_numpy):
         program = program or framework.default_main_program()
         if isinstance(program, _CompiledProgramProxy):
             return program._run(self, feed, fetch_list, scope, return_numpy)
@@ -1283,13 +1408,11 @@ class Executor:
             # through run(): a loader with no feed vars pulls an empty
             # dict, and re-entering this branch would pull again
             return self._loader_fed_run(
-                program._loader,
+                program._loader, scope,
                 lambda f: self._run_resolved(program, f, fetch_list,
                                              scope, return_numpy),
-                lambda f, k: self.run_window(program, feed=f,
-                                             fetch_list=fetch_list,
-                                             scope=scope, steps_per_run=k,
-                                             return_numpy=False))
+                lambda f, k: self._run_window(program, f, fetch_list,
+                                              scope, k, False))
         return self._run_resolved(program, feed, fetch_list, scope,
                                   return_numpy)
 
@@ -1334,6 +1457,11 @@ class Executor:
         the legacy per-step ``run()`` remains the default and the A/B
         control."""
         K = flags.steps_per_run_value(steps_per_run)
+        return self._as_step(scope, K, self._run_window, program, feed,
+                             fetch_list, scope, K, return_numpy)
+
+    def _run_window(self, program, feed, fetch_list, scope, K,
+                    return_numpy):
         program = program or framework.default_main_program()
         if isinstance(program, _CompiledProgramProxy):
             return program._run_window(self, feed, fetch_list, scope, K,
@@ -1362,7 +1490,7 @@ class Executor:
         feed_vals = compiled.globalize_feeds(feed_vals)
         return self._dispatch(compiled, scope, feed_vals, return_numpy)
 
-    def _loader_fed_run(self, loader, run_step, run_window):
+    def _loader_fed_run(self, loader, scope, run_step, run_window):
         """Pull one staged batch from a program-bound loader and
         dispatch it — ONE flow shared by ``Executor.run`` and
         ``CompiledProgram._run`` so the loader contract cannot drift
@@ -1382,7 +1510,7 @@ class Executor:
         (GSPMD feeds arrive sharded instead of
         replicated-then-resharded)."""
         loader._consumer_device = self._device
-        feed = loader.next_feed()
+        feed = loader.next_feed(step=scope.step_counter)
         if getattr(loader, "_steps_per_run", 1) > 1:
             k = int(np.shape(next(iter(feed.values())))[0]) if feed else 1
             out = run_window(feed, k)
@@ -1458,6 +1586,17 @@ class Executor:
         return self._dispatch(compiled, scope, feed_vals, return_numpy)
 
     def _dispatch(self, compiled, scope, feed_vals, return_numpy):
+        # fluid.dispatch less its child fluid.enqueue (the jitted call)
+        # is the executor's own Python around the call: placement
+        # guards, state gather and write-back, the step-event record
+        with telemetry.span("dispatch", step=int(scope.step_counter),
+                            k=compiled.steps_per_run,
+                            fresh=compiled._fresh,
+                            window=compiled.is_window):
+            return self._dispatch_in_span(compiled, scope, feed_vals,
+                                          return_numpy)
+
+    def _dispatch_in_span(self, compiled, scope, feed_vals, return_numpy):
         self._last_compiled = compiled
         if (compiled.feed_shardings is not None or
                 compiled.feed_placement_shardings is not None) and \
@@ -1489,30 +1628,36 @@ class Executor:
         # watchdog names "dispatch".  One dict read + return when the
         # watchdog is off — the zero-overhead contract
         telemetry.record_progress("dispatch")
-        # FLAGS_device_profile=N: bracket the next N dispatched steps in
-        # a jax.profiler trace (profiler.py) — one cached-int read when
-        # the flag is 0
-        profiler.device_profile_begin()
         t0 = time.perf_counter_ns()
         with jax.default_device(self._device):
             ro_vals = _scope_state(scope, compiled.state_ro)
             if compiled.state_ro_shardings is not None and \
                     jax.process_count() <= 1:
                 ro_vals = compiled.place_ro_state(ro_vals)
+            mut_vals = _scope_state(scope, compiled.state_mut)
             # first call = trace + XLA compile (legitimately minutes
             # on real models): phase-aware grace so an armed watchdog
-            # doesn't call a long compile a hang; the cached-hit path
-            # enters the shared no-op context instead (one call site —
-            # the dispatch arguments can never diverge between paths)
-            with watchdog.extend_deadline(
-                    "compile",
-                    flags.get_flag("watchdog_compile_grace_s")) \
+            # doesn't call a long compile a hang, and a fluid.compile
+            # span inside fluid.enqueue; the cached-hit path enters the
+            # shared no-op context instead (one call site — the dispatch
+            # arguments can never diverge between paths).  What jit
+            # compiles inside the call is counted by why: a compile in
+            # the call of an executable that has run before is a
+            # recompile
+            with _compiling_for("dispatch" if fresh else "recompile"), \
+                    telemetry.span("enqueue", step=int(step)), \
+                    watchdog.extend_deadline(
+                        "compile",
+                        flags.get_flag("watchdog_compile_grace_s")) \
+                    if fresh else _NULL_CTX, \
+                    telemetry.span(
+                        "compile", why="dispatch",
+                        sig=costmodel.signature(
+                            compiled.program_fingerprint, k=k)) \
                     if fresh else _NULL_CTX:
                 fetches, new_state = compiled.fn(
-                    _scope_state(scope, compiled.state_mut),
-                    ro_vals, tuple(feed_vals), step)
+                    mut_vals, ro_vals, tuple(feed_vals), step)
         t1 = time.perf_counter_ns()
-        profiler.device_profile_end(k)
         compile_s = None
         if fresh:
             # the first call of a fresh executable carries trace + XLA
@@ -1600,21 +1745,6 @@ class Executor:
             comm_bytes=comm_bytes, comm_by=comm_by,
             comm_by_axis=comm_by_axis,
             comm_buckets=comm_buckets, opt_state_bytes=opt_bytes)
-        # pod-tracing span of the dispatch region (same [t0, t1] the
-        # step event carries, plus the wall anchor pod_trace.py aligns
-        # ranks with); record_span is a no-op unless spans are on
-        telemetry.record_span("dispatch", t0, t1 - t0, step=int(step),
-                              k=k, window=compiled.is_window)
-        if flags.get_flag("metrics_device_memory"):
-            # HBM watermarks: nbytes attribute reads over the live-array
-            # list — no device sync (committed arrays know their size)
-            live = 0
-            for a in jax.live_arrays():
-                live += int(getattr(a, "nbytes", 0) or 0)
-            _m_device_mem.set(live, kind="live")
-            if live > _mem_peak[0]:
-                _mem_peak[0] = live
-            _m_device_mem.set(_mem_peak[0], kind="peak")
         return out
 
     def _run_pserver(self, program, scope):

@@ -100,13 +100,9 @@ _DEFS = {
                                      # tools/pod_trace.py merging; off
                                      # (default) = bit-exact zero-sync
                                      # hot path (docs/observability.md
-                                     # "Pod-level tracing")
-    "metrics_device_memory": False,  # executor: sample device_memory_
-                                     # bytes{kind=live|peak} gauges from
-                                     # jax.live_arrays() at dispatch
-                                     # boundaries (attribute reads, no
-                                     # sync); off = no per-dispatch
-                                     # live-array walk
+                                     # "Pod-level tracing"); a span's
+                                     # jax.profiler annotation needs no
+                                     # flag
     "bad_step_rollback": 0,          # K>0: under FLAGS_check_nan_inf=
                                      # skip, K CONSECUTIVE bad-step
                                      # verdicts make train_from_dataset

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from .data_types import is_floating
+from .framework import OpRole
 from .registry import get_op_def
 from . import telemetry
 
@@ -27,8 +28,6 @@ _STRUCTURAL_OPS = frozenset(["feed", "fetch"])
 # state steps is a retrace leak — the classic silent step-time killer
 _m_blocks = telemetry.counter(
     "lowering_blocks_traced_total", "program blocks traced to XLA")
-_m_ops = telemetry.counter(
-    "lowering_ops_lowered_total", "ops dispatched through lowering rules")
 
 
 def step_prng_key(seed, step):
@@ -219,19 +218,35 @@ def run_block(block, env, state):
         dispatch(op, env, state, block)
 
 
+def role_scope(role):
+    """The step phase an op's ``op_role`` attribute puts it in:
+    ``role_bwd`` (the loss gradient carries ``Backward | Loss``),
+    ``role_opt`` (optimizer ops and the learning-rate schedule) or
+    ``role_fwd``."""
+    if role & OpRole.Backward:
+        return "role_bwd"
+    if role & (OpRole.Optimize | OpRole.LRSched):
+        return "role_opt"
+    return "role_fwd"
+
+
 def dispatch(op, env, state, block):
     if op.type in _STRUCTURAL_OPS:
         return
-    _m_ops.inc()
     ctx = LowerCtx(env, op, state, block)
-    # Every op lowers inside a named scope so HLO instruction metadata
-    # (op_name="jit(..)/fluid_<type>/..") maps device cost back to the
-    # ProgramDesc op that produced it — the attribution substrate of the
-    # device-cost ledger (costmodel.op_attribution, tools/cost_ledger.py).
-    # Metadata only: the scope never changes the lowered math, so it stays
-    # unconditional rather than joining flags.trace_time_key().
+    # Every op lowers inside two named scopes so HLO instruction metadata
+    # (op_name="jit(..)/role_bwd/fluid_<type>/..") maps device cost back
+    # to the ProgramDesc op that produced it — the attribution substrate
+    # of the device-cost ledger (costmodel.op_attribution,
+    # tools/cost_ledger.py) — and to the phase of the step it belongs to
+    # (a device trace's forward / backward / optimizer split).  Readers
+    # take the FIRST "fluid_" match of an op_name, so the role scope's
+    # name must not begin with it.  Metadata only: the scopes never change
+    # the lowered math, so they stay unconditional rather than joining
+    # flags.trace_time_key().
     try:
-        with jax.named_scope("fluid_" + op.type):
+        with jax.named_scope(role_scope(op.op_role)), \
+                jax.named_scope("fluid_" + op.type):
             if op.type.endswith("_grad"):
                 fwd_type = op.type[:-len("_grad")]
                 from .registry import OP_DEFS

@@ -80,7 +80,7 @@ def conv3x3_bn_relu(x, w, scale=None, shift=None, relu=True):
     kern = functools.partial(_conv3x3_kernel, bh=bh, W=W, C=C, O=O,
                              relu=relu)
     return _pallas_call(
-        kern,
+        kern, "conv3x3_bn_relu",
         grid=(N, H // bh),
         in_specs=[
             pl.BlockSpec((1, H + 2, W + 2, C), lambda n, i: (n, 0, 0, 0)),

@@ -40,12 +40,17 @@ from ..registry import register_op
 _NEG = -1e30
 
 
-def _pallas_call(kernel, **kwargs):
+def _pallas_call(kernel, name, **kwargs):
     """``pl.pallas_call`` whose mode follows the platform the computation
     is LOWERED for, not the process's default backend: compiled by Mosaic
     for a TPU, interpreted for anything else (a ``CPUPlace`` executor on a
     TPU host included).  ``lax.platform_dependent`` lowers only the chosen
     branch, so a TPU executable never holds an interpreted kernel.
+
+    ``name`` is the kernel's stable name: XLA:TPU names the custom call's
+    instruction after it (``%flash_dq.3`` in a device trace, where an
+    unnamed kernel reads ``branch_1_fun``), and it is the named scope
+    around the call in every instruction's ``op_name``.
 
     Inside a ``shard_map`` the outputs vary over every mesh axis an input
     varies over; saying so in ``out_shape`` lets the kernels run under
@@ -57,11 +62,13 @@ def _pallas_call(kernel, **kwargs):
         shapes = jax.tree.map(
             lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, vma=vma),
             out_shape)
-        return jax.lax.platform_dependent(
-            *args,
-            tpu=pl.pallas_call(kernel, out_shape=shapes, **kwargs),
-            default=pl.pallas_call(kernel, out_shape=shapes,
-                                   interpret=True, **kwargs))
+        with jax.named_scope(name):
+            return jax.lax.platform_dependent(
+                *args,
+                tpu=pl.pallas_call(kernel, out_shape=shapes, name=name,
+                                   **kwargs),
+                default=pl.pallas_call(kernel, out_shape=shapes, name=name,
+                                       interpret=True, **kwargs))
     return call
 
 
@@ -329,7 +336,7 @@ def _flash_forward(q, k, v, bias, scale, *, with_lse=False,
         out_specs.append(_row_stat_spec(block_q))
         out_shape.append(jax.ShapeDtypeStruct((BH, S_q, 1), jnp.float32))
     res = _pallas_call(
-        kern,
+        kern, "flash_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
@@ -372,7 +379,7 @@ def _flash_backward(q, k, v, bias, scale, out, lse, g, causal=False):
         _row_stat_spec(block_q),                                # delta
     ]
     dq = _pallas_call(
-        dq_kern,
+        dq_kern, "flash_dq",
         grid=(BH, S_q // block_q),
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, block_q, D), lambda i, j: (i, j, 0)),
@@ -404,7 +411,7 @@ def _flash_backward(q, k, v, bias, scale, out, lse, g, causal=False):
         pl.BlockSpec((1, S_q, 1), lambda i, j: (i, 0, 0)),      # delta
     ]
     dk, dv = _pallas_call(
-        dkv_kern,
+        dkv_kern, "flash_dkv",
         grid=(BH, S_kv // block_k),
         in_specs=dkv_specs,
         out_specs=[pl.BlockSpec((1, block_k, D), lambda i, j: (i, j, 0)),
@@ -427,6 +434,7 @@ def _flash_backward(q, k, v, bias, scale, out, lse, g, causal=False):
         dbias = _pallas_call(
             functools.partial(_dbias_kernel, scale=scale,
                               block_k=block_k, causal=causal),
+            "flash_dbias",
             grid=(BH, S_q // block_q),
             in_specs=db_specs,
             out_specs=pl.BlockSpec((1, block_q, S_kv),
@@ -751,7 +759,7 @@ def _pallas_layer_norm(x2d, scale, bias, eps):
     while M % block_m and block_m > 1:
         block_m //= 2
     return _pallas_call(
-        functools.partial(_layer_norm_kernel, eps=eps),
+        functools.partial(_layer_norm_kernel, eps=eps), "fused_layer_norm",
         grid=(M // block_m,),
         in_specs=[pl.BlockSpec((block_m, D), lambda i: (i, 0)),
                   pl.BlockSpec((D,), lambda i: (0,)),

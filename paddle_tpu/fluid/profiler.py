@@ -10,47 +10,51 @@ like tools/timeline.py.
 import contextlib
 import json
 import os
+import re
 import threading
 import time
 
 from . import telemetry
 
-_events = []
-_enabled = [False]
-_lock = threading.Lock()
+_lock = threading.Lock()        # the bad-step verdict pool's
 _jax_trace_dir = [None]
+# a profiler session: RecordEvent spans are kept (in the telemetry ring)
+# while ``on``; stop_profiler exports those that began after ``since``
+_session = {"on": False, "since": 0}
 
 
 class RecordEvent:
-    """RAII span (platform/profiler.h:81)."""
+    """RAII span (platform/profiler.h:81): ``telemetry.span("user",
+    name=...)`` — in the jax.profiler trace always, in the ring (and so
+    in ``stop_profiler``'s Chrome trace and table) during a
+    ``start_profiler`` session."""
 
     def __init__(self, name):
         self.name = name
-        self.t0 = None
+        self._span = None
 
     def __enter__(self):
-        self.t0 = time.perf_counter_ns()
+        self._span = telemetry.span("user", name=self.name)
+        self._span.record = _session["on"]
+        self._span.__enter__()
         return self
 
     def __exit__(self, *exc):
-        if _enabled[0]:
-            t1 = time.perf_counter_ns()
-            with _lock:
-                _events.append((self.name, self.t0, t1,
-                                threading.get_ident()))
-        return False
+        return self._span.__exit__(*exc)
 
 
 record_event = RecordEvent
 
 
+def _user_spans():
+    """This session's RecordEvent spans, oldest first."""
+    return [ev for ev in telemetry.step_events()
+            if ev.get("kind") == "span" and ev.get("span") == "user"
+            and ev["ts_ns"] >= _session["since"]]
+
+
 def start_profiler(state="All", trace_dir=None):
-    _enabled[0] = True
-    with _lock:
-        # under _lock: DataLoader worker threads append from
-        # RecordEvent.__exit__ concurrently — an unlocked clear() races
-        # them (list.clear vs append is not atomic as a pair)
-        _events.clear()
+    _session.update(on=True, since=time.perf_counter_ns())
     if trace_dir is not None:
         import jax
         jax.profiler.start_trace(trace_dir)
@@ -58,25 +62,25 @@ def start_profiler(state="All", trace_dir=None):
 
 
 def stop_profiler(sorted_key=None, profile_path="/tmp/profile"):
-    _enabled[0] = False
+    _session["on"] = False
     if _jax_trace_dir[0] is not None:
         import jax
         jax.profiler.stop_trace()
         _jax_trace_dir[0] = None
     # chrome trace export (tools/timeline.py analogue)
     trace = {"traceEvents": []}
-    with _lock:
-        for name, t0, t1, tid in _events:
-            trace["traceEvents"].append({
-                "name": name, "ph": "X", "ts": t0 / 1000.0,
-                "dur": (t1 - t0) / 1000.0, "pid": os.getpid(), "tid": tid,
-                "cat": "host"})
+    user = _user_spans()
+    for ev in user:
+        trace["traceEvents"].append({
+            "name": ev["name"], "ph": "X", "ts": ev["ts_ns"] / 1000.0,
+            "dur": ev["dur_ns"] / 1000.0, "pid": os.getpid(),
+            "tid": ev["tid"], "cat": "host"})
     # executor step-events interleave on their own track: same
     # perf_counter_ns clock as the host spans, so "why was step N slow"
     # lines up a dispatch against the host work around it
     for ev in telemetry.step_events():
         ts = ev.get("ts_ns")
-        if ts is None:
+        if ts is None or ev.get("span") == "user":
             continue
         if ev.get("kind") == "span":     # timed region (FLAGS_trace_spans)
             name = "span:%s" % ev.get("span", "?")
@@ -101,10 +105,9 @@ def stop_profiler(sorted_key=None, profile_path="/tmp/profile"):
             json.dump(trace, f, default=telemetry._json_default)
     # aggregated table, like the reference's PrintProfiler
     agg = {}
-    with _lock:
-        for name, t0, t1, _ in _events:
-            tot, cnt = agg.get(name, (0.0, 0))
-            agg[name] = (tot + (t1 - t0) / 1e6, cnt + 1)
+    for ev in user:
+        tot, cnt = agg.get(ev["name"], (0.0, 0))
+        agg[ev["name"]] = (tot + ev["dur_ns"] / 1e6, cnt + 1)
     if agg:
         rows = sorted(agg.items(), key=lambda kv: -kv[1][0])
         print("%-40s %10s %8s" % ("Event", "total_ms", "calls"))
@@ -189,6 +192,40 @@ def device_profile_dir():
     """Directory the current/last FLAGS_device_profile trace wrote to
     (None if no capture started)."""
     return _device_profile["dir"]
+
+
+# -- names of the compiled step, for readers of a device trace ---------------
+# A device trace's ``XLA Ops`` events carry an instruction's HLO text and no
+# metadata; the scopes the lowering puts around every Fluid op (``role_fwd``
+# / ``role_bwd`` / ``role_opt`` outside ``fluid_<op type>``, lowering.py;
+# the Pallas kernels' names, ops/pallas_ops.py) live in the compiled HLO's
+# ``metadata={op_name=...}``.  The executor notes the executable its
+# introspection (``compiled_hlo`` / ``_cost`` / ``_memory``) handed out
+# last; nothing is read from it until ``step_scopes()`` is asked.
+
+_HLO_OP_NAME = re.compile(
+    r'^\s*(?:ROOT )?%?(\S+) = .*metadata=\{[^}]*op_name="([^"]*)"')
+_step_executable = {"executable": None, "scopes": None}
+
+
+def note_step_executable(executable):
+    if executable is not _step_executable["executable"]:
+        _step_executable.update(executable=executable, scopes=None)
+
+
+def step_scopes():
+    """``{instruction name: op_name}`` of the executable the executor's
+    introspection handed out last, parsed from its optimized HLO text on
+    first asking; ``{}`` before any introspection call."""
+    st = _step_executable
+    if st["scopes"] is None and st["executable"] is not None:
+        scopes = {}
+        for line in st["executable"].as_text().splitlines():
+            m = _HLO_OP_NAME.match(line)
+            if m:
+                scopes[m.group(1)] = m.group(2)
+        st["scopes"] = scopes
+    return st["scopes"] or {}
 
 
 # -- host-sync accounting ----------------------------------------------------
@@ -448,9 +485,7 @@ def reset_benchmark_stats():
 
 def reset_profiler():
     """Drop collected span data (reference profiler.py reset_profiler)."""
-    with _lock:
-        # same race as start_profiler: worker threads may be appending
-        _events.clear()
+    _session["since"] = time.perf_counter_ns()
     reset_benchmark_stats()
 
 

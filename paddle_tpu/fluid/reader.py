@@ -26,7 +26,7 @@ from . import framework
 from . import preemption
 from . import telemetry
 from .data_feeder import DataFeeder
-from .executor import _device_for_place, TPUPlace
+from .executor import _device_for_place, feed_nbytes, TPUPlace
 from .core_shim import EOFException
 
 # input-pipeline telemetry (docs/observability.md): batches produced by
@@ -178,6 +178,7 @@ class FeedRing:
         self._slots = threading.Semaphore(self._depth)
         self._closed = threading.Event()
         self._out = None              # window handed out, freed on next pull
+        self._pulled = 0              # windows handed out so far
         self._staged_ready = 0        # real windows in _ready (gauge src)
         self._occ_lock = threading.Lock()   # += / -= cross two threads
         self._stage_s = 0.0           # producer staging wall (fill + put)
@@ -215,11 +216,13 @@ class FeedRing:
                 if not acquired:
                     return
                 t0 = time.perf_counter()
-                # feed_stage span: the device_put staging work, on the
-                # producer thread's own track in tools/pod_trace.py (no
-                # phase arg — the progress stamp below stays AFTER the
-                # put: a stamp means COMPLETED staging work)
-                with telemetry.span("feed_stage"):
+                # fluid.feed_stage: the device_put staging work, on the
+                # producer thread's own line of a trace (no phase arg —
+                # the progress stamp below stays AFTER the put: a stamp
+                # means COMPLETED staging work); ``batch`` is the one the
+                # consumer's fluid.feed_wait carries for this window
+                with telemetry.span("feed_stage", batch=staged,
+                                    bytes=feed_nbytes(host)):
                     dev = self._put(host)
                 self._stage_s += time.perf_counter() - t0
                 # hang-detection stamp: each window staged is forward
@@ -270,25 +273,23 @@ class FeedRing:
     def __next__(self):
         self._recycle()
         t0 = time.perf_counter()
-        while True:
-            if self._closed.is_set():
-                raise StopIteration
-            try:
-                item = self._ready.get(timeout=0.1)
-                break
-            except queue.Empty:
-                if self._stopping():
-                    # preemption/external stop drained the producer —
-                    # never park on a queue nothing will fill
+        # fluid.feed_wait: the consumer's starvation window, the region
+        # _record_wait accounts
+        with telemetry.span("feed_wait", batch=self._pulled):
+            while True:
+                if self._closed.is_set():
                     raise StopIteration
+                try:
+                    item = self._ready.get(timeout=0.1)
+                    break
+                except queue.Empty:
+                    if self._stopping():
+                        # preemption/external stop drained the producer
+                        # — never park on a queue nothing will fill
+                        raise StopIteration
         wait = time.perf_counter() - t0
         self._wait_s += wait
         _record_wait(wait, pending=not isinstance(item, _EndSentinel))
-        # post-hoc feed_wait span from the already-measured wait (the
-        # consumer starvation window; perf_counter and perf_counter_ns
-        # share a clock, so t0 converts directly)
-        telemetry.record_span("feed_wait", int(t0 * 1e9),
-                              int(wait * 1e9))
         if isinstance(item, _EndSentinel):
             # exhausted: further __next__ calls must keep raising
             # StopIteration (iterator protocol — a second epoch loop
@@ -319,6 +320,7 @@ class FeedRing:
             _m_overlap.set(max(0.0, min(
                 1.0, 1.0 - self._wait_s / self._stage_s)))
         self._out = item
+        self._pulled += 1
         return item[0]
 
     def close(self):
@@ -374,6 +376,7 @@ class GeneratorLoader:
         self._queue = None
         self._thread = None
         self._stop_event = None
+        self._pulled = 0          # batches next_feed handed over since start
         # set by Executor.run on the first program-bound pull: when no
         # explicit places were given, the producer thread device_puts
         # subsequent batches to the CONSUMING executor's device, so the
@@ -548,6 +551,7 @@ class GeneratorLoader:
 
         self._queue = q
         self._stop_event = stop
+        self._pulled = 0
         self._thread = threading.Thread(target=worker, daemon=True)
         self._thread.start()
 
@@ -577,8 +581,10 @@ class GeneratorLoader:
     def reset(self):
         self._stop_worker()
 
-    def next_feed(self):
-        """Called by Executor.run when no explicit feed is given."""
+    def next_feed(self, step=None):
+        """Called by Executor.run when no explicit feed is given (with
+        the ``step`` the feed is for, which the ``fluid.feed_wait`` span
+        carries beside the index of the batch handed over)."""
         if self._queue is None:
             raise RuntimeError(
                 "DataLoader not started: call loader.start() before "
@@ -587,7 +593,9 @@ class GeneratorLoader:
         # a preemption stop request drains the PRODUCER without a
         # sentinel (the consumer may be gone); a consumer that is still
         # here must not block forever on the dead queue — end the pass
-        item = stop_aware_get(self._queue)
+        labels = {} if step is None else {"step": int(step)}
+        with telemetry.span("feed_wait", batch=self._pulled, **labels):
+            item = stop_aware_get(self._queue)
         if item is QUEUE_DRAINED:
             self._queue = None
             self._thread = None
@@ -618,6 +626,7 @@ class GeneratorLoader:
                 ) from item.err
             raise EOFException(
                 "pass end: there is no data in the DataLoader queue")
+        self._pulled += 1
         return item
 
 

@@ -41,6 +41,8 @@ import os
 import threading
 import time
 
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
 from . import flags
 
 # ONE lock for registry + ring mutation: every record is a handful of
@@ -511,46 +513,52 @@ def last_progress_age_s():
 
 
 # ---------------------------------------------------------------------------
-# Spans (pod-level tracing — docs/observability.md "Pod-level tracing")
+# Spans (docs/observability.md "Spans", "Pod-level tracing")
 # ---------------------------------------------------------------------------
-# A span is one timed region recorded into the SAME step-event ring/JSONL
-# as dispatch records, with ``kind="span"`` so per-step aggregators skip
-# it.  Spans are emitted at the PR 15 progress-stamp boundaries (dispatch,
-# barrier/consensus entry, feed-ring staging, checkpoint phases) so the
-# instrumentation lives in one place: ``span(kind, phase=...)`` stamps
-# progress on entry and, when tracing is on, records the region on exit.
+# ``span(kind, phase=None, **labels)`` is the ONE way the program times a
+# region.  It writes to two places:
+#
+# - **the profiler's trace, always.**  On entry the span enters a
+#   ``jax.profiler.TraceAnnotation("fluid." + kind, **labels)`` (kind
+#   ``"step"``: a ``StepTraceAnnotation``, the per-step marker the
+#   profiler's own tools group by), so whoever runs ``jax.profiler`` over
+#   the process — ``FLAGS_device_profile``, a benchmark, TensorBoard's
+#   capture — finds the program's regions on the SAME clock as the device
+#   trace's ``XLA Ops`` lines, with no flag of ours set.  With no profiler
+#   session live the annotation builds no name and records nothing (under
+#   a microsecond, measured).
+# - **the step-event ring/JSONL, under ``FLAGS_trace_spans`` /
+#   ``enable_spans()``**, as a ``kind="span"`` record beside the dispatch
+#   records, for ``tools/pod_trace.py`` to merge ranks by.  Off (the
+#   default) nothing is recorded and no clock is read.
 #
 # Field schema of a span record:
 #   kind     "span" (ring/JSONL discriminator)
-#   span     the span kind ("dispatch" | "barrier" | "consensus" |
-#            "feed_stage" | "feed_wait" | "checkpoint" | "ckpt" | ...)
+#   span     the span kind ("step" | "dispatch" | "enqueue" | "compile" |
+#            "feed_stage" | "feed_wait" | "barrier" | "consensus" |
+#            "checkpoint" | "ckpt" | "user" | ...)
 #   ts_ns    perf_counter_ns at entry (process-local clock — interleaves
-#            with this process's dispatch records and profiler spans)
+#            with this process's dispatch records)
 #   dur_ns   exit - entry on the same clock
 #   wall_ns  time_ns() at entry — the ONLY cross-process-comparable
 #            stamp.  tools/pod_trace.py derives each rank's
 #            perf_counter->wall offset from it to merge N per-process
 #            streams onto one timeline and compute barrier-entry skew
 #            (straggler attribution).
-#   k        0 (spans are not dispatches)
+#   tid      threading.get_ident() of the recording thread
+#   k        0 unless the caller labels it (spans are not dispatches)
 # plus any caller labels (e.g. ``name`` for named barriers).
 #
-# Off (the default) ``span()`` costs a progress stamp (itself a no-op
-# unless the watchdog/a hook armed it) and records NOTHING: the hot path
-# stays bit-exact with zero added host syncs.  On: two clock reads on
-# entry, one on exit, one ring append.  Enable via ``FLAGS_trace_spans``
-# or ``enable_spans()``.
-#
-# The progress stamp fires BEFORE the entry clocks are read.  That
-# ordering is what makes injected-straggler tests honest: a thread a
-# ``faultinject.hang_at`` hook parks at the boundary gets a LATE wall_ns
-# entry stamp, exactly like a rank that genuinely arrived late.
+# ``phase`` (when given) stamps progress on entry, BEFORE the clocks are
+# read.  That ordering is what makes injected-straggler tests honest: a
+# thread a ``faultinject.hang_at`` hook parks at the boundary gets a LATE
+# wall_ns entry stamp, exactly like a rank that genuinely arrived late.
 _spans = {"enabled": False}
 
 
 def enable_spans(on=True):
-    """Programmatic switch for span recording (the env path is
-    ``FLAGS_trace_spans``)."""
+    """Programmatic switch for span RING records (the env path is
+    ``FLAGS_trace_spans``); the profiler annotation needs no switch."""
     _spans["enabled"] = bool(on)
 
 
@@ -559,53 +567,50 @@ def spans_enabled():
 
 
 class _SpanCtx:
-    __slots__ = ("kind", "phase", "labels", "_t0", "_w0", "_on")
+    __slots__ = ("kind", "phase", "labels", "record", "_ann", "_t0", "_w0")
 
     def __init__(self, kind, phase, labels):
         self.kind, self.phase, self.labels = kind, phase, labels
-        self._on = False
+        # ring record wanted whatever the flag says (profiler.RecordEvent
+        # inside a start_profiler session)
+        self.record = False
+        self._t0 = None
+
+    def label(self, **labels):
+        """Labels learned inside the region (a staged batch's bytes)."""
+        self.labels.update(labels)
+        self._ann.set_metadata(**labels)
 
     def __enter__(self):
         if self.phase is not None:
             record_progress(self.phase)   # BEFORE the clocks — see above
-        if _spans["enabled"] or flags.get_flag("trace_spans"):
-            self._on = True
+        cls = StepTraceAnnotation if self.kind == "step" else TraceAnnotation
+        self._ann = cls("fluid." + self.kind, **self.labels)
+        self._ann.__enter__()
+        if self.record or _spans["enabled"] or flags.get_flag("trace_spans"):
             self._w0 = time.time_ns()
             self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        if self._on:
+        if self._t0 is not None:
             t1 = time.perf_counter_ns()
             self.labels.setdefault("k", 0)
             record_step_event(kind="span", span=self.kind,
                               ts_ns=self._t0, dur_ns=t1 - self._t0,
-                              wall_ns=self._w0, **self.labels)
+                              wall_ns=self._w0, tid=threading.get_ident(),
+                              **self.labels)
+        self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
 def span(kind, phase=None, **labels):
-    """Context manager timing one region as a span record.  ``phase``
-    (when given) is stamped via :func:`record_progress` on entry, so a
-    call site that previously stamped progress keeps exactly that
-    behavior with tracing off."""
+    """Context manager timing one region: a ``fluid.<kind>`` annotation
+    in the profiler's trace, and a span record in the ring when span
+    records are on.  ``phase`` (when given) is stamped via
+    :func:`record_progress` on entry.  Label values are host scalars or
+    short strings."""
     return _SpanCtx(kind, phase, labels)
-
-
-def record_span(kind, ts_ns, dur_ns, wall_ns=None, **labels):
-    """Post-hoc span record for regions whose timing was already
-    measured (dispatch, reader feed waits).  ``wall_ns`` defaults to
-    the entry wall time derived from ``ts_ns``'s perf_counter stamp
-    (now_wall - (now_perf - ts_ns)) — exact regardless of how long
-    after the region this is called."""
-    if not (_spans["enabled"] or flags.get_flag("trace_spans")):
-        return
-    ts_ns, dur_ns = int(ts_ns), int(dur_ns)
-    if wall_ns is None:
-        wall_ns = time.time_ns() - (time.perf_counter_ns() - ts_ns)
-    labels.setdefault("k", 0)
-    record_step_event(kind="span", span=kind, ts_ns=ts_ns,
-                      dur_ns=dur_ns, wall_ns=int(wall_ns), **labels)
 
 
 # Consumer data-wait accounting: reader.py/FeedRing record each

@@ -147,10 +147,12 @@ def test_dygraph_conv3d_transpose_and_tree_conv():
 
 def test_reset_profiler():
     from paddle_tpu.fluid import profiler
+    profiler.start_profiler()
     with profiler.RecordEvent("evt"):
         pass
     profiler.reset_profiler()
-    assert profiler._events == []
+    trace = profiler.stop_profiler(profile_path=None)
+    assert not [e for e in trace["traceEvents"] if e.get("cat") == "host"]
 
 
 def test_utils_ploter_and_image(tmp_path):

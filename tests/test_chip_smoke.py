@@ -105,12 +105,16 @@ def test_compile_cache_dir_comes_from_the_environment(monkeypatch):
 
     class Dev:
         platform = "cpu"
+    # whatever cache serves, its key covers the scope names (metadata)
+    # (scopes and the lowering rule's line, not the caller's stack)
+    keyed = [("jax_compilation_cache_include_metadata_in_key", True),
+             ("jax_traceback_in_locations_limit", 1)]
     executor.maybe_enable_compile_cache(Dev)
-    assert updates == []
+    assert updates == keyed
     Dev.platform = "tpu"
     executor.maybe_enable_compile_cache(Dev)
-    assert updates == [("jax_compilation_cache_dir",
-                        os.path.join(REPO, ".jax_cache"))]
+    assert updates[2:] == keyed + [("jax_compilation_cache_dir",
+                                    os.path.join(REPO, ".jax_cache"))]
 
 
 def test_launcher_refuses_several_plain_processes_on_a_tpu_host(

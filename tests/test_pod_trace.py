@@ -95,25 +95,6 @@ def test_spans_off_bit_exact_no_syncs_no_records(tmp_path):
     assert any(e["span"] == "dispatch" for e in spans)
 
 
-def test_record_span_wall_default_is_entry_anchored():
-    """record_span without an explicit wall_ns back-derives the ENTRY
-    wall clock (now - elapsed-since-ts), not the call-time wall — the
-    post-hoc dispatch span stays alignable."""
-    import time
-    telemetry.reset_all()
-    telemetry.enable_spans()
-    try:
-        t0 = time.perf_counter_ns()
-        w0 = time.time_ns()
-        time.sleep(0.05)
-        telemetry.record_span("dispatch", t0, 1000, step=1)
-    finally:
-        telemetry.enable_spans(False)
-    ev = [e for e in telemetry.step_events()
-          if e.get("kind") == "span"][-1]
-    assert abs(ev["wall_ns"] - w0) < 25_000_000   # ±25 ms of true entry
-
-
 def test_set_process_index_resuffixes_open_jsonl_stream(tmp_path):
     """Identity change while the JSONL handle is open (elastic resize
     re-init) must close + re-suffix the stream: records never keep

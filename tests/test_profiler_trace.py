@@ -1,7 +1,8 @@
 """Chrome-trace export coverage (profiler.py stop_profiler): emitted
 traceEvents schema (phase, ts/dur in microseconds, tid propagation), the
 file landing at profile_path, the aggregation-table ordering, the
-step-event interleave track, and the locked _events lifecycle."""
+step-event interleave track, and the session lifecycle of RecordEvent
+spans (they live in the telemetry ring)."""
 
 import json
 import os
@@ -142,20 +143,25 @@ def test_trace_export_survives_numpy_fields(tmp_path):
     telemetry.reset_step_events()
 
 
-def test_start_profiler_clears_previous_events_under_lock():
-    """Satellite fix: start/reset clear _events while holding _lock so
-    concurrent RecordEvent appends from worker threads cannot race the
-    clear; a fresh session never inherits old spans."""
+def test_start_profiler_clears_previous_events():
+    """A fresh session never inherits old spans, and a RecordEvent
+    outside any session leaves no ring record at all."""
+    telemetry.reset_step_events()
+    with profiler.RecordEvent("outside"):
+        pass
+    assert telemetry.step_events() == []
     profiler.start_profiler()
     with profiler.RecordEvent("stale"):
         pass
     profiler.stop_profiler(profile_path=None)
     profiler.start_profiler()
-    assert profiler._events == []
     with profiler.RecordEvent("fresh"):
         pass
     trace = profiler.stop_profiler(profile_path=None)
     names = [e["name"] for e in _host_events(trace)]
     assert names == ["fresh"]
+    profiler.start_profiler()
+    with profiler.RecordEvent("dropped"):
+        pass
     profiler.reset_profiler()
-    assert profiler._events == []
+    assert _host_events(profiler.stop_profiler(profile_path=None)) == []

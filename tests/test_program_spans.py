@@ -1,0 +1,315 @@
+"""The program's own measurement (PR 26): ``telemetry.span`` regions as
+``fluid.*`` events of a ``jax.profiler`` trace with no flag set, the compile
+counters fed by the executor's ``jax.monitoring`` listener, and the names the
+lowering leaves in the compiled step (op role scopes, Pallas kernel names).
+"""
+
+import glob
+import itertools
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu.fluid as fluid
+from paddle_tpu.fluid import profiler, telemetry
+from paddle_tpu.fluid.ops import pallas_ops
+
+SPANS = ("fluid.step", "fluid.feed_wait", "fluid.feed_stage",
+         "fluid.dispatch", "fluid.enqueue", "fluid.compile")
+
+
+def _train_program(with_loader):
+    main, startup = fluid.Program(), fluid.Program()
+    loader = None
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        x = fluid.layers.data(name="x", shape=[8], dtype="float32")
+        y = fluid.layers.data(name="y", shape=[1], dtype="float32")
+        if with_loader:
+            loader = fluid.DataLoader.from_generator(
+                feed_list=[x, y], capacity=2, iterable=False)
+        pred = fluid.layers.fc(x, size=1)
+        loss = fluid.layers.mean(fluid.layers.square_error_cost(pred, y))
+        fluid.optimizer.SGD(0.01).minimize(loss)
+    return main, startup, loss, loader
+
+
+def _batches(n):
+    rng = np.random.default_rng(0)
+    return [{"x": rng.standard_normal((4, 8), dtype=np.float32),
+             "y": rng.standard_normal((4, 1), dtype=np.float32)}
+            for _ in range(n)]
+
+
+def _fluid_events(trace_dir):
+    """``[(line index, name, start ns, end ns, labels)]`` of the trace's
+    ``fluid.*`` events; a line of the host plane is a thread."""
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                            recursive=True))[-1]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for i, line in enumerate(plane.lines):
+            out += [(i, e.name, e.start_ns, e.start_ns + e.duration_ns,
+                     dict(e.stats))
+                    for e in line.events if e.name.startswith("fluid.")]
+    return out
+
+
+@pytest.fixture(scope="module")
+def loader_trace(tmp_path_factory):
+    """Three loader-fed steps of a tiny training program under
+    ``jax.profiler``, with no flag of ours set."""
+    trace_dir = str(tmp_path_factory.mktemp("trace"))
+    main, startup, loss, loader = _train_program(with_loader=True)
+    pool = _batches(2)
+    loader.set_batch_generator(lambda: itertools.cycle(pool))
+    telemetry.reset_step_events()
+    compiles = telemetry.registry().counter("xla_backend_compiles_total")
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        before = compiles.value(why="dispatch")
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
+        try:
+            loader.start()
+            for _ in range(3):
+                out = exe.run(main, fetch_list=[loss], return_numpy=False)
+            jax.block_until_ready(out)
+        finally:
+            jax.profiler.stop_trace()
+            loader.reset()
+    return {"events": _fluid_events(trace_dir),
+            "ring": telemetry.step_events(),
+            "dispatch_compiles": compiles.value(why="dispatch") - before}
+
+
+def _named(events, name):
+    return sorted((e for e in events if e[1] == name), key=lambda e: e[2])
+
+
+def _inside(inner, outer):
+    return inner[0] == outer[0] and outer[2] <= inner[2] and \
+        inner[3] <= outer[3]
+
+
+def test_step_spans_nest_on_the_consumer_thread(loader_trace):
+    events = loader_trace["events"]
+    steps = _named(events, "fluid.step")
+    assert len(steps) == 3
+    assert [s[4]["step_num"] for s in steps] == \
+        [steps[0][4]["step_num"] + i for i in range(3)]
+    for name in ("fluid.feed_wait", "fluid.dispatch", "fluid.enqueue"):
+        spans = _named(events, name)
+        assert len(spans) == 3, name
+        for step, span in zip(steps, spans):
+            assert _inside(span, step), (name, span, step)
+            assert span[4]["step"] == step[4]["step_num"]
+    for dispatch, enqueue in zip(_named(events, "fluid.dispatch"),
+                                 _named(events, "fluid.enqueue")):
+        assert _inside(enqueue, dispatch)
+    assert [w[4]["batch"] for w in _named(events, "fluid.feed_wait")] == \
+        [0, 1, 2]
+
+
+def test_feed_stage_is_on_the_worker_thread_and_shares_a_batch(loader_trace):
+    events = loader_trace["events"]
+    stages = _named(events, "fluid.feed_stage")
+    consumer = _named(events, "fluid.step")[0][0]
+    assert stages and all(s[0] != consumer for s in stages)
+    waited = {w[4]["batch"] for w in _named(events, "fluid.feed_wait")}
+    assert waited <= {s[4]["batch"] for s in stages}
+    assert all(s[4]["bytes"] == 4 * 8 * 4 + 4 * 4 for s in stages
+               if s[4]["batch"] in waited)
+
+
+def test_a_fresh_executable_shows_one_dispatch_compile(loader_trace):
+    compiles = _named(loader_trace["events"], "fluid.compile")
+    assert len(compiles) == 1
+    assert compiles[0][4]["why"] == "dispatch"
+    assert re.fullmatch(r"[0-9a-f]+:k1", compiles[0][4]["sig"])
+    first = _named(loader_trace["events"], "fluid.enqueue")[0]
+    assert _inside(compiles[0], first)
+    fresh = [d[4]["fresh"] for d in
+             _named(loader_trace["events"], "fluid.dispatch")]
+    assert [bool(f) for f in fresh] == [True, False, False]
+    assert loader_trace["dispatch_compiles"] >= 1
+
+
+def test_no_span_record_in_the_ring_with_span_records_off(loader_trace):
+    assert not telemetry.spans_enabled()
+    assert loader_trace["ring"]      # the dispatch records are there
+    assert not [e for e in loader_trace["ring"] if e.get("kind") == "span"]
+
+
+def test_span_records_carry_labels_and_thread_when_on():
+    telemetry.reset_step_events()
+    telemetry.enable_spans()
+    try:
+        with telemetry.span("feed_stage", batch=3) as staging:
+            staging.label(bytes=17)
+    finally:
+        telemetry.enable_spans(False)
+    (rec,) = telemetry.step_events()
+    assert rec["kind"] == "span" and rec["span"] == "feed_stage"
+    assert rec["batch"] == 3 and rec["bytes"] == 17 and rec["k"] == 0
+    assert rec["dur_ns"] >= 0 and rec["wall_ns"] > 0 and rec["tid"]
+    telemetry.reset_step_events()
+
+
+def test_recompile_of_a_step_that_has_run_is_counted():
+    """jit compiles the same step again when a feed arrives committed to
+    the device: ``exe.compile_count()`` does not see it (one Fluid-level
+    build), ``xla_backend_compiles_total{why=recompile}`` does."""
+    main, startup, loss, _ = _train_program(with_loader=False)
+    compiles = telemetry.registry().counter("xla_backend_compiles_total")
+    seconds = telemetry.registry().counter("xla_compile_seconds_total")
+    (batch,) = _batches(1)
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        before = {w: compiles.value(why=w)
+                  for w in ("dispatch", "recompile", "introspection")}
+        trace_s = seconds.value(phase="trace", why="dispatch")
+        exe.run(main, feed=batch, fetch_list=[loss])
+        built = exe.compile_count()
+        assert compiles.value(why="dispatch") == before["dispatch"] + 1
+        assert seconds.value(phase="trace", why="dispatch") > trace_s
+        exe.run(main, feed=batch, fetch_list=[loss])
+        assert compiles.value(why="recompile") == before["recompile"]
+        committed = {k: jax.device_put(v, jax.devices("cpu")[0])
+                     for k, v in batch.items()}
+        exe.run(main, feed=committed, fetch_list=[loss])
+        assert compiles.value(why="recompile") == before["recompile"] + 1
+        assert exe.compile_count() == built
+        exe.compiled_hlo(main, feed=batch, fetch_list=[loss])
+        assert compiles.value(why="introspection") == \
+            before["introspection"] + 1
+
+
+def test_the_compile_cache_keys_on_scopes_and_not_on_the_callers_stack():
+    """Scope names are metadata; a cache keyed without them serves another
+    checkout's names, and one keyed on whole Python stacks misses whenever
+    the same step is lowered from another call site (PERF.md, PR 26)."""
+    fluid.Executor(fluid.CPUPlace())
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
+
+    def fresh_step():       # a new function each time: traced anew
+        def step(x):
+            with jax.named_scope("role_fwd"):
+                return jnp.tanh(x) * 2
+        return step
+
+    def from_here():
+        return jax.jit(fresh_step()).lower(jnp.ones(4)).as_text(
+            debug_info=True)
+
+    def from_deeper():
+        return (lambda: from_here())()
+
+    # what the key is computed from: the module with its locations
+    assert from_here() == from_deeper()
+    assert "role_fwd" in from_here()
+    limit = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 10)
+    try:
+        assert from_here() != from_deeper()     # JAX's default: the stack
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", limit)
+
+
+FLUID_SCOPE = re.compile(r"fluid_[A-Za-z0-9_]+")
+
+
+def test_every_instruction_of_a_step_sits_under_its_ops_role():
+    main, startup, loss, _ = _train_program(with_loader=False)
+    (batch,) = _batches(1)
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        hlo = exe.compiled_hlo(main, feed=batch, fetch_list=[loss])
+    scopes = profiler.step_scopes()
+    assert scopes and all("%" + name in hlo or name in hlo
+                          for name in scopes)
+    seen = set()
+    for op_name in scopes.values():
+        # an op_name may join several ops' names with ';'
+        for part in op_name.split(";"):
+            op = FLUID_SCOPE.search(part)
+            if op is None:
+                continue
+            role = re.search(r"role_(fwd|bwd|opt)", part)
+            assert role, part
+            # the role is the scope right outside the op's
+            assert "/%s/%s" % (role.group(0), op.group(0)) in part, part
+            want = "role_bwd" if op.group(0).endswith("_grad") else \
+                "role_opt" if op.group(0) == "fluid_sgd" else "role_fwd"
+            assert role.group(0) == want, part
+            seen.add(role.group(0))
+    assert seen == {"role_fwd", "role_bwd", "role_opt"}
+    # what the benchmark and costmodel.op_attribution read is what it was:
+    # the first fluid_ match of an op_name is the op's own scope
+    first = {FLUID_SCOPE.search(v).group(0) for v in scopes.values()
+             if FLUID_SCOPE.search(v)}
+    assert {"fluid_mul", "fluid_mul_grad", "fluid_sgd",
+            "fluid_mean"} <= first
+    assert not [f for f in first if f.startswith("fluid_role")]
+
+
+def test_role_scope_of_every_op_role():
+    from paddle_tpu.fluid.framework import OpRole
+    from paddle_tpu.fluid.lowering import role_scope
+
+    assert role_scope(OpRole.Forward) == "role_fwd"
+    assert role_scope(OpRole.Forward | OpRole.Loss) == "role_fwd"
+    assert role_scope(OpRole.Backward) == "role_bwd"
+    assert role_scope(OpRole.Backward | OpRole.Loss) == "role_bwd"
+    assert role_scope(OpRole.Optimize) == "role_opt"
+    assert role_scope(OpRole.LRSched) == "role_opt"
+    assert role_scope(OpRole.Optimize | OpRole.LRSched) == "role_opt"
+
+
+def _flash_loss(q, k, v):
+    return pallas_ops.flash_attention(q, k, v, None, 0.125).sum()
+
+
+def _flash_args(sharding=None):
+    shape = jax.ShapeDtypeStruct((2, 256, 64), jnp.bfloat16,
+                                 sharding=sharding)
+    return shape, shape, shape
+
+
+def test_flash_kernels_are_named_in_op_names():
+    text = jax.jit(jax.grad(_flash_loss, argnums=(0, 1, 2))).lower(
+        *_flash_args()).as_text(debug_info=True)
+    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert "/%s/" % name in text, name
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_flash_kernels_name_their_tpu_instructions(one_chip):
+    """Compiled for a v5e (no chip needed): XLA:TPU names each Mosaic
+    custom call's instruction after the kernel, which is what a device
+    trace's ``XLA Ops`` events show."""
+    text = jax.jit(jax.grad(_flash_loss, argnums=(0, 1, 2))).lower(
+        *_flash_args(one_chip)).compile().as_text()
+    calls = re.findall(r"%(\w+?)(?:\.\d+)? = [^\n]*"
+                       r'custom_call_target="tpu_custom_call"', text)
+    assert sorted(calls) == ["flash_dkv", "flash_dq", "flash_fwd"], calls
