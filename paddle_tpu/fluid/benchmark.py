@@ -48,7 +48,7 @@ _DEFAULT_OUTPUTS = {
     "softmax_with_cross_entropy": {"Softmax": 1, "Loss": 1},
     "dropout": {"Out": 1, "Mask": 1},
     "lookup_table": {"Out": 1},
-    "fused_attention": {"Out": 1},
+    "fused_attention": {"Out": 1, "LSE": 1},
     "switch_moe": {"Out": 1, "AuxLoss": 1},
     "pool2d": {"Out": 1},
     "relu": {"Out": 1},

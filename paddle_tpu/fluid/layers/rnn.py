@@ -278,15 +278,25 @@ def fused_attention(q, k, v, attn_bias=None, scale=1.0, causal=False,
     (static block indices — no [S, S] mask tensor).  ``dropout_prob``
     applies upscale_in_train dropout to the attention probabilities
     (routes through the exact composition — flash has no in-kernel
-    RNG; clone(for_test=True) flips ``is_test`` and disables it)."""
+    RNG; clone(for_test=True) flips ``is_test`` and disables it).
+
+    The op's second output ``LSE`` (float32 [B, H, S_q], the softmax's
+    logsumexp rows; lane-dense, never [..., 1]) is the flash kernels'
+    residual: written by the forward kernel of a training program and read
+    by ``fused_attention_grad`` in place of a second forward run, as
+    ``batch_norm`` hands on ``SavedMean`` and ``dropout`` its ``Mask``."""
     helper = LayerHelper("fused_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
     out.shape = q.shape
+    lse = helper.create_variable_for_type_inference("float32",
+                                                    stop_gradient=True)
+    if q.shape:
+        lse.shape = tuple(q.shape[:3])
     inputs = {"Q": [q], "K": [k], "V": [v]}
     if attn_bias is not None:
         inputs["BiasQK"] = [attn_bias]
     helper.append_op("fused_attention", inputs=inputs,
-                     outputs={"Out": [out]},
+                     outputs={"Out": [out], "LSE": [lse]},
                      attrs={"scale": float(scale),
                             "causal": bool(causal),
                             "attn_dropout": float(dropout_prob),

@@ -344,7 +344,7 @@ class _FwdShim:
         return name in self.attrs
 
 
-def generic_grad_lower(ctx, op):
+def generic_grad_lower(ctx, op, residual_slots=()):
     """Default grad kernel: replay the forward lowering under ``jax.vjp``.
 
     The grad OpDesc (built by ``backward.append_backward``) carries the
@@ -352,9 +352,16 @@ def generic_grad_lower(ctx, op):
     rebuild the forward as a pure function of its differentiable inputs,
     vjp it, and seed the cotangents with the output grads present in the
     environment (zeros for outputs nobody differentiated).
+
+    ``residual_slots`` names forward outputs that exist only to feed a
+    custom grad lowering (``fused_attention``'s ``LSE``): the replayed
+    forward is built without them, so it neither writes nor is asked for
+    them.
     """
     fwd_inputs = op.attr("__fwd_inputs__")
-    fwd_outputs = op.attr("__fwd_outputs__")
+    fwd_outputs = {slot: names
+                   for slot, names in op.attr("__fwd_outputs__").items()
+                   if slot not in residual_slots}
     fwd_type = op.type[:-len("_grad")]
     fwd_def = get_op_def(fwd_type)
     fwd_attrs = {k: v for k, v in op.attrs.items()

@@ -9,8 +9,17 @@ path is one fused kernel).  Backward is the tiled FlashAttention-2 pair
 saved logsumexp — [S, S] never exists in HBM in either direction for
 dq/dk/dv.  Bias gradients are exact too, via a separate tiled pass whose
 [S, S]-sized output is inherent to d(bias) itself; when the bias is a
-non-trainable mask XLA dead-code-eliminates that pass.  Non-tileable
-shapes fall back to differentiating the identical XLA composition.
+non-trainable mask the op's grad lowering skips that pass (and under
+``jax.grad`` XLA dead-code-eliminates it).  Non-tileable shapes fall
+back to differentiating the identical XLA composition.
+
+What goes from forward to backward is the logsumexp alone, lane-dense
+(``[BH, S_q]``; the op's ``LSE`` output, ``[B, H, S_q]``): the dQ pass
+forms delta = sum_d dO * O = sum_j P * dP from the P and dP tiles it
+computes anyway, so ``out`` is no residual, and ``fused_attention_grad``
+has a lowering of its own that runs the backward kernels on the forward
+op's ``LSE`` (the generic replay of the forward lowering would trace a
+second forward kernel: XLA merges replayed HLO, never two custom calls).
 
 Every kernel goes through ``_pallas_call``: Mosaic compiles it when the
 computation is lowered for a TPU, and Pallas interpret mode runs it on any
@@ -26,6 +35,13 @@ in the dbias pass — and no ``vmem_limit_bytes`` is set, so the compiler's
 and stop at S=16384 (the forward asks for 21.5 MiB); with a bias the
 backward stops at S=8192 (17.5 MiB) and fits at S=4096.  Longer sequences
 need K/V streamed block by block through the grid, not a higher limit.
+The dQ pass that forms delta holds the row's P and dP tiles besides, in two
+``[128, S_kv]`` float32 scratches (2 x 128 x S_kv x 4 bytes: 0.5 MiB at
+S_kv=512, 4 MiB at 4096, 8 MiB at 8192): it compiles through S_kv=4096
+with and without a bias or the causal mask, and at 8192 no longer under
+the causal mask, where the dQ pass with a passed delta still fits.  So
+past ``_DELTA_IN_KERNEL_MAX_SKV`` = 4096 the forward keeps ``out`` and the
+backward passes delta in, as every caller did before.
 """
 
 import functools
@@ -34,8 +50,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..registry import register_op
+from .. import telemetry
+from ..registry import register_grad_lower, register_op
 
 _NEG = -1e30
 
@@ -156,39 +174,69 @@ def _causal_mask(s, q0, k0):
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
-               dq_ref, *, scale, block_k, causal=False):
+               dq_ref, delta_out_ref, p_scr, dp_scr, *, scale, block_k,
+               causal=False):
     """FlashAttention-2 backward, dQ pass: one q block vs all k blocks.
-    p is recomputed from the saved LSE — no [S, S] materialization."""
+    p is recomputed from the saved LSE — no [S, S] materialization.
+
+    delta_i = sum_d dO_id O_id = sum_j P_ij dP_ij.  With ``delta_ref`` the
+    caller passed it and every K block is one pass.  Without it
+    (``delta_ref`` None) the kernel forms it from what it computes anyway:
+    the row's P and dP tiles are held in the ``[bq, S_kv]`` float32
+    scratches ``p_scr`` / ``dp_scr`` while P * dP is summed, then dS and
+    dQ come from the held tiles — the same five products, no ``out``
+    operand — and delta is written to ``delta_out_ref`` for the dK/dV and
+    dbias passes."""
     q = q_ref[0]                                   # [bq, D]
-    do = do_ref[0].astype(jnp.float32)             # [bq, D]
+    do = do_ref[0].astype(q.dtype)                 # [bq, D]
     lse = lse_ref[0]                               # [bq, 1] fp32
-    delta = delta_ref[0]                           # [bq, 1] fp32
     S = k_ref.shape[1]
     bq, D = q.shape
     pid = pl.program_id(1)
-    acc = jnp.zeros((bq, D), jnp.float32)
-    for kb in range(S // block_k):
-        ks = k_ref[0, kb * block_k:(kb + 1) * block_k, :]
-        vs = v_ref[0, kb * block_k:(kb + 1) * block_k, :]
+    blocks = [(kb, slice(kb * block_k, (kb + 1) * block_k))
+              for kb in range(S // block_k)]
 
-        def blk(acc, ks=ks, vs=vs, kb=kb):
-            s = jnp.dot(q, ks.T,
-                        preferred_element_type=jnp.float32) * scale
-            s = s + _bias_block(bias_ref, 0, bq, kb * block_k, block_k)
-            if causal:
-                s = _causal_mask(s, pid * bq, kb * block_k)
-            p = jnp.exp(s - lse)
-            dp = jnp.dot(do.astype(q.dtype), vs.T,
-                         preferred_element_type=jnp.float32)
-            ds = p * (dp - delta) * scale
-            return acc + jnp.dot(ds.astype(q.dtype), ks,
-                                 preferred_element_type=jnp.float32)
+    def live(kb):
+        return (pid + 1) * bq > kb * block_k
 
+    def tiles(kb, cols):
+        s = jnp.dot(q, k_ref[0, cols, :].T,
+                    preferred_element_type=jnp.float32) * scale
+        s = s + _bias_block(bias_ref, 0, bq, kb * block_k, block_k)
         if causal:
-            live = (pid + 1) * bq > kb * block_k
-            acc = jax.lax.cond(live, blk, lambda a: a, acc)
-        else:
-            acc = blk(acc)
+            s = _causal_mask(s, pid * bq, kb * block_k)
+        p = jnp.exp(s - lse)
+        dp = jnp.dot(do, v_ref[0, cols, :].T,
+                     preferred_element_type=jnp.float32)
+        return p, dp
+
+    if delta_ref is None:
+        # blocks above the causal diagonal are skipped in both sweeps:
+        # their P is zero, so they add nothing to delta or dQ
+        w = jnp.zeros((bq, block_k), jnp.float32)
+        for kb, cols in blocks:
+            def hold(w, kb=kb, cols=cols):
+                p, dp = tiles(kb, cols)
+                p_scr[:, cols] = p
+                dp_scr[:, cols] = dp
+                return w + p * dp
+            w = jax.lax.cond(live(kb), hold, lambda w: w, w) if causal \
+                else hold(w)
+        delta = w.sum(axis=-1, keepdims=True)      # [bq, 1] fp32
+        delta_out_ref[0] = delta
+    else:
+        delta = delta_ref[0]                       # [bq, 1] fp32
+
+    acc = jnp.zeros((bq, D), jnp.float32)
+    for kb, cols in blocks:
+        def blk(acc, kb=kb, cols=cols):
+            p, dp = (p_scr[:, cols], dp_scr[:, cols]) \
+                if delta_ref is None else tiles(kb, cols)
+            ds = p * (dp - delta) * scale
+            return acc + jnp.dot(ds.astype(q.dtype), k_ref[0, cols, :],
+                                 preferred_element_type=jnp.float32)
+        acc = jax.lax.cond(live(kb), blk, lambda a: a, acc) if causal \
+            else blk(acc)
     dq_ref[0] = acc.astype(dq_ref.dtype)
 
 
@@ -345,46 +393,93 @@ def _flash_forward(q, k, v, bias, scale, *, with_lse=False,
     return (res[0], res[1]) if with_lse else res[0]
 
 
-def _flash_backward(q, k, v, bias, scale, out, lse, g, causal=False):
-    """Tiled dQ/dK/dV — recomputes p blockwise from the saved LSE; the
-    [S, S] score matrix never exists in HBM (FlashAttention-2 backward)."""
+# Longest S_kv at which the dQ pass holds the row's P and dP tiles
+# (2 x 128 x S_kv x 4 bytes of VMEM scratch) to form delta itself; beyond
+# it the caller keeps ``out`` and passes delta in (module docstring).
+_DELTA_IN_KERNEL_MAX_SKV = 4096
+
+
+def _delta_in_kernel(S_kv):
+    return S_kv <= _DELTA_IN_KERNEL_MAX_SKV
+
+
+def _row_delta(g, out):
+    """delta = sum_d dO * O per row, [BH, S_q, 1] float32: for callers
+    that hold ``out`` (ring attention's GLOBAL output; S_kv beyond
+    ``_DELTA_IN_KERNEL_MAX_SKV``)."""
+    return jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                   axis=-1, keepdims=True)
+
+
+def _flash_dq(q, k, v, bias, scale, lse, g, causal, delta):
+    """The dQ pass (grid over q blocks): ``(dq, delta)``.  With
+    ``delta=None`` the kernel forms it (``_dq_kernel``) and writes it as a
+    second output; a passed delta comes back as it went in."""
     BH, S_q, D = q.shape
     S_kv = k.shape[1]
     _, block_q, block_k = _tileable(S_q, S_kv)
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1, keepdims=True)        # [BH, S_q, 1]
-
-    # dQ pass: grid over q blocks
-    dq_specs = [
-        pl.BlockSpec((1, block_q, D), lambda i, j: (i, j, 0)),  # q
-        pl.BlockSpec((1, S_kv, D), lambda i, j: (i, 0, 0)),     # k
-        pl.BlockSpec((1, S_kv, D), lambda i, j: (i, 0, 0)),     # v
-    ]
-    dq_args = [q, k, v]
-    bias_spec_q = pl.BlockSpec((1, block_q, S_kv), lambda i, j: (i, j, 0))
+    in_kernel = delta is None
+    q_block = pl.BlockSpec((1, block_q, D), lambda i, j: (i, j, 0))
+    kv_whole = pl.BlockSpec((1, S_kv, D), lambda i, j: (i, 0, 0))
+    in_specs, args = [q_block, kv_whole, kv_whole], [q, k, v]
     if bias is not None:
-        dq_specs.append(bias_spec_q)
-        dq_args.append(bias)
-        dq_kern = functools.partial(_dq_kernel, scale=scale,
-                                    block_k=block_k, causal=causal)
+        in_specs.append(pl.BlockSpec((1, block_q, S_kv),
+                                     lambda i, j: (i, j, 0)))
+        args.append(bias)
+    in_specs += [q_block, _row_stat_spec(block_q)]              # dO, lse
+    args += [g, lse]
+    out_specs = [q_block]
+    out_shape = [jax.ShapeDtypeStruct((BH, S_q, D), q.dtype)]
+    scratch = []
+    if in_kernel:
+        out_specs.append(_row_stat_spec(block_q))
+        out_shape.append(jax.ShapeDtypeStruct((BH, S_q, 1), jnp.float32))
+        scratch = [pltpu.VMEM((block_q, S_kv), jnp.float32)] * 2
     else:
-        def dq_kern(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dq_ref):
-            _dq_kernel(q_ref, k_ref, v_ref, None, do_ref, lse_ref,
-                       delta_ref, dq_ref, scale=scale, block_k=block_k,
-                       causal=causal)
-    dq_specs += [
-        pl.BlockSpec((1, block_q, D), lambda i, j: (i, j, 0)),  # dO
-        _row_stat_spec(block_q),                                # lse
-        _row_stat_spec(block_q),                                # delta
-    ]
-    dq = _pallas_call(
-        dq_kern, "flash_dq",
+        in_specs.append(_row_stat_spec(block_q))
+        args.append(delta)
+
+    def kern(q_ref, k_ref, v_ref, *refs):
+        refs = list(refs)
+        bias_ref = refs.pop(0) if bias is not None else None
+        do_ref, lse_ref = refs[:2]
+        if in_kernel:
+            delta_ref = None
+            dq_ref, delta_out_ref, p_scr, dp_scr = refs[2:]
+        else:
+            delta_ref, dq_ref = refs[2:]
+            delta_out_ref = p_scr = dp_scr = None
+        _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
+                   delta_ref, dq_ref, delta_out_ref, p_scr, dp_scr,
+                   scale=scale, block_k=block_k, causal=causal)
+
+    res = _pallas_call(
+        kern, "flash_dq",
         grid=(BH, S_q // block_q),
-        in_specs=dq_specs,
-        out_specs=pl.BlockSpec((1, block_q, D), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, S_q, D), q.dtype),
-    )(*dq_args, g, lse, delta)
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+    )(*args)
+    return res[0], (res[1] if in_kernel else delta)
+
+
+def _flash_backward(q, k, v, bias, scale, lse, g, causal=False, delta=None,
+                    bias_grad=True):
+    """Tiled dQ/dK/dV — recomputes p blockwise from the saved LSE
+    ([BH, S_q, 1]); the [S, S] score matrix never exists in HBM
+    (FlashAttention-2 backward).
+
+    ``delta`` ([BH, S_q, 1] float32, ``_row_delta``) is passed by a caller
+    that holds ``out``; it MUST be by one whose K/V are a shard of the
+    row (ring attention: a delta summed over one device's K/V is wrong).
+    With ``delta=None`` the dQ kernel forms it over the whole row and
+    hands it to the other passes, so no ``out`` is needed at all.
+    ``bias_grad=False`` skips the dbias pass."""
+    BH, S_q, D = q.shape
+    S_kv = k.shape[1]
+    _, block_q, block_k = _tileable(S_q, S_kv)
+    dq, delta = _flash_dq(q, k, v, bias, scale, lse, g, causal, delta)
 
     # dK/dV pass: grid over k blocks
     dkv_specs = [
@@ -421,12 +516,12 @@ def _flash_backward(q, k, v, bias, scale, out, lse, g, causal=False):
     )(*dkv_args, g, lse, delta)
 
     dbias = None
-    if bias is not None:
+    if bias is not None and bias_grad:
         db_specs = [
             pl.BlockSpec((1, block_q, D), lambda i, j: (i, j, 0)),  # q
             pl.BlockSpec((1, S_kv, D), lambda i, j: (i, 0, 0)),     # k
             pl.BlockSpec((1, S_kv, D), lambda i, j: (i, 0, 0)),     # v
-            bias_spec_q,                                            # bias
+            pl.BlockSpec((1, block_q, S_kv), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, block_q, D), lambda i, j: (i, j, 0)),  # dO
             _row_stat_spec(block_q),                                # lse
             _row_stat_spec(block_q),                                # delta
@@ -444,6 +539,34 @@ def _flash_backward(q, k, v, bias, scale, out, lse, g, causal=False):
     return dq, dk, dv, dbias
 
 
+def _forward_keeping_lse(q, k, v, bias, scale, causal):
+    """Training forward on a tileable shape: (out, what the backward
+    needs beside its inputs).  The row statistic leaves as ``[BH, S_q]``
+    behind an ``optimization_barrier``: XLA:TPU lays the kernel's
+    ``[BH, S_q, 1]`` out with the size-1 minor dimension padded to 128
+    lanes (100.7 MB for 0.79 MB of numbers at BH=384, S=512), and without
+    the barrier it cancels a squeeze/expand pair and keeps THAT buffer
+    alive from the forward to the backward, in every layer.  ``out`` is
+    kept only where the dQ pass cannot form delta itself."""
+    out, lse = _flash_forward(q, k, v, bias, scale, with_lse=True,
+                              causal=causal)
+    lse = jax.lax.optimization_barrier(lse[..., 0])
+    return out, lse, (None if _delta_in_kernel(k.shape[1]) else out)
+
+
+def _backward_from_lse(q, k, v, bias, scale, causal, lse, out, g,
+                       bias_grad=True):
+    """dq, dk, dv, dbias from the residuals of ``_forward_keeping_lse``.
+    ``lse`` and ``g`` pass one barrier together, so the padded
+    ``[BH, S_q, 1]`` expansion cannot be scheduled before the layer's
+    output gradient exists."""
+    lse, g = jax.lax.optimization_barrier((lse, g))
+    return _flash_backward(
+        q, k, v, bias, scale, lse[..., None], g, causal=causal,
+        delta=None if out is None else _row_delta(g, out),
+        bias_grad=bias_grad)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
 def flash_attention(q, k, v, bias, scale, causal=False):
     return _flash_forward(q, k, v, bias, scale, causal=causal)
@@ -455,14 +578,13 @@ def _fa_fwd(q, k, v, bias, scale, causal):
         # non-tileable shapes keep the exact-composition fallback
         return _flash_forward(q, k, v, bias, scale, causal=causal), \
             (q, k, v, bias, None, None)
-    out, lse = _flash_forward(q, k, v, bias, scale, with_lse=True,
-                              causal=causal)
-    return out, (q, k, v, bias, out, lse)
+    out, lse, kept = _forward_keeping_lse(q, k, v, bias, scale, causal)
+    return out, (q, k, v, bias, lse, kept)
 
 
 def _fa_bwd(scale, causal, res, g):
-    q, k, v, bias, out, lse = res
-    if out is None:                        # composition fallback path
+    q, k, v, bias, lse, out = res
+    if lse is None:                        # composition fallback path
         if bias is None:
             _, vjp = jax.vjp(
                 lambda q_, k_, v_: _reference_attention(
@@ -474,12 +596,32 @@ def _fa_bwd(scale, causal, res, g):
                 q_, k_, v_, b_, scale, causal=causal),
             q, k, v, bias)
         return vjp(g)
-    dq, dk, dv, dbias = _flash_backward(q, k, v, bias, scale, out, lse, g,
-                                        causal=causal)
-    return dq, dk, dv, dbias
+    return _backward_from_lse(q, k, v, bias, scale, causal, lse, out, g)
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def flash_attention_lse(q, k, v, bias, scale, causal=False):
+    """``flash_attention`` on a tileable shape that also returns the
+    logsumexp rows ``[BH, S_q]`` (float32): the training forward of the
+    ``fused_attention`` op, whose grad op reads them back as its ``LSE``
+    input.  The statistic is a residual, not a differentiable output: its
+    cotangent is dropped."""
+    return _forward_keeping_lse(q, k, v, bias, scale, causal)[:2]
+
+
+def _fal_fwd(q, k, v, bias, scale, causal):
+    out, lse, kept = _forward_keeping_lse(q, k, v, bias, scale, causal)
+    return (out, lse), (q, k, v, bias, lse, kept)
+
+
+def _fal_bwd(scale, causal, res, gs):
+    return _fa_bwd(scale, causal, res, gs[0])
+
+
+flash_attention_lse.defvjp(_fal_fwd, _fal_bwd)
 
 
 def _sp_attention(q, k, v, mesh, axis, mode, scale, causal, bias=None):
@@ -649,6 +791,56 @@ def _sp_gather_attention(q, k, v, mesh, axis, scale, causal, bias,
                      out_specs=spec_q, check_vma=False)(*args)
 
 
+def _is_test(ctx):
+    return bool(ctx.attr("is_test", False) or ctx.state.is_test)
+
+
+def _attention_route(ctx, S_q, S_kv):
+    """Which path a ``fused_attention`` op — or its grad op, which
+    carries the same attributes — takes, from what it can observe:
+    ``(sp_active, dropout, flash)``.  ``sp_active``: the sequence-parallel
+    transpiler stamped the op and the step compiles over a mesh carrying
+    that axis; ``dropout``: the attention-probability rate in effect;
+    ``flash``: neither, and the shape tiles, so the Pallas kernels run."""
+    dropout = 0.0 if _is_test(ctx) else \
+        float(ctx.attr("attn_dropout", 0.0) or 0.0)
+    sp_axis = ctx.attr("sp_axis", None)
+    mesh = getattr(ctx.state, "mesh", None)
+    sp = dict(mesh.shape).get(sp_axis, 1) if (sp_axis and mesh is not None) \
+        else 1
+    sp_active = sp > 1 and S_q % sp == 0 and _axis_is_auto(mesh, sp_axis)
+    flash = not sp_active and not dropout and _tileable(S_q, S_kv)[0]
+    return sp_active, dropout, flash
+
+
+def _norm_bias(spb, q, S_kv):
+    """Normalize every broadcastable bias shape ([S,S], [B,S,S],
+    [B,1,1,S] key-padding, ...) to the rank-4 [B, 1|H, S_q, S_kv] the
+    shard_map specs partition on."""
+    if spb is None:
+        return None
+    B, H, S_q, _ = q.shape
+    if spb.ndim == 3:               # [B|1, S_q, S_kv]: insert head dim
+        spb = spb[:, None]
+    hb = H if (spb.ndim == 4 and spb.shape[1] == H) else 1
+    return jnp.broadcast_to(spb.astype(q.dtype), (B, hb, S_q, S_kv))
+
+
+def _flat(x):
+    """[B, H, S, D] -> the kernels' [BH, S, D]."""
+    return x.reshape((-1,) + x.shape[2:])
+
+
+def _flat_bias(bias, q, S_kv):
+    """Any broadcastable bias -> the kernels' [BH, S_q, S_kv] in q's
+    dtype, or None."""
+    if bias is None:
+        return None
+    B, H, S_q, _ = q.shape
+    return _flat(jnp.broadcast_to(_norm_bias(bias, q, S_kv),
+                                  (B, H, S_q, S_kv)))
+
+
 @register_op("fused_attention")
 def _fused_attention(ctx, op):
     """Fused multi-head attention core: Q [B, H, S_q, D], K/V
@@ -662,16 +854,20 @@ def _fused_attention(ctx, op):
     shard_map (transpiler/sequence_parallel.py); cross-length attention
     and attention dropout route through the q-row-sharded gather island
     (``_sp_gather_attention`` — r5).  Off-mesh, dropout runs the exact
-    composition and everything else the flash kernel."""
+    composition and everything else the flash kernel.
+
+    ``LSE`` [B, H, S_q] float32 (an intermediate output, like
+    ``batch_norm``'s ``SavedMean``) is written where the flash kernels run
+    in a training program: ``fused_attention_grad`` reads it back and
+    runs the backward kernels on it (``_fused_attention_grad``).  On
+    every other path, and under ``is_test``, it stays unwritten and the
+    kernel computes no statistic."""
     q = ctx.i("Q")
     k = ctx.i("K")
     v = ctx.i("V")
     bias = ctx.i_opt("BiasQK")
     scale = ctx.attr("scale", 1.0)
     causal = bool(ctx.attr("causal", False))
-    dropout = float(ctx.attr("attn_dropout", 0.0) or 0.0)
-    if ctx.attr("is_test", False) or ctx.state.is_test:
-        dropout = 0.0
     B, H, S_q, D = q.shape
     S_kv = k.shape[2]
     if causal and S_q != S_kv:
@@ -684,35 +880,22 @@ def _fused_attention(ctx, op):
             "%d) — the causal alignment for cross-length attention is "
             "ambiguous; pass an explicit additive bias instead"
             % (S_q, S_kv))
+    sp_active, dropout, flash = _attention_route(ctx, S_q, S_kv)
     sp_axis = ctx.attr("sp_axis", None)
     mesh = getattr(ctx.state, "mesh", None)
-    sp = dict(mesh.shape).get(sp_axis, 1) if (sp_axis and mesh is not None) \
-        else 1
-    sp_active = sp > 1 and S_q % sp == 0 and _axis_is_auto(mesh, sp_axis)
-
-    def norm_bias(spb):
-        # normalize every broadcastable bias shape ([S,S], [B,S,S],
-        # [B,1,1,S] key-padding, ...) to the rank-4 [B, 1|H, S_q, S_kv]
-        # the shard_map specs partition on
-        if spb is None:
-            return None
-        if spb.ndim == 3:               # [B|1, S_q, S_kv]: insert head dim
-            spb = spb[:, None]
-        hb = H if (spb.ndim == 4 and spb.shape[1] == H) else 1
-        return jnp.broadcast_to(spb.astype(q.dtype), (B, hb, S_q, S_kv))
 
     if sp_active and (S_q != S_kv or dropout):
         # cross-attention and/or attention dropout: q rows stay sharded,
         # kv all-gathered in-island (VERDICT r4 item 6a/6b)
         out = _sp_gather_attention(q, k, v, mesh, sp_axis, float(scale),
-                                   causal, norm_bias(bias), dropout,
-                                   ctx.rng() if dropout else None)
+                                   causal, _norm_bias(bias, q, S_kv),
+                                   dropout, ctx.rng() if dropout else None)
         ctx.set("Out", out)
         return
     if sp_active:
         out = _sp_attention(q, k, v, mesh, sp_axis,
                             ctx.attr("sp_mode", "ring"), float(scale),
-                            causal, bias=norm_bias(bias))
+                            causal, bias=_norm_bias(bias, q, S_kv))
         ctx.set("Out", out)
         return
     if dropout:
@@ -721,18 +904,67 @@ def _fused_attention(ctx, op):
         # extra axes; replayed identically by the grad op: __op_seed__
         # rides the grad attrs)
         out = _attn_core_remat(float(scale), causal, dropout)(
-            q, k, v, norm_bias(bias), 0, ctx.rng())
+            q, k, v, _norm_bias(bias, q, S_kv), 0, ctx.rng())
         ctx.set("Out", out)
         return
-    qf = q.reshape(B * H, S_q, D)
-    kf = k.reshape(B * H, S_kv, D)
-    vf = v.reshape(B * H, S_kv, D)
-    bf = None
-    if bias is not None:
-        bf = jnp.broadcast_to(norm_bias(bias),
-                              (B, H, S_q, S_kv)).reshape(B * H, S_q, S_kv)
-    out = flash_attention(qf, kf, vf, bf, float(scale), causal)
+    args = (_flat(q), _flat(k), _flat(v), _flat_bias(bias, q, S_kv),
+            float(scale), causal)
+    if flash and op.output("LSE") and not _is_test(ctx):
+        out, lse = flash_attention_lse(*args)
+        ctx.set("LSE", lse.reshape(B, H, S_q))
+    else:
+        out = flash_attention(*args)
     ctx.set("Out", out.reshape(B, H, S_q, D))
+
+
+_m_grad_lowered = telemetry.counter(
+    "fused_attention_grad_lowered_total",
+    "fused_attention_grad ops lowered, by path: 'residual' runs the flash "
+    "backward kernels on the forward op's LSE, 'replay' differentiates a "
+    "second run of the forward lowering")
+
+
+@register_grad_lower("fused_attention")
+def _fused_attention_grad(ctx, op):
+    """Where the forward op ran the flash kernels and left its ``LSE``,
+    run the backward kernels directly on Q, K, V, BiasQK, ``Out@GRAD`` and
+    ``LSE``: ``generic_grad_lower``'s replay would trace a second
+    ``flash_fwd`` Mosaic call, which XLA cannot merge with the forward
+    op's (it folds a replay of plain HLO, never a custom call).
+    Everywhere else — sequence-parallel islands, the dropout composition,
+    non-tileable shapes, a program built without the ``LSE`` slot — the
+    replay stands."""
+    from ..lowering import generic_grad_lower
+
+    q, k, v = ctx.i("Q"), ctx.i("K"), ctx.i("V")
+    lse, g = ctx.i_opt("LSE"), ctx.i_opt("Out@GRAD")
+    S_q, S_kv = q.shape[2], k.shape[2]
+    if lse is None or g is None or \
+            not _attention_route(ctx, S_q, S_kv)[2]:
+        _m_grad_lowered.inc(path="replay")
+        generic_grad_lower(ctx, op, residual_slots=("LSE",))
+        return
+    _m_grad_lowered.inc(path="residual")
+    want = {slot: (op.output(slot + "@GRAD") or [""])[0]
+            for slot in ("Q", "K", "V", "BiasQK")}
+    bias = ctx.i_opt("BiasQK")
+    if want["BiasQK"]:
+        # the transpose of the bias's broadcast sums dbias back to its shape
+        bf, bias_vjp = jax.vjp(lambda b: _flat_bias(b, q, S_kv), bias)
+    else:
+        bf = _flat_bias(bias, q, S_kv)
+    dq, dk, dv, dbias = _backward_from_lse(
+        _flat(q), _flat(k), _flat(v), bf, float(ctx.attr("scale", 1.0)),
+        bool(ctx.attr("causal", False)), _flat(lse),
+        None if _delta_in_kernel(S_kv) else _flat(ctx.i("Out")),
+        _flat(g.astype(q.dtype)), bias_grad=bool(want["BiasQK"]))
+    grads = {"Q": dq.reshape(q.shape), "K": dk.reshape(k.shape),
+             "V": dv.reshape(v.shape)}
+    if dbias is not None:
+        grads["BiasQK"], = bias_vjp(dbias)
+    for slot, name in want.items():
+        if name:
+            ctx.env[name] = grads[slot]
 
 
 # ---------------------------------------------------------------------------

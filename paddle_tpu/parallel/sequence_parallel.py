@@ -128,14 +128,16 @@ def _ring_flash_fwd(q, k, v, axis_name, scale):
 
 
 def _ring_flash_bwd(axis_name, scale, res, g):
-    from paddle_tpu.fluid.ops.pallas_ops import _flash_backward
+    from paddle_tpu.fluid.ops.pallas_ops import _flash_backward, _row_delta
 
     q, k, v, out, lse = res
     P = lax.axis_size(axis_name)
     B, Tl, H, D = q.shape
     perm = [(j, (j + 1) % P) for j in range(P)]
     qf, gf = _bhsd(q), _bhsd(g.astype(q.dtype))
-    outf = _bhsd(out)
+    # delta belongs to the GLOBAL row: formed here from the merged output,
+    # never by the dQ kernel over one ring step's K/V shard
+    delta = _row_delta(gf, _bhsd(out))
     lsef = lse.reshape(B * H, Tl, 1)
     kb, vb = k, v
     dq = jnp.zeros((B * H, Tl, D), jnp.float32)
@@ -143,7 +145,7 @@ def _ring_flash_bwd(axis_name, scale, res, g):
     dvb = jnp.zeros_like(v, dtype=jnp.float32)
     for step in range(P):
         dq_s, dk_s, dv_s, _ = _flash_backward(
-            qf, _bhsd(kb), _bhsd(vb), None, scale, outf, lsef, gf)
+            qf, _bhsd(kb), _bhsd(vb), None, scale, lsef, gf, delta=delta)
         dq = dq + dq_s.astype(jnp.float32)
         dkb = dkb + _bshd(dk_s, B, H).astype(jnp.float32)
         dvb = dvb + _bshd(dv_s, B, H).astype(jnp.float32)

@@ -313,3 +313,115 @@ def test_flash_kernels_name_their_tpu_instructions(one_chip):
     calls = re.findall(r"%(\w+?)(?:\.\d+)? = [^\n]*"
                        r'custom_call_target="tpu_custom_call"', text)
     assert sorted(calls) == ["flash_dkv", "flash_dq", "flash_fwd"], calls
+
+
+# -- the flash residual path, compiled for a v5e (no chip needed) -----------
+
+LAYERS, BATCH, SEQ, HEADS = 3, 8, 512, 4
+
+
+def _bert_stack(with_lse):
+    """A small BERT pretraining step at the flash cell's attention shapes
+    per head (S=512, D=64, pure-bf16 AMP, attention dropout off).
+    ``with_lse=False`` strips the op's ``LSE`` slot before the backward is
+    appended: the program as it was built before the slot existed, whose
+    grad ops replay the forward."""
+    from paddle_tpu import models
+    from paddle_tpu.fluid.layers import rnn
+
+    cfg = models.bert.BertConfig(
+        vocab_size=512, hidden_size=64 * HEADS, num_layers=LAYERS,
+        num_heads=HEADS, ffn_size=512, max_position=SEQ, type_vocab_size=2,
+        hidden_dropout=0.0, attn_dropout=0.0, max_seq_len=SEQ)
+    real = rnn.fused_attention
+
+    def without_lse(*args, **kwargs):
+        out = real(*args, **kwargs)
+        del out.block.ops[-1].outputs["LSE"]
+        return out
+
+    main, startup = fluid.Program(), fluid.Program()
+    fluid.layers.fused_attention = real if with_lse else without_lse
+    try:
+        with fluid.program_guard(main, startup), fluid.unique_name.guard():
+            opt = fluid.contrib.mixed_precision.decorate(
+                fluid.optimizer.SGD(0.01), use_pure_bf16=True)
+            loss = models.bert.build_pretrain(cfg, optimizer=opt,
+                                              max_pred_per_seq=4)["loss"]
+    finally:
+        fluid.layers.fused_attention = real
+    rng = np.random.default_rng(0)
+    feed = {
+        "src_ids": rng.integers(0, 512, (BATCH, SEQ, 1), dtype=np.int64),
+        "pos_ids": np.tile(np.arange(SEQ, dtype=np.int64)[None, :, None],
+                           (BATCH, 1, 1)),
+        "sent_ids": np.zeros((BATCH, SEQ, 1), np.int64),
+        "input_mask": np.ones((BATCH, SEQ, 1), np.float32),
+        "mask_pos": (rng.integers(0, SEQ, (BATCH, 4))
+                     + np.arange(BATCH)[:, None] * SEQ)
+        .reshape(-1, 1).astype(np.int32),
+        "mask_label": rng.integers(0, 512, (BATCH * 4, 1), dtype=np.int64),
+        "nsp_label": rng.integers(0, 2, (BATCH, 1), dtype=np.int64),
+    }
+    return main, startup, loss, feed
+
+
+def _compile_step_for(sharding, main, startup, loss, feed):
+    """The executor's jitted step of ``main``, lowered on shapes placed
+    on the described chip: XLA:TPU and Mosaic compile it here."""
+    from paddle_tpu.fluid import executor
+
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        compiled, feed_vals = exe._resolve_compiled(main, feed, [loss],
+                                                    scope, None)
+        args = (executor._scope_state(scope, compiled.state_mut),
+                executor._scope_state(scope, compiled.state_ro),
+                tuple(feed_vals), np.int32(0))
+        shapes = jax.tree.map(
+            lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype,
+                                           sharding=sharding), args)
+        return compiled._jitted.lower(*shapes).compile()
+
+
+def _mosaic_calls(executable):
+    return sorted(re.findall(r"%(\w+?)(?:\.\d+)? = [^\n]*"
+                             r'custom_call_target="tpu_custom_call"',
+                             executable.as_text()))
+
+
+@pytest.fixture(scope="module")
+def bert_stack_steps(one_chip):
+    return {with_lse: _compile_step_for(one_chip, *_bert_stack(with_lse))
+            for with_lse in (True, False)}
+
+
+def test_training_step_holds_one_flash_forward_per_layer(bert_stack_steps):
+    """N attention layers compile to N ``flash_fwd``, N ``flash_dq`` and N
+    ``flash_dkv`` Mosaic calls; the replay compiled 2N / N / N, because
+    XLA cannot merge two custom calls as it merges replayed HLO."""
+    kernels = ["flash_dkv", "flash_dq", "flash_fwd"]
+    assert _mosaic_calls(bert_stack_steps[True]) == sorted(kernels * LAYERS)
+    assert _mosaic_calls(bert_stack_steps[False]) == sorted(
+        kernels * LAYERS + ["flash_fwd"] * LAYERS)
+
+
+def test_lse_residual_costs_its_own_bytes_and_no_more(bert_stack_steps):
+    """Handing the LSE from the forward op to the grad op costs the step's
+    temporaries the statistic's own bytes a layer.  XLA:TPU holds the
+    kernel's ``[BH, S, 1]`` float32 rows with the size-1 dimension padded
+    to 128 lanes (128 times the numbers), and keeps THAT buffer alive
+    from forward to backward unless the lowering hands on a lane-dense
+    ``[BH, S]`` behind ``optimization_barrier``s (pallas_ops
+    ``_forward_keeping_lse``)."""
+    new, replay = (bert_stack_steps[w].memory_analysis().temp_size_in_bytes
+                   for w in (True, False))
+    # [B, H, S] float32 in the (8, 128) tiling: H rounds up to 8 sublanes
+    lse_bytes = BATCH * -(-HEADS // 8) * 8 * SEQ * 4
+    # two schedules of one step differ by a few hundred KB whatever they
+    # hold (here 0.34 MB, and 0.06-0.42 MB at the flash cell's size); kept
+    # naively every layer holds BATCH * HEADS * SEQ * 128 * 4 = 8.4 MB
+    slack = 1 << 20
+    assert new - replay <= LAYERS * lse_bytes + slack, (new, replay)
