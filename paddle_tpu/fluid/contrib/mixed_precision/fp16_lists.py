@@ -5,8 +5,10 @@ precision — the lowering keeps activations fp32 — so the lists exist for
 API parity and to let users veto bf16 for specific ops.
 """
 
+# ``routed_experts``: its grouped expert matmuls run in the compute dtype;
+# the router inside it (scores, top-k, weights) is float32 in every mode
 white_list = {"conv2d", "depthwise_conv2d", "conv2d_transpose", "matmul",
-              "mul"}
+              "mul", "routed_experts"}
 
 black_list = {"exp", "square", "log", "mean", "sum", "cos_sim",
               "softmax", "softmax_with_cross_entropy",
@@ -18,7 +20,7 @@ gray_list = {"elementwise_add", "elementwise_sub", "elementwise_mul",
              "elementwise_pow", "batch_norm", "tanh", "sigmoid",
              "lookup_table", "relu", "layer_norm", "slice", "concat",
              "dropout", "reshape2", "transpose2", "pool2d", "top_k",
-             "scale", "gelu"}
+             "scale", "gelu", "rms_norm", "rotary_embedding", "swish"}
 
 
 class AutoMixedPrecisionLists:

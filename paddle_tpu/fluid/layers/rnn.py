@@ -8,12 +8,14 @@ an explicit ``length`` Variable ([batch]) over padded [batch, time, ...]
 data, the same convention as ``layers/sequence.py``.
 """
 
+from .. import unique_name
 from ..layer_helper import LayerHelper
 
 __all__ = [
     "dynamic_lstm", "dynamic_gru", "linear_chain_crf", "crf_decoding",
     "nce", "hsigmoid", "cos_sim", "beam_search", "beam_search_decode",
-    "fused_attention", "switch_moe",
+    "fused_attention", "switch_moe", "rms_norm", "rotary_embedding",
+    "routed_experts", "moe_bias_update",
 ]
 
 
@@ -270,7 +272,8 @@ def beam_search_decode(ids, scores, parent_idx, beam_size, end_id,
 
 
 def fused_attention(q, k, v, attn_bias=None, scale=1.0, causal=False,
-                    dropout_prob=0.0, is_test=False, name=None):
+                    dropout_prob=0.0, is_test=False, name=None,
+                    q_rope=None, k_rope=None):
     """Fused attention core (ops/pallas_ops.py flash-attention kernel):
     q [B, H, S_q, D], k/v [B, H, S_kv, D] (cross-attention supported),
     optional additive bias [B, 1|H, S_q, S_kv].
@@ -287,7 +290,8 @@ def fused_attention(q, k, v, attn_bias=None, scale=1.0, causal=False,
     ``batch_norm`` hands on ``SavedMean`` and ``dropout`` its ``Mask``."""
     helper = LayerHelper("fused_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
-    out.shape = q.shape
+    out.shape = tuple(q.shape[:3]) + tuple(v.shape[3:]) if q.shape and \
+        v.shape else q.shape
     lse = helper.create_variable_for_type_inference("float32",
                                                     stop_gradient=True)
     if q.shape:
@@ -295,6 +299,10 @@ def fused_attention(q, k, v, attn_bias=None, scale=1.0, causal=False,
     inputs = {"Q": [q], "K": [k], "V": [v]}
     if attn_bias is not None:
         inputs["BiasQK"] = [attn_bias]
+    if (q_rope is None) != (k_rope is None):
+        raise ValueError("fused_attention: q_rope and k_rope come together")
+    if q_rope is not None:
+        inputs["QRope"], inputs["KRope"] = [q_rope], [k_rope]
     helper.append_op("fused_attention", inputs=inputs,
                      outputs={"Out": [out], "LSE": [lse]},
                      attrs={"scale": float(scale),
@@ -304,6 +312,19 @@ def fused_attention(q, k, v, attn_bias=None, scale=1.0, causal=False,
                             "__op_seed__":
                                 helper.main_program.next_op_seed()})
     return out
+
+
+def _suffixed_attr(param_attr, suffix):
+    """A layer's several parameters from ONE user attr: a NAMED ParamAttr
+    must not collapse them onto one variable, so suffix a COPY's name
+    (copy.copy keeps subclass fields like WeightNormParamAttr.dim;
+    rebuilding via ParamAttr(**__dict__) would TypeError on them)."""
+    import copy
+    from ..param_attr import ParamAttr
+    attr = copy.copy(ParamAttr._to_attr(param_attr))
+    if getattr(attr, "name", None):
+        attr.name = attr.name + "." + suffix
+    return attr
 
 
 def switch_moe(x, num_experts, ffn_dim, capacity_factor=1.25, act="relu",
@@ -326,16 +347,7 @@ def switch_moe(x, num_experts, ffn_dim, capacity_factor=1.25, act="relu",
                          "is not supported")
 
     def attr_for(suffix):
-        # three distinct parameters: a user-supplied NAMED ParamAttr must
-        # not collapse them onto one variable, so suffix a COPY's name
-        # (copy.copy keeps subclass fields like WeightNormParamAttr.dim;
-        # rebuilding via ParamAttr(**__dict__) would TypeError on them)
-        import copy
-        from ..param_attr import ParamAttr
-        attr = copy.copy(ParamAttr._to_attr(param_attr))
-        if getattr(attr, "name", None):
-            attr.name = attr.name + "." + suffix
-        return attr
+        return _suffixed_attr(param_attr, suffix)
 
     router_w = helper.create_parameter(attr_for("router"), [D, E], x.dtype)
     w1 = helper.create_parameter(attr_for("w1"), [E, D, F], x.dtype)
@@ -355,3 +367,102 @@ def switch_moe(x, num_experts, ffn_dim, capacity_factor=1.25, act="relu",
                      attrs={"capacity_factor": float(capacity_factor),
                             "act": act})
     return out, aux
+
+
+def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
+    """RMS norm over the last axis (ops/decoder_ops.py): ``scale * x /
+    sqrt(mean(x^2) + epsilon)``, statistics in float32; the scale is a
+    float32 parameter that starts at 1."""
+    from ..initializer import ConstantInitializer
+    helper = LayerHelper("rms_norm", param_attr=param_attr, name=name)
+    scale = helper.create_parameter(
+        helper.param_attr, [int(input.shape[-1])], "float32",
+        default_initializer=ConstantInitializer(1.0))
+    out = helper.create_variable_for_type_inference(input.dtype)
+    out.shape = input.shape
+    helper.append_op("rms_norm", inputs={"X": [input], "Scale": [scale]},
+                     outputs={"Y": [out]}, attrs={"epsilon": float(epsilon)})
+    return out
+
+
+def rotary_embedding(x, theta=10000.0, name=None):
+    """Rotary position embedding on the last axis of ``x`` [B, S, heads, D]
+    at positions 0..S-1 (the caller slices the rotary part of a head off
+    first).  Lanes are paired (2i, 2i+1) as in the published ``deepseek_v3``
+    weights and de-interleaved before the rotate-half."""
+    helper = LayerHelper("rotary_embedding", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    out.shape = x.shape
+    helper.append_op("rotary_embedding", inputs={"X": [x]},
+                     outputs={"Out": [out]},
+                     attrs={"theta": float(theta)})
+    return out
+
+
+def routed_experts(x, num_experts, top_k, ffn_dim, num_held=None,
+                   first_expert=0, routed_scaling_factor=1.0,
+                   param_attr=None, name=None):
+    """Routed SwiGLU experts without capacity or drops (ops/decoder_ops.py).
+
+    x [..., H] -> (out [..., H], expert_load [num_experts], select_bias
+    [num_experts]).  The layer routes over all ``num_experts`` (float32
+    sigmoid scores, the ``top_k`` largest of score + ``select_bias``,
+    weights normalised over the chosen and scaled) and holds ``num_held`` of
+    them, ``first_expert`` onward (default: all): ``out`` is the part of the
+    routed sum these give — one chip's share under expert parallelism.
+    ``select_bias`` is persistable state without a gradient; move it with
+    ``moe_bias_update`` ops after ``minimize`` (``models/deepseek_v3.py``),
+    which read ``expert_load`` (tokens that chose each expert).  The load is
+    persistable too: the last step's count stays in the scope, where a
+    monitor reads the balance without a fetch of its own."""
+    from ..initializer import ConstantInitializer
+    helper = LayerHelper("routed_experts", param_attr=param_attr, name=name)
+    H, E, I = int(x.shape[-1]), int(num_experts), int(ffn_dim)
+    n_held = E if num_held is None else int(num_held)
+    if not 0 <= first_expert <= E - n_held:
+        raise ValueError("routed_experts: experts %d..%d are not among %d"
+                         % (first_expert, first_expert + n_held - 1, E))
+
+    def param(suffix, shape):
+        return helper.create_parameter(_suffixed_attr(param_attr, suffix),
+                                       shape, "float32")
+
+    router_w = param("router", [H, E])
+    w_gate = param("gate", [n_held, H, I])
+    w_up = param("up", [n_held, H, I])
+    w_down = param("down", [n_held, I, H])
+    def state(suffix):
+        var = helper.create_global_variable(
+            name=unique_name.generate(helper.name + suffix),
+            shape=(E,), dtype="float32", persistable=True)
+        var.stop_gradient = True
+        helper.set_variable_initializer(var, ConstantInitializer(0.0))
+        return var
+
+    bias, load = state(".select_bias"), state(".expert_load")
+    out = helper.create_variable_for_type_inference(x.dtype)
+    out.shape = x.shape
+    helper.append_op("routed_experts",
+                     inputs={"X": [x], "RouterW": [router_w],
+                             "SelectBias": [bias], "WGate": [w_gate],
+                             "WUp": [w_up], "WDown": [w_down]},
+                     outputs={"Out": [out], "ExpertLoad": [load]},
+                     attrs={"top_k": int(top_k),
+                            "first_expert": int(first_expert),
+                            "routed_scaling_factor":
+                                float(routed_scaling_factor)})
+    return out, load, bias
+
+
+def moe_bias_update(select_bias, expert_load, gamma=0.001):
+    """Move a ``routed_experts`` layer's selection bias in place:
+    ``bias += gamma * sign(mean(load) - load)`` (the auxiliary-loss-free
+    balancing of the DeepSeek-V3 report).  Call it after ``minimize``, under
+    ``program._optimized_guard([])``: it is optimizer-role state motion."""
+    helper = LayerHelper("moe_bias_update")
+    helper.append_op("moe_bias_update",
+                     inputs={"Bias": [select_bias],
+                             "ExpertLoad": [expert_load]},
+                     outputs={"BiasOut": [select_bias]},
+                     attrs={"gamma": float(gamma)})
+    return select_bias

@@ -38,10 +38,30 @@ need K/V streamed block by block through the grid, not a higher limit.
 The dQ pass that forms delta holds the row's P and dP tiles besides, in two
 ``[128, S_kv]`` float32 scratches (2 x 128 x S_kv x 4 bytes: 0.5 MiB at
 S_kv=512, 4 MiB at 4096, 8 MiB at 8192): it compiles through S_kv=4096
-with and without a bias or the causal mask, and at 8192 no longer under
-the causal mask, where the dQ pass with a passed delta still fits.  So
-past ``_DELTA_IN_KERNEL_MAX_SKV`` = 4096 the forward keeps ``out`` and the
-backward passes delta in, as every caller did before.
+with a bias, and at 8192 no longer, where the dQ pass with a passed delta
+still fits.  So past ``_DELTA_IN_KERNEL_MAX_SKV`` = 4096 the forward keeps
+``out`` and the backward passes delta in, as every caller did before.
+
+Latent attention (PR 28): V's head size may differ from Q's and K's, and
+a head may have a second, rotary part whose keys are ONE head shared by
+the sequence's heads (``rope``: the kernels add ``qr kr^T`` to the scores
+and read ``kr`` at block row ``i // H``, so no per-head copy of it and no
+padding of 128 + 64 into one head size exists in HBM; the dK/dV pass
+writes each head's ``dkr`` and the caller sums them).  At the Moonlight
+cell's shapes (16 heads, S=4096, 128 + 64 | 128, bf16, causal) the dK/dV
+pass's Q side — Q, its rotary part, dO and the two lane-padded row
+statistics — is 7 MiB and asked for 16.4 MiB double-buffered inside the
+step, so whole-sequence operands beyond ``_DOUBLE_BUFFER_MAX_BYTES`` take
+one buffer (``_whole_seq``).  Under the causal mask without a bias the
+three kernels walk the other side's blocks in ONE ``fori_loop`` bounded by
+the diagonal instead of a static unroll with a ``cond`` a block
+(``_loops_over_blocks``): at S=4096 the unrolled bodies were most of a
+step's tracing, lowering and Mosaic compile time (3.4 s to lower and 5.6 s
+to compile a layer's three kernels against 0.4 and 0.6, compiled for a v5e
+here); that dQ pass is one sweep and takes delta from the kept ``out``.
+The rotary pair exists in that looped form alone (``_rope_runs_looped``):
+an op with a pair and a bias, or without the causal mask, composes one head
+size before the kernels (``_attention_route``).
 """
 
 import functools
@@ -103,49 +123,78 @@ def _reference_attention(q, k, v, bias, scale, causal=False):
     return jnp.einsum("bqk,bkd->bqd", p, v)
 
 
+def _scores(q, ks, scale, qr=None, krs=None):
+    """[bq, bk] float32 scaled scores of one tile: ``q ks^T``, plus
+    ``qr krs^T`` where the head has a second (rotary) part whose keys
+    ``krs`` are shared by every head of the sequence."""
+    s = jnp.dot(q, ks.T, preferred_element_type=jnp.float32)
+    if qr is not None:
+        s = s + jnp.dot(qr, krs.T, preferred_element_type=jnp.float32)
+    return s * scale
+
+
 def _attention_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
-                      scale, block_k, causal=False):
+                      scale, block_k, causal=False, qr_ref=None,
+                      kr_ref=None):
     # dots run in the INPUT dtype (bf16 under pure-bf16 AMP — a single
     # fast MXU pass) and accumulate fp32 via preferred_element_type;
     # casting inputs to fp32 first forces multi-pass fp32 MXU emulation
     q = q_ref[0]                                  # [bq, D], native dtype
+    qr = None if qr_ref is None else qr_ref[0]    # [bq, R]
     S = k_ref.shape[1]
-    bq, D = q.shape
+    bq = q.shape[0]
     num_kb = S // block_k
     pid = pl.program_id(1)          # q-block index (hoisted: program_id
     #                                 is not available inside cond branches)
 
-    acc = jnp.zeros((bq, D), jnp.float32)
+    acc = jnp.zeros((bq, v_ref.shape[2]), jnp.float32)
     m = jnp.full((bq, 1), _NEG, jnp.float32)
     l = jnp.zeros((bq, 1), jnp.float32)
-    for kb in range(num_kb):                      # static unroll
-        ks = k_ref[0, kb * block_k:(kb + 1) * block_k, :]   # [bk, D]
-        vs = v_ref[0, kb * block_k:(kb + 1) * block_k, :]
-
-        def blk(carry, ks=ks, vs=vs, kb=kb):
+    if _loops_over_blocks(causal, bias_ref):
+        def step(kb, carry):
             m, l, acc = carry
-            s = jnp.dot(q, ks.T,
-                        preferred_element_type=jnp.float32) * scale
-            if bias_ref is not None:
-                s = s + bias_ref[0, :, kb * block_k:(kb + 1) * block_k] \
-                    .astype(jnp.float32)
-            if causal:
-                s = _causal_mask(s, pid * bq, kb * block_k)
+            rows = _rows(kb, block_k)
+            s = _scores(q, k_ref[0, rows, :], scale, qr,
+                        None if kr_ref is None else kr_ref[0, rows, :])
+            s = _causal_mask(s, pid * bq, kb * block_k)
             m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
             alpha = jnp.exp(m - m_new)
             p = jnp.exp(s - m_new)
             l = l * alpha + p.sum(axis=-1, keepdims=True)
-            acc = acc * alpha + jnp.dot(p.astype(q.dtype), vs,
+            acc = acc * alpha + jnp.dot(p.astype(q.dtype), v_ref[0, rows, :],
                                         preferred_element_type=jnp.float32)
             return m_new, l, acc
+        # the k blocks that start at or before this q block's last row
+        live = jnp.minimum(pl.cdiv((pid + 1) * bq, block_k), num_kb)
+        m, l, acc = jax.lax.fori_loop(0, live, step, (m, l, acc))
+    else:
+        for kb in range(num_kb):                      # static unroll
+            ks = k_ref[0, kb * block_k:(kb + 1) * block_k, :]   # [bk, D]
+            vs = v_ref[0, kb * block_k:(kb + 1) * block_k, :]
 
-        if causal:
-            # blocks fully above the diagonal contribute nothing — skip
-            # their dots (roughly halves causal attention FLOPs)
-            live = (pid + 1) * bq > kb * block_k
-            m, l, acc = jax.lax.cond(live, blk, lambda c: c, (m, l, acc))
-        else:
-            m, l, acc = blk((m, l, acc))
+            def blk(carry, ks=ks, vs=vs, kb=kb):
+                m, l, acc = carry
+                s = _scores(q, ks, scale)
+                if bias_ref is not None:
+                    s = s + bias_ref[0, :, kb * block_k:(kb + 1) * block_k] \
+                        .astype(jnp.float32)
+                if causal:
+                    s = _causal_mask(s, pid * bq, kb * block_k)
+                m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+                alpha = jnp.exp(m - m_new)
+                p = jnp.exp(s - m_new)
+                l = l * alpha + p.sum(axis=-1, keepdims=True)
+                acc = acc * alpha + jnp.dot(p.astype(q.dtype), vs,
+                                            preferred_element_type=jnp.float32)
+                return m_new, l, acc
+
+            if causal:
+                # blocks fully above the diagonal contribute nothing — skip
+                # their dots (roughly halves causal attention FLOPs)
+                live = (pid + 1) * bq > kb * block_k
+                m, l, acc = jax.lax.cond(live, blk, lambda c: c, (m, l, acc))
+            else:
+                m, l, acc = blk((m, l, acc))
     l = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / l).astype(o_ref.dtype)
     # logsumexp per row — the statistic the tiled backward replays
@@ -154,6 +203,32 @@ def _attention_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
     # (an unused output of a pallas_call is still computed)
     if lse_ref is not None:
         lse_ref[0] = m + jnp.log(l)
+
+
+def _loops_over_blocks(causal, bias_ref):
+    """Whether a kernel walks the other side's blocks in a ``fori_loop``
+    bounded by the causal diagonal instead of a static unroll with a
+    ``cond`` a block: under the causal mask and without an additive bias
+    (a bias tile would need a dynamic slice along lanes).  One traced body
+    instead of S / 128: at S=4096 the unrolled kernels took most of a
+    step's tracing, lowering and Mosaic compile time, and the blocks above
+    the diagonal are never visited."""
+    return causal and bias_ref is None
+
+
+def _rope_runs_looped(rope, causal, bias):
+    """A rotary pair exists in the kernels' looped sweeps alone — under the
+    causal mask, without a bias: the decoder's case.  ``_attention_route``
+    composes one head size for every other op."""
+    if rope is not None and not _loops_over_blocks(causal, bias):
+        raise ValueError("the flash kernels take a rotary pair only under "
+                         "the causal mask and without a bias")
+
+
+def _rows(block, size):
+    """Rows ``block * size .. + size`` of a whole-sequence operand, for a
+    traced block index."""
+    return pl.ds(pl.multiple_of(block * size, size), size)
 
 
 def _bias_block(bias_ref, rows, row_len, cols, col_len):
@@ -175,7 +250,7 @@ def _causal_mask(s, q0, k0):
 
 def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
                dq_ref, delta_out_ref, p_scr, dp_scr, *, scale, block_k,
-               causal=False):
+               causal=False, qr_ref=None, kr_ref=None, dqr_ref=None):
     """FlashAttention-2 backward, dQ pass: one q block vs all k blocks.
     p is recomputed from the saved LSE — no [S, S] materialization.
 
@@ -186,8 +261,14 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
     scratches ``p_scr`` / ``dp_scr`` while P * dP is summed, then dS and
     dQ come from the held tiles — the same five products, no ``out``
     operand — and delta is written to ``delta_out_ref`` for the dK/dV and
-    dbias passes."""
+    dbias passes.
+
+    With a rotary part (``qr_ref`` [bq, R], ``kr_ref`` [S_kv, R], the keys
+    shared by the sequence's heads; in the looped sweep only,
+    ``_rope_runs_looped``) the scores hold the second dot and ``dqr_ref``
+    takes ``dS kr``."""
     q = q_ref[0]                                   # [bq, D]
+    qr = None if qr_ref is None else qr_ref[0]
     do = do_ref[0].astype(q.dtype)                 # [bq, D]
     lse = lse_ref[0]                               # [bq, 1] fp32
     S = k_ref.shape[1]
@@ -200,8 +281,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
         return (pid + 1) * bq > kb * block_k
 
     def tiles(kb, cols):
-        s = jnp.dot(q, k_ref[0, cols, :].T,
-                    preferred_element_type=jnp.float32) * scale
+        s = _scores(q, k_ref[0, cols, :], scale)
         s = s + _bias_block(bias_ref, 0, bq, kb * block_k, block_k)
         if causal:
             s = _causal_mask(s, pid * bq, kb * block_k)
@@ -227,57 +307,121 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
     else:
         delta = delta_ref[0]                       # [bq, 1] fp32
 
+    def ds_tile(kb, cols):
+        p, dp = (p_scr[:, cols], dp_scr[:, cols]) \
+            if delta_ref is None else tiles(kb, cols)
+        return (p * (dp - delta) * scale).astype(q.dtype)
+
     acc = jnp.zeros((bq, D), jnp.float32)
-    for kb, cols in blocks:
-        def blk(acc, kb=kb, cols=cols):
-            p, dp = (p_scr[:, cols], dp_scr[:, cols]) \
-                if delta_ref is None else tiles(kb, cols)
-            ds = p * (dp - delta) * scale
-            return acc + jnp.dot(ds.astype(q.dtype), k_ref[0, cols, :],
-                                 preferred_element_type=jnp.float32)
-        acc = jax.lax.cond(live(kb), blk, lambda a: a, acc) if causal \
-            else blk(acc)
+    if _loops_over_blocks(causal, bias_ref):
+        # one sweep over the live k blocks; delta is passed in
+        # (``_delta_in_kernel``), so nothing is held between two sweeps
+        acc_r = None if qr_ref is None else jnp.zeros(qr.shape, jnp.float32)
+
+        def step(kb, accs):
+            rows = _rows(kb, block_k)
+            ks = k_ref[0, rows, :]
+            krs = None if kr_ref is None else kr_ref[0, rows, :]
+            s = _causal_mask(_scores(q, ks, scale, qr, krs), pid * bq,
+                             kb * block_k)
+            dp = jnp.dot(do, v_ref[0, rows, :].T,
+                         preferred_element_type=jnp.float32)
+            ds = (jnp.exp(s - lse) * (dp - delta) * scale).astype(q.dtype)
+            acc, acc_r = accs
+            acc = acc + jnp.dot(ds, ks, preferred_element_type=jnp.float32)
+            if krs is not None:
+                acc_r = acc_r + jnp.dot(ds, krs,
+                                        preferred_element_type=jnp.float32)
+            return acc, acc_r
+        acc, acc_r = jax.lax.fori_loop(
+            0, jnp.minimum(pl.cdiv((pid + 1) * bq, block_k), S // block_k),
+            step, (acc, acc_r))
+        if qr_ref is not None:
+            dqr_ref[0] = acc_r.astype(dqr_ref.dtype)
+    else:
+        for kb, cols in blocks:
+            def blk(acc, kb=kb, cols=cols):
+                return acc + jnp.dot(ds_tile(kb, cols), k_ref[0, cols, :],
+                                     preferred_element_type=jnp.float32)
+            acc = jax.lax.cond(live(kb), blk, lambda a: a, acc) if causal \
+                else blk(acc)
     dq_ref[0] = acc.astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, *, scale, block_q, causal=False):
-    """dK/dV pass: one k block vs all q blocks."""
+                dk_ref, dv_ref, *, scale, block_q, causal=False,
+                qr_ref=None, kr_ref=None, dkr_ref=None):
+    """dK/dV pass: one k block vs all q blocks.  With a rotary part
+    (``qr_ref`` [S_q, R], ``kr_ref`` [bk, R]; in the looped sweep only)
+    ``dkr_ref`` takes THIS head's ``dS^T qr`` in float32; the caller sums
+    it over the heads that share the rotary keys."""
     ks = k_ref[0]                                  # [bk, D]
     vs = v_ref[0]
+    krs = None if kr_ref is None else kr_ref[0]    # [bk, R]
     S = q_ref.shape[1]
     bk, D = ks.shape
     pid = pl.program_id(1)
     dk = jnp.zeros((bk, D), jnp.float32)
-    dv = jnp.zeros((bk, D), jnp.float32)
-    for qb in range(S // block_q):
-        q = q_ref[0, qb * block_q:(qb + 1) * block_q, :]
-        do = do_ref[0, qb * block_q:(qb + 1) * block_q, :]
-        lse = lse_ref[0, qb * block_q:(qb + 1) * block_q, :]     # [bq, 1]
-        delta = delta_ref[0, qb * block_q:(qb + 1) * block_q, :]
+    dv = jnp.zeros(vs.shape, jnp.float32)
+    num_qb = S // block_q
+    if _loops_over_blocks(causal, bias_ref):
+        if krs is not None:
+            dk = (dk, jnp.zeros(krs.shape, jnp.float32))
 
-        def blk(carry, q=q, do=do, lse=lse, delta=delta, qb=qb):
+        def step(qb, carry):
             dk, dv = carry
-            s = jnp.dot(q, ks.T,
-                        preferred_element_type=jnp.float32) * scale
-            s = s + _bias_block(bias_ref, qb * block_q, block_q, 0, bk)
-            if causal:
-                s = _causal_mask(s, qb * block_q, pid * bk)
-            p = jnp.exp(s - lse)                   # [bq, bk]
-            pc = p.astype(q.dtype)
-            dv = dv + jnp.dot(pc.T, do, preferred_element_type=jnp.float32)
-            dp = jnp.dot(do, vs.T, preferred_element_type=jnp.float32)
-            ds = p * (dp - delta) * scale
-            dk = dk + jnp.dot(ds.astype(q.dtype).T, q,
+            rows = _rows(qb, block_q)
+            q, do = q_ref[0, rows, :], do_ref[0, rows, :]
+            qr = None if qr_ref is None else qr_ref[0, rows, :]
+            s = _causal_mask(_scores(q, ks, scale, qr, krs), qb * block_q,
+                             pid * bk)
+            p = jnp.exp(s - lse_ref[0, rows, :])
+            dv = dv + jnp.dot(p.astype(q.dtype).T, do,
                               preferred_element_type=jnp.float32)
+            dp = jnp.dot(do, vs.T, preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta_ref[0, rows, :]) * scale).astype(q.dtype)
+            if qr is None:
+                dk = dk + jnp.dot(ds.T, q,
+                                  preferred_element_type=jnp.float32)
+            else:
+                dk = (dk[0] + jnp.dot(ds.T, q,
+                                      preferred_element_type=jnp.float32),
+                      dk[1] + jnp.dot(ds.T, qr,
+                                      preferred_element_type=jnp.float32))
             return dk, dv
+        # from the first q block whose last row reaches this k block
+        dk, dv = jax.lax.fori_loop((pid * bk) // block_q, num_qb, step,
+                                   (dk, dv))
+        if krs is not None:
+            dk, dkr = dk
+            dkr_ref[0] = dkr.astype(dkr_ref.dtype)
+    else:
+        for qb in range(num_qb):
+            q = q_ref[0, qb * block_q:(qb + 1) * block_q, :]
+            do = do_ref[0, qb * block_q:(qb + 1) * block_q, :]
+            lse = lse_ref[0, qb * block_q:(qb + 1) * block_q, :]     # [bq, 1]
+            delta = delta_ref[0, qb * block_q:(qb + 1) * block_q, :]
 
-        if causal:
-            # q blocks entirely before this k block see none of it
-            live = (qb + 1) * block_q > pid * bk
-            dk, dv = jax.lax.cond(live, blk, lambda c: c, (dk, dv))
-        else:
-            dk, dv = blk((dk, dv))
+            def blk(carry, q=q, do=do, lse=lse, delta=delta, qb=qb):
+                dk, dv = carry
+                s = _scores(q, ks, scale)
+                s = s + _bias_block(bias_ref, qb * block_q, block_q, 0, bk)
+                if causal:
+                    s = _causal_mask(s, qb * block_q, pid * bk)
+                p = jnp.exp(s - lse)                   # [bq, bk]
+                pc = p.astype(q.dtype)
+                dv = dv + jnp.dot(pc.T, do, preferred_element_type=jnp.float32)
+                dp = jnp.dot(do, vs.T, preferred_element_type=jnp.float32)
+                ds = (p * (dp - delta) * scale).astype(q.dtype)
+                dk = dk + jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
+                return dk, dv
+
+            if causal:
+                # q blocks entirely before this k block see none of it
+                live = (qb + 1) * block_q > pid * bk
+                dk, dv = jax.lax.cond(live, blk, lambda c: c, (dk, dv))
+            else:
+                dk, dv = blk((dk, dv))
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -334,12 +478,73 @@ def _tileable(S_q, S_kv):
     return (S_q % block_q == 0 and S_kv % block_k == 0), block_q, block_k
 
 
+# VMEM the whole-sequence operands of one grid cell may take when double-
+# buffered (the pipeline's default) before ``_whole_seq`` single-buffers
+# them; the compiler's scoped limit is 16 MiB and the kernel's own tiles,
+# blocks and outputs need the rest.
+_DOUBLE_BUFFER_MAX_BYTES = 12 << 20
+
+
+def _lane_padded_bytes(rows, cols, itemsize):
+    """VMEM bytes of a [rows, cols] operand: the minor dimension is laid
+    out in whole 128-lane tiles (a [S, 1] float32 statistic costs what a
+    [S, 128] one does)."""
+    return rows * -(-cols // 128) * 128 * itemsize
+
+
+def _whole_seq(operands):
+    """``pipeline_mode`` keywords for the BlockSpecs of a pass's
+    whole-sequence operands (``(rows, cols, itemsize)`` each).  Their block
+    index changes only with the grid's outer index (one head of one
+    sequence), yet the pipeline double-buffers them; where that would take
+    more than ``_DOUBLE_BUFFER_MAX_BYTES`` they get ONE buffer: the next
+    head's copy then waits for the last cell of this one, once a head."""
+    need = 2 * sum(_lane_padded_bytes(*o) for o in operands)
+    if need <= _DOUBLE_BUFFER_MAX_BYTES:
+        return {}
+    return {"pipeline_mode": pl.Buffered(1)}
+
+
+def _rope_specs(rope, q_block, k_block, whole_mode=None):
+    """Block specs of a rotary pair ``(qr [BH, S_q, R], kr [B, S_kv, R])``:
+    ``qr`` cut like Q (``q_block`` rows), ``kr`` like K, except that grid
+    row ``i`` (one head of one sequence) reads the ONE rotary key head of
+    its sequence, ``i // H``.  ``None`` for a block = the whole sequence
+    (``whole_mode``: ``_whole_seq``'s keywords for it)."""
+    qr, kr = rope
+    heads = qr.shape[0] // kr.shape[0]
+    R = qr.shape[2]
+
+    def spec(rows, whole, row_of):
+        if rows is None:
+            return pl.BlockSpec((1, whole, R), lambda i, j: (row_of(i), 0, 0),
+                                **(whole_mode or {}))
+        return pl.BlockSpec((1, rows, R), lambda i, j: (row_of(i), j, 0))
+    return [spec(q_block, qr.shape[1], lambda i: i),
+            spec(k_block, kr.shape[1], lambda i: i // heads)]
+
+
+def _compose_rope(q, k, rope):
+    """The one-head-size form of a rotary pair, for the composition paths:
+    Q and K with the rotary part appended, the shared key head repeated."""
+    if rope is None:
+        return q, k
+    qr, kr = rope
+    return (jnp.concatenate([q, qr], axis=-1),
+            jnp.concatenate([k, jnp.repeat(kr, q.shape[0] // kr.shape[0],
+                                           axis=0)], axis=-1))
+
+
 def _flash_forward(q, k, v, bias, scale, *, with_lse=False,
-                   causal=False):
-    """q: [BH, S_q, D]; k/v: [BH, S_kv, D] (cross-attention supported);
-    bias: [BH, S_q, S_kv] or None."""
+                   causal=False, rope=None):
+    """q: [BH, S_q, D]; k: [BH, S_kv, D]; v: [BH, S_kv, D_v]
+    (cross-attention supported; D_v may differ from D, the output has D_v);
+    bias: [BH, S_q, S_kv] or None; rope: ``(qr [BH, S_q, R], kr [B, S_kv,
+    R])`` or None — a second part of every head whose keys are shared by
+    the H = BH / B heads of a sequence (``_scores``)."""
     BH, S_q, D = q.shape
     S_kv = k.shape[1]
+    D_v = v.shape[2]
     if causal and S_q != S_kv:
         # the diagonal alignment for unequal lengths is ambiguous
         # (top-left for truncated self-attention, bottom-right for
@@ -350,36 +555,44 @@ def _flash_forward(q, k, v, bias, scale, *, with_lse=False,
             % (S_q, S_kv))
     ok, block_q, block_k = _tileable(S_q, S_kv)
     if not ok:
-        out = _reference_attention(q, k, v, bias, scale, causal=causal)
+        out = _reference_attention(*_compose_rope(q, k, rope), v, bias,
+                                   scale, causal=causal)
         if not with_lse:
             return out
         # (with_lse is only requested by _fa_fwd AFTER the same
         # tileability check, so this fallback never computes an LSE)
         raise AssertionError("with_lse requested for a non-tileable "
                              "shape — caller bug")
+    _rope_runs_looped(rope, causal, bias)
     grid = (BH, S_q // block_q)
     in_specs = [
         pl.BlockSpec((1, block_q, D), lambda i, j: (i, j, 0)),
         pl.BlockSpec((1, S_kv, D), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((1, S_kv, D), lambda i, j: (i, 0, 0)),
+        pl.BlockSpec((1, S_kv, D_v), lambda i, j: (i, 0, 0)),
     ]
     args = [q, k, v]
     if bias is not None:
         in_specs.append(pl.BlockSpec((1, block_q, S_kv),
                                      lambda i, j: (i, j, 0)))
         args.append(bias)
+    if rope is not None:
+        in_specs += _rope_specs(rope, block_q, None)
+        args += list(rope)
     n_in = len(args)
 
     def kern(*refs):
         q_ref, k_ref, v_ref = refs[:3]
         bias_ref = refs[3] if bias is not None else None
+        qr_ref, kr_ref = refs[n_in - 2:n_in] if rope is not None \
+            else (None, None)
         o_ref = refs[n_in]
         lse_ref = refs[n_in + 1] if with_lse else None
         _attention_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
-                          scale=scale, block_k=block_k, causal=causal)
+                          scale=scale, block_k=block_k, causal=causal,
+                          qr_ref=qr_ref, kr_ref=kr_ref)
 
-    out_specs = [pl.BlockSpec((1, block_q, D), lambda i, j: (i, j, 0))]
-    out_shape = [jax.ShapeDtypeStruct((BH, S_q, D), q.dtype)]
+    out_specs = [pl.BlockSpec((1, block_q, D_v), lambda i, j: (i, j, 0))]
+    out_shape = [jax.ShapeDtypeStruct((BH, S_q, D_v), q.dtype)]
     if with_lse:
         out_specs.append(_row_stat_spec(block_q))
         out_shape.append(jax.ShapeDtypeStruct((BH, S_q, 1), jnp.float32))
@@ -399,8 +612,13 @@ def _flash_forward(q, k, v, bias, scale, *, with_lse=False,
 _DELTA_IN_KERNEL_MAX_SKV = 4096
 
 
-def _delta_in_kernel(S_kv):
-    return S_kv <= _DELTA_IN_KERNEL_MAX_SKV
+def _delta_in_kernel(S_kv, causal=False, bias=None):
+    """Whether the dQ pass forms delta itself (two sweeps over held tiles)
+    and the forward keeps no ``out``.  Not where it walks the k blocks in a
+    loop (``_loops_over_blocks``): that pass is one sweep and takes delta
+    from ``_row_delta`` of the ``out`` the forward keeps."""
+    return S_kv <= _DELTA_IN_KERNEL_MAX_SKV and \
+        not _loops_over_blocks(causal, bias)
 
 
 def _row_delta(g, out):
@@ -411,25 +629,37 @@ def _row_delta(g, out):
                    axis=-1, keepdims=True)
 
 
-def _flash_dq(q, k, v, bias, scale, lse, g, causal, delta):
+def _flash_dq(q, k, v, bias, scale, lse, g, causal, delta, rope=None):
     """The dQ pass (grid over q blocks): ``(dq, delta)``.  With
     ``delta=None`` the kernel forms it (``_dq_kernel``) and writes it as a
-    second output; a passed delta comes back as it went in."""
+    further output; a passed delta comes back as it went in.  With a rotary
+    pair ``dq`` is the pair ``(dq, dqr)``."""
     BH, S_q, D = q.shape
     S_kv = k.shape[1]
+    D_v = v.shape[2]
     _, block_q, block_k = _tileable(S_q, S_kv)
+    _rope_runs_looped(rope, causal, bias)
     in_kernel = delta is None
     q_block = pl.BlockSpec((1, block_q, D), lambda i, j: (i, j, 0))
-    kv_whole = pl.BlockSpec((1, S_kv, D), lambda i, j: (i, 0, 0))
-    in_specs, args = [q_block, kv_whole, kv_whole], [q, k, v]
+    in_specs = [q_block,
+                pl.BlockSpec((1, S_kv, D), lambda i, j: (i, 0, 0)),
+                pl.BlockSpec((1, S_kv, D_v), lambda i, j: (i, 0, 0))]
+    args = [q, k, v]
     if bias is not None:
         in_specs.append(pl.BlockSpec((1, block_q, S_kv),
                                      lambda i, j: (i, j, 0)))
         args.append(bias)
-    in_specs += [q_block, _row_stat_spec(block_q)]              # dO, lse
+    if rope is not None:
+        in_specs += _rope_specs(rope, block_q, None)
+        args += list(rope)
+    in_specs += [pl.BlockSpec((1, block_q, D_v), lambda i, j: (i, j, 0)),
+                 _row_stat_spec(block_q)]                       # dO, lse
     args += [g, lse]
     out_specs = [q_block]
     out_shape = [jax.ShapeDtypeStruct((BH, S_q, D), q.dtype)]
+    if rope is not None:
+        out_specs.append(_rope_specs(rope, block_q, None)[0])
+        out_shape.append(jax.ShapeDtypeStruct(rope[0].shape, rope[0].dtype))
     scratch = []
     if in_kernel:
         out_specs.append(_row_stat_spec(block_q))
@@ -442,16 +672,18 @@ def _flash_dq(q, k, v, bias, scale, lse, g, causal, delta):
     def kern(q_ref, k_ref, v_ref, *refs):
         refs = list(refs)
         bias_ref = refs.pop(0) if bias is not None else None
-        do_ref, lse_ref = refs[:2]
-        if in_kernel:
-            delta_ref = None
-            dq_ref, delta_out_ref, p_scr, dp_scr = refs[2:]
-        else:
-            delta_ref, dq_ref = refs[2:]
-            delta_out_ref = p_scr = dp_scr = None
+        qr_ref, kr_ref = (refs.pop(0), refs.pop(0)) if rope is not None \
+            else (None, None)
+        do_ref, lse_ref = refs.pop(0), refs.pop(0)
+        delta_ref = None if in_kernel else refs.pop(0)
+        dq_ref = refs.pop(0)
+        dqr_ref = refs.pop(0) if rope is not None else None
+        delta_out_ref, p_scr, dp_scr = refs if in_kernel \
+            else (None, None, None)
         _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                    delta_ref, dq_ref, delta_out_ref, p_scr, dp_scr,
-                   scale=scale, block_k=block_k, causal=causal)
+                   scale=scale, block_k=block_k, causal=causal,
+                   qr_ref=qr_ref, kr_ref=kr_ref, dqr_ref=dqr_ref)
 
     res = _pallas_call(
         kern, "flash_dq",
@@ -461,11 +693,12 @@ def _flash_dq(q, k, v, bias, scale, lse, g, causal, delta):
         out_shape=out_shape,
         scratch_shapes=scratch,
     )(*args)
-    return res[0], (res[1] if in_kernel else delta)
+    return (res[0] if rope is None else (res[0], res[1])), \
+        (res[-1] if in_kernel else delta)
 
 
 def _flash_backward(q, k, v, bias, scale, lse, g, causal=False, delta=None,
-                    bias_grad=True):
+                    bias_grad=True, rope=None):
     """Tiled dQ/dK/dV — recomputes p blockwise from the saved LSE
     ([BH, S_q, 1]); the [S, S] score matrix never exists in HBM
     (FlashAttention-2 backward).
@@ -475,45 +708,73 @@ def _flash_backward(q, k, v, bias, scale, lse, g, causal=False, delta=None,
     row (ring attention: a delta summed over one device's K/V is wrong).
     With ``delta=None`` the dQ kernel forms it over the whole row and
     hands it to the other passes, so no ``out`` is needed at all.
-    ``bias_grad=False`` skips the dbias pass."""
+    ``bias_grad=False`` skips the dbias pass.  With a rotary pair ``dq`` and
+    ``dk`` are pairs, ``(dq, dqr)`` and ``(dk, dkr)``, ``dkr`` summed over
+    the heads that share the rotary keys (``_rope_runs_looped``: never
+    beside a bias)."""
     BH, S_q, D = q.shape
     S_kv = k.shape[1]
+    D_v = v.shape[2]
     _, block_q, block_k = _tileable(S_q, S_kv)
-    dq, delta = _flash_dq(q, k, v, bias, scale, lse, g, causal, delta)
+    dq, delta = _flash_dq(q, k, v, bias, scale, lse, g, causal, delta, rope)
 
-    # dK/dV pass: grid over k blocks
+    # dK/dV pass: grid over k blocks, the whole Q side of a head in VMEM
+    q_side = [(S_q, D, q.dtype.itemsize), (S_q, D_v, g.dtype.itemsize),
+              (S_q, 1, 4), (S_q, 1, 4)]
+    if rope is not None:
+        q_side.append((S_q, rope[0].shape[2], rope[0].dtype.itemsize))
+    whole = _whole_seq(q_side)
     dkv_specs = [
-        pl.BlockSpec((1, S_q, D), lambda i, j: (i, 0, 0)),      # q
+        pl.BlockSpec((1, S_q, D), lambda i, j: (i, 0, 0), **whole),  # q
         pl.BlockSpec((1, block_k, D), lambda i, j: (i, j, 0)),  # k
-        pl.BlockSpec((1, block_k, D), lambda i, j: (i, j, 0)),  # v
+        pl.BlockSpec((1, block_k, D_v), lambda i, j: (i, j, 0)),  # v
     ]
     dkv_args = [q, k, v]
     if bias is not None:
         dkv_specs.append(pl.BlockSpec((1, S_q, block_k),
                                       lambda i, j: (i, 0, j)))
         dkv_args.append(bias)
-        dkv_kern = functools.partial(_dkv_kernel, scale=scale,
-                                     block_q=block_q, causal=causal)
-    else:
-        def dkv_kern(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                     dk_ref, dv_ref):
-            _dkv_kernel(q_ref, k_ref, v_ref, None, do_ref, lse_ref,
-                        delta_ref, dk_ref, dv_ref, scale=scale,
-                        block_q=block_q, causal=causal)
+    if rope is not None:
+        dkv_specs += _rope_specs(rope, None, block_k, whole)
+        dkv_args += list(rope)
+
+    def dkv_kern(q_ref, k_ref, v_ref, *refs):
+        refs = list(refs)
+        bias_ref = refs.pop(0) if bias is not None else None
+        qr_ref, kr_ref = (refs.pop(0), refs.pop(0)) if rope is not None \
+            else (None, None)
+        _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, *refs[:5],
+                    scale=scale, block_q=block_q, causal=causal,
+                    qr_ref=qr_ref, kr_ref=kr_ref,
+                    dkr_ref=refs[5] if rope is not None else None)
     dkv_specs += [
-        pl.BlockSpec((1, S_q, D), lambda i, j: (i, 0, 0)),      # dO
-        pl.BlockSpec((1, S_q, 1), lambda i, j: (i, 0, 0)),      # lse
-        pl.BlockSpec((1, S_q, 1), lambda i, j: (i, 0, 0)),      # delta
+        pl.BlockSpec((1, S_q, D_v), lambda i, j: (i, 0, 0), **whole),  # dO
+        pl.BlockSpec((1, S_q, 1), lambda i, j: (i, 0, 0), **whole),   # lse
+        pl.BlockSpec((1, S_q, 1), lambda i, j: (i, 0, 0), **whole),   # delta
     ]
-    dk, dv = _pallas_call(
+    dkv_out_specs = [
+        pl.BlockSpec((1, block_k, D), lambda i, j: (i, j, 0)),
+        pl.BlockSpec((1, block_k, D_v), lambda i, j: (i, j, 0))]
+    dkv_out_shape = [jax.ShapeDtypeStruct((BH, S_kv, D), k.dtype),
+                     jax.ShapeDtypeStruct((BH, S_kv, D_v), v.dtype)]
+    if rope is not None:
+        R = rope[1].shape[2]
+        dkv_out_specs.append(
+            pl.BlockSpec((1, block_k, R), lambda i, j: (i, j, 0)))
+        dkv_out_shape.append(
+            jax.ShapeDtypeStruct((BH, S_kv, R), jnp.float32))
+    dk, dv, *dkr = _pallas_call(
         dkv_kern, "flash_dkv",
         grid=(BH, S_kv // block_k),
         in_specs=dkv_specs,
-        out_specs=[pl.BlockSpec((1, block_k, D), lambda i, j: (i, j, 0)),
-                   pl.BlockSpec((1, block_k, D), lambda i, j: (i, j, 0))],
-        out_shape=[jax.ShapeDtypeStruct((BH, S_kv, D), k.dtype),
-                   jax.ShapeDtypeStruct((BH, S_kv, D), v.dtype)],
+        out_specs=dkv_out_specs,
+        out_shape=dkv_out_shape,
     )(*dkv_args, g, lse, delta)
+    if rope is not None:
+        kr = rope[1]
+        dkr = dkr[0].reshape(kr.shape[0], -1, S_kv, kr.shape[2]) \
+            .sum(axis=1).astype(kr.dtype)
+        return dq, (dk, dkr), dv, None
 
     dbias = None
     if bias is not None and bias_grad:
@@ -539,7 +800,7 @@ def _flash_backward(q, k, v, bias, scale, lse, g, causal=False, delta=None,
     return dq, dk, dv, dbias
 
 
-def _forward_keeping_lse(q, k, v, bias, scale, causal):
+def _forward_keeping_lse(q, k, v, bias, scale, causal, rope=None):
     """Training forward on a tileable shape: (out, what the backward
     needs beside its inputs).  The row statistic leaves as ``[BH, S_q]``
     behind an ``optimization_barrier``: XLA:TPU lays the kernel's
@@ -549,13 +810,14 @@ def _forward_keeping_lse(q, k, v, bias, scale, causal):
     alive from the forward to the backward, in every layer.  ``out`` is
     kept only where the dQ pass cannot form delta itself."""
     out, lse = _flash_forward(q, k, v, bias, scale, with_lse=True,
-                              causal=causal)
+                              causal=causal, rope=rope)
     lse = jax.lax.optimization_barrier(lse[..., 0])
-    return out, lse, (None if _delta_in_kernel(k.shape[1]) else out)
+    return out, lse, (None if _delta_in_kernel(k.shape[1], causal, bias)
+                      else out)
 
 
 def _backward_from_lse(q, k, v, bias, scale, causal, lse, out, g,
-                       bias_grad=True):
+                       bias_grad=True, rope=None):
     """dq, dk, dv, dbias from the residuals of ``_forward_keeping_lse``.
     ``lse`` and ``g`` pass one barrier together, so the padded
     ``[BH, S_q, 1]`` expansion cannot be scheduled before the layer's
@@ -564,57 +826,57 @@ def _backward_from_lse(q, k, v, bias, scale, causal, lse, out, g,
     return _flash_backward(
         q, k, v, bias, scale, lse[..., None], g, causal=causal,
         delta=None if out is None else _row_delta(g, out),
-        bias_grad=bias_grad)
+        bias_grad=bias_grad, rope=rope)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def flash_attention(q, k, v, bias, scale, causal=False):
-    return _flash_forward(q, k, v, bias, scale, causal=causal)
+def flash_attention(q, k, v, bias, scale, causal=False, rope=None):
+    """``rope``: a rotary pair ``(qr [BH, S_q, R], kr [B, S_kv, R])`` or
+    None (``_flash_forward``); its gradient is the pair ``(dqr, dkr)``."""
+    return _flash_forward(q, k, v, bias, scale, causal=causal, rope=rope)
 
 
-def _fa_fwd(q, k, v, bias, scale, causal):
+def _fa_fwd(q, k, v, bias, scale, causal, rope=None):
     ok, _, _ = _tileable(q.shape[1], k.shape[1])
     if not ok:
         # non-tileable shapes keep the exact-composition fallback
-        return _flash_forward(q, k, v, bias, scale, causal=causal), \
-            (q, k, v, bias, None, None)
-    out, lse, kept = _forward_keeping_lse(q, k, v, bias, scale, causal)
-    return out, (q, k, v, bias, lse, kept)
+        return _flash_forward(q, k, v, bias, scale, causal=causal,
+                              rope=rope), (q, k, v, bias, rope, None, None)
+    out, lse, kept = _forward_keeping_lse(q, k, v, bias, scale, causal, rope)
+    return out, (q, k, v, bias, rope, lse, kept)
 
 
 def _fa_bwd(scale, causal, res, g):
-    q, k, v, bias, lse, out = res
+    q, k, v, bias, rope, lse, out = res
     if lse is None:                        # composition fallback path
-        if bias is None:
-            _, vjp = jax.vjp(
-                lambda q_, k_, v_: _reference_attention(
-                    q_, k_, v_, None, scale, causal=causal), q, k, v)
-            dq, dk, dv = vjp(g)
-            return dq, dk, dv, None
-        _, vjp = jax.vjp(
-            lambda q_, k_, v_, b_: _reference_attention(
-                q_, k_, v_, b_, scale, causal=causal),
-            q, k, v, bias)
+        def composed(q_, k_, v_, b_, r_):
+            return _reference_attention(*_compose_rope(q_, k_, r_), v_, b_,
+                                        scale, causal=causal)
+        _, vjp = jax.vjp(composed, q, k, v, bias, rope)
         return vjp(g)
-    return _backward_from_lse(q, k, v, bias, scale, causal, lse, out, g)
+    dq, dk, dv, dbias = _backward_from_lse(q, k, v, bias, scale, causal,
+                                           lse, out, g, rope=rope)
+    if rope is None:
+        return dq, dk, dv, dbias, None
+    return dq[0], dk[0], dv, dbias, (dq[1], dk[1])
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def flash_attention_lse(q, k, v, bias, scale, causal=False):
+def flash_attention_lse(q, k, v, bias, scale, causal=False, rope=None):
     """``flash_attention`` on a tileable shape that also returns the
     logsumexp rows ``[BH, S_q]`` (float32): the training forward of the
     ``fused_attention`` op, whose grad op reads them back as its ``LSE``
     input.  The statistic is a residual, not a differentiable output: its
     cotangent is dropped."""
-    return _forward_keeping_lse(q, k, v, bias, scale, causal)[:2]
+    return _forward_keeping_lse(q, k, v, bias, scale, causal, rope)[:2]
 
 
-def _fal_fwd(q, k, v, bias, scale, causal):
-    out, lse, kept = _forward_keeping_lse(q, k, v, bias, scale, causal)
-    return (out, lse), (q, k, v, bias, lse, kept)
+def _fal_fwd(q, k, v, bias, scale, causal, rope=None):
+    out, lse, kept = _forward_keeping_lse(q, k, v, bias, scale, causal, rope)
+    return (out, lse), (q, k, v, bias, rope, lse, kept)
 
 
 def _fal_bwd(scale, causal, res, gs):
@@ -801,7 +1063,10 @@ def _attention_route(ctx, S_q, S_kv):
     ``(sp_active, dropout, flash)``.  ``sp_active``: the sequence-parallel
     transpiler stamped the op and the step compiles over a mesh carrying
     that axis; ``dropout``: the attention-probability rate in effect;
-    ``flash``: neither, and the shape tiles, so the Pallas kernels run."""
+    ``flash``: neither, and the shape tiles, so the Pallas kernels run on
+    the op's operands as they are.  A rotary pair is among them only under
+    the causal mask and without a bias (``_rope_runs_looped``); any other
+    op with a pair composes one head size first."""
     dropout = 0.0 if _is_test(ctx) else \
         float(ctx.attr("attn_dropout", 0.0) or 0.0)
     sp_axis = ctx.attr("sp_axis", None)
@@ -809,7 +1074,9 @@ def _attention_route(ctx, S_q, S_kv):
     sp = dict(mesh.shape).get(sp_axis, 1) if (sp_axis and mesh is not None) \
         else 1
     sp_active = sp > 1 and S_q % sp == 0 and _axis_is_auto(mesh, sp_axis)
-    flash = not sp_active and not dropout and _tileable(S_q, S_kv)[0]
+    flash = not sp_active and not dropout and _tileable(S_q, S_kv)[0] and \
+        not (ctx.has_input("QRope") and (
+            ctx.has_input("BiasQK") or not ctx.attr("causal", False)))
     return sp_active, dropout, flash
 
 
@@ -861,11 +1128,19 @@ def _fused_attention(ctx, op):
     in a training program: ``fused_attention_grad`` reads it back and
     runs the backward kernels on it (``_fused_attention_grad``).  On
     every other path, and under ``is_test``, it stays unwritten and the
-    kernel computes no statistic."""
+    kernel computes no statistic.
+
+    V [B, H, S_kv, D_v] may have another head size than Q and K; Out has
+    V's.  ``QRope`` [B, H, S_q, R] / ``KRope`` [B, 1, S_kv, R] (latent
+    attention: the rotary part of each head, its keys ONE head shared by
+    all H) add ``QRope KRope^T`` to the scores.  The flash kernels read the
+    shared head as it is (``_scores``); every other path composes one head
+    size first (``_compose_rope``)."""
     q = ctx.i("Q")
     k = ctx.i("K")
     v = ctx.i("V")
     bias = ctx.i_opt("BiasQK")
+    qr, kr = ctx.i_opt("QRope"), ctx.i_opt("KRope")
     scale = ctx.attr("scale", 1.0)
     causal = bool(ctx.attr("causal", False))
     B, H, S_q, D = q.shape
@@ -883,6 +1158,19 @@ def _fused_attention(ctx, op):
     sp_active, dropout, flash = _attention_route(ctx, S_q, S_kv)
     sp_axis = ctx.attr("sp_axis", None)
     mesh = getattr(ctx.state, "mesh", None)
+    _m_lowered.inc(shape="mha" if qr is None else "mla",
+                   path="sequence_parallel" if sp_active
+                   else "flash" if flash else "composition")
+    rope = None
+    if qr is not None and flash:
+        rope = (_flat(qr), kr.reshape(B, S_kv, -1))
+    elif qr is not None:
+        if sp_active:
+            raise NotImplementedError(
+                "fused_attention: a rotary pair under sequence parallelism")
+        q = jnp.concatenate([q, qr], axis=-1)
+        k = jnp.concatenate(
+            [k, jnp.broadcast_to(kr, k.shape[:3] + kr.shape[3:])], axis=-1)
 
     if sp_active and (S_q != S_kv or dropout):
         # cross-attention and/or attention dropout: q rows stay sharded,
@@ -908,14 +1196,21 @@ def _fused_attention(ctx, op):
         ctx.set("Out", out)
         return
     args = (_flat(q), _flat(k), _flat(v), _flat_bias(bias, q, S_kv),
-            float(scale), causal)
+            float(scale), causal, rope)
     if flash and op.output("LSE") and not _is_test(ctx):
         out, lse = flash_attention_lse(*args)
         ctx.set("LSE", lse.reshape(B, H, S_q))
     else:
         out = flash_attention(*args)
-    ctx.set("Out", out.reshape(B, H, S_q, D))
+    ctx.set("Out", out.reshape(B, H, S_q, v.shape[3]))
 
+
+_m_lowered = telemetry.counter(
+    "fused_attention_lowered_total",
+    "fused_attention lowerings traced (a grad op that replays the forward "
+    "counts again), by shape ('mla': with a rotary pair, 'mha': without) "
+    "and path ('flash': the Pallas kernels, 'composition': XLA, "
+    "'sequence_parallel': a shard_map island)")
 
 _m_grad_lowered = telemetry.counter(
     "fused_attention_grad_lowered_total",
@@ -946,8 +1241,11 @@ def _fused_attention_grad(ctx, op):
         return
     _m_grad_lowered.inc(path="residual")
     want = {slot: (op.output(slot + "@GRAD") or [""])[0]
-            for slot in ("Q", "K", "V", "BiasQK")}
+            for slot in ("Q", "K", "V", "BiasQK", "QRope", "KRope")}
     bias = ctx.i_opt("BiasQK")
+    qr, kr = ctx.i_opt("QRope"), ctx.i_opt("KRope")
+    rope = None if qr is None else \
+        (_flat(qr), kr.reshape(kr.shape[0], S_kv, -1))
     if want["BiasQK"]:
         # the transpose of the bias's broadcast sums dbias back to its shape
         bf, bias_vjp = jax.vjp(lambda b: _flat_bias(b, q, S_kv), bias)
@@ -956,10 +1254,16 @@ def _fused_attention_grad(ctx, op):
     dq, dk, dv, dbias = _backward_from_lse(
         _flat(q), _flat(k), _flat(v), bf, float(ctx.attr("scale", 1.0)),
         bool(ctx.attr("causal", False)), _flat(lse),
-        None if _delta_in_kernel(S_kv) else _flat(ctx.i("Out")),
-        _flat(g.astype(q.dtype)), bias_grad=bool(want["BiasQK"]))
-    grads = {"Q": dq.reshape(q.shape), "K": dk.reshape(k.shape),
-             "V": dv.reshape(v.shape)}
+        None if _delta_in_kernel(S_kv, bool(ctx.attr("causal", False)), bias)
+        else _flat(ctx.i("Out")),
+        _flat(g.astype(q.dtype)), bias_grad=bool(want["BiasQK"]), rope=rope)
+    grads = {}
+    if rope is not None:
+        (dq, dqr), (dk, dkr) = dq, dk
+        grads = {"QRope": dqr.reshape(qr.shape),
+                 "KRope": dkr.reshape(kr.shape)}
+    grads.update({"Q": dq.reshape(q.shape), "K": dk.reshape(k.shape),
+                  "V": dv.reshape(v.shape)})
     if dbias is not None:
         grads["BiasQK"], = bias_vjp(dbias)
     for slot, name in want.items():

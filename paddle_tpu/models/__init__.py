@@ -16,3 +16,5 @@ from . import deepfm
 from . import mobilenet
 from . import vgg
 from . import se_resnext
+from . import deepseek_v3
+from . import deepseek_v3_reference

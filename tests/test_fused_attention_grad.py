@@ -296,9 +296,9 @@ def test_ring_attention_passes_its_global_delta(monkeypatch):
     passed = []
     real = pallas_ops._flash_dq
 
-    def spy(q, k, v, bias, scale, lse, g, causal, delta):
+    def spy(q, k, v, bias, scale, lse, g, causal, delta, rope=None):
         passed.append(delta is not None)
-        return real(q, k, v, bias, scale, lse, g, causal, delta)
+        return real(q, k, v, bias, scale, lse, g, causal, delta, rope)
 
     monkeypatch.setattr(pallas_ops, "_flash_dq", spy)
     sp = 4

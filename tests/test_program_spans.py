@@ -425,3 +425,31 @@ def test_lse_residual_costs_its_own_bytes_and_no_more(bert_stack_steps):
     # naively every layer holds BATCH * HEADS * SEQ * 128 * 4 = 8.4 MB
     slack = 1 << 20
     assert new - replay <= LAYERS * lse_bytes + slack, (new, replay)
+
+
+# -- latent attention's kernels at the cell's shapes, compiled for a v5e ------
+
+def test_latent_attention_kernels_fit_the_v5e_at_s4096(one_chip):
+    """One sequence of the Moonlight cell's attention (16 heads, S=4096,
+    nope 128 + a shared rotary key head of 64, V 128, bf16, causal):
+    forward, dQ and dK/dV keep a whole sequence of the other side in VMEM
+    and compile within the 16 MiB scoped default (no ``vmem_limit_bytes``),
+    as three named Mosaic calls."""
+    heads, seq = 16, 4096
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v, qr, kr):
+        return pallas_ops.flash_attention(
+            q, k, v, None, 192 ** -0.5, True, (qr, kr)) \
+            .astype(jnp.float32).sum()
+
+    step = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        shape(heads, seq, 128), shape(heads, seq, 128),
+        shape(heads, seq, 128), shape(heads, seq, 64),
+        shape(1, seq, 64)).compile()
+    assert _mosaic_calls(step) == ["flash_dkv", "flash_dq", "flash_fwd"]
+    # no per-head copy of the shared rotary keys: nothing of [16, 4096, 64]
+    # is built from the [1, 4096, 64] operand
+    assert "bf16[16,4096,192]" not in step.as_text()
