@@ -1,10 +1,19 @@
-"""Flash-attention A/B: pallas tiled kernel (fwd+bwd) vs plain XLA
-composition at long sequence lengths, on the attached chip.
+"""Flash-attention tile sweep: ms a call of each of the three kernels
+(``flash_fwd``, ``flash_dq``, ``flash_dkv``) on the attached chip, at the
+tile the chooser picks (``pallas_ops._tiles``) and at every forced square
+and mixed tile from 128 to 512, at the shapes of the benchmark's two kernel
+cells:
 
-Run: python -m paddle_tpu.fluid.flash_bench [BH] [D]
-Prints one JSON line per sequence length with ms/step for both paths and
-the speedup.  Protocol is the bench.py fence (async dispatch, scalar
-fetch, RTT-subtracted).
+* ``bert``: BH=384 (batch 32 x 12 heads), S=512, D=64, bf16, with a bias,
+  non-causal — the unrolled form (``bert_base_s512_flash``);
+* ``moonlight``: 16 heads, S=4096, 128 + 64 | 128, bf16, causal, a shared
+  rotary key head — the looped form (``moonlight_ep8share_s4096_train``).
+
+Run: python -m paddle_tpu.fluid.flash_bench [bert|moonlight ...]
+Prints one JSON line per shape, kernel and tile.  Each kernel is timed
+alone (the dQ call is dead code in the dK/dV timing: delta is passed in),
+by the bench.py fence (async dispatch, one scalar fetch, RTT subtracted).
+A time is a chip's: off a TPU the module refuses to run.
 """
 
 import json
@@ -12,61 +21,111 @@ import sys
 
 import numpy as np
 
-
-def _timed(step, steps=20, warmup=3):
-    from .timing import timed_steps
-    dt, _ = timed_steps(step, steps, warmup=warmup,
-                        fetch=lambda out: float(np.asarray(out)))
-    return dt / steps
+TILES = ((128, 128), (256, 256), (512, 128), (128, 512), (512, 256),
+         (256, 512), (512, 512))
 
 
-def bench_seq(S, BH=16, D=64, dtype="bfloat16"):
+def _operands(shape):
     import jax
     import jax.numpy as jnp
-    from paddle_tpu.fluid.ops.pallas_ops import (flash_attention,
-                                                 _reference_attention)
-
-    rng = np.random.RandomState(0)
-    dt = jnp.dtype(dtype)
-    scale = 1.0 / np.sqrt(D)
-    # local_devices: under jax.distributed, devices()[0] may be a
-    # REMOTE device this process cannot device_put to
     from .mesh_utils import local_devices
+
+    rng = np.random.default_rng(0)
     dev = local_devices()[0]
-    q, k, v, g = (jax.device_put(
-        rng.normal(0, 1, (BH, S, D)).astype(np.float32).astype(dt), dev)
-        for _ in range(4))
 
-    def make_step(fn):
-        def loss(q_, k_, v_):
-            return jnp.sum(fn(q_, k_, v_).astype(jnp.float32) *
-                           g.astype(jnp.float32))
-        grad_fn = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+    def arr(*dims, dtype=jnp.bfloat16, scale=1.0):
+        return jax.device_put(
+            (rng.standard_normal(dims, dtype=np.float32) * scale)
+            .astype(dtype), dev)
+    if shape == "bert":
+        BH, S, D = 384, 512, 64
+        q, k, v, g = (arr(BH, S, D) for _ in range(4))
+        return dict(q=q, k=k, v=v, g=g, bias=arr(BH, S, S, scale=0.1),
+                    rope=None, causal=False, scale=D ** -0.5)
+    H, S = 16, 4096
+    q, k, v, g = (arr(H, S, 128) for _ in range(4))
+    return dict(q=q, k=k, v=v, g=g, bias=None, causal=True,
+                rope=(arr(H, S, 64), arr(1, S, 64)), scale=192 ** -0.5)
 
-        def step(i):
-            val, (dq, dk, dv) = grad_fn(q, k, v)
-            return val + jnp.sum(dq[0, 0].astype(jnp.float32))
-        return step
 
-    flash_ms = _timed(make_step(
-        lambda a, b, c: flash_attention(a, b, c, None, float(scale)))) * 1e3
-    plain_ms = _timed(make_step(
-        lambda a, b, c: _reference_attention(a, b, c, None,
-                                             float(scale)))) * 1e3
-    return {"seq": S, "bh": BH, "d": D, "dtype": str(dtype),
-            "flash_ms": round(flash_ms, 3), "plain_ms": round(plain_ms, 3),
-            "speedup": round(plain_ms / flash_ms, 3)}
+def _kernel_calls(ops):
+    """``{kernel: zero-argument call}``, each running ONE jitted kernel on
+    ``ops``; built anew for every tile, so nothing traced is reused."""
+    import functools
+    import jax
+    import jax.numpy as jnp
+    from .ops import pallas_ops as po
+
+    scale, causal = float(ops["scale"]), ops["causal"]
+    arrays = {n: ops[n] for n in ("q", "k", "v", "g", "bias", "rope")}
+    # the dQ pass forms delta itself where the lowering lets it
+    in_kernel = po._delta_in_kernel(ops["k"].shape[1], causal,
+                                    ops["bias"] is not None)
+
+    def forward(a):
+        return po._flash_forward(a["q"], a["k"], a["v"], a["bias"], scale,
+                                 with_lse=True, causal=causal,
+                                 rope=a["rope"])
+
+    def dq(a, lse, delta):
+        return po._flash_dq(a["q"], a["k"], a["v"], a["bias"], scale, lse,
+                            a["g"], causal, None if in_kernel else delta,
+                            a["rope"])[0]
+
+    def dkv(a, lse, delta):
+        return po._flash_backward(a["q"], a["k"], a["v"], a["bias"], scale,
+                                  lse, a["g"], causal, delta,
+                                  bias_grad=False, rope=a["rope"])[1:3]
+
+    def corner(fn):
+        """One scalar that needs every output, for the fence."""
+        return jax.jit(lambda *args: sum(
+            jnp.sum(x[..., :1, :1].astype(jnp.float32))
+            for x in jax.tree.leaves(fn(*args))))
+    out, lse = jax.jit(forward)(arrays)
+    delta = po._row_delta(ops["g"], out)
+    return {"fwd": functools.partial(corner(forward), arrays),
+            "dq": functools.partial(corner(dq), arrays, lse, delta),
+            "dkv": functools.partial(corner(dkv), arrays, lse, delta)}
+
+
+def sweep(shape, steps=30):
+    """Yield one record per kernel and tile of ``shape``; the first of a
+    kernel's records is the chooser's own pick."""
+    import jax
+    from .ops import pallas_ops as po
+    from .timing import timed_steps
+
+    if jax.default_backend() != "tpu":
+        raise RuntimeError("flash_bench times the chip's kernels: no TPU "
+                           "here (%s)" % jax.default_backend())
+    ops = _operands(shape)
+    chooser = po._tiles
+    try:
+        for forced in (None,) + TILES:
+            po._tiles = chooser if forced is None else \
+                (lambda kernel, *shape: (True,) + forced)
+            key = po._shape_key(ops["q"], ops["k"], ops["v"], ops["bias"],
+                                ops["causal"], ops["rope"])
+            for kernel, call in _kernel_calls(ops).items():
+                _, block_q, block_k = po._tiles(kernel, *key)
+                try:
+                    dt, _ = timed_steps(lambda i: call(), steps, warmup=3,
+                                        fetch=lambda out: float(out))
+                    rec = {"ms": round(dt / steps * 1e3, 4)}
+                except Exception as e:      # a tile Mosaic refuses
+                    rec = {"error": str(e)[:200]}
+                yield dict(shape=shape, kernel=kernel, block_q=block_q,
+                           block_k=block_k, chosen=forced is None, **rec)
+    finally:
+        po._tiles = chooser
 
 
 def main():
-    BH = int(sys.argv[1]) if len(sys.argv) > 1 else 16
-    D = int(sys.argv[2]) if len(sys.argv) > 2 else 64
-    for S in (1024, 2048, 4096):
-        try:
-            print(json.dumps(bench_seq(S, BH, D)))
-        except Exception as e:
-            print(json.dumps({"seq": S, "error": str(e)[:200]}))
-        sys.stdout.flush()
+    for shape in sys.argv[1:] or ("bert", "moonlight"):
+        for rec in sweep(shape):
+            print(json.dumps(rec))
+            sys.stdout.flush()
 
 
 if __name__ == "__main__":

@@ -26,21 +26,39 @@ computation is lowered for a TPU, and Pallas interpret mode runs it on any
 other platform (CPU tests, virtual meshes), so behavior is identical
 everywhere.
 
-VMEM: a grid cell holds one 128-row block of its own operand and the WHOLE
-sequence of the other side — K and V in the forward, dQ and dbias passes, Q
-and dO (plus a [S_q, 128] bias tile) in the dK/dV pass, a [128, S_kv] tile
-in the dbias pass — and no ``vmem_limit_bytes`` is set, so the compiler's
-16 MiB scoped default is the ceiling.  Compiled for a v5e at D=64, bf16
-(libtpu 0.0.34): without a bias, forward and backward fit through S=8192
-and stop at S=16384 (the forward asks for 21.5 MiB); with a bias the
-backward stops at S=8192 (17.5 MiB) and fits at S=4096.  Longer sequences
-need K/V streamed block by block through the grid, not a higher limit.
-The dQ pass that forms delta holds the row's P and dP tiles besides, in two
-``[128, S_kv]`` float32 scratches (2 x 128 x S_kv x 4 bytes: 0.5 MiB at
-S_kv=512, 4 MiB at 4096, 8 MiB at 8192): it compiles through S_kv=4096
-with a bias, and at 8192 no longer, where the dQ pass with a passed delta
-still fits.  So past ``_DELTA_IN_KERNEL_MAX_SKV`` = 4096 the forward keeps
-``out`` and the backward passes delta in, as every caller did before.
+Tiles and VMEM: a grid cell holds ``block_q`` rows of its own operand
+(``block_k`` in the dK/dV pass) and the WHOLE sequence of the other side —
+K and V in the forward, dQ and dbias passes, Q and dO (plus a [S_q,
+block_k] bias tile) in the dK/dV pass — and walks that side in tiles of
+``block_k`` (``block_q``) rows.  A cell and a tile each cost the same
+fixed time whatever their size (the pipeline's step; a serial matmul ->
+row max -> exp -> row sum -> matmul round; MXU weights loaded for the rows
+streamed), so ``_tiles`` picks, per kernel and from the call's shapes
+alone, the largest ``block_q x block_k`` from {512, 256, 128} that divides
+the sequences and whose VMEM estimate (``_vmem_bytes``: own blocks and
+bias tile double-buffered, the other side double- or single-buffered as
+``_whole_seq`` decides, lane-padded row statistics, the float32 score / P
+/ dP / dS tiles, accumulators, the dQ pass's scratches) fits
+``_VMEM_BUDGET_BYTES`` = 32 MiB, a quarter of a v5e core's VMEM.  At
+S=512, D=64 with a bias that is ONE 512 x 512 tile a head in all three
+kernels (grid ``(BH, 1)``; the forward is a plain softmax with no rescale,
+the dQ pass holds the row's P and dP as values); at 16 heads, S=4096,
+128 + 64 | 128 causal it is 512 x 512 too.  The compiler's scoped default
+is 16 MiB: where the estimate and an eighth over it pass that,
+``_pallas_call`` hands Mosaic ``vmem_limit_bytes`` = that sum
+(``_vmem_limit``; the Moonlight dK/dV pass: 15.5 -> 17.4 MiB), and where no
+tile fits the budget the op composes (``_flash_fits``: S=32768 at D=128).
+Mosaic's own count is lower than the estimate (compiled for a v5e, libtpu
+0.0.34: 3.0 / 4.2 / 4.2 MiB for the flash cell's three kernels against
+6.8 / 9.0 / 11.0 estimated; 7.9 / 9.7 / 7.7 against 10.5 / 13.3 / 15.5
+for Moonlight's), which leaves room for what XLA parks in VMEM inside a
+step.  Past a budget's worth of K/V the sequence has to be streamed block
+by block through the grid.  The dQ pass that forms delta over more than
+one tile holds the row's P and dP in two ``[block_q, S_kv]`` float32
+scratches (counted by the chooser, which gives such a pass fewer rows:
+256 x 512 at S=4096 with a bias); past ``_DELTA_IN_KERNEL_MAX_SKV`` = 4096
+the forward keeps ``out`` and the backward passes delta in, as every
+caller did before.
 
 Latent attention (PR 28): V's head size may differ from Q's and K's, and
 a head may have a second, rotary part whose keys are ONE head shared by
@@ -53,8 +71,8 @@ pass's Q side — Q, its rotary part, dO and the two lane-padded row
 statistics — is 7 MiB and asked for 16.4 MiB double-buffered inside the
 step, so whole-sequence operands beyond ``_DOUBLE_BUFFER_MAX_BYTES`` take
 one buffer (``_whole_seq``).  Under the causal mask without a bias the
-three kernels walk the other side's blocks in ONE ``fori_loop`` bounded by
-the diagonal instead of a static unroll with a ``cond`` a block
+three kernels walk the other side's tiles in ONE ``fori_loop`` bounded by
+the diagonal instead of a static unroll with a ``cond`` a tile
 (``_loops_over_blocks``): at S=4096 the unrolled bodies were most of a
 step's tracing, lowering and Mosaic compile time (3.4 s to lower and 5.6 s
 to compile a layer's three kernels against 0.4 and 0.6, compiled for a v5e
@@ -78,7 +96,7 @@ from ..registry import register_grad_lower, register_op
 _NEG = -1e30
 
 
-def _pallas_call(kernel, name, **kwargs):
+def _pallas_call(kernel, name, vmem_limit_bytes=None, **kwargs):
     """``pl.pallas_call`` whose mode follows the platform the computation
     is LOWERED for, not the process's default backend: compiled by Mosaic
     for a TPU, interpreted for anything else (a ``CPUPlace`` executor on a
@@ -90,10 +108,16 @@ def _pallas_call(kernel, name, **kwargs):
     unnamed kernel reads ``branch_1_fun``), and it is the named scope
     around the call in every instruction's ``op_name``.
 
+    ``vmem_limit_bytes`` (``_vmem_limit``) raises Mosaic's scoped VMEM
+    limit for this kernel; None leaves the compiler's default.
+
     Inside a ``shard_map`` the outputs vary over every mesh axis an input
     varies over; saying so in ``out_shape`` lets the kernels run under
     ``check_vma=True``."""
     out_shape = kwargs.pop("out_shape")
+    mosaic = {} if vmem_limit_bytes is None else {
+        "compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes)}
 
     def call(*args):
         vma = frozenset().union(*(jax.typeof(a).vma for a in args))
@@ -104,7 +128,7 @@ def _pallas_call(kernel, name, **kwargs):
             return jax.lax.platform_dependent(
                 *args,
                 tpu=pl.pallas_call(kernel, out_shape=shapes, name=name,
-                                   **kwargs),
+                                   **mosaic, **kwargs),
                 default=pl.pallas_call(kernel, out_shape=shapes, name=name,
                                        interpret=True, **kwargs))
     return call
@@ -133,6 +157,25 @@ def _scores(q, ks, scale, qr=None, krs=None):
     return s * scale
 
 
+def _online_softmax(carry, s, vs, dtype):
+    """Fold one tile into the row's running ``(m, l, acc)``: scores ``s``
+    [bq, bk] float32, values ``vs`` [bk, D_v].  ``carry`` None is the row's
+    first tile, which has nothing to rescale — a row that is ONE tile
+    (block_k = S_kv) is a plain softmax."""
+    m_tile = s.max(axis=-1, keepdims=True)
+    if carry is None:
+        p = jnp.exp(s - m_tile)
+        return m_tile, p.sum(axis=-1, keepdims=True), \
+            jnp.dot(p.astype(dtype), vs, preferred_element_type=jnp.float32)
+    m, l, acc = carry
+    m_new = jnp.maximum(m, m_tile)
+    alpha = jnp.exp(m - m_new)
+    p = jnp.exp(s - m_new)
+    return m_new, l * alpha + p.sum(axis=-1, keepdims=True), \
+        acc * alpha + jnp.dot(p.astype(dtype), vs,
+                              preferred_element_type=jnp.float32)
+
+
 def _attention_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
                       scale, block_k, causal=False, qr_ref=None,
                       kr_ref=None):
@@ -147,54 +190,41 @@ def _attention_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
     pid = pl.program_id(1)          # q-block index (hoisted: program_id
     #                                 is not available inside cond branches)
 
-    acc = jnp.zeros((bq, v_ref.shape[2]), jnp.float32)
-    m = jnp.full((bq, 1), _NEG, jnp.float32)
-    l = jnp.zeros((bq, 1), jnp.float32)
-    if _loops_over_blocks(causal, bias_ref):
+    if _loops_over_blocks(causal, bias_ref is not None):
         def step(kb, carry):
-            m, l, acc = carry
             rows = _rows(kb, block_k)
             s = _scores(q, k_ref[0, rows, :], scale, qr,
                         None if kr_ref is None else kr_ref[0, rows, :])
             s = _causal_mask(s, pid * bq, kb * block_k)
-            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-            alpha = jnp.exp(m - m_new)
-            p = jnp.exp(s - m_new)
-            l = l * alpha + p.sum(axis=-1, keepdims=True)
-            acc = acc * alpha + jnp.dot(p.astype(q.dtype), v_ref[0, rows, :],
-                                        preferred_element_type=jnp.float32)
-            return m_new, l, acc
-        # the k blocks that start at or before this q block's last row
-        live = jnp.minimum(pl.cdiv((pid + 1) * bq, block_k), num_kb)
-        m, l, acc = jax.lax.fori_loop(0, live, step, (m, l, acc))
+            return _online_softmax(carry, s, v_ref[0, rows, :], q.dtype)
+        # the k tiles that start at or before this q block's last row
+        m, l, acc = jax.lax.fori_loop(
+            0, jnp.minimum(pl.cdiv((pid + 1) * bq, block_k), num_kb), step,
+            (jnp.full((bq, 1), _NEG, jnp.float32),
+             jnp.zeros((bq, 1), jnp.float32),
+             jnp.zeros((bq, v_ref.shape[2]), jnp.float32)))
     else:
+        carry = None
         for kb in range(num_kb):                      # static unroll
             ks = k_ref[0, kb * block_k:(kb + 1) * block_k, :]   # [bk, D]
             vs = v_ref[0, kb * block_k:(kb + 1) * block_k, :]
 
             def blk(carry, ks=ks, vs=vs, kb=kb):
-                m, l, acc = carry
                 s = _scores(q, ks, scale)
-                if bias_ref is not None:
-                    s = s + bias_ref[0, :, kb * block_k:(kb + 1) * block_k] \
-                        .astype(jnp.float32)
+                s = _add_bias(s, bias_ref, 0, bq, kb * block_k, block_k)
                 if causal:
                     s = _causal_mask(s, pid * bq, kb * block_k)
-                m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-                alpha = jnp.exp(m - m_new)
-                p = jnp.exp(s - m_new)
-                l = l * alpha + p.sum(axis=-1, keepdims=True)
-                acc = acc * alpha + jnp.dot(p.astype(q.dtype), vs,
-                                            preferred_element_type=jnp.float32)
-                return m_new, l, acc
+                return _online_softmax(carry, s, vs, q.dtype)
 
-            if causal:
-                # blocks fully above the diagonal contribute nothing — skip
-                # their dots (roughly halves causal attention FLOPs)
+            if causal and kb:
+                # tiles fully above the diagonal contribute nothing — skip
+                # their dots (roughly halves causal attention FLOPs); the
+                # first tile holds column 0, which every row sees
                 live = (pid + 1) * bq > kb * block_k
-                m, l, acc = jax.lax.cond(live, blk, lambda c: c, (m, l, acc))
+                carry = jax.lax.cond(live, blk, lambda c: c, carry)
             else:
-                m, l, acc = blk((m, l, acc))
+                carry = blk(carry)
+        m, l, acc = carry
     l = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / l).astype(o_ref.dtype)
     # logsumexp per row — the statistic the tiled backward replays
@@ -205,22 +235,12 @@ def _attention_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
         lse_ref[0] = m + jnp.log(l)
 
 
-def _loops_over_blocks(causal, bias_ref):
-    """Whether a kernel walks the other side's blocks in a ``fori_loop``
-    bounded by the causal diagonal instead of a static unroll with a
-    ``cond`` a block: under the causal mask and without an additive bias
-    (a bias tile would need a dynamic slice along lanes).  One traced body
-    instead of S / 128: at S=4096 the unrolled kernels took most of a
-    step's tracing, lowering and Mosaic compile time, and the blocks above
-    the diagonal are never visited."""
-    return causal and bias_ref is None
-
-
 def _rope_runs_looped(rope, causal, bias):
     """A rotary pair exists in the kernels' looped sweeps alone — under the
     causal mask, without a bias: the decoder's case.  ``_attention_route``
     composes one head size for every other op."""
-    if rope is not None and not _loops_over_blocks(causal, bias):
+    if rope is not None and not _loops_over_blocks(causal,
+                                                   bias is not None):
         raise ValueError("the flash kernels take a rotary pair only under "
                          "the causal mask and without a bias")
 
@@ -231,10 +251,11 @@ def _rows(block, size):
     return pl.ds(pl.multiple_of(block * size, size), size)
 
 
-def _bias_block(bias_ref, rows, row_len, cols, col_len):
+def _add_bias(s, bias_ref, rows, row_len, cols, col_len):
+    """Scores plus the bias tile at static offsets, widened to float32."""
     if bias_ref is None:
-        return 0.0
-    return bias_ref[0, rows:rows + row_len, cols:cols + col_len] \
+        return s
+    return s + bias_ref[0, rows:rows + row_len, cols:cols + col_len] \
         .astype(jnp.float32)
 
 
@@ -251,17 +272,17 @@ def _causal_mask(s, q0, k0):
 def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
                dq_ref, delta_out_ref, p_scr, dp_scr, *, scale, block_k,
                causal=False, qr_ref=None, kr_ref=None, dqr_ref=None):
-    """FlashAttention-2 backward, dQ pass: one q block vs all k blocks.
+    """FlashAttention-2 backward, dQ pass: one q block vs all k tiles.
     p is recomputed from the saved LSE — no [S, S] materialization.
 
     delta_i = sum_d dO_id O_id = sum_j P_ij dP_ij.  With ``delta_ref`` the
-    caller passed it and every K block is one pass.  Without it
+    caller passed it and every k tile is one pass.  Without it
     (``delta_ref`` None) the kernel forms it from what it computes anyway:
-    the row's P and dP tiles are held in the ``[bq, S_kv]`` float32
-    scratches ``p_scr`` / ``dp_scr`` while P * dP is summed, then dS and
-    dQ come from the held tiles — the same five products, no ``out``
-    operand — and delta is written to ``delta_out_ref`` for the dK/dV and
-    dbias passes.
+    the row's P and dP tiles are held while P * dP is summed — as values
+    where the row is ONE tile (block_k = S_kv), else in the ``[bq, S_kv]``
+    float32 scratches ``p_scr`` / ``dp_scr`` — then dS and dQ come from
+    the held tiles: the same five products, no ``out`` operand, and delta
+    is written to ``delta_out_ref`` for the dK/dV and dbias passes.
 
     With a rotary part (``qr_ref`` [bq, R], ``kr_ref`` [S_kv, R], the keys
     shared by the sequence's heads; in the looped sweep only,
@@ -282,7 +303,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
 
     def tiles(kb, cols):
         s = _scores(q, k_ref[0, cols, :], scale)
-        s = s + _bias_block(bias_ref, 0, bq, kb * block_k, block_k)
+        s = _add_bias(s, bias_ref, 0, bq, kb * block_k, block_k)
         if causal:
             s = _causal_mask(s, pid * bq, kb * block_k)
         p = jnp.exp(s - lse)
@@ -290,8 +311,14 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
                      preferred_element_type=jnp.float32)
         return p, dp
 
-    if delta_ref is None:
-        # blocks above the causal diagonal are skipped in both sweeps:
+    held = None
+    if delta_ref is not None:
+        delta = delta_ref[0]                       # [bq, 1] fp32
+    elif len(blocks) == 1:
+        held = tiles(*blocks[0])                   # the whole row, as values
+        delta = (held[0] * held[1]).sum(axis=-1, keepdims=True)
+    else:
+        # tiles above the causal diagonal are skipped in both sweeps:
         # their P is zero, so they add nothing to delta or dQ
         w = jnp.zeros((bq, block_k), jnp.float32)
         for kb, cols in blocks:
@@ -303,18 +330,12 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
             w = jax.lax.cond(live(kb), hold, lambda w: w, w) if causal \
                 else hold(w)
         delta = w.sum(axis=-1, keepdims=True)      # [bq, 1] fp32
+    if delta_ref is None:
         delta_out_ref[0] = delta
-    else:
-        delta = delta_ref[0]                       # [bq, 1] fp32
-
-    def ds_tile(kb, cols):
-        p, dp = (p_scr[:, cols], dp_scr[:, cols]) \
-            if delta_ref is None else tiles(kb, cols)
-        return (p * (dp - delta) * scale).astype(q.dtype)
 
     acc = jnp.zeros((bq, D), jnp.float32)
-    if _loops_over_blocks(causal, bias_ref):
-        # one sweep over the live k blocks; delta is passed in
+    if _loops_over_blocks(causal, bias_ref is not None):
+        # one sweep over the live k tiles; delta is passed in
         # (``_delta_in_kernel``), so nothing is held between two sweeps
         acc_r = None if qr_ref is None else jnp.zeros(qr.shape, jnp.float32)
 
@@ -339,6 +360,14 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
         if qr_ref is not None:
             dqr_ref[0] = acc_r.astype(dqr_ref.dtype)
     else:
+        def ds_tile(kb, cols):
+            if delta_ref is not None:
+                p, dp = tiles(kb, cols)
+            else:
+                p, dp = held if held is not None \
+                    else (p_scr[:, cols], dp_scr[:, cols])
+            return (p * (dp - delta) * scale).astype(q.dtype)
+
         for kb, cols in blocks:
             def blk(acc, kb=kb, cols=cols):
                 return acc + jnp.dot(ds_tile(kb, cols), k_ref[0, cols, :],
@@ -351,7 +380,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
 def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, *, scale, block_q, causal=False,
                 qr_ref=None, kr_ref=None, dkr_ref=None):
-    """dK/dV pass: one k block vs all q blocks.  With a rotary part
+    """dK/dV pass: one k block vs all q tiles.  With a rotary part
     (``qr_ref`` [S_q, R], ``kr_ref`` [bk, R]; in the looped sweep only)
     ``dkr_ref`` takes THIS head's ``dS^T qr`` in float32; the caller sums
     it over the heads that share the rotary keys."""
@@ -364,32 +393,36 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
     dk = jnp.zeros((bk, D), jnp.float32)
     dv = jnp.zeros(vs.shape, jnp.float32)
     num_qb = S // block_q
-    if _loops_over_blocks(causal, bias_ref):
+
+    def tile(carry, q, do, lse, delta, s, qr=None):
+        """One [bq, bk] tile's part of dV, dK and (with ``qr``) dKr, from
+        its float32 scores ``s``."""
+        dk, dv = carry
+        p = jnp.exp(s - lse)                       # [bq, bk]
+        dv = dv + jnp.dot(p.astype(q.dtype).T, do,
+                          preferred_element_type=jnp.float32)
+        dp = jnp.dot(do, vs.T, preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta) * scale).astype(q.dtype)
+        if qr is None:
+            return dk + jnp.dot(ds.T, q,
+                                preferred_element_type=jnp.float32), dv
+        return (dk[0] + jnp.dot(ds.T, q, preferred_element_type=jnp.float32),
+                dk[1] + jnp.dot(ds.T, qr,
+                                preferred_element_type=jnp.float32)), dv
+
+    if _loops_over_blocks(causal, bias_ref is not None):
         if krs is not None:
             dk = (dk, jnp.zeros(krs.shape, jnp.float32))
 
         def step(qb, carry):
-            dk, dv = carry
             rows = _rows(qb, block_q)
-            q, do = q_ref[0, rows, :], do_ref[0, rows, :]
+            q = q_ref[0, rows, :]
             qr = None if qr_ref is None else qr_ref[0, rows, :]
             s = _causal_mask(_scores(q, ks, scale, qr, krs), qb * block_q,
                              pid * bk)
-            p = jnp.exp(s - lse_ref[0, rows, :])
-            dv = dv + jnp.dot(p.astype(q.dtype).T, do,
-                              preferred_element_type=jnp.float32)
-            dp = jnp.dot(do, vs.T, preferred_element_type=jnp.float32)
-            ds = (p * (dp - delta_ref[0, rows, :]) * scale).astype(q.dtype)
-            if qr is None:
-                dk = dk + jnp.dot(ds.T, q,
-                                  preferred_element_type=jnp.float32)
-            else:
-                dk = (dk[0] + jnp.dot(ds.T, q,
-                                      preferred_element_type=jnp.float32),
-                      dk[1] + jnp.dot(ds.T, qr,
-                                      preferred_element_type=jnp.float32))
-            return dk, dv
-        # from the first q block whose last row reaches this k block
+            return tile(carry, q, do_ref[0, rows, :], lse_ref[0, rows, :],
+                        delta_ref[0, rows, :], s, qr)
+        # from the first q tile whose last row reaches this k block
         dk, dv = jax.lax.fori_loop((pid * bk) // block_q, num_qb, step,
                                    (dk, dv))
         if krs is not None:
@@ -397,27 +430,19 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
             dkr_ref[0] = dkr.astype(dkr_ref.dtype)
     else:
         for qb in range(num_qb):
-            q = q_ref[0, qb * block_q:(qb + 1) * block_q, :]
-            do = do_ref[0, qb * block_q:(qb + 1) * block_q, :]
-            lse = lse_ref[0, qb * block_q:(qb + 1) * block_q, :]     # [bq, 1]
-            delta = delta_ref[0, qb * block_q:(qb + 1) * block_q, :]
+            rows = slice(qb * block_q, (qb + 1) * block_q)
 
-            def blk(carry, q=q, do=do, lse=lse, delta=delta, qb=qb):
-                dk, dv = carry
+            def blk(carry, rows=rows, qb=qb):
+                q = q_ref[0, rows, :]
                 s = _scores(q, ks, scale)
-                s = s + _bias_block(bias_ref, qb * block_q, block_q, 0, bk)
+                s = _add_bias(s, bias_ref, qb * block_q, block_q, 0, bk)
                 if causal:
                     s = _causal_mask(s, qb * block_q, pid * bk)
-                p = jnp.exp(s - lse)                   # [bq, bk]
-                pc = p.astype(q.dtype)
-                dv = dv + jnp.dot(pc.T, do, preferred_element_type=jnp.float32)
-                dp = jnp.dot(do, vs.T, preferred_element_type=jnp.float32)
-                ds = (p * (dp - delta) * scale).astype(q.dtype)
-                dk = dk + jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
-                return dk, dv
+                return tile(carry, q, do_ref[0, rows, :],
+                            lse_ref[0, rows, :], delta_ref[0, rows, :], s)
 
             if causal:
-                # q blocks entirely before this k block see none of it
+                # q tiles entirely before this k block see none of it
                 live = (qb + 1) * block_q > pid * bk
                 dk, dv = jax.lax.cond(live, blk, lambda c: c, (dk, dv))
             else:
@@ -445,7 +470,7 @@ def _dbias_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
         def blk(ks=ks, vs=vs, kb=kb):
             s = jnp.dot(q, ks.T,
                         preferred_element_type=jnp.float32) * scale
-            s = s + _bias_block(bias_ref, 0, bq, kb * block_k, block_k)
+            s = _add_bias(s, bias_ref, 0, bq, kb * block_k, block_k)
             if causal:
                 s = _causal_mask(s, pid * bq, kb * block_k)
             p = jnp.exp(s - lse)
@@ -473,16 +498,26 @@ def _row_stat_spec(block_q):
     return pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0))
 
 
-def _tileable(S_q, S_kv):
-    block_q, block_k = min(128, S_q), min(128, S_kv)
-    return (S_q % block_q == 0 and S_kv % block_k == 0), block_q, block_k
+# Tile sides the chooser tries, largest first.  128 is the least the
+# (8, 128) layout allows; 512 x 512 is where a float32 score tile is 1 MiB.
+_TILE_SIDES = (512, 256, 128)
 
+# What one kernel may ask of VMEM by the chooser's estimate: a quarter of a
+# v5e TensorCore's 128 MiB.  The compiler's scoped default is 16 MiB, a
+# default and not the chip: past it ``_vmem_limit`` raises the kernel's
+# own limit to what the estimate says.
+_VMEM_BUDGET_BYTES = 32 << 20
+_VMEM_SCOPED_DEFAULT_BYTES = 16 << 20
 
 # VMEM the whole-sequence operands of one grid cell may take when double-
 # buffered (the pipeline's default) before ``_whole_seq`` single-buffers
-# them; the compiler's scoped limit is 16 MiB and the kernel's own tiles,
-# blocks and outputs need the rest.
+# them.
 _DOUBLE_BUFFER_MAX_BYTES = 12 << 20
+
+# Longest S_kv at which the dQ pass holds the row's P and dP tiles (2 x
+# block_q x S_kv x 4 bytes of VMEM scratch) to form delta itself; beyond
+# it the caller keeps ``out`` and passes delta in (module docstring).
+_DELTA_IN_KERNEL_MAX_SKV = 4096
 
 
 def _lane_padded_bytes(rows, cols, itemsize):
@@ -503,6 +538,168 @@ def _whole_seq(operands):
     if need <= _DOUBLE_BUFFER_MAX_BYTES:
         return {}
     return {"pipeline_mode": pl.Buffered(1)}
+
+
+def _loops_over_blocks(causal, has_bias):
+    """Whether a kernel walks the other side's tiles in a ``fori_loop``
+    bounded by the causal diagonal instead of a static unroll with a
+    ``cond`` a tile: under the causal mask and without an additive bias
+    (a bias tile would need a dynamic slice along lanes).  One traced body
+    instead of S / block: at S=4096 the unrolled kernels took most of a
+    step's tracing, lowering and Mosaic compile time, and the tiles above
+    the diagonal are never visited."""
+    return causal and not has_bias
+
+
+def _delta_in_kernel(S_kv, causal=False, has_bias=False):
+    """Whether the dQ pass forms delta itself (from held P and dP tiles)
+    and the forward keeps no ``out``.  Not where it walks the k tiles in a
+    loop (``_loops_over_blocks``): that pass is one sweep and takes delta
+    from ``_row_delta`` of the ``out`` the forward keeps."""
+    return S_kv <= _DELTA_IN_KERNEL_MAX_SKV and \
+        not _loops_over_blocks(causal, has_bias)
+
+
+def _whole_side(kernel, S_q, S_kv, D, D_v, R, itemsize):
+    """The whole-sequence operands of one grid cell, ``(rows, cols,
+    itemsize)`` each: K, V and the shared rotary keys for the passes over
+    q blocks; Q, dO, Q's rotary part and both row statistics for the dK/dV
+    pass.  (No rotary part: R = 0, an operand of no bytes.)"""
+    if kernel == "dkv":
+        return [(S_q, D, itemsize), (S_q, D_v, itemsize), (S_q, R, itemsize),
+                (S_q, 1, 4), (S_q, 1, 4)]
+    return [(S_kv, D, itemsize), (S_kv, D_v, itemsize), (S_kv, R, itemsize)]
+
+
+def _vmem_bytes(kernel, block_q, block_k, S_q, S_kv, D, D_v, R, has_bias,
+                causal, itemsize):
+    """VMEM one grid cell of ``kernel`` ('fwd', 'dq', 'dkv' or 'dbias')
+    asks for at tiles of ``block_q x block_k``, from shapes alone: what the
+    chooser holds against ``_VMEM_BUDGET_BYTES`` and ``_vmem_limit`` hands
+    the compiler.  Every operand is lane-padded (``_lane_padded_bytes``).
+
+    * the cell's own blocks, in and out, twice (the pipeline's two
+      buffers): ``block_q`` rows of Q, its rotary part, dO, the row
+      statistics and the outputs; in the dK/dV pass ``block_k`` rows of K,
+      V, the rotary keys and dK, dV, and the per-head float32 dKr;
+    * the bias tile, twice: ``[block_q, S_kv]``, ``[S_q, block_k]`` in the
+      dK/dV pass, and as much again for the dbias pass's output;
+    * the whole other side (``_whole_side``), twice or once as
+      ``_whole_seq`` decides;
+    * the float32 tiles a step of the sweep holds at once — scores, P, dP
+      and dS, the widened bias tile — at ``block_q x block_k x 4`` each,
+      and the copies in the input dtype that feed the MXU (P, dS, and in
+      the dK/dV pass their transposes);
+    * the float32 accumulators, and the forward's two running statistics;
+    * the dQ pass's two ``[block_q, S_kv]`` float32 scratches, where it
+      forms delta over more than one tile (``_delta_in_kernel``)."""
+    if kernel == "dkv":
+        b = block_k
+        own = [(b, D, itemsize), (b, D_v, itemsize), (b, R, itemsize),  # in
+               (b, D, itemsize), (b, D_v, itemsize), (b, R, 4)]        # out
+        bias_tile = (S_q, b, itemsize)
+        acc = [(b, D, 4), (b, D_v, 4), (b, R, 4)]
+        wide, narrow = 4, 4
+    else:
+        b = block_q
+        own = [(b, D, itemsize), (b, R, itemsize), (b, 1, 4)]  # Q, Qr, lse
+        bias_tile = (b, S_kv, itemsize)
+        if kernel == "fwd":
+            own += [(b, D_v, itemsize)]                        # out
+            acc = [(b, D_v, 4), (b, 1, 4), (b, 1, 4)]          # acc, m, l
+            wide, narrow = 2, 1
+        else:
+            own += [(b, D_v, itemsize), (b, 1, 4)]             # dO, delta
+            if kernel == "dq":
+                own += [(b, D, itemsize), (b, R, itemsize)]    # dQ, dQr
+                acc = [(b, D, 4), (b, R, 4)]
+                wide, narrow = 4, 1
+            else:
+                own += [bias_tile]                             # dbias
+                acc = []
+                wide, narrow = 4, 0
+    if has_bias:
+        own += [bias_tile]
+    whole = _whole_side(kernel, S_q, S_kv, D, D_v, R, itemsize)
+    need = 2 * sum(_lane_padded_bytes(*o) for o in own)
+    need += (1 if _whole_seq(whole) else 2) * sum(
+        _lane_padded_bytes(*o) for o in whole)
+    need += block_q * block_k * (4 * (wide + has_bias) + itemsize * narrow)
+    need += sum(_lane_padded_bytes(*o) for o in acc)
+    if kernel == "dq" and S_kv > block_k and \
+            _delta_in_kernel(S_kv, causal, has_bias):
+        need += 2 * block_q * S_kv * 4
+    return need
+
+
+def _vmem_limit(need):
+    """The ``vmem_limit_bytes`` a kernel whose estimate is ``need`` is
+    compiled with: none while an eighth over the estimate stays within the
+    compiler's scoped default, else the estimate and that eighth (what the
+    estimate cannot see: Mosaic's own scratch, an operand XLA parks in
+    VMEM inside a step)."""
+    limit = need + need // 8
+    return None if limit <= _VMEM_SCOPED_DEFAULT_BYTES else limit
+
+
+def _tiles(kernel, S_q, S_kv, D, D_v, R, has_bias, causal, itemsize):
+    """``(ok, block_q, block_k)`` of one kernel ('fwd', 'dq', 'dkv',
+    'dbias') at one shape: the largest tile, sides from ``_TILE_SIDES``
+    that divide the sequence, whose ``_vmem_bytes`` fits
+    ``_VMEM_BUDGET_BYTES``.  A grid cell's fixed cost (the pipeline's step,
+    the serial matmul -> row max -> exp -> row sum -> matmul round, MXU
+    weights loaded for the streamed rows) is paid per tile, not per FLOP,
+    so the largest tile wins; of two equal areas the one with more rows of
+    the grid's own side, which cuts the cells.  ``ok`` False: no side
+    divides a sequence (the caller composes), or nothing fits."""
+    def sides(S):
+        # a sequence shorter than the least side is one block
+        return [S] if S < _TILE_SIDES[-1] else \
+            [t for t in _TILE_SIDES if S % t == 0]
+
+    def size(tile):
+        return tile[0] * tile[1], tile[kernel == "dkv"]
+    for block_q, block_k in sorted(
+            ((bq, bk) for bq in sides(S_q) for bk in sides(S_kv)),
+            key=size, reverse=True):
+        if _vmem_bytes(kernel, block_q, block_k, S_q, S_kv, D, D_v, R,
+                       has_bias, causal, itemsize) <= _VMEM_BUDGET_BYTES:
+            return True, block_q, block_k
+    return False, min(_TILE_SIDES[-1], S_q), min(_TILE_SIDES[-1], S_kv)
+
+
+def _shape_key(q, k, v, bias, causal, rope):
+    """What the chooser sees of a call: ``_tiles``'s arguments after the
+    kernel's name."""
+    return (q.shape[1], k.shape[1], q.shape[2], v.shape[2],
+            0 if rope is None else rope[0].shape[2], bias is not None,
+            bool(causal), q.dtype.itemsize)
+
+
+def _flash_fits(*shape):
+    """Whether every kernel of forward and backward has a tile at this
+    shape (``_shape_key``); else the caller composes."""
+    return all(_tiles(kernel, *shape)[0]
+               for kernel in ("fwd", "dq", "dkv") + ("dbias",) * shape[5])
+
+
+def _plan(kernel, *shape):
+    """One kernel call's ``(block_q, block_k, keywords for the whole-
+    sequence BlockSpecs, vmem_limit_bytes)`` at this shape
+    (``_shape_key``), counted in ``flash_tiles_total``."""
+    _, block_q, block_k = _tiles(kernel, *shape)
+    _m_tiles.inc(kernel=kernel, block_q=block_q, block_k=block_k)
+    S_q, S_kv, D, D_v, R, _, _, itemsize = shape
+    return (block_q, block_k,
+            _whole_seq(_whole_side(kernel, S_q, S_kv, D, D_v, R, itemsize)),
+            _vmem_limit(_vmem_bytes(kernel, block_q, block_k, *shape)))
+
+
+_m_tiles = telemetry.counter(
+    "flash_tiles_total",
+    "flash kernel calls traced, by kernel ('fwd', 'dq', 'dkv', 'dbias') and "
+    "the tile the chooser picked for the call's shape: block_q rows of Q by "
+    "block_k rows of K a step of the sweep")
 
 
 def _rope_specs(rope, q_block, k_block, whole_mode=None):
@@ -553,8 +750,8 @@ def _flash_forward(q, k, v, bias, scale, *, with_lse=False,
             "causal=True needs S_q == S_kv (got %d vs %d); apply an "
             "explicit bias for cross-length causal masking"
             % (S_q, S_kv))
-    ok, block_q, block_k = _tileable(S_q, S_kv)
-    if not ok:
+    shape = _shape_key(q, k, v, bias, causal, rope)
+    if not _flash_fits(*shape):
         out = _reference_attention(*_compose_rope(q, k, rope), v, bias,
                                    scale, causal=causal)
         if not with_lse:
@@ -564,11 +761,12 @@ def _flash_forward(q, k, v, bias, scale, *, with_lse=False,
         raise AssertionError("with_lse requested for a non-tileable "
                              "shape — caller bug")
     _rope_runs_looped(rope, causal, bias)
+    block_q, block_k, whole, vmem = _plan("fwd", *shape)
     grid = (BH, S_q // block_q)
     in_specs = [
         pl.BlockSpec((1, block_q, D), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((1, S_kv, D), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((1, S_kv, D_v), lambda i, j: (i, 0, 0)),
+        pl.BlockSpec((1, S_kv, D), lambda i, j: (i, 0, 0), **whole),
+        pl.BlockSpec((1, S_kv, D_v), lambda i, j: (i, 0, 0), **whole),
     ]
     args = [q, k, v]
     if bias is not None:
@@ -576,7 +774,7 @@ def _flash_forward(q, k, v, bias, scale, *, with_lse=False,
                                      lambda i, j: (i, j, 0)))
         args.append(bias)
     if rope is not None:
-        in_specs += _rope_specs(rope, block_q, None)
+        in_specs += _rope_specs(rope, block_q, None, whole)
         args += list(rope)
     n_in = len(args)
 
@@ -597,28 +795,13 @@ def _flash_forward(q, k, v, bias, scale, *, with_lse=False,
         out_specs.append(_row_stat_spec(block_q))
         out_shape.append(jax.ShapeDtypeStruct((BH, S_q, 1), jnp.float32))
     res = _pallas_call(
-        kern, "flash_fwd",
+        kern, "flash_fwd", vmem_limit_bytes=vmem,
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
     )(*args)
     return (res[0], res[1]) if with_lse else res[0]
-
-
-# Longest S_kv at which the dQ pass holds the row's P and dP tiles
-# (2 x 128 x S_kv x 4 bytes of VMEM scratch) to form delta itself; beyond
-# it the caller keeps ``out`` and passes delta in (module docstring).
-_DELTA_IN_KERNEL_MAX_SKV = 4096
-
-
-def _delta_in_kernel(S_kv, causal=False, bias=None):
-    """Whether the dQ pass forms delta itself (two sweeps over held tiles)
-    and the forward keeps no ``out``.  Not where it walks the k blocks in a
-    loop (``_loops_over_blocks``): that pass is one sweep and takes delta
-    from ``_row_delta`` of the ``out`` the forward keeps."""
-    return S_kv <= _DELTA_IN_KERNEL_MAX_SKV and \
-        not _loops_over_blocks(causal, bias)
 
 
 def _row_delta(g, out):
@@ -637,20 +820,22 @@ def _flash_dq(q, k, v, bias, scale, lse, g, causal, delta, rope=None):
     BH, S_q, D = q.shape
     S_kv = k.shape[1]
     D_v = v.shape[2]
-    _, block_q, block_k = _tileable(S_q, S_kv)
+    block_q, block_k, whole, vmem = _plan(
+        "dq", *_shape_key(q, k, v, bias, causal, rope))
     _rope_runs_looped(rope, causal, bias)
     in_kernel = delta is None
     q_block = pl.BlockSpec((1, block_q, D), lambda i, j: (i, j, 0))
     in_specs = [q_block,
-                pl.BlockSpec((1, S_kv, D), lambda i, j: (i, 0, 0)),
-                pl.BlockSpec((1, S_kv, D_v), lambda i, j: (i, 0, 0))]
+                pl.BlockSpec((1, S_kv, D), lambda i, j: (i, 0, 0), **whole),
+                pl.BlockSpec((1, S_kv, D_v), lambda i, j: (i, 0, 0),
+                             **whole)]
     args = [q, k, v]
     if bias is not None:
         in_specs.append(pl.BlockSpec((1, block_q, S_kv),
                                      lambda i, j: (i, j, 0)))
         args.append(bias)
     if rope is not None:
-        in_specs += _rope_specs(rope, block_q, None)
+        in_specs += _rope_specs(rope, block_q, None, whole)
         args += list(rope)
     in_specs += [pl.BlockSpec((1, block_q, D_v), lambda i, j: (i, j, 0)),
                  _row_stat_spec(block_q)]                       # dO, lse
@@ -664,7 +849,9 @@ def _flash_dq(q, k, v, bias, scale, lse, g, causal, delta, rope=None):
     if in_kernel:
         out_specs.append(_row_stat_spec(block_q))
         out_shape.append(jax.ShapeDtypeStruct((BH, S_q, 1), jnp.float32))
-        scratch = [pltpu.VMEM((block_q, S_kv), jnp.float32)] * 2
+        # a row that is one tile is held as values (``_dq_kernel``)
+        scratch = [pltpu.VMEM((block_q, S_kv), jnp.float32)] * 2 \
+            if S_kv > block_k else []
     else:
         in_specs.append(_row_stat_spec(block_q))
         args.append(delta)
@@ -678,15 +865,15 @@ def _flash_dq(q, k, v, bias, scale, lse, g, causal, delta, rope=None):
         delta_ref = None if in_kernel else refs.pop(0)
         dq_ref = refs.pop(0)
         dqr_ref = refs.pop(0) if rope is not None else None
-        delta_out_ref, p_scr, dp_scr = refs if in_kernel \
-            else (None, None, None)
+        delta_out_ref = refs.pop(0) if in_kernel else None
+        p_scr, dp_scr = refs or (None, None)
         _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                    delta_ref, dq_ref, delta_out_ref, p_scr, dp_scr,
                    scale=scale, block_k=block_k, causal=causal,
                    qr_ref=qr_ref, kr_ref=kr_ref, dqr_ref=dqr_ref)
 
     res = _pallas_call(
-        kern, "flash_dq",
+        kern, "flash_dq", vmem_limit_bytes=vmem,
         grid=(BH, S_q // block_q),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -715,15 +902,11 @@ def _flash_backward(q, k, v, bias, scale, lse, g, causal=False, delta=None,
     BH, S_q, D = q.shape
     S_kv = k.shape[1]
     D_v = v.shape[2]
-    _, block_q, block_k = _tileable(S_q, S_kv)
+    shape = _shape_key(q, k, v, bias, causal, rope)
     dq, delta = _flash_dq(q, k, v, bias, scale, lse, g, causal, delta, rope)
 
     # dK/dV pass: grid over k blocks, the whole Q side of a head in VMEM
-    q_side = [(S_q, D, q.dtype.itemsize), (S_q, D_v, g.dtype.itemsize),
-              (S_q, 1, 4), (S_q, 1, 4)]
-    if rope is not None:
-        q_side.append((S_q, rope[0].shape[2], rope[0].dtype.itemsize))
-    whole = _whole_seq(q_side)
+    block_q, block_k, whole, vmem = _plan("dkv", *shape)
     dkv_specs = [
         pl.BlockSpec((1, S_q, D), lambda i, j: (i, 0, 0), **whole),  # q
         pl.BlockSpec((1, block_k, D), lambda i, j: (i, j, 0)),  # k
@@ -764,7 +947,7 @@ def _flash_backward(q, k, v, bias, scale, lse, g, causal=False, delta=None,
         dkv_out_shape.append(
             jax.ShapeDtypeStruct((BH, S_kv, R), jnp.float32))
     dk, dv, *dkr = _pallas_call(
-        dkv_kern, "flash_dkv",
+        dkv_kern, "flash_dkv", vmem_limit_bytes=vmem,
         grid=(BH, S_kv // block_k),
         in_specs=dkv_specs,
         out_specs=dkv_out_specs,
@@ -778,19 +961,20 @@ def _flash_backward(q, k, v, bias, scale, lse, g, causal=False, delta=None,
 
     dbias = None
     if bias is not None and bias_grad:
+        block_q, block_k, whole, vmem = _plan("dbias", *shape)
         db_specs = [
             pl.BlockSpec((1, block_q, D), lambda i, j: (i, j, 0)),  # q
-            pl.BlockSpec((1, S_kv, D), lambda i, j: (i, 0, 0)),     # k
-            pl.BlockSpec((1, S_kv, D), lambda i, j: (i, 0, 0)),     # v
+            pl.BlockSpec((1, S_kv, D), lambda i, j: (i, 0, 0), **whole),
+            pl.BlockSpec((1, S_kv, D_v), lambda i, j: (i, 0, 0), **whole),
             pl.BlockSpec((1, block_q, S_kv), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_q, D), lambda i, j: (i, j, 0)),  # dO
+            pl.BlockSpec((1, block_q, D_v), lambda i, j: (i, j, 0)),  # dO
             _row_stat_spec(block_q),                                # lse
             _row_stat_spec(block_q),                                # delta
         ]
         dbias = _pallas_call(
             functools.partial(_dbias_kernel, scale=scale,
                               block_k=block_k, causal=causal),
-            "flash_dbias",
+            "flash_dbias", vmem_limit_bytes=vmem,
             grid=(BH, S_q // block_q),
             in_specs=db_specs,
             out_specs=pl.BlockSpec((1, block_q, S_kv),
@@ -812,7 +996,8 @@ def _forward_keeping_lse(q, k, v, bias, scale, causal, rope=None):
     out, lse = _flash_forward(q, k, v, bias, scale, with_lse=True,
                               causal=causal, rope=rope)
     lse = jax.lax.optimization_barrier(lse[..., 0])
-    return out, lse, (None if _delta_in_kernel(k.shape[1], causal, bias)
+    return out, lse, (None if _delta_in_kernel(k.shape[1], causal,
+                                               bias is not None)
                       else out)
 
 
@@ -837,8 +1022,7 @@ def flash_attention(q, k, v, bias, scale, causal=False, rope=None):
 
 
 def _fa_fwd(q, k, v, bias, scale, causal, rope=None):
-    ok, _, _ = _tileable(q.shape[1], k.shape[1])
-    if not ok:
+    if not _flash_fits(*_shape_key(q, k, v, bias, causal, rope)):
         # non-tileable shapes keep the exact-composition fallback
         return _flash_forward(q, k, v, bias, scale, causal=causal,
                               rope=rope), (q, k, v, bias, rope, None, None)
@@ -1057,14 +1241,15 @@ def _is_test(ctx):
     return bool(ctx.attr("is_test", False) or ctx.state.is_test)
 
 
-def _attention_route(ctx, S_q, S_kv):
+def _attention_route(ctx, q, k, v):
     """Which path a ``fused_attention`` op — or its grad op, which
     carries the same attributes — takes, from what it can observe:
     ``(sp_active, dropout, flash)``.  ``sp_active``: the sequence-parallel
     transpiler stamped the op and the step compiles over a mesh carrying
     that axis; ``dropout``: the attention-probability rate in effect;
-    ``flash``: neither, and the shape tiles, so the Pallas kernels run on
-    the op's operands as they are.  A rotary pair is among them only under
+    ``flash``: neither, and every kernel has a tile at the shape
+    (``_flash_fits``), so the Pallas kernels run on the op's operands (Q,
+    K, V ``[B, H, S, D]``) as they are.  A rotary pair is among them only under
     the causal mask and without a bias (``_rope_runs_looped``); any other
     op with a pair composes one head size first."""
     dropout = 0.0 if _is_test(ctx) else \
@@ -1073,10 +1258,16 @@ def _attention_route(ctx, S_q, S_kv):
     mesh = getattr(ctx.state, "mesh", None)
     sp = dict(mesh.shape).get(sp_axis, 1) if (sp_axis and mesh is not None) \
         else 1
+    S_q = q.shape[2]
     sp_active = sp > 1 and S_q % sp == 0 and _axis_is_auto(mesh, sp_axis)
-    flash = not sp_active and not dropout and _tileable(S_q, S_kv)[0] and \
-        not (ctx.has_input("QRope") and (
-            ctx.has_input("BiasQK") or not ctx.attr("causal", False)))
+    causal, has_bias = bool(ctx.attr("causal", False)), \
+        ctx.has_input("BiasQK")
+    qr = ctx.i_opt("QRope")
+    flash = not sp_active and not dropout and \
+        not (qr is not None and (has_bias or not causal)) and \
+        _flash_fits(S_q, k.shape[2], q.shape[3], v.shape[3],
+                    0 if qr is None else qr.shape[3], has_bias, causal,
+                    q.dtype.itemsize)
     return sp_active, dropout, flash
 
 
@@ -1155,7 +1346,7 @@ def _fused_attention(ctx, op):
             "%d) — the causal alignment for cross-length attention is "
             "ambiguous; pass an explicit additive bias instead"
             % (S_q, S_kv))
-    sp_active, dropout, flash = _attention_route(ctx, S_q, S_kv)
+    sp_active, dropout, flash = _attention_route(ctx, q, k, v)
     sp_axis = ctx.attr("sp_axis", None)
     mesh = getattr(ctx.state, "mesh", None)
     _m_lowered.inc(shape="mha" if qr is None else "mla",
@@ -1234,8 +1425,7 @@ def _fused_attention_grad(ctx, op):
     q, k, v = ctx.i("Q"), ctx.i("K"), ctx.i("V")
     lse, g = ctx.i_opt("LSE"), ctx.i_opt("Out@GRAD")
     S_q, S_kv = q.shape[2], k.shape[2]
-    if lse is None or g is None or \
-            not _attention_route(ctx, S_q, S_kv)[2]:
+    if lse is None or g is None or not _attention_route(ctx, q, k, v)[2]:
         _m_grad_lowered.inc(path="replay")
         generic_grad_lower(ctx, op, residual_slots=("LSE",))
         return
@@ -1254,7 +1444,8 @@ def _fused_attention_grad(ctx, op):
     dq, dk, dv, dbias = _backward_from_lse(
         _flat(q), _flat(k), _flat(v), bf, float(ctx.attr("scale", 1.0)),
         bool(ctx.attr("causal", False)), _flat(lse),
-        None if _delta_in_kernel(S_kv, bool(ctx.attr("causal", False)), bias)
+        None if _delta_in_kernel(S_kv, bool(ctx.attr("causal", False)),
+                                 bias is not None)
         else _flat(ctx.i("Out")),
         _flat(g.astype(q.dtype)), bias_grad=bool(want["BiasQK"]), rope=rope)
     grads = {}

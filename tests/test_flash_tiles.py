@@ -1,0 +1,219 @@
+"""The flash kernels' tile chooser (``pallas_ops._tiles``): what it picks
+from a shape, as a pure function; the kernels' numerics at tiles above
+128 x 128, interpreted on the CPU against the XLA composition at the
+tolerances of test_pallas_attention.py; and the counter that says which
+tile engaged."""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.fluid import telemetry
+from paddle_tpu.fluid.ops import pallas_ops
+from paddle_tpu.fluid.ops.pallas_ops import (_reference_attention,
+                                             flash_attention)
+
+KERNELS = ("fwd", "dq", "dkv")
+
+# (S_q, S_kv, D, D_v, R, has_bias, causal, itemsize): ``_tiles``'s arguments
+# after the kernel's name
+SHAPES = {
+    # bert_base_s512_flash: BH=384, bf16, the padding mask as a bias
+    "flash_cell": (512, 512, 64, 64, 0, True, False, 2),
+    # moonlight_ep8share_s4096_train: 16 heads, 128 + 64 | 128, causal
+    "moonlight_cell": (4096, 4096, 128, 128, 64, False, True, 2),
+    "s128_d16": (128, 128, 16, 16, 0, True, False, 4),
+    "cross_128x256": (128, 256, 16, 16, 0, False, False, 4),
+    "s384": (384, 384, 64, 64, 0, False, False, 2),
+    "s640": (640, 640, 64, 64, 0, True, False, 2),
+    "s64": (64, 64, 16, 16, 0, False, False, 4),
+    "s8192_bias": (8192, 8192, 64, 64, 0, True, False, 2),
+    "s16384_causal": (16384, 16384, 64, 64, 0, False, True, 2),
+}
+
+CHOSEN = {
+    "flash_cell": {k: (512, 512) for k in KERNELS},
+    "moonlight_cell": {k: (512, 512) for k in KERNELS},
+    "s128_d16": {k: (128, 128) for k in KERNELS},
+    "cross_128x256": {k: (128, 256) for k in KERNELS},
+    # divisors only: 384 = 3 x 128, 640 = 5 x 128
+    "s384": {k: (128, 128) for k in KERNELS},
+    "s640": {k: (128, 128) for k in KERNELS},
+    # shorter than the least side: the sequence is one block
+    "s64": {k: (64, 64) for k in KERNELS},
+    # a [512, 8192] bias tile and the whole K and V leave the dK/dV pass,
+    # whose bias tile is [8192, block_k], no room for 512 columns
+    "s8192_bias": {"fwd": (512, 512), "dq": (512, 512), "dkv": (512, 256)},
+    "s16384_causal": {k: (512, 512) for k in KERNELS},
+}
+
+
+@pytest.mark.parametrize("kernel", KERNELS)
+@pytest.mark.parametrize("shape", sorted(CHOSEN))
+def test_chooser_picks_the_largest_tile_that_fits(shape, kernel):
+    ok, block_q, block_k = pallas_ops._tiles(kernel, *SHAPES[shape])
+    assert ok
+    assert (block_q, block_k) == CHOSEN[shape][kernel]
+    S_q, S_kv = SHAPES[shape][:2]
+    assert S_q % block_q == 0 and S_kv % block_k == 0
+
+
+@pytest.mark.parametrize("kernel", KERNELS + ("dbias",))
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_estimate_fits_the_budget_and_the_limit_it_hands_on(shape, kernel):
+    """The chosen tile's estimate is within the budget, no smaller tile
+    asks for more, and ``vmem_limit_bytes`` — set only past the compiler's
+    16 MiB default — is never under the estimate."""
+    _, block_q, block_k = pallas_ops._tiles(kernel, *SHAPES[shape])
+    need = pallas_ops._vmem_bytes(kernel, block_q, block_k, *SHAPES[shape])
+    assert need <= pallas_ops._VMEM_BUDGET_BYTES
+    limit = pallas_ops._vmem_limit(need)
+    if limit is None:
+        assert need < pallas_ops._VMEM_SCOPED_DEFAULT_BYTES
+    else:
+        assert need < limit <= 128 << 20
+    for bq, bk in ((block_q // 2, block_k), (block_q, block_k // 2)):
+        if min(bq, bk) >= 128:
+            assert pallas_ops._vmem_bytes(kernel, bq, bk,
+                                          *SHAPES[shape]) <= need
+
+
+@pytest.mark.parametrize("shape,why", [
+    ((192, 192, 16, 16, 0, False, False, 4), "no side divides 192"),
+    ((128, 200, 16, 16, 0, False, False, 4), "nor 200"),
+    ((32768, 32768, 128, 128, 0, False, True, 2),
+     "the dK/dV pass's whole Q side alone is past the budget"),
+])
+def test_shapes_without_a_tile_compose(shape, why):
+    assert not pallas_ops._flash_fits(*shape), why
+
+
+def test_the_budget_is_a_share_of_the_chip():
+    """A quarter of a v5e core's 128 MiB, above the 16 MiB default: the
+    Moonlight cell's dK/dV pass at 512 x 512 is the kernel that needs the
+    raised limit."""
+    assert pallas_ops._VMEM_SCOPED_DEFAULT_BYTES < \
+        pallas_ops._VMEM_BUDGET_BYTES <= (128 << 20) // 4
+    need = pallas_ops._vmem_bytes("dkv", 512, 512, *SHAPES["moonlight_cell"])
+    assert pallas_ops._vmem_limit(need) > 16 << 20
+    assert pallas_ops._vmem_limit(
+        pallas_ops._vmem_bytes("fwd", 512, 512,
+                               *SHAPES["flash_cell"])) is None
+
+
+# -- numerics above 128 x 128, interpreted ----------------------------------
+
+def _close(got, want, atol=2e-4):
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=atol)
+
+
+@pytest.fixture(scope="module")
+def biased_s512():
+    """S=512, D=64 with a bias: one 512 x 512 tile a head in every kernel
+    (no rescale in the forward, the dQ pass's row held as values)."""
+    rng = np.random.RandomState(21)
+    BH, S, D = 2, 512, 64
+    q, k, v, g = (jnp.asarray(rng.randn(BH, S, D).astype(np.float32) * 0.5)
+                  for _ in range(4))
+    bias = jnp.asarray((rng.randn(BH, S, S) * 0.3).astype(np.float32))
+    scale = D ** -0.5
+    for kernel in KERNELS + ("dbias",):
+        assert pallas_ops._tiles(
+            kernel, *pallas_ops._shape_key(q, k, v, bias, False, None)
+        )[1:] == (512, 512)
+    want, vjp = jax.vjp(lambda *a: _reference_attention(*a, scale),
+                        q, k, v, bias)
+    got, fvjp = jax.vjp(lambda *a: flash_attention(*a, scale), q, k, v, bias)
+    names = ("dq", "dk", "dv", "dbias")
+    return dict(zip(names, fvjp(g)), out=got), \
+        dict(zip(names, vjp(g)), out=want)
+
+
+@pytest.mark.parametrize("what", ["out", "dq", "dk", "dv", "dbias"])
+def test_one_tile_a_head_matches_the_composition(biased_s512, what):
+    got, want = biased_s512
+    _close(got[what], want[what], atol=2e-5 if what == "out" else 2e-4)
+
+
+def _rotary_case(S, D, D_v, R, seed):
+    """Two heads of one sequence sharing one rotary key head, causal."""
+    rng = np.random.RandomState(seed)
+
+    def arr(*dims):
+        return jnp.asarray(rng.randn(*dims).astype(np.float32) * 0.3)
+    args = (arr(2, S, D), arr(2, S, D), arr(2, S, D_v), arr(2, S, R),
+            arr(1, S, R))
+    scale = (D + R) ** -0.5
+
+    def composed(q, k, v, qr, kr):
+        return _reference_attention(
+            *pallas_ops._compose_rope(q, k, (qr, kr)), v, None, scale,
+            causal=True)
+
+    def flash(q, k, v, qr, kr):
+        return flash_attention(q, k, v, None, scale, True, (qr, kr))
+    g = arr(2, S, D_v)
+    want, vjp = jax.vjp(composed, *args)
+    got, fvjp = jax.vjp(flash, *args)
+    names = ("dq", "dk", "dv", "dqr", "dkr")
+    return dict(zip(names, fvjp(g)), out=got), \
+        dict(zip(names, vjp(g)), out=want)
+
+
+@pytest.fixture(scope="module")
+def rotary_s1024():
+    """Causal S=1024 at latent attention's head sizes (128 + 64 | 128):
+    512 x 512 tiles, so each looped sweep walks a tile wholly below the
+    diagonal and one on it."""
+    shape = (1024, 1024, 128, 128, 64, False, True, 4)
+    for kernel in KERNELS:
+        assert pallas_ops._tiles(kernel, *shape)[1:] == (512, 512)
+    return _rotary_case(1024, 128, 128, 64, seed=22)
+
+
+@pytest.mark.parametrize("what", ["out", "dq", "dk", "dv", "dqr", "dkr"])
+def test_looped_sweeps_match_the_composition(rotary_s1024, what):
+    got, want = rotary_s1024
+    _close(got[what], want[what])
+
+
+@pytest.mark.parametrize("block_q,block_k", [(512, 256), (256, 512),
+                                             (128, 512), (512, 128)])
+def test_looped_sweeps_with_unequal_sides(monkeypatch, block_q, block_k):
+    """The loop bounds — every tile the diagonal crosses or that lies below
+    it, none above — hold for block_q != block_k, which the chooser gives
+    where VMEM holds one side back."""
+    monkeypatch.setattr(pallas_ops, "_tiles",
+                        lambda kernel, *shape: (True, block_q, block_k))
+    got, want = _rotary_case(1024, 16, 8, 8, seed=23)
+    for what in want:
+        _close(got[what], want[what])
+
+
+# -- the counter --------------------------------------------------------------
+
+@pytest.mark.parametrize("form,causal,with_bias,S", [
+    ("unrolled", False, True, 512), ("looped", True, False, 256)])
+def test_flash_tiles_total_counts_each_kernel_of_a_lowering(form, causal,
+                                                            with_bias, S):
+    """One lowering of forward and backward counts one ``fwd``, one ``dq``
+    and one ``dkv`` call at the tile the chooser picked (trace-time, like
+    ``fused_attention_lowered_total``), in either form."""
+    counter = telemetry.registry().get("flash_tiles_total")
+    x = jax.ShapeDtypeStruct((2, S, 16), jnp.float32)
+    bias = jax.ShapeDtypeStruct((2, S, S), jnp.float32) if with_bias \
+        else None
+    block = min(S, 512)
+    labels = [dict(kernel=k, block_q=block, block_k=block)
+              for k in KERNELS + ("dbias",)]
+    before = [counter.value(**lb) for lb in labels]
+    jax.jit(jax.grad(
+        lambda q, k, v, b: flash_attention(q, k, v, b, 0.25, causal).sum(),
+        argnums=(0, 1, 2))).lower(x, x, x, bias)
+    # the dbias pass is traced wherever there is a bias (XLA drops it when
+    # nothing reads its output)
+    assert [counter.value(**lb) - b for lb, b in zip(labels, before)] == \
+        [1, 1, 1, int(with_bias)]

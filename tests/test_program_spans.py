@@ -4,6 +4,7 @@ counters fed by the executor's ``jax.monitoring`` listener, and the names the
 lowering leaves in the compiled step (op role scopes, Pallas kernel names).
 """
 
+import collections
 import glob
 import itertools
 import os
@@ -392,10 +393,24 @@ def _mosaic_calls(executable):
                              executable.as_text()))
 
 
+def _tiles_counted():
+    """``{(kernel, block_q, block_k): calls traced}`` so far."""
+    values = telemetry.registry().snapshot()["flash_tiles_total"]["values"]
+    return collections.Counter({
+        tuple(v["labels"][n] for n in ("kernel", "block_q", "block_k")):
+        v["value"] for v in values})
+
+
 @pytest.fixture(scope="module")
 def bert_stack_steps(one_chip):
-    return {with_lse: _compile_step_for(one_chip, *_bert_stack(with_lse))
-            for with_lse in (True, False)}
+    """The compiled step with and without the ``LSE`` slot, and under
+    ``"tiles"`` what ``flash_tiles_total`` counted while the first was
+    traced."""
+    before = _tiles_counted()
+    steps = {True: _compile_step_for(one_chip, *_bert_stack(True))}
+    steps["tiles"] = _tiles_counted() - before
+    steps[False] = _compile_step_for(one_chip, *_bert_stack(False))
+    return steps
 
 
 def test_training_step_holds_one_flash_forward_per_layer(bert_stack_steps):
@@ -406,6 +421,16 @@ def test_training_step_holds_one_flash_forward_per_layer(bert_stack_steps):
     assert _mosaic_calls(bert_stack_steps[True]) == sorted(kernels * LAYERS)
     assert _mosaic_calls(bert_stack_steps[False]) == sorted(
         kernels * LAYERS + ["flash_fwd"] * LAYERS)
+
+
+def test_flash_cell_shape_compiles_with_one_tile_a_head(bert_stack_steps):
+    """At the flash cell's attention shape (S=512, D=64, bf16, the padding
+    mask as a bias) the chooser gives every kernel ONE 512 x 512 tile a
+    head, and the STEP compiles for the v5e with them (inside a step XLA
+    may park a kernel's operand in VMEM, so a kernel that compiles alone
+    proves nothing): one traced call a layer and kernel."""
+    assert bert_stack_steps["tiles"] == {
+        (kernel, 512, 512): LAYERS for kernel in ("fwd", "dq", "dkv")}
 
 
 def test_lse_residual_costs_its_own_bytes_and_no_more(bert_stack_steps):
@@ -432,9 +457,11 @@ def test_lse_residual_costs_its_own_bytes_and_no_more(bert_stack_steps):
 def test_latent_attention_kernels_fit_the_v5e_at_s4096(one_chip):
     """One sequence of the Moonlight cell's attention (16 heads, S=4096,
     nope 128 + a shared rotary key head of 64, V 128, bf16, causal):
-    forward, dQ and dK/dV keep a whole sequence of the other side in VMEM
-    and compile within the 16 MiB scoped default (no ``vmem_limit_bytes``),
-    as three named Mosaic calls."""
+    forward, dQ and dK/dV keep a whole sequence of the other side in VMEM,
+    walk it in 512 x 512 tiles and compile as three named Mosaic calls.
+    The dK/dV pass's estimate (15.5 MiB and an eighth) is past the
+    compiler's 16 MiB scoped default, so that call alone carries a
+    ``vmem_limit_bytes``, computed from the estimate."""
     heads, seq = 16, 4096
 
     def shape(*dims):
@@ -445,10 +472,21 @@ def test_latent_attention_kernels_fit_the_v5e_at_s4096(one_chip):
             q, k, v, None, 192 ** -0.5, True, (qr, kr)) \
             .astype(jnp.float32).sum()
 
-    step = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+    before = _tiles_counted()
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
         shape(heads, seq, 128), shape(heads, seq, 128),
         shape(heads, seq, 128), shape(heads, seq, 64),
-        shape(1, seq, 64)).compile()
+        shape(1, seq, 64))
+    assert _tiles_counted() - before == {
+        (kernel, 512, 512): 1 for kernel in ("fwd", "dq", "dkv")}
+    key = (seq, seq, 128, 128, 64, False, True, 2)
+    limits = [pallas_ops._vmem_limit(pallas_ops._vmem_bytes(
+        kernel, 512, 512, *key)) for kernel in ("fwd", "dq", "dkv")]
+    assert limits[:2] == [None, None] and limits[2] > 16 << 20
+    config = lowered.as_text().replace("\\22", '"')
+    assert config.count('"scoped_memory_configs"') == 1
+    assert '"size": %d' % limits[2] in config
+    step = lowered.compile()
     assert _mosaic_calls(step) == ["flash_dkv", "flash_dq", "flash_fwd"]
     # no per-head copy of the shared rotary keys: nothing of [16, 4096, 64]
     # is built from the [1, 4096, 64] operand
