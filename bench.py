@@ -500,15 +500,12 @@ def bench_lenet(batch, steps):
 def bench_hot_path(steps=2000):
     """Host overhead per cached-hit ``run()`` step (``--hot-path``).
 
-    Times three per-step paths on ONE compiled tiny train step (fc +
+    Times two per-step paths on ONE compiled tiny train step (fc +
     mean + SGD, device-resident feed, async fetches):
 
     * ``bare_jit``   — the jitted callable invoked directly with
       pre-resolved state (the floor: zero executor involvement);
-    * ``plan``       — ``exe.run`` via the cached dispatch plan
-      (FLAGS_dispatch_plan=1, the default);
-    * ``legacy``     — ``exe.run`` with FLAGS_dispatch_plan=0 (the
-      pre-plan per-step key/coerce/sort path, kept as the A/B control).
+    * ``plan``       — ``exe.run`` via the cached dispatch plan.
 
     ``host_overhead_us_per_step`` = plan − bare_jit.  The computation is
     deliberately tiny so the host, not the device, is the bottleneck —
@@ -555,14 +552,6 @@ def bench_hot_path(steps=2000):
             return exe.run(main_prog, feed=feed, fetch_list=[loss],
                            return_numpy=False)
 
-        def legacy_step(i):
-            _flags.set_flag("dispatch_plan", False)
-            try:
-                return exe.run(main_prog, feed=feed, fetch_list=[loss],
-                               return_numpy=False)
-            finally:
-                _flags.set_flag("dispatch_plan", True)
-
         # compile + warm every path once; everything below is cached-hit
         window(run_step)
         assert exe._compile_count == 2, \
@@ -584,15 +573,15 @@ def bench_hot_path(steps=2000):
                 scope.set_var(n, v)
             return fetches
 
-        # interleave the three paths round-robin and keep per-path minima:
+        # interleave the two paths round-robin and keep per-path minima:
         # the shared host is noisy and this measures HOST work — sampling
         # all paths across the same noise windows makes the deltas honest
-        paths = {"bare": bare_step, "plan": run_step, "legacy": legacy_step}
+        paths = {"bare": bare_step, "plan": run_step}
         best = {k: float("inf") for k in paths}
         for _ in range(5):
             for name, fn in paths.items():
                 best[name] = min(best[name], window(fn))
-        bare_s, plan_s, legacy_s = best["bare"], best["plan"], best["legacy"]
+        bare_s, plan_s = best["bare"], best["plan"]
 
         out = {
             "metric": "executor_hot_path",
@@ -601,14 +590,10 @@ def bench_hot_path(steps=2000):
             "steps_per_sec": round(1.0 / plan_s, 1),
             "bare_jit_us_per_step": round(bare_s * 1e6, 2),
             "plan_us_per_step": round(plan_s * 1e6, 2),
-            "legacy_us_per_step": round(legacy_s * 1e6, 2),
             "host_overhead_us_per_step": round((plan_s - bare_s) * 1e6, 2),
-            "legacy_host_overhead_us_per_step":
-                round((legacy_s - bare_s) * 1e6, 2),
             "value": round((plan_s - bare_s) * 1e6, 2),
-            "vs_baseline": round((legacy_s - bare_s) / (plan_s - bare_s), 2)
-                if plan_s > bare_s else 0.0,
-            "vs_baseline_kind": "legacy_over_plan_host_overhead",
+            "vs_baseline": round(plan_s / bare_s, 2),
+            "vs_baseline_kind": "plan_over_bare_jit_step_time",
             "metrics": _telemetry_metrics(since=tele0),
         }
         # device-cost ledger record of the hot-path step (AFTER the
@@ -1537,7 +1522,7 @@ def _main():
             result = bench_hot_path_window(focus_k=focus)
         else:
             # host-overhead microbenchmark: dispatch-plan run() vs the
-            # bare jitted call vs the legacy per-step-key path —
+            # bare jitted call —
             # measures the executor, not the chip (valid on any
             # backend, incl. CPU CI)
             result = bench_hot_path()

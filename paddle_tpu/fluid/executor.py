@@ -14,6 +14,7 @@ as device arrays; each run threads them through the compiled function with
 buffer donation, so in-place optimizer updates stay in-place on device.
 """
 
+import collections
 import itertools
 import os
 import threading
@@ -415,10 +416,21 @@ def _feed_val_sig(val):
     return (a.shape, a.dtype)
 
 
+# What Executor._target hands to _resolve: the Program to compile, the
+# target's own part of both cache keys, the executable and plan caches to
+# use, and (data-parallel CompiledProgram only) ``in_shardings(device,
+# scope)`` giving Executor._compile's argument of that name.
+_Target = collections.namedtuple(
+    "_Target", "program extra cache plans in_shardings")
+
+
+def _key_extra(target, K):
+    return target.extra if K is None else target.extra + ("window", int(K))
+
+
 def _executable_key(program, feed_names, feed_vals, fetch_names, extra=()):
-    """Cache key for a compiled executable — ONE builder shared by
-    Executor._lookup_compiled and CompiledProgram._lookup_compiled so a
-    key component added for one can never be missed by the other.
+    """Cache key for a compiled executable (``extra``: the target's own
+    key part and the window size, ``_key_extra``).
 
     Trace-time flags and program annotations change the lowered
     computation: fold them in so toggling FLAGS_* (or mutating
@@ -490,9 +502,8 @@ def sharded_put(d, shardings, device, coerce=None):
 
 
 def prefetch_ahead(put, batches, depth=None, stop_when=None):
-    """Input staging ahead of consumption — ONE entry point shared by
-    the DataLoader producer (reader.py) and ``train_from_dataset`` so
-    the prefetch contract cannot drift between them.
+    """Input staging ahead of consumption, for the DataLoader producer
+    (reader.py) and ``train_from_dataset``.
 
     ``depth`` (default ``FLAGS_feed_ring_depth``) selects the pipeline:
 
@@ -502,10 +513,13 @@ def prefetch_ahead(put, batches, depth=None, stop_when=None):
       windows ahead, so the host-side window fill and the H2D transfer
       both overlap the consumer's device compute, and the consumer
       blocks only when the ring is empty (starvation, counted).
-    - ``depth == 0`` — the legacy synchronous one-batch lookahead (the
+    - ``depth == 0`` — a one-batch lookahead on the CALLING thread (the
       buffered_reader.cc double buffer, XLA style): ``put`` is applied
-      to the NEXT batch before the current one is yielded on the
-      consumer's own thread.  Bit-exact same feeds; the A/B control.
+      to the NEXT batch before the current one is yielded.  This is the
+      staging a program-bound loader's worker thread does (reader.py:
+      the worker IS the producer and the capacity queue its buffer), so
+      it is the input path of every benchmark cell.  Same feeds, bit
+      for bit, as the ring's.
 
     The returned iterator supports ``close()`` (via the generator
     protocol at depth 0): closing it closes the source iterator and, on
@@ -527,9 +541,9 @@ def feed_nbytes(feed):
 
 
 def _prefetch_ahead_sync(put, batches):
-    """The depth-0 legacy path of ``prefetch_ahead`` (see there).  Each
-    batch is drawn from the source and ``put`` inside one
-    ``fluid.feed_stage`` span on the calling thread (a program-bound
+    """Depth 0 of ``prefetch_ahead`` (see there).  Each batch is drawn
+    from the source and ``put`` inside one ``fluid.feed_stage`` span on
+    the calling thread (a program-bound
     loader's worker), numbered as the consumer's ``fluid.feed_wait``
     numbers the batch it is handed."""
     it = iter(batches)
@@ -1164,9 +1178,9 @@ class Executor:
         self._plans = {}
         self._plan_hits = 0
         self._compile_count = 0   # test hook: recompile detection
-        # plan-path outcome of the dispatch in flight (True/False), or
-        # None on the legacy per-step-key path — read by the step-event
-        self._last_plan_hit = None
+        # whether the dispatch in flight found its plan cached — read by
+        # the step-event
+        self._last_plan_hit = False
         # the executable behind the most recent dispatch: input-pipeline
         # producers read its feed shardings so feeds land already
         # sharded (GSPMD) / on the right device ahead of the next pull
@@ -1185,53 +1199,54 @@ class Executor:
         recompile-detection test hook read it here."""
         return self._compile_count
 
-    def _lookup_compiled(self, program, feed, fetch_list, steps_per_run=None):
-        """Resolve (program, feed signature, fetches) to the cached
-        executable, compiling on miss.  Shared by run() and
-        compiled_hlo() so the cache key can never drift between them.
-        ``steps_per_run=K`` (not None) resolves the fused K-step WINDOW
-        executable (feed values stacked [K, ...] — K=1 is a window of
-        one, still scanned, so the bench A/B isolates the window size
-        rather than the code path); None is the plain per-step
-        executable."""
-        feed = dict(feed or {})
-        fetch_list = fetch_list or []
-        fetch_names = [v.name if isinstance(v, framework.Variable) else v
-                       for v in fetch_list]
+    def _target(self, program):
+        """What a run of ``program`` compiles and where it caches it.  A
+        ``CompiledProgram`` describes itself (``_compile_spec``: the
+        data-parallel GSPMD step with caches of its own, or, plain, just
+        its Program); a Program compiles as it is, into this executor's
+        caches."""
+        program = program or framework.default_main_program()
+        spec = getattr(program, "_compile_spec", None)
+        if spec is not None:
+            target = spec()
+            if target.in_shardings is not None:
+                return target
+            program = target.program
+        return _Target(program, (), self._cache, self._plans, None)
 
+    def _lookup_compiled(self, target, feed, fetch_list, scope, K):
+        """(compiled block, coerced feeds) from the target's executable
+        cache, compiling on a miss: the lower half of ``_resolve``, and
+        where introspection enters.  ``K`` (not None) is the fused
+        K-step WINDOW executable (feed values stacked [K, ...]; K=1 is a
+        window of one, still scanned); None is the per-step one."""
+        program = target.program
+        fetch_names = [v.name if isinstance(v, framework.Variable) else v
+                       for v in fetch_list or ()]
         feed_names = sorted(feed)
         block = program.global_block()
         feed_vals = [coerce_feed_value(block, n, feed[n]) for n in feed_names]
-
-        extra = () if steps_per_run is None else \
-            ("window", int(steps_per_run))
         key = _executable_key(program, feed_names, feed_vals, fetch_names,
-                              extra=extra)
-        compiled = self._cache.get(key)
+                              extra=_key_extra(target, K))
+        compiled = target.cache.get(key)
+        _m_exec_cache.inc(result="miss" if compiled is None else "hit")
         if compiled is None:
-            _m_exec_cache.inc(result="miss")
-            compiled = self._compile(program, feed_names,
-                                     [tuple(np.shape(v)) for v in feed_vals],
-                                     fetch_names,
-                                     steps_per_run=steps_per_run)
-            self._cache[key] = compiled
-        else:
-            _m_exec_cache.inc(result="hit")
-        return compiled, feed_vals, fetch_names
+            compiled = target.cache[key] = self._compile(
+                program, feed_names,
+                [tuple(np.shape(v)) for v in feed_vals], fetch_names,
+                in_shardings=target.in_shardings and
+                target.in_shardings(self._device, scope),
+                steps_per_run=K)
+        return compiled, feed_vals
 
     def _resolve_compiled(self, program, feed, fetch_list, scope,
                           steps_per_run=None):
         """(compiled block, coerced feeds) that ``run`` would dispatch
-        for ``program`` — a raw Program through this executor's cache, a
-        CompiledProgram through its own (the data-parallel GSPMD
-        executable, not the raw program's single-device one)."""
-        program = program or framework.default_main_program()
-        if isinstance(program, _CompiledProgramProxy):
-            return program._lookup_executable(self, feed, fetch_list, scope,
-                                              steps_per_run)
-        compiled, feed_vals, _ = self._lookup_compiled(
-            program, feed, fetch_list, steps_per_run=steps_per_run)
-        return compiled, feed_vals
+        for ``program``: a raw Program's through this executor's cache,
+        a data-parallel CompiledProgram's GSPMD one through its own."""
+        return self._lookup_compiled(
+            self._target(program), feed or {}, fetch_list,
+            scope or global_scope(), steps_per_run)
 
     def _lowered_executable(self, program, feed, fetch_list, scope,
                             steps_per_run=None):
@@ -1373,7 +1388,7 @@ class Executor:
             fetch_var_name="fetch", scope=None, return_numpy=True,
             use_program_cache=True):
         return self._as_step(scope, 1, self._run, program, feed, fetch_list,
-                             scope, return_numpy)
+                             scope, None, return_numpy)
 
     def _as_step(self, scope, k, body, *args):
         """One call of ``run`` / ``run_window``, as a reader of a trace
@@ -1393,49 +1408,6 @@ class Executor:
         profiler.device_profile_end(last.steps_per_run if last else k)
         return out
 
-    def _run(self, program, feed, fetch_list, scope, return_numpy):
-        program = program or framework.default_main_program()
-        if isinstance(program, _CompiledProgramProxy):
-            return program._run(self, feed, fetch_list, scope, return_numpy)
-        scope = scope or global_scope()
-        if getattr(program, "_ps_endpoint", None) is not None and \
-                not getattr(program, "_ps_applying", False):
-            return self._run_pserver(program, scope)
-        if not feed and getattr(program, "_loader", None) is not None:
-            # non-iterable DataLoader bound to the program (the
-            # reference PyReader-in-program contract, reader.py).  The
-            # pulled feed dispatches through _run_resolved, NEVER back
-            # through run(): a loader with no feed vars pulls an empty
-            # dict, and re-entering this branch would pull again
-            return self._loader_fed_run(
-                program._loader, scope,
-                lambda f: self._run_resolved(program, f, fetch_list,
-                                             scope, return_numpy),
-                lambda f, k: self._run_window(program, f, fetch_list,
-                                              scope, k, False))
-        return self._run_resolved(program, feed, fetch_list, scope,
-                                  return_numpy)
-
-    def _run_resolved(self, program, feed, fetch_list, scope,
-                      return_numpy):
-        """The dispatch tail of ``run()`` once any program-bound loader
-        pull has happened: plan-cache path, or the legacy per-step path
-        (FLAGS_dispatch_plan=0 / unhashable feed signature)."""
-        feed = feed or {}
-        self._last_plan_hit = None   # legacy path unless the plan says so
-        if flags.get_flag("dispatch_plan"):
-            key = self._plan_key(program, feed, fetch_list)
-            if key is not None:
-                plan = self._plan_get_or_build(
-                    self._plans, key, program,
-                    lambda: self._lookup_compiled(program, feed,
-                                                  fetch_list)[0])
-                return self._run_plan(plan, scope, feed, return_numpy)
-        compiled, feed_vals, _ = self._lookup_compiled(
-            program, feed, fetch_list)
-        feed_vals = compiled.globalize_feeds(feed_vals)
-        return self._dispatch(compiled, scope, feed_vals, return_numpy)
-
     def run_window(self, program=None, feed=None, fetch_list=None,
                    scope=None, steps_per_run=None, return_numpy=False):
         """Run K training steps in ONE jitted dispatch — the multi-step
@@ -1454,70 +1426,84 @@ class Executor:
         ``steps_per_run`` defaults to ``FLAGS_steps_per_run``.
         ``scope.step_counter`` advances by K per call, so checkpoints
         land on window boundaries.  K=1 is valid (a window of one) but
-        the legacy per-step ``run()`` remains the default and the A/B
-        control."""
+        the per-step ``run()`` remains the default."""
         K = flags.steps_per_run_value(steps_per_run)
-        return self._as_step(scope, K, self._run_window, program, feed,
+        return self._as_step(scope, K, self._run, program, feed,
                              fetch_list, scope, K, return_numpy)
 
-    def _run_window(self, program, feed, fetch_list, scope, K,
-                    return_numpy):
-        program = program or framework.default_main_program()
-        if isinstance(program, _CompiledProgramProxy):
-            return program._run_window(self, feed, fetch_list, scope, K,
-                                       return_numpy)
+    def _run(self, program, feed, fetch_list, scope, K, return_numpy):
+        """One step (``K`` None) or one fused window of K steps, of a
+        Program or a CompiledProgram: pserver branch, the pull from a
+        program-bound loader, ``_resolve``, dispatch."""
+        target = self._target(program)
+        program = target.program
         scope = scope or global_scope()
-        feed = dict(feed or {})
-        for n, v in feed.items():
-            shape = np.shape(v)
-            if not shape or shape[0] != K:
-                raise ValueError(
-                    "run_window(steps_per_run=%d): feed %r must be "
-                    "stacked [K, per-step shape...] with leading dim %d, "
-                    "got shape %s" % (K, n, K, shape))
-        self._last_plan_hit = None   # legacy path unless the plan says so
-        if flags.get_flag("dispatch_plan"):
-            key = self._plan_key(program, feed, fetch_list)
-            if key is not None:
-                key = key + ("__window__", K)
-                plan = self._plan_get_or_build(
-                    self._plans, key, program,
-                    lambda: self._lookup_compiled(
-                        program, feed, fetch_list, steps_per_run=K)[0])
-                return self._run_plan(plan, scope, feed, return_numpy)
-        compiled, feed_vals, _ = self._lookup_compiled(
-            program, feed, fetch_list, steps_per_run=K)
-        feed_vals = compiled.globalize_feeds(feed_vals)
-        return self._dispatch(compiled, scope, feed_vals, return_numpy)
-
-    def _loader_fed_run(self, loader, scope, run_step, run_window):
-        """Pull one staged batch from a program-bound loader and
-        dispatch it — ONE flow shared by ``Executor.run`` and
-        ``CompiledProgram._run`` so the loader contract cannot drift
-        between them.  Raises ``core.EOFException`` at pass end.
-
-        Binds this executor's device first so the producer thread
-        device_puts upcoming batches (H2D overlaps the current step's
-        compute; re-bound every pull so a later executor on a DIFFERENT
-        device never receives batches committed to a stale one).  A
-        loader staging stacked ``[K, ...]`` windows routes to
-        ``run_window(feed, k)`` with ``return_numpy=False`` — the
-        per-step ``return_numpy=True`` default would make every pull
-        raise the K>1 numpy guard (the trailing window may be shorter
-        than K); per-step loaders go through ``run_step(feed)``.  After
-        the dispatch, the plan's feed shardings are handed back to the
-        producer so SUBSEQUENT batches land with the compiled layout
-        (GSPMD feeds arrive sharded instead of
-        replicated-then-resharded)."""
-        loader._consumer_device = self._device
-        feed = loader.next_feed(step=scope.step_counter)
-        if getattr(loader, "_steps_per_run", 1) > 1:
-            k = int(np.shape(next(iter(feed.values())))[0]) if feed else 1
-            out = run_window(feed, k)
-        else:
-            out = run_step(feed)
-        self._bind_loader_shardings(loader)
+        if getattr(program, "_ps_endpoint", None) is not None and \
+                not getattr(program, "_ps_applying", False):
+            return self._run_pserver(program, scope)
+        loader = getattr(program, "_loader", None) \
+            if K is None and not feed else None
+        if loader is not None:
+            # non-iterable DataLoader bound to the program (the
+            # reference PyReader-in-program contract, reader.py): pull
+            # one staged batch; core.EOFException ends the pass.  Bind
+            # this executor's device first, every pull, so the producer
+            # thread device_puts upcoming batches where they will run
+            # (H2D overlaps the current step; a later executor on
+            # another device never gets batches committed to a stale one)
+            loader._consumer_device = self._device
+            feed = loader.next_feed(step=scope.step_counter)
+            if getattr(loader, "_steps_per_run", 1) > 1:
+                # a loader staging stacked [K, ...] windows (the last
+                # may be shorter): a window never returns numpy
+                K = int(np.shape(next(iter(feed.values())))[0]) \
+                    if feed else 1
+                return_numpy = False
+        feed = feed or {}
+        if K is not None:
+            for n, v in feed.items():
+                shape = np.shape(v)
+                if not shape or shape[0] != K:
+                    raise ValueError(
+                        "run_window(steps_per_run=%d): feed %r must be "
+                        "stacked [K, per-step shape...] with leading dim "
+                        "%d, got shape %s" % (K, n, K, shape))
+        plan = self._resolve(target, feed, fetch_list, scope, K)
+        out = self._run_plan(plan, scope, feed, return_numpy)
+        if loader is not None:
+            self._bind_loader_shardings(loader)
         return out
+
+    def _resolve(self, target, feed, fetch_list, scope, K):
+        """The dispatch plan of (target, feed signature, fetches, K):
+        plan cache, then the executable cache, then ``_compile``.  The
+        plan key reads the RAW feed dtypes from attributes (no numpy
+        coercion, no SHA: program.fingerprint is version-cached) over an
+        executable keyed on the canonical ones, so a steady-state step is
+        one lookup here.  annotation_key and trace_time_key ARE
+        recomputed per step on purpose: direct attribute / flag mutation
+        between runs must recompile, and neither is version-tracked."""
+        program = target.program
+        names = tuple(sorted(feed))
+        key = (program.fingerprint, names,
+               tuple(_feed_val_sig(feed[n]) for n in names),
+               tuple(v.name if isinstance(v, framework.Variable) else v
+                     for v in fetch_list or ()),
+               getattr(program, "_amp_dtype", None),
+               getattr(program, "_amp_keep", False),
+               framework.annotation_key(program),
+               flags.trace_time_key()) + _key_extra(target, K)
+        plan = target.plans.get(key)
+        self._last_plan_hit = plan is not None
+        _m_plan.inc(result="hit" if self._last_plan_hit else "miss")
+        if plan is None:
+            compiled, _ = self._lookup_compiled(target, feed, fetch_list,
+                                                  scope, K)
+            plan = target.plans[key] = _DispatchPlan(
+                compiled, program.global_block())
+        else:
+            self._plan_hits += 1
+        return plan
 
     def _bind_loader_shardings(self, loader):
         """Hand the just-dispatched executable's feed shardings back to
@@ -1538,44 +1524,6 @@ class Executor:
             sh = {n: s for n, s in zip(compiled.feed_names, fsh)
                   if s is not None}
         loader._consumer_shardings = sh or None
-
-    def _plan_key(self, program, feed, fetch_list):
-        """Hot-path cache key: no numpy coercion of feed values, no SHA
-        hashing (program.fingerprint is version-cached).  annotation_key
-        and trace_time_key ARE recomputed per step on purpose — direct
-        attribute/flag mutation between runs must recompile, and neither
-        is version-tracked (same freshness contract as the legacy key).
-        Returns None when a component is unhashable — those runs take
-        the legacy path."""
-        try:
-            names = tuple(sorted(feed))
-            return (program.fingerprint,
-                    names,
-                    tuple(_feed_val_sig(feed[n]) for n in names),
-                    tuple(v.name if isinstance(v, framework.Variable) else v
-                          for v in (fetch_list or ())),
-                    getattr(program, "_amp_dtype", None),
-                    getattr(program, "_amp_keep", False),
-                    framework.annotation_key(program),
-                    flags.trace_time_key())
-        except TypeError:
-            return None
-
-    def _plan_get_or_build(self, plans, key, program, lookup_compiled):
-        """Get-or-build + hit accounting for a dispatch-plan cache — ONE
-        flow shared by Executor.run and CompiledProgram._run so the
-        hit/miss semantics cannot drift between them."""
-        plan = plans.get(key)
-        if plan is None:
-            self._last_plan_hit = False
-            _m_plan.inc(result="miss")
-            plan = _DispatchPlan(lookup_compiled(), program.global_block())
-            plans[key] = plan
-        else:
-            self._plan_hits += 1
-            self._last_plan_hit = True
-            _m_plan.inc(result="hit")
-        return plan
 
     def _run_plan(self, plan, scope, feed, return_numpy):
         """Steady-state step: pre-bound coercers + the jitted call."""
@@ -2523,7 +2471,7 @@ class Executor:
             by Executor._lowered_executable so the explicit-collective
             path is HLO-introspectable like every other path.
             ``feed_vals`` carry GLOBAL shapes (multi-host callers
-            globalize first — _run_plan/_run_resolved already do)."""
+            globalize first — _run_plan already does)."""
             if state["jitted"] is not None:
                 return state["jitted"]
             # out_specs need output ranks: probe with eval_shape on the
@@ -2605,18 +2553,3 @@ class Executor:
             cblock.feed_placement_shardings = tuple(
                 NamedSharding(mesh, per_feed) for _ in feed_names)
         return cblock
-
-
-class _CompiledProgramProxy:
-    """Marker base so Executor.run can detect CompiledProgram (compiler.py)."""
-
-    def _run(self, exe, feed, fetch_list, scope, return_numpy):
-        raise NotImplementedError
-
-    def _run_window(self, exe, feed, fetch_list, scope, steps_per_run,
-                    return_numpy):
-        raise NotImplementedError
-
-    def _lookup_executable(self, exe, feed, fetch_list, scope,
-                           steps_per_run=None):
-        raise NotImplementedError

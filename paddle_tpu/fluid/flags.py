@@ -44,16 +44,6 @@ import os
 
 _DEFS = {
     "matmul_precision": "default",   # default | high | highest
-    "conv_layout": "NCHW",           # NCHW (reference) | NHWC (TPU-native)
-    "conv_pallas": False,            # route 3x3/s1 convs through the
-                                     # pallas implicit-GEMM kernel (fwd;
-                                     # bwd stays XLA) — ops/conv_pallas.py
-    "conv_im2col": "off",            # off | all | 3x3: lower conv2d as
-                                     # extracted patches x matmul so the MXU
-                                     # contracts over C*kh*kw instead of C
-                                     # (small-C layers underfill the MXU —
-                                     # the r3 ResNet ceiling experiment)
-    "amp_keep_activations": False,   # AMP: keep conv/matmul outputs bf16
     "check_nan_inf": "off",          # off | raise | skip — non-finite
                                      # policy (nan_inf_policy(); bools
                                      # accepted for back-compat)
@@ -64,24 +54,23 @@ _DEFS = {
     "rpc_retry_times": 3.0,          # call-level retries on broken conns
     "prng_impl": "rbg",              # rbg (HW RngBitGenerator) | threefry
                                      # | unsafe_rbg (rbg-keyed split too)
-    "dispatch_plan": True,           # cached executor dispatch plans; off
-                                     # keeps the legacy per-step key path
-                                     # (bench.py --hot-path A/B control)
     "steps_per_run": 1,              # K>1 fuses K training steps into ONE
                                      # jitted dispatch (lax.scan window,
                                      # Executor.run_window) — host overhead
                                      # per step drops ~1/K (the TF
                                      # iterations_per_loop / MLPerf TPU
-                                     # multi-step contract); 1 = legacy
-                                     # per-step dispatch (A/B control)
+                                     # multi-step contract); 1 = one
+                                     # dispatch a step
     "feed_ring_depth": 2,            # device-resident input pipeline: the
                                      # producer thread stages up to DEPTH
                                      # feed windows ahead (async sharded
                                      # device_put, host stacking off the
                                      # consumer's critical path — reader.
-                                     # FeedRing); 0 = legacy synchronous
-                                     # one-batch lookahead (A/B control,
-                                     # bit-exact same losses)
+                                     # FeedRing); 0 = a one-batch look-
+                                     # ahead on the calling thread, what a
+                                     # program-bound loader's worker does
+                                     # at any depth (same losses, bit for
+                                     # bit)
     "checkpoint_async": True,        # CheckpointManager: serialize+commit
                                      # on a background thread (snapshot
                                      # stays synchronous)
@@ -301,10 +290,8 @@ def trace_time_key():
     """Tuple of every flag that affects tracing/lowering — part of each
     compiled-executable cache key so toggling a flag between runs
     recompiles instead of silently reusing a stale executable."""
-    return (get_flag("conv_layout"), get_flag("amp_keep_activations"),
-            get_flag("matmul_precision"), nan_inf_policy(),
-            get_flag("prng_impl"), get_flag("conv_im2col"),
-            get_flag("conv_pallas"))
+    return (get_flag("matmul_precision"), nan_inf_policy(),
+            get_flag("prng_impl"))
 
 
 def matmul_precision():

@@ -137,9 +137,7 @@ def amp_operands(state, *vals):
             all(v.dtype == cdt for v in vals):
         # non-AMP dtypes involved, or already uniformly bf16: untouched
         return vals + (None,)
-    from . import flags
-    if getattr(state, "amp_keep", False) or \
-            flags.get_flag("amp_keep_activations"):
+    if getattr(state, "amp_keep", False):
         # pure-bf16 activations: skip the fp32 round trip between MXU ops
         # (halves activation HBM traffic; BN still accumulates fp32)
         return tuple(v.astype(cdt) for v in vals) + (None,)

@@ -13,7 +13,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..registry import register_op
-from .. import flags
 from ..flags import matmul_precision
 from ..lowering import amp_operands
 
@@ -25,66 +24,6 @@ def _prec(x):
     return matmul_precision() if x.dtype == jnp.float32 else None
 
 
-def _im2col_applies(mode, w, groups):
-    if groups != 1 or mode in ("off", "", "0"):
-        return False
-    if mode == "all":
-        return True
-    return mode == "3x3" and w.shape[2] == 3 and w.shape[3] == 3
-
-
-@jax.custom_vjp
-def _pallas_conv3x3(x, w):
-    """3x3/s1/p1 conv, forward through the pallas implicit-GEMM kernel
-    (ops/conv_pallas.py — in-VMEM im2col), backward through XLA's conv
-    grads.  NCHW in/out (transposes fuse into neighbors)."""
-    from .conv_pallas import conv3x3_bn_relu
-    out = conv3x3_bn_relu(x.transpose(0, 2, 3, 1),
-                          w.transpose(2, 3, 1, 0), relu=False)
-    return out.transpose(0, 3, 1, 2)
-
-
-def _xla_conv3x3(x, w):
-    return lax.conv_general_dilated(
-        x, w, (1, 1), [(1, 1), (1, 1)],
-        dimension_numbers=("NCHW", "OIHW", "NCHW"))
-
-
-def _pallas_conv3x3_fwd(x, w):
-    return _pallas_conv3x3(x, w), (x, w)
-
-
-def _pallas_conv3x3_bwd(res, g):
-    x, w = res
-    _, vjp = jax.vjp(_xla_conv3x3, x, w)
-    return vjp(g)
-
-
-_pallas_conv3x3.defvjp(_pallas_conv3x3_fwd, _pallas_conv3x3_bwd)
-
-
-def _conv2d_im2col(x, w, strides, pads, dilations):
-    """conv2d as extracted patches x one MXU matmul.
-
-    At ResNet's small channel counts a native conv contracts over C
-    (3..64 — underfilling the 128-wide MXU contraction); the im2col form
-    contracts over C*kh*kw (e.g. 64*9=576), the r3-verdict ceiling
-    experiment (FLAGS_conv_im2col, A/B harness fluid/conv_bench.py).
-    """
-    N, C, _, _ = x.shape
-    O, I, kh, kw = w.shape
-    patches = lax.conv_general_dilated_patches(
-        x, (kh, kw), strides,
-        [(pads[0], pads[0]), (pads[1], pads[1])],
-        rhs_dilation=dilations,
-        dimension_numbers=("NCHW", "OIHW", "NCHW"))  # [N, C*kh*kw, Ho, Wo]
-    Ho, Wo = patches.shape[2], patches.shape[3]
-    p = patches.transpose(0, 2, 3, 1).reshape(N * Ho * Wo, C * kh * kw)
-    wm = w.reshape(O, I * kh * kw).T                 # channel-major order
-    out = jnp.matmul(p, wm, precision=_prec(x))     # [N*Ho*Wo, O]
-    return out.reshape(N, Ho, Wo, O).transpose(0, 3, 1, 2)
-
-
 @register_op("conv2d")
 def _conv2d(ctx, op):
     x = ctx.i("Input")          # NCHW
@@ -94,46 +33,16 @@ def _conv2d(ctx, op):
     dilations = tuple(ctx.attr("dilations", [1, 1]))
     groups = ctx.attr("groups", 1) or 1
     x, w, acc = amp_operands(ctx.state, x, w.astype(x.dtype))
-    # pallas kernel keeps one padded image [H+2, W+2, C] resident in VMEM
-    # per grid cell — bound it well under the ~16 MB/core budget or fall
-    # back to the XLA path (ADVICE r4: the flag gate must not let a large
-    # spatial input fail at compile time)
-    pallas_vmem_ok = (x.shape[2] + 2) * (x.shape[3] + 2) * x.shape[1] * \
-        x.dtype.itemsize <= 8 * 2 ** 20
-    if flags.get_flag("conv_pallas") and groups == 1 and pallas_vmem_ok and \
-            tuple(w.shape[2:]) == (3, 3) and strides == (1, 1) and \
-            pads == (1, 1) and dilations == (1, 1):
-        out = _pallas_conv3x3(x, w)
-        if acc is not None:
-            out = out.astype(acc)
-        ctx.set("Output", out)
-        return
-    if _im2col_applies(flags.get_flag("conv_im2col"), w, groups):
-        out = _conv2d_im2col(x, w, strides, pads, dilations)
-        if acc is not None:
-            out = out.astype(acc)
-        ctx.set("Output", out)
-        return
-    if flags.get_flag("conv_layout") == "NHWC":
-        # TPU-native layout: convolve channels-last; the wrapping
-        # transposes between adjacent convs cancel in XLA, so the whole
-        # network runs NHWC internally while the program stays NCHW
-        out = lax.conv_general_dilated(
-            x.transpose(0, 2, 3, 1), w.transpose(2, 3, 1, 0),
-            window_strides=strides,
-            padding=[(pads[0], pads[0]), (pads[1], pads[1])],
-            rhs_dilation=dilations,
-            dimension_numbers=("NHWC", "HWIO", "NHWC"),
-            feature_group_count=groups,
-            precision=_prec(x)).transpose(0, 3, 1, 2)
-    else:
-        out = lax.conv_general_dilated(
-            x, w, window_strides=strides,
-            padding=[(pads[0], pads[0]), (pads[1], pads[1])],
-            rhs_dilation=dilations,
-            dimension_numbers=("NCHW", "OIHW", "NCHW"),
-            feature_group_count=groups,
-            precision=_prec(x))
+    # XLA's NCHW convolution is the one lowering: channels-last, im2col
+    # and a Pallas implicit-GEMM forward lost their chip run (PERF.md §6,
+    # PR 30)
+    out = lax.conv_general_dilated(
+        x, w, window_strides=strides,
+        padding=[(pads[0], pads[0]), (pads[1], pads[1])],
+        rhs_dilation=dilations,
+        dimension_numbers=("NCHW", "OIHW", "NCHW"),
+        feature_group_count=groups,
+        precision=_prec(x))
     # AMP: conv runs fully in bf16 (the MXU accumulates fp32 internally and
     # rounds once at output); cast back so activations stay fp32.  Unlike
     # matmul, lax.conv's transpose rule rejects mixed-dtype operands, so

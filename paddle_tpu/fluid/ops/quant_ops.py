@@ -235,23 +235,12 @@ def _quantized_conv2d(ctx, op):
     groups = int(ctx.attr("groups", 1) or 1)
     xq = _quant(x.astype(jnp.float32), jnp.float32(x_scale),
                 8).astype(jnp.int8)
-    from .. import flags
-    if flags.get_flag("conv_layout") == "NHWC":
-        # mirror the fp32 conv kernel's TPU-native layout branch
-        acc = lax.conv_general_dilated(
-            xq.transpose(0, 2, 3, 1), w8.transpose(2, 3, 1, 0),
-            strides, [(pads[0], pads[0]), (pads[1], pads[1])],
-            rhs_dilation=dilations,
-            dimension_numbers=("NHWC", "HWIO", "NHWC"),
-            feature_group_count=groups,
-            preferred_element_type=jnp.int32).transpose(0, 3, 1, 2)
-    else:
-        acc = lax.conv_general_dilated(
-            xq, w8, strides, [(pads[0], pads[0]), (pads[1], pads[1])],
-            rhs_dilation=dilations,
-            dimension_numbers=("NCHW", "OIHW", "NCHW"),
-            feature_group_count=groups,
-            preferred_element_type=jnp.int32)
+    acc = lax.conv_general_dilated(
+        xq, w8, strides, [(pads[0], pads[0]), (pads[1], pads[1])],
+        rhs_dilation=dilations,
+        dimension_numbers=("NCHW", "OIHW", "NCHW"),
+        feature_group_count=groups,
+        preferred_element_type=jnp.int32)
     out = acc.astype(jnp.float32) * (x_scale / 127.0) \
         * w_scale[None, :, None, None]
     ctx.set("Output", out)

@@ -334,8 +334,7 @@ def reset_metrics():
 #   step       scope.step_counter at dispatch start (the step/window id)
 #   k          inner steps this dispatch ran (1, or steps_per_run)
 #   window     True for a fused run_window dispatch
-#   plan_hit   True/False for the dispatch-plan path, None on the legacy
-#              (FLAGS_dispatch_plan=0 / unhashable-feed) path
+#   plan_hit   whether the dispatch found its plan in the plan cache
 #   compile_s  seconds the first-ever call of this executable took
 #              (trace + XLA compile ride the first dispatch), else None
 #   feed_bytes sum of feed array nbytes (attribute reads — no sync)

@@ -8,6 +8,8 @@ semantics (reuse, invalidation) and the async dispatch contract
 syncs the host only at print_period boundaries and the final drain).
 """
 
+import functools
+
 import numpy as np
 import jax
 import pytest
@@ -157,25 +159,83 @@ def test_compiled_hlo_works_under_check_nan_inf():
         flags.set_flag("check_nan_inf", False)
 
 
-def test_legacy_path_matches_plan_path():
-    """FLAGS_dispatch_plan=0 (the bench A/B control) computes the same
-    results as the plan path."""
-    main, startup, loss = _train_program()
-    xs = np.full((2, 4), 0.5, np.float32)
+_ROUTE_BATCHES = [np.random.RandomState(i).rand(16, 8).astype(np.float32)
+                  for i in range(4)]
 
-    def losses(use_plan):
-        flags.set_flag("dispatch_plan", use_plan)
-        try:
-            exe = fluid.Executor(fluid.CPUPlace())
-            with fluid.scope_guard(fluid.Scope()):
-                exe.run(startup)
-                return [np.asarray(exe.run(main, feed={"x": xs},
-                                           fetch_list=[loss])[0])
-                        for _ in range(3)]
-        finally:
-            flags.set_flag("dispatch_plan", True)
 
-    np.testing.assert_allclose(losses(True), losses(False), rtol=1e-6)
+@functools.lru_cache(maxsize=None)   # the reference route runs once
+def _route_losses(route, source, K):
+    """Train 4 steps of one small program down one route of
+    ``Executor.run`` / ``run_window`` and check, on the way, what every
+    route owes: ONE compile, ONE plan miss then hits, and introspection
+    resolving the executable the step ran."""
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        x = fluid.layers.data(name="x", shape=[8], dtype="float32")
+        loss = fluid.layers.mean(fluid.layers.square(
+            fluid.layers.fc(x, size=8)))
+        fluid.optimizer.SGD(0.1).minimize(loss)
+        if source == "loader":
+            loader = fluid.DataLoader.from_generator(
+                feed_list=[x], capacity=4, iterable=False,
+                steps_per_run=K)
+            loader.set_batch_generator(
+                lambda: ({"x": b} for b in _ROUTE_BATCHES))
+    target = main
+    if route != "program":
+        target = fluid.CompiledProgram(main)
+    if route.startswith("data_parallel"):
+        bs = fluid.BuildStrategy()
+        bs.zero_shard_optimizer_state = route.endswith("zero")
+        target = target.with_data_parallel(loss_name=loss.name,
+                                           build_strategy=bs)
+    if K is None:
+        feeds = [{"x": b} for b in _ROUTE_BATCHES]
+    else:
+        feeds = [{"x": np.stack(_ROUTE_BATCHES[i:i + K])}
+                 for i in range(0, len(_ROUTE_BATCHES), K)]
+    exe = fluid.Executor(fluid.CPUPlace())
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+        compiles0, hits0 = exe.compile_count(), exe._plan_hits
+        if source == "loader":
+            loader.start()
+        out = []
+        for feed in feeds:
+            if source == "loader":
+                got = exe.run(target, fetch_list=[loss])
+            elif K is None:
+                got = exe.run(target, feed=feed, fetch_list=[loss])
+            else:
+                got = exe.run_window(target, feed=feed, fetch_list=[loss],
+                                     steps_per_run=K)
+            out.extend(np.asarray(got[0]).reshape(-1).tolist())
+        assert exe.compile_count() == compiles0 + 1
+        assert exe._plan_hits == hits0 + len(feeds) - 1
+        ran = exe._last_compiled
+        assert ran.steps_per_run == (K or 1) and ran.is_window == bool(K)
+        hlo = exe.compiled_hlo(target, feed=feeds[0], fetch_list=[loss],
+                               steps_per_run=K)
+        assert ("all-reduce" in hlo) == route.startswith("data_parallel")
+        assert exe._resolve_compiled(target, feeds[0], [loss], scope,
+                                     K)[0] is ran
+        assert exe.compile_count() == compiles0 + 1
+    return out
+
+
+@pytest.mark.parametrize("K", [None, 2], ids=["step", "window2"])
+@pytest.mark.parametrize("source", ["fed", "loader"])
+@pytest.mark.parametrize("route", ["program", "plain_compiled",
+                                   "data_parallel", "data_parallel_zero"])
+def test_every_route_is_the_one_dispatch(route, source, K):
+    """{Program, plain CompiledProgram, with_data_parallel, + ZeRO} x
+    {fed, program-bound loader} x {step, window of 2} all go through
+    Executor._run -> _resolve -> _run_plan and train alike."""
+    want = _route_losses("program", "fed", None)
+    assert want[-1] < want[0]
+    np.testing.assert_allclose(_route_losses(route, source, K), want,
+                               rtol=1e-5)
 
 
 def _write_dataset(tmp_path, n_lines):
@@ -282,7 +342,7 @@ def test_noniterable_loader_prefetches_to_consumer_device():
         loader.reset()
 
 
-def test_dispatch_plan_cache_cleared_on_close():
+def test_plan_cache_cleared_on_close():
     main, startup, y = _scale_program()
     exe = fluid.Executor(fluid.CPUPlace())
     with fluid.scope_guard(fluid.Scope()):

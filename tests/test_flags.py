@@ -271,38 +271,24 @@ def test_prng_impl_flag_recompiles_and_is_deterministic():
         flags.set_flag("prng_impl", orig)
 
 
-def test_conv_im2col_flag_parity():
-    """FLAGS_conv_im2col=3x3 lowers 3x3 convs as patches x matmul; the
-    program output must match the native conv lowering exactly (the r3
-    conv-ceiling experiment path, fluid/conv_bench.py)."""
-    import numpy as np
-    import paddle_tpu.fluid as fluid
-
-    def run():
-        main, startup = fluid.Program(), fluid.Program()
-        main.random_seed = startup.random_seed = 3
-        with fluid.program_guard(main, startup), fluid.unique_name.guard():
-            img = fluid.layers.data(name="img", shape=[4, 12, 12],
-                                    dtype="float32")
-            c = fluid.layers.conv2d(img, num_filters=8, filter_size=3,
-                                    padding=1, act="relu")
-            c2 = fluid.layers.conv2d(c, num_filters=8, filter_size=1)
-            out = fluid.layers.reduce_mean(c2, dim=[1, 2, 3])
-        rng = np.random.RandomState(0)
-        x = rng.randn(2, 4, 12, 12).astype(np.float32)
-        with fluid.scope_guard(fluid.Scope()):
-            exe = fluid.Executor(fluid.CPUPlace())
-            exe.run(startup)
-            val, = exe.run(main, feed={"img": x}, fetch_list=[out])
-        return np.asarray(val)
-
-    ref = run()
-    flags.set_flag("conv_im2col", "3x3")
-    try:
-        got = run()
-    finally:
-        flags.set_flag("conv_im2col", "off")
-    np.testing.assert_allclose(got, ref, rtol=2e-5, atol=2e-6)
+# names split in two so that a grep for a deleted flag finds uses only
+@pytest.mark.parametrize("name", [
+    "dispatch_" "plan",       # PR 30: the per-step-key tail it kept went
+    "amp_keep_" "activations",  # PR 30: program._amp_keep says it
+    "conv_layout",            # PR 30: NHWC, im2col and the Pallas forward
+    "conv_im2col",            # each lost their chip run to XLA's NCHW
+    "conv_pallas",            # convolution (PERF.md §6)
+])
+def test_removed_flag_is_refused(name, monkeypatch):
+    """A flag that was deleted is an error to set and to read, from code
+    and with its environment variable present: a stale FLAGS_* in a
+    launch script must not look honoured."""
+    monkeypatch.setenv("FLAGS_" + name, "1")
+    with pytest.raises(KeyError):
+        flags.set_flag(name, True)
+    with pytest.raises(KeyError):
+        flags.get_flag(name)
+    flags.trace_time_key()      # reads no flag that is gone
 
 
 def test_pe_profile_fname_dumps(tmp_path, monkeypatch):

@@ -1,10 +1,19 @@
 """Layer-surface batch 4: smoke + oracle checks for the wrappers closing
 the reference layers/nn.py __all__ gap."""
 
+import os
+
 import numpy as np
+import pytest
 
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import layers
+
+_REFERENCE = "/root/reference/python/paddle/fluid"
+needs_reference = pytest.mark.skipif(
+    not os.path.isdir(_REFERENCE),
+    reason="the reference checkout (%s) is not mounted on this machine"
+    % _REFERENCE)
 
 
 def _run(build, feeds):
@@ -21,12 +30,13 @@ def _run(build, feeds):
                 exe.run(main, feed=feeds, fetch_list=list(fetch))]
 
 
+@needs_reference
 def test_surface_parity_with_reference_nn():
     """The FULL reference layers/nn.py __all__ resolves here (171/171
     since r2 second half — similarity_focus, tree_conv, deformable_conv,
     deformable_roi_pooling were the last four)."""
     import re
-    src = open("/root/reference/python/paddle/fluid/layers/nn.py").read()
+    src = open(_REFERENCE + "/layers/nn.py").read()
     m = re.search(r"__all__ = \[(.*?)\]", src, re.S)
     ref = re.findall(r"'([a-z0-9_]+)'", m.group(1))
     have = [n for n in ref if hasattr(layers, n)]
@@ -195,13 +205,14 @@ def _reference_all(path):
     return []
 
 
+@needs_reference
 def test_all_reference_layer_modules_resolve():
     """Every name in every reference layers/<mod>.py __all__ resolves on
     fluid.layers (nn.py is asserted separately above)."""
     import pathlib
     import paddle_tpu.fluid as fluid
 
-    base = pathlib.Path("/root/reference/python/paddle/fluid/layers")
+    base = pathlib.Path(_REFERENCE) / "layers"
     missing = {}
     for mod in ["control_flow", "tensor", "io", "detection", "metric_op",
                 "learning_rate_scheduler"]:
@@ -212,6 +223,7 @@ def test_all_reference_layer_modules_resolve():
     assert not missing, missing
 
 
+@needs_reference
 def test_all_reference_fluid_module_surfaces_resolve():
     """Every __all__ name in the reference's top-level fluid modules
     resolves on the matching paddle_tpu module (the r2 surface audit,
@@ -219,7 +231,7 @@ def test_all_reference_fluid_module_surfaces_resolve():
     import pathlib
     import paddle_tpu.fluid as fluid
 
-    base = pathlib.Path("/root/reference/python/paddle/fluid")
+    base = pathlib.Path(_REFERENCE)
 
     targets = {
         "optimizer": fluid.optimizer, "initializer": fluid.initializer,

@@ -65,9 +65,7 @@ def test_framework_paths_use_helper():
     prog._mp_degree = 2
     cp = fluid.CompiledProgram(prog).with_data_parallel(loss_name=None)
 
-    class FakeExe:
-        class _device:
-            platform = "cpu"
-    m = cp._mesh(FakeExe())
+    import jax
+    m = cp._mesh(jax.devices("cpu")[0])
     assert m.axis_names == ("dp", "mp")
     assert m.devices.shape[1] == 2

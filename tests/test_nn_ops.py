@@ -242,29 +242,3 @@ class TestDropoutInfer(OpTest):
             np.round(1 / 0.7, 4))}
 
 
-def test_conv_layout_nhwc_parity():
-    """FLAGS_conv_layout=NHWC produces identical results (layout is an
-    implementation detail; the program contract stays NCHW)."""
-    import paddle_tpu.fluid as fluid
-    from paddle_tpu.fluid import flags as _flags
-    from tests.test_misc_ops2 import _run_ops
-
-    rng = np.random.RandomState(0)
-    x = rng.randn(2, 3, 8, 8).astype(np.float32)
-    w = rng.randn(4, 3, 3, 3).astype(np.float32)
-    spec = [("conv2d", {"Input": ["x"], "Filter": ["w"]},
-             {"Output": ["o"]},
-             {"strides": [2, 2], "paddings": [1, 1], "dilations": [1, 1],
-              "groups": 1})]
-    base, = _run_ops(spec, {"x": x, "w": w}, ["o"])
-    had = "conv_layout" in _flags._cache
-    prev = _flags._cache.get("conv_layout")
-    _flags._cache["conv_layout"] = "NHWC"
-    try:
-        nhwc, = _run_ops(spec, {"x": x, "w": w}, ["o"])
-    finally:
-        if had:
-            _flags._cache["conv_layout"] = prev
-        else:
-            _flags._cache.pop("conv_layout", None)
-    np.testing.assert_allclose(nhwc, base, rtol=1e-5, atol=1e-5)

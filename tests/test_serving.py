@@ -157,9 +157,13 @@ def test_zero_steady_state_recompiles_across_randomized_batches(served):
 
 
 def test_padding_isolation_property_across_the_ladder(served):
-    """A request's response is bit-identical whether served alone or
-    packed into ANY bucket alongside arbitrary other requests — padding
-    rows and co-batched rows can never leak into real rows."""
+    """A request's response is the same whether served alone or packed
+    into ANY bucket alongside arbitrary other requests — padding rows
+    and co-batched rows can never leak into real rows.  Alone and packed
+    run in two bucket shapes, which are two XLA:CPU matmuls with their
+    own accumulation order (observed apart by 1.5e-8), so the comparison
+    is to rtol 1e-6; a leaked row of N(0, 1) input is off by orders of
+    magnitude more."""
     infer, out, scope = served
     shared = fluid.Executor(fluid.CPUPlace())
     sv_alone = _serving(infer, out, scope, max_batch=8, max_wait_ms=0.0,
@@ -188,7 +192,7 @@ def test_padding_isolation_property_across_the_ladder(served):
             packed, = tfut.result(timeout=60)
             for f in futs:
                 f.result(timeout=60)
-            np.testing.assert_array_equal(alone, packed)
+            np.testing.assert_allclose(alone, packed, rtol=1e-6, atol=1e-7)
     sv_alone.close()
     sv_pack.close()
 

@@ -389,13 +389,11 @@ def test_compile_and_cache_counters():
         exe.run(startup)
         exe.run(main, feed={"x": xs}, fetch_list=[loss],
                 return_numpy=False)
-        # legacy path (dispatch_plan off) hits the executable cache
-        flags.set_flag("dispatch_plan", False)
-        try:
-            exe.run(main, feed={"x": xs}, fetch_list=[loss],
-                    return_numpy=False)
-        finally:
-            flags.set_flag("dispatch_plan", True)
+        # a second raw dtype is a second plan over the SAME executable
+        # (the plan keys on the raw dtype, the executable on the one the
+        # program declares): a plan miss that hits the executable cache
+        exe.run(main, feed={"x": xs.astype(np.float64)}, fetch_list=[loss],
+                return_numpy=False)
     assert compiles.value() == c0 + 2          # startup + main
     assert cache.value(result="hit") == hit0 + 1
     # compile durations landed in the histogram
