@@ -414,7 +414,14 @@ def routed_experts(x, num_experts, top_k, ffn_dim, num_held=None,
     ``moe_bias_update`` ops after ``minimize`` (``models/deepseek_v3.py``),
     which read ``expert_load`` (tokens that chose each expert).  The load is
     persistable too: the last step's count stays in the scope, where a
-    monitor reads the balance without a fetch of its own."""
+    monitor reads the balance without a fetch of its own.
+
+    The op's ``Kept`` outputs (four variables without a gradient) are the
+    rows its backward reads, handed from the forward op to
+    ``routed_experts_grad`` where the lowering sizes its row buffers by a
+    rung chosen on the device (a layer that holds a small share of the
+    experts; ops/decoder_ops.py), as ``fused_attention`` hands on its
+    ``LSE``; elsewhere they stay unwritten."""
     from ..initializer import ConstantInitializer
     helper = LayerHelper("routed_experts", param_attr=param_attr, name=name)
     H, E, I = int(x.shape[-1]), int(num_experts), int(ffn_dim)
@@ -442,11 +449,15 @@ def routed_experts(x, num_experts, top_k, ffn_dim, num_held=None,
     bias, load = state(".select_bias"), state(".expert_load")
     out = helper.create_variable_for_type_inference(x.dtype)
     out.shape = x.shape
+    kept = [helper.create_variable_for_type_inference(x.dtype,
+                                                      stop_gradient=True)
+            for _ in range(4)]
     helper.append_op("routed_experts",
                      inputs={"X": [x], "RouterW": [router_w],
                              "SelectBias": [bias], "WGate": [w_gate],
                              "WUp": [w_up], "WDown": [w_down]},
-                     outputs={"Out": [out], "ExpertLoad": [load]},
+                     outputs={"Out": [out], "ExpertLoad": [load],
+                              "Kept": kept},
                      attrs={"top_k": int(top_k),
                             "first_expert": int(first_expert),
                             "routed_scaling_factor":

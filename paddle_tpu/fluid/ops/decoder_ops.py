@@ -17,19 +17,39 @@ the rows actually present — ``jax.lax.ragged_dot``, which XLA:TPU compiles
 to its own Mosaic grouped-matmul kernel that skips the tiles past the last
 group, and whose transpose rules give both backward products.  (On the
 chip a hand-written Pallas kernel over tile-aligned groups ran the forward
-product in 0.25 ms against ``ragged_dot``'s 0.57 at the cell's 3072 live
-rows when a whole expert matrix was one block, and in 1.8 ms with 128-wide
-column blocks; it has no backward yet.  PERF.md section 6, PR 28.)  The
-buffers are sized for the worst case (every assignment held here); the
-matmul work follows the rows that came.
+product in 0.25 ms against ``ragged_dot``'s 0.57 at 3072 live rows in a
+worst-case buffer of 24576 when a whole expert matrix was one block, and in
+1.8 ms with 128-wide column blocks; it has no backward yet.  PERF.md
+section 6, PR 28.)
+
+The row buffers follow the rows that came.  Their size is a RUNG, and the
+ladder comes from shapes alone (``_rungs``): twice the rows an even router
+sends to the experts held here, rounded up to 512, then every assignment
+(``T * top_k``) — 6144 | 24576 rows for 4096 tokens choosing 6 of 64 with
+8 held.  Each step, each layer, a ``lax.cond`` on the step's own
+``group_sizes`` takes the first rung that holds the rows (``_first_rung``:
+gather, SwiGLU and the token-side sums over 6144 rows) and the last one
+otherwise (``_every_row``: the layer as it was before it had rungs), so
+nothing is ever dropped, capped or approximated.  The conditional lives
+inside one ``custom_vjp`` (``_ladder``) whose forward keeps the first
+rung's rows alone and whose last rung computes its rows again in the
+backward: the rung that runs every step pays nothing for the other.  The
+Fluid op hands those rows to its grad op through the ``Kept`` outputs (XLA
+merges a replayed forward's plain HLO with the forward op's, never two
+conditionals).  A layer whose first rung would be half the last or more —
+every layer that holds all ``E`` experts, and small shapes — has one rung
+and traces no conditional.  PERF.md section 6, PR 31.
 """
+
+import contextlib
+import functools
 
 import jax
 import jax.numpy as jnp
 
 from .. import telemetry
 from ..lowering import amp_operands
-from ..registry import register_op
+from ..registry import register_grad_lower, register_op
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -157,40 +177,291 @@ def _combine_bwd(res, g):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+def _plan(idx, first_expert, n_held):
+    """The step's assignments ``idx`` [T, k] sorted by held expert:
+    ``order`` [T * k] (sorted row -> flat assignment; assignments to
+    absent experts sort behind every held group, so the live rows are a
+    prefix), ``token_of`` [T * k] (the token of each sorted row),
+    ``slot_of`` [T, k] (the row of each assignment), ``held`` [T, k] and
+    ``group_sizes`` [n_held] (the rows of each held expert)."""
+    T, top_k = idx.shape
+    local = idx - first_expert
+    held = (local >= 0) & (local < n_held)               # [T, k]
+    key = jnp.where(held, local, n_held).reshape(-1)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    slot_of = jnp.argsort(order).astype(jnp.int32).reshape(T, top_k)
+    group_sizes = (key[:, None] == jnp.arange(n_held)).sum(axis=0) \
+        .astype(jnp.int32)
+    return order, order // top_k, slot_of, held, group_sizes
+
+
+# the first rung: this many times the rows an even router sends here,
+# rounded up to a multiple of _RUNG_MULTIPLE
+_RUNG_FACTOR = 2
+_RUNG_MULTIPLE = 512
+
+
+def _rungs(T, top_k, n_held, E):
+    """The row counts the layer's buffers may take, from shapes alone.  An
+    even router sends ``T * top_k * n_held / E`` rows to the experts held
+    here; the first rung is ``_RUNG_FACTOR`` times that, the last is every
+    assignment (``T * top_k``: nothing is ever dropped).  A first rung of
+    half the last or more is not worth a conditional: ONE rung, the worst
+    case — every uncut layer, and small shapes."""
+    full = T * top_k
+    first = -(-_RUNG_FACTOR * full * n_held // (E * _RUNG_MULTIPLE)) \
+        * _RUNG_MULTIPLE
+    return (full,) if 2 * first >= full else (first, full)
+
+
+@contextlib.contextmanager
+def _rows_scope(stage, R):
+    """``moe_dispatch`` / ``moe_experts`` / ``moe_combine`` and, inside
+    it, the rung whose rows the operations work on: under
+    ``moe_rows_<R>`` a device trace shows which rung a step took."""
+    with jax.named_scope(stage), jax.named_scope("moe_rows_%d" % R):
+        yield
+
+
+def _grouped(a, w, group_sizes, acc):
+    return jax.lax.ragged_dot(
+        a, w, group_sizes, preferred_element_type=acc,
+        precision=_HIGHEST if a.dtype == jnp.float32 else None)
+
+
+def _gate_up(xs, wg, wu, group_sizes, acc):
+    return _grouped(xs, wg, group_sizes, acc), \
+        _grouped(xs, wu, group_sizes, acc)
+
+
+def _swiglu(gate, up, dtype):
+    return (jax.nn.silu(gate) * up).astype(dtype)
+
+
+def _every_row(dtypes, x, weight, wg, wu, wd, plan):
+    """The held experts' part of the routed sum through row buffers of
+    every assignment (``T * k`` rows, whatever came): the last rung, and
+    the whole layer where it has one.  Gather, grouped SwiGLU, weighted
+    gather-sum; differentiable as it stands (``_dispatch`` and
+    ``_combine`` carry their gathers' backward).  ``dtypes``: the one the
+    rows are computed in and the matmuls' accumulator (``amp_operands``)."""
+    order, token_of, slot_of, held, group_sizes = plan
+    rows_dtype, acc = dtypes
+    R = order.shape[0]
+    with _rows_scope("moe_dispatch", R):
+        xs = _dispatch(x, token_of, slot_of, held)           # [T * k, H]
+    with _rows_scope("moe_experts", R):
+        xs = xs.astype(rows_dtype)
+        gate, up = _gate_up(xs, wg, wu, group_sizes, acc)
+        ys = _grouped(_swiglu(gate, up, rows_dtype), wd, group_sizes,
+                      acc).astype(x.dtype)
+    with _rows_scope("moe_combine", R):
+        return _combine(ys, weight, order, token_of, slot_of, held)
+
+
+def _sum_by_token(rows, slot_of, held, weight=None):
+    """[T, H] float32: each token's sum over its k assignments of the row
+    ``rows[slot_of[t, j]]`` of every HELD one (times ``weight[t, j]``).
+    One gather of ``[T, H]`` a choice: a single ``[T, k, H]`` gather costs
+    a copy into the layout of a k that is no multiple of 8 before it can
+    be summed.  The rows of the others are masked, not trusted: past the
+    last group a grouped matmul leaves what was there."""
+    total = 0
+    for j in range(slot_of.shape[1]):
+        chosen = jnp.where(held[:, j, None], rows[slot_of[:, j]], 0) \
+            .astype(jnp.float32)
+        total = total + (chosen if weight is None
+                         else chosen * weight[:, j, None])
+    return total
+
+
+def _first_rows(plan, R):
+    """``plan`` for row buffers of ``R`` rows: the held assignments sort
+    first, so the live rows are the prefix ``[:R]`` of the sorted order;
+    the slot of an assignment that is not held is read nowhere and only
+    has to lie inside the buffer."""
+    order, token_of, slot_of, held, group_sizes = plan
+    return order[:R], token_of[:R], jnp.minimum(slot_of, R - 1), held, \
+        group_sizes
+
+
+def _first_rung(R, dtypes, x, weight, wg, wu, wd, plan):
+    """``(out [T, H], kept)``: what ``_every_row`` gives, through row
+    buffers of ``R`` rows, for a step whose rows fit (``group_sizes.sum()
+    <= R``), and the rows its backward reads: ``(xs [R, H], gate, up
+    [R, I], ys [R, H])``.  The same rows, the same float32 sum over ``k``;
+    ``ys`` stays in the dtype the matmul gives where widening it to
+    ``x``'s is exact (the sum is float32 either way: half the bytes for
+    the token side to read)."""
+    _, token_of, slot_of, held, group_sizes = _first_rows(plan, R)
+    rows_dtype, acc = dtypes
+    with _rows_scope("moe_dispatch", R):
+        xs = x[token_of].astype(rows_dtype)                  # [R, H]
+    with _rows_scope("moe_experts", R):
+        gate, up = _gate_up(xs, wg, wu, group_sizes, acc)
+        ys = _grouped(_swiglu(gate, up, rows_dtype), wd, group_sizes, acc)
+        if jnp.promote_types(ys.dtype, x.dtype) != x.dtype:
+            ys = ys.astype(x.dtype)     # a narrowing, as ``_every_row``'s
+    with _rows_scope("moe_combine", R):
+        out = _sum_by_token(ys, slot_of, held, weight)
+    return out.astype(x.dtype), (xs, gate, up, ys)
+
+
+def _first_rung_backward(R, dtypes, x, weight, wg, wu, wd, plan, kept, g):
+    """``(dx, dweight, dwg, dwu, dwd)`` of ``_first_rung(R, ...)[0]`` for
+    the cotangent ``g`` [T, H], from the rows its forward ``kept``.  No
+    matmul of the forward runs again: the grouped products are linear, and
+    the primal side of their ``jax.vjp`` is dead code.  The weights'
+    gradient is formed on the row side, ``<ys[r], g[token_of[r]]>`` over
+    ``R`` rows and then a gather of scalars, where ``_combine``'s backward
+    gathers ``[T, k, H]`` a second time."""
+    order, token_of, slot_of, held, group_sizes = _first_rows(plan, R)
+    rows_dtype, acc = dtypes
+    xs, gate, up, ys = kept
+    with _rows_scope("moe_combine", R):
+        g_rows = g[token_of].astype(jnp.float32)             # [R, H]
+        w_row = jnp.where(held, weight, 0).reshape(-1)[order]
+        dy = (g_rows * w_row[:, None]).astype(ys.dtype)
+        dw_row = (ys.astype(jnp.float32) * g_rows).sum(axis=-1)
+        dweight = jnp.where(held, dw_row[slot_of], 0).astype(weight.dtype)
+    with _rows_scope("moe_experts", R):
+        hidden, swiglu_vjp = jax.vjp(
+            lambda a, b: _swiglu(a, b, rows_dtype), gate, up)
+        dhidden, dwd = jax.vjp(
+            lambda h, w: _grouped(h, w, group_sizes, acc).astype(ys.dtype),
+            hidden, wd)[1](dy)
+        dxs, dwg, dwu = jax.vjp(
+            lambda a, b, c: _gate_up(a, b, c, group_sizes, acc),
+            xs, wg, wu)[1](swiglu_vjp(dhidden))
+    with _rows_scope("moe_dispatch", R):
+        dx = _sum_by_token(dxs.astype(x.dtype), slot_of, held)
+    return dx.astype(x.dtype), dweight, dwg, dwu, dwd
+
+
+def _cond_on_rows(rungs, plan, first_rung, last_rung, operands):
+    """``first_rung(*operands)`` where the step's rows fit the first rung,
+    else ``last_rung(*operands)``: a ``jax.lax.cond`` on the step's own
+    ``group_sizes`` whose branches hold exactly what was traced into them.
+    XLA's conditional code motion otherwise moves work across the
+    boundary: out of it, the tail both branches share (the weighted sum
+    over ``k`` left the conditional and the gathered ``[T, k, H]`` float32
+    rows became its output, 201 MB a layer at the Moonlight cell's
+    shapes); into it, the producers and users next to it (casts of the
+    weights' gradients, twice).  Barriers on the operands, on each
+    branch's result and on the conditional's pin all three."""
+    def pinned(branch):
+        return lambda *args: jax.lax.optimization_barrier(branch(*args))
+    return jax.lax.optimization_barrier(jax.lax.cond(
+        plan[-1].sum() <= rungs[0], pinned(first_rung), pinned(last_rung),
+        *jax.lax.optimization_barrier(operands)))
+
+
+def _forward_by_rung(rungs, dtypes, x, weight, wg, wu, wd, plan):
+    """``(out, kept)`` through the first rung that holds the step's rows,
+    chosen on the device.  The last rung keeps nothing (zeros in the first
+    rung's shapes) and its backward computes its rows again: the rung that
+    runs every step pays nothing for the one that almost never does."""
+    first_rung = functools.partial(_first_rung, rungs[0], dtypes)
+
+    def last_rung(*args):
+        return _every_row(dtypes, *args), jax.tree.map(
+            lambda s: jnp.zeros(s.shape, s.dtype),
+            jax.eval_shape(first_rung, *args)[1])
+    return _cond_on_rows(rungs, plan, first_rung, last_rung,
+                         (x, weight, wg, wu, wd, plan))
+
+
+def _backward_by_rung(rungs, dtypes, x, weight, wg, wu, wd, plan, kept, g):
+    """The backward of ``_forward_by_rung`` on the same predicate: from
+    ``kept`` in the first rung; in the last, ``_every_row`` again under
+    ``jax.vjp`` (dearer than the layer without rungs by one forward: it
+    is the rung that is not expected to run)."""
+    def last_rung(x, weight, wg, wu, wd, plan, kept, g):
+        return jax.vjp(lambda *a: _every_row(dtypes, *a, plan),
+                       x, weight, wg, wu, wd)[1](g)
+    return _cond_on_rows(
+        rungs, plan, functools.partial(_first_rung_backward, rungs[0], dtypes),
+        last_rung, (x, weight, wg, wu, wd, plan, kept, g))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _ladder(rungs, dtypes, x, weight, wg, wu, wd, plan):
+    """``_forward_by_rung`` as ONE differentiable function; ``kept`` is
+    handed out beside ``out`` and has no gradient.  Left to ``jax.vjp``, a
+    ``lax.cond`` makes the residuals of every branch outputs of the
+    forward conditional and fills the untaken branch's with zeros: the
+    worst case's rows would be written on every step.  Here the forward
+    conditional keeps the first rung's rows alone."""
+    return _forward_by_rung(rungs, dtypes, x, weight, wg, wu, wd, plan)
+
+
+def _ladder_fwd(rungs, dtypes, x, weight, wg, wu, wd, plan):
+    out, kept = _forward_by_rung(rungs, dtypes, x, weight, wg, wu, wd, plan)
+    return (out, kept), (x, weight, wg, wu, wd, plan, kept)
+
+
+def _ladder_bwd(rungs, dtypes, res, cotangents):
+    return _backward_by_rung(rungs, dtypes, *res, cotangents[0]) + (None,)
+
+
+_ladder.defvjp(_ladder_fwd, _ladder_bwd)
+
+
+def _route_and_plan(x, router_w, select_bias, *, top_k, scale, first_expert,
+                    n_held):
+    """``weight`` [T, k] and ``(plan, load)``: everything of the layer
+    that does not depend on a rung."""
+    with jax.named_scope("moe_route"):
+        idx, weight, load = route(x, router_w, select_bias, top_k, scale)
+    with jax.named_scope("moe_dispatch"):
+        plan = _plan(idx, first_expert, n_held)
+    return weight, (plan, load)
+
+
+def _held_part(x, router_w, select_bias, w_gate, w_up, w_down, state, *,
+               top_k, scale, first_expert):
+    """``(out, load, kept)``; ``kept`` is None where the layer has one
+    rung, whose backward needs nothing handed over."""
+    weight, (plan, load) = _route_and_plan(
+        x, router_w, select_bias, top_k=top_k, scale=scale,
+        first_expert=first_expert, n_held=w_gate.shape[0])
+    operands, dtypes, rungs = _rows_operands(state, x, router_w, w_gate,
+                                             w_up, w_down, top_k)
+    if len(rungs) == 1:
+        return _every_row(dtypes, x, weight, *operands, plan), load, None
+    out, kept = _ladder(rungs, dtypes, x, weight, *operands, plan)
+    return out, load, kept
+
+
+def _rows_operands(state, x, router_w, w_gate, w_up, w_down, top_k):
+    """The expert weights in the compute dtype, the rungs' ``dtypes`` and
+    the layer's rungs (counted: one lowering traced)."""
+    xc, wg, wu, wd, acc = amp_operands(state, x, w_gate, w_up, w_down)
+    rungs = _rungs(x.shape[0], top_k, w_gate.shape[0], router_w.shape[-1])
+    _m_experts_lowered.inc(path="ragged_dot",
+                           rows="|".join(str(R) for R in rungs))
+    return (wg, wu, wd), (xc.dtype, acc), rungs
+
+
 def routed_experts(x, router_w, select_bias, w_gate, w_up, w_down, *,
                    top_k, scale, first_expert, state=None):
     """x [T, H]; router_w [H, E]; select_bias [E]; w_gate / w_up
     [held, H, I]; w_down [held, I, H] -> (out [T, H], load [E]): the part
     of ``sum_i w_i E_i(x)`` given by the experts ``first_expert ..
     first_expert + held - 1``, ``E_i`` a SwiGLU."""
-    T, H = x.shape
-    n_held = w_gate.shape[0]
-    with jax.named_scope("moe_route"):
-        idx, weight, load = route(x, router_w, select_bias, top_k, scale)
-    with jax.named_scope("moe_dispatch"):
-        local = idx - first_expert
-        held = (local >= 0) & (local < n_held)               # [T, k]
-        # assignments to absent experts sort behind every held group
-        key = jnp.where(held, local, n_held).reshape(-1)
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        slot_of = jnp.argsort(order).astype(jnp.int32).reshape(T, top_k)
-        group_sizes = (key[:, None] == jnp.arange(n_held)).sum(axis=0) \
-            .astype(jnp.int32)
-        token_of = order // top_k
-        xs = _dispatch(x, token_of, slot_of, held)           # [T * k, H]
-    with jax.named_scope("moe_experts"):
-        _m_experts_lowered.inc(path="ragged_dot")
-        xs, wg, wu, wd, acc = amp_operands(state, xs, w_gate, w_up, w_down)
-        prec = _HIGHEST if xs.dtype == jnp.float32 else None
+    return _held_part(x, router_w, select_bias, w_gate, w_up, w_down, state,
+                      top_k=top_k, scale=scale,
+                      first_expert=first_expert)[:2]
 
-        def grouped(a, w):
-            return jax.lax.ragged_dot(a, w, group_sizes, precision=prec,
-                                      preferred_element_type=acc)
-        hidden = jax.nn.silu(grouped(xs, wg)) * grouped(xs, wu)
-        ys = grouped(hidden.astype(xs.dtype), wd).astype(x.dtype)
-    with jax.named_scope("moe_combine"):
-        out = _combine(ys, weight, order, token_of, slot_of, held)
-    return out, load
+
+def _op_operands(ctx):
+    x = ctx.i("X")
+    return (x.reshape(-1, x.shape[-1]), ctx.i("RouterW"),
+            ctx.i("SelectBias"), ctx.i("WGate"), ctx.i("WUp"),
+            ctx.i("WDown")), dict(
+        top_k=int(ctx.attr("top_k")),
+        scale=float(ctx.attr("routed_scaling_factor", 1.0)),
+        first_expert=int(ctx.attr("first_expert", 0)))
 
 
 @register_op("routed_experts", nondiff_inputs=("SelectBias",))
@@ -198,16 +469,54 @@ def _routed_experts(ctx, op):
     """X [..., H]; RouterW [H, E]; SelectBias [E] (no gradient: it is moved
     by ``moe_bias_update``); WGate / WUp [held, H, I]; WDown [held, I, H]
     -> Out [..., H] (the held experts' part of the routed sum) and
-    ExpertLoad [E] float32 (tokens that chose each expert, over all E)."""
-    x = ctx.i("X")
-    out, load = routed_experts(
-        x.reshape(-1, x.shape[-1]), ctx.i("RouterW"), ctx.i("SelectBias"),
-        ctx.i("WGate"), ctx.i("WUp"), ctx.i("WDown"),
-        top_k=int(ctx.attr("top_k")),
-        scale=float(ctx.attr("routed_scaling_factor", 1.0)),
-        first_expert=int(ctx.attr("first_expert", 0)), state=ctx.state)
-    ctx.set("Out", out.reshape(x.shape))
+    ExpertLoad [E] float32 (tokens that chose each expert, over all E).
+
+    ``Kept`` (four variables, optional): where the layer has two rungs,
+    the first rung's rows ``(xs, gate, up, ys)`` as the forward
+    conditional left them, for ``routed_experts_grad``: XLA merges a
+    replay of plain HLO with the forward op's, never two conditionals.  A
+    layer of one rung leaves them unwritten; its grad op replays."""
+    operands, attrs = _op_operands(ctx)
+    out, load, kept = _held_part(*operands, ctx.state, **attrs)
+    if kept is not None and len(op.output("Kept")) == len(kept):
+        ctx.set_all("Kept", kept)
+    ctx.set("Out", out.reshape(ctx.i("X").shape))
     ctx.set("ExpertLoad", load)
+
+
+@register_grad_lower("routed_experts")
+def _routed_experts_grad(ctx, op):
+    """Where the forward op left its first rung's rows in ``Kept``, the
+    backward conditional reads them; the route, the sort and the casts are
+    traced again here as plain HLO, which XLA merges with the forward
+    op's.  Everywhere else (one rung; a program built without the slot)
+    ``generic_grad_lower`` replays the forward."""
+    from ..lowering import generic_grad_lower
+
+    kept = tuple(ctx.env.get(name) for name in op.input("Kept"))
+    g = ctx.i_opt("Out@GRAD")
+    if len(kept) != 4 or g is None or any(v is None for v in kept):
+        generic_grad_lower(ctx, op, residual_slots=("Kept",))
+        return
+    (x, router_w, select_bias, w_gate, w_up, w_down), attrs = \
+        _op_operands(ctx)
+    weight, route_vjp, (plan, _) = jax.vjp(
+        lambda a, b: _route_and_plan(a, b, select_bias,
+                                     n_held=w_gate.shape[0], **attrs),
+        x, router_w, has_aux=True)
+    (wg, wu, wd), dtypes, rungs = _rows_operands(
+        ctx.state, x, router_w, w_gate, w_up, w_down, attrs["top_k"])
+    dx, dweight, dwg, dwu, dwd = _backward_by_rung(
+        rungs, dtypes, x, weight, wg, wu, wd, plan, kept,
+        g.reshape(x.shape).astype(x.dtype))
+    dx_route, drouter = route_vjp(dweight)
+    grads = {"X": (dx + dx_route).reshape(ctx.i("X").shape),
+             "RouterW": drouter, "WGate": dwg.astype(w_gate.dtype),
+             "WUp": dwu.astype(w_up.dtype), "WDown": dwd.astype(w_down.dtype)}
+    for slot, grad in grads.items():
+        name = (op.output(slot + "@GRAD") or [""])[0]
+        if name:
+            ctx.env[name] = grad
 
 
 @register_op("moe_bias_update", stop_gradient=True)

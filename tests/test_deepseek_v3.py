@@ -10,6 +10,8 @@ is ten times what the worst case showed and far below what a wrong term
 gives (a dropped expert or a bias that weighs moves the output by percents).
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -210,7 +212,7 @@ T, HID, E, K, WIDTH = 24, 32, 8, 2, 24
 SCALE = 2.446
 
 
-def _expert_params(seed=0, router=None, bias=None):
+def _expert_params(seed=0, router=None, bias=None, T=T, E=E):
     rng = np.random.default_rng(seed)
     p = {"mlp.experts.router": rng.normal(size=(HID, E), scale=0.5),
          "mlp.experts.gate": rng.normal(size=(E, HID, WIDTH), scale=0.2),
@@ -229,31 +231,34 @@ def _expert_params(seed=0, router=None, bias=None):
     return p, x
 
 
-def _cfg(held=E, first=0):
+def _cfg(held=E, first=0, E=E):
     return {"num_experts_per_tok": K, "routed_scaling_factor": SCALE,
             "n_routed_experts": E, "n_routed_experts_held": held,
             "first_expert_held": first}
 
 
-def _op(p, x, first=0, held=E):
+def _op(p, x, first=0, held=None):
     e = "mlp.experts."
+    held = p[e + "router"].shape[1] if held is None else held
     return decoder_ops.routed_experts(
         x, p[e + "router"], p["select_bias"],
         *(p[e + n][first:first + held] for n in ("gate", "up", "down")),
         top_k=K, scale=SCALE, first_expert=first)
 
 
-def _reference_routed(p, x, first=0, held=E):
+def _reference_routed(p, x, first=0, held=None):
     """The reference layer's routed part alone (its shared experts off)."""
     zero = {n: jnp.zeros_like(v) if "shared" in n else v
             for n, v in p.items()}
-    if held < E:
-        e = "mlp.experts."
+    e = "mlp.experts."
+    experts = p[e + "router"].shape[1]
+    held = experts if held is None else held
+    if held < experts:
         zero.update({e + n: zero[e + n][first:first + held]
                      for n in ("gate", "up", "down")})
     with jax.default_matmul_precision("highest"):
-        y, load = ref.expert_ffn(x[None], zero, _cfg(held, first), "mlp",
-                                 p["select_bias"])
+        y, load = ref.expert_ffn(x[None], zero, _cfg(held, first, experts),
+                                 "mlp", p["select_bias"])
     return y[0], load
 
 
@@ -341,6 +346,150 @@ def test_routed_experts_layer_is_told_what_it_holds():
             layers.routed_experts(x, 64, 6, 24, num_held=8, first_expert=60)
 
 
+# -- the rungs of a share's row buffers ------------------------------------------
+
+# 1024 tokens, 2 of 16 experts held: 2048 assignments, an even router sends
+# 256 here, so the buffers have the rungs 512 | 2048
+RT, RE, RHELD, RUNG = 1024, 16, 2, 512
+
+
+def test_the_rungs_come_from_shapes():
+    rungs = decoder_ops._rungs
+    assert rungs(RT, K, RHELD, RE) == (RUNG, RT * K)
+    # the Moonlight cell: 4096 tokens, 6 of 64 each, 8 held
+    assert rungs(4096, 6, 8, 64) == (6144, 24576)
+    # an uncut layer, and small shapes: one rung, the worst case
+    assert rungs(4096, 6, 64, 64) == (24576,)
+    assert rungs(RT, K, RE, RE) == (RT * K,)
+    assert rungs(T, K, 2, E) == (T * K,)
+    assert rungs(2 * 128, 2, 2, 8) == (512,)
+    for shape in [(4096, 6, 8, 64), (4096, 8, 32, 256), (333, 3, 1, 40)]:
+        ladder = rungs(*shape)
+        assert ladder[-1] == shape[0] * shape[1]
+        assert all(R % 512 == 0 for R in ladder[:-1])
+        assert all(2 * R < ladder[-1] for R in ladder[:-1])
+
+
+def _share_params(both, one, bias=None, seed=11):
+    """Seeded weights and ``RT`` tokens of which the first ``both`` choose
+    the held experts 0 AND 1, the next ``one`` expert 0 and an absent one,
+    the rest two absent ones: ``2 * both + one`` rows come.  (Experts 0 and
+    1 score the tokens' first two features alone, set to +-10.)"""
+    p, x = _expert_params(seed, T=RT, E=RE)
+    router = np.array(p["mlp.experts.router"]) * 0.4
+    router[:2], router[:, :2] = 0.0, 0.0
+    router[0, 0] = router[1, 1] = 1.0
+    x = np.array(x)
+    x[:, :2] = -10.0
+    x[:both + one, 0] = 10.0
+    x[:both, 1] = 10.0
+    p["mlp.experts.router"] = jnp.asarray(router)
+    if bias is not None:
+        p["select_bias"] = jnp.asarray(bias, jnp.float32)
+    return p, jnp.asarray(x)
+
+
+ALL_HELD = np.where(np.arange(RE) < RHELD, 30.0, 0.0)
+
+
+@pytest.mark.parametrize("both,one,bias,rows", [
+    (100, 50, None, 250), (200, 112, None, RUNG), (200, 113, None, RUNG + 1),
+    (0, 0, None, 0), (0, 0, ALL_HELD, RT * K)],
+    ids=["well_under", "exactly_the_rung", "one_row_more", "no_row",
+         "every_assignment_held"])
+def test_the_rung_follows_the_rows_that_came(both, one, bias, rows):
+    """Output and every gradient against the float32 reference with the
+    rung forced each way by the data: the first rung up to exactly its 512
+    rows, the last from 513 on, and every assignment of every token held
+    here (a selection bias lifts the two held experts over all others: the
+    last rung's forward AND its recomputing backward, no token dropped)."""
+    p, x = _share_params(both, one, bias)
+    (out, load), vjp = jax.vjp(
+        lambda x_, p_: _op(p_, x_, held=RHELD), x, p)
+    (want, want_load), want_vjp = jax.vjp(
+        lambda x_, p_: _reference_routed(p_, x_, held=RHELD), x, p)
+    assert float(load[:RHELD].sum()) == rows
+    np.testing.assert_array_equal(load, want_load)
+    close(out, want, what="out")
+    g = jnp.asarray(np.random.default_rng(4).normal(size=out.shape),
+                    jnp.float32)
+    (dx, dp), (wdx, wdp) = vjp((g, jnp.zeros(RE))), \
+        want_vjp((g, jnp.zeros(RE)))
+    close(dx, wdx, what="dx")
+    e = "mlp.experts."
+    for n in ("router", "gate", "up", "down"):
+        want_grad = wdp[e + n] if n == "router" else wdp[e + n][:RHELD]
+        got = dp[e + n] if n == "router" else dp[e + n][:RHELD]
+        if rows == 0:
+            assert not np.asarray(got).any() and not np.asarray(want_grad).any()
+        else:
+            close(got, want_grad, what="d" + n)
+
+
+def _rung_operands(p, x):
+    e = "mlp.experts."
+    weight, (plan, _) = decoder_ops._route_and_plan(
+        x, p[e + "router"], p["select_bias"], top_k=K, scale=SCALE,
+        first_expert=0, n_held=RHELD)
+    return (x, weight) + tuple(p[e + n][:RHELD]
+                               for n in ("gate", "up", "down")), plan
+
+
+@pytest.mark.parametrize("rows,compute,tol", [
+    (250, jnp.float32, 1e-6), (RUNG, jnp.float32, 1e-6),
+    (250, jnp.bfloat16, 1e-2)])
+def test_the_two_rungs_agree(rows, compute, tol):
+    """On rows that fit the first rung, both rungs give the same numbers to
+    float32 round-off, forward and backward: the first from the rows its
+    forward kept, the last by ``jax.vjp`` through every row.  Under
+    pure-bf16 AMP as the Moonlight cell runs it (``x`` float32, the rows
+    and the expert weights bfloat16) they agree to bfloat16's: the first
+    rung keeps ``ys`` as the matmul gave it and widens the sum."""
+    p, x = _share_params(100, rows - 200)
+    diff, plan = _rung_operands(p, x)
+    diff = diff[:2] + tuple(w.astype(compute) for w in diff[2:])
+    dtypes = (compute, None)
+    g = jnp.asarray(np.random.default_rng(5).normal(size=x.shape),
+                    jnp.float32)
+    first, kept = decoder_ops._first_rung(RUNG, dtypes, *diff, plan)
+    last, vjp = jax.vjp(
+        lambda *a: decoder_ops._every_row(dtypes, *a, plan), *diff)
+    assert [a.shape[0] for a in kept] == [RUNG] * 4
+    assert {a.dtype for a in kept} == {jnp.dtype(compute)}
+    assert first.dtype == last.dtype == x.dtype
+    close(first, last, tol=tol, what="forward")
+    for name, got, want in zip(
+            ("dx", "dweight", "dgate", "dup", "ddown"),
+            decoder_ops._first_rung_backward(RUNG, dtypes, *diff, plan,
+                                             kept, g),
+            vjp(g)):
+        assert got.dtype == want.dtype and got.shape == want.shape
+        close(got, want, tol=tol, what=name)
+
+
+def _conditionals(fn, *args):
+    return str(jax.make_jaxpr(fn)(*args)).count(" cond[")
+
+
+@pytest.mark.parametrize("tokens,experts,held,conds,rows", [
+    (RT, RE, RHELD, 2, "512|2048"), (RT, RE, RE, 0, "2048"),
+    (T, E, 2, 0, "48"), (T, E, E, 0, "48")])
+def test_one_rung_traces_no_conditional(tokens, experts, held, conds, rows):
+    """A share with two rungs traces one forward and one backward
+    conditional; an uncut layer (``held == E``) and the tiny shapes trace
+    none: their program is the one from before there were rungs.  The
+    counter says what was traced."""
+    p, x = _expert_params(2, T=tokens, E=experts)
+    telemetry.reset_metrics()
+
+    def loss(x_, p_):
+        return _op(p_, x_, held=held)[0].sum()
+    assert _conditionals(jax.grad(loss, argnums=(0, 1)), x, p) == conds
+    counter = telemetry.registry().get("moe_experts_lowered_total")
+    assert counter.value(path="ragged_dot", rows=rows) == 1
+    assert counter.value(path="ragged_dot") == 1
+
+
 # -- the whole model -------------------------------------------------------------
 
 def _reference_cfg(cfg):
@@ -376,27 +525,43 @@ def _squeeze(feed):
     return jnp.asarray(feed["ids"][..., 0]), jnp.asarray(feed["labels"][..., 0])
 
 
-@pytest.mark.parametrize("held,first", [(8, 0), (2, 4)])
-def test_model_loss_and_every_gradient_through_executor(held, first):
+@pytest.mark.parametrize("held,first,experts,top_k,batch,rows", [
+    (8, 0, 8, 2, 2, "512"), (2, 4, 8, 2, 2, "512"),
+    (2, 4, 32, 4, 4, "512|2048")])
+def test_model_loss_and_every_gradient_through_executor(
+        held, first, experts, top_k, batch, rows):
     """S=128 tiles, so attention runs the (interpreted) flash kernels; the
-    second case is a share: 2 of 8 experts held, routed over all 8."""
+    second case is a share: 2 of 8 experts held, routed over all 8; the
+    third a share whose row buffers have two rungs (512 tokens choosing 4
+    of 32 experts, 2 held: 128 of 2048 assignments expected): the forward
+    op hands its first rung's rows to the grad op through ``Kept``, and
+    the step holds one forward and one backward conditional."""
     cfg = models.deepseek_v3.tiny_config(
-        max_seq_len=128, n_routed_experts_held=held, first_expert_held=first)
+        max_seq_len=128, n_routed_experts_held=held, first_expert_held=first,
+        n_routed_experts=experts, num_experts_per_tok=top_k)
+    telemetry.reset_metrics()
     main, startup = fluid.Program(), fluid.Program()
     main.random_seed = startup.random_seed = 11
     with fluid.program_guard(main, startup), fluid.unique_name.guard():
         handles = models.deepseek_v3.build_train(
             cfg, optimizer=fluid.optimizer.SGD(learning_rate=0.0))
-    feed = _batch(cfg, 0)
+    feed = _batch(cfg, 0, batch)
     names = [p.name for p in main.global_block().all_parameters()]
+    fetch_list = [handles["loss"]] + [
+        main._grad_name_map.get(n, n + "@GRAD") for n in names] + \
+        handles["expert_loads"]
     with fluid.scope_guard(fluid.Scope()) as _:
         scope = fluid.global_scope()
         exe = fluid.Executor(fluid.CPUPlace())
         exe.run(startup)
         params = _reference_params(scope, handles)
-        got = exe.run(main, feed=feed, fetch_list=[handles["loss"]] + [
-            main._grad_name_map.get(n, n + "@GRAD") for n in names] +
-            handles["expert_loads"])
+        got = exe.run(main, feed=feed, fetch_list=fetch_list)
+        hlo = exe.compiled_hlo(main, feed, fetch_list)
+    # the forward op and its grad op each trace the layer's rows
+    counter = telemetry.registry().get("moe_experts_lowered_total")
+    assert counter.value(path="ragged_dot", rows=rows) == \
+        counter.value(path="ragged_dot") >= 2
+    assert len(re.findall(r" conditional\(", hlo)) == 2 * ("|" in rows)
     want_loss, want_grads, want_loads = ref.loss_and_grads(
         params, *_squeeze(feed), _reference_cfg(cfg))
     assert abs(float(got[0][0]) - float(want_loss)) < 2e-5 * float(want_loss)

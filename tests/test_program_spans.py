@@ -452,6 +452,98 @@ def test_lse_residual_costs_its_own_bytes_and_no_more(bert_stack_steps):
     assert new - replay <= LAYERS * lse_bytes + slack, (new, replay)
 
 
+# -- the routed experts' rungs, compiled for a v5e (no chip needed) ----------
+
+EXPERT_LAYERS = 2
+
+
+def _expert_stack(with_kept):
+    """A decoder of one dense and two expert layers at a share's shapes in
+    small (2048 tokens choosing 6 of 64 experts, 8 held: row buffers of
+    3072 | 12288 rows), pure-bf16 AMP.  ``with_kept=False`` strips the
+    op's ``Kept`` slot before the backward is appended: the program as it
+    was built before the slot existed, whose grad ops replay the
+    forward."""
+    from paddle_tpu import models
+    from paddle_tpu.fluid.layers import rnn
+
+    cfg = models.deepseek_v3.DeepseekV3Config(
+        vocab_size=512, hidden_size=512, num_hidden_layers=1 + EXPERT_LAYERS,
+        num_attention_heads=2, kv_lora_rank=64, intermediate_size=512,
+        moe_intermediate_size=256, n_routed_experts=64,
+        num_experts_per_tok=6, n_routed_experts_held=8, max_seq_len=2048)
+    real = rnn.routed_experts
+
+    def without_kept(*args, **kwargs):
+        outs = real(*args, **kwargs)
+        del outs[0].block.ops[-1].outputs["Kept"]
+        return outs
+
+    main, startup = fluid.Program(), fluid.Program()
+    fluid.layers.routed_experts = real if with_kept else without_kept
+    try:
+        with fluid.program_guard(main, startup), fluid.unique_name.guard():
+            opt = fluid.contrib.mixed_precision.decorate(
+                fluid.optimizer.SGD(0.01), use_pure_bf16=True)
+            loss = models.deepseek_v3.build_train(cfg, optimizer=opt)["loss"]
+    finally:
+        fluid.layers.routed_experts = real
+    ids = np.random.default_rng(0).integers(0, 512, (1, 2048 + 1))
+    feed = {"ids": ids[:, :-1, None].astype(np.int64),
+            "labels": ids[:, 1:, None].astype(np.int64)}
+    return main, startup, loss, feed
+
+
+def _conditionals(executable):
+    return len(re.findall(r" conditional\(", executable.as_text()))
+
+
+@pytest.fixture(scope="module")
+def monkeypatch_module():
+    patch = pytest.MonkeyPatch()
+    yield patch
+    patch.undo()
+
+
+@pytest.fixture(scope="module")
+def expert_stack_steps(one_chip, monkeypatch_module):
+    """The compiled step with the ``Kept`` slot, without it, and (``"one
+    rung"``) with the rungs taken away: the layer as it was before it had
+    any."""
+    from paddle_tpu.fluid.ops import decoder_ops
+
+    steps = {True: _compile_step_for(one_chip, *_expert_stack(True)),
+             False: _compile_step_for(one_chip, *_expert_stack(False))}
+    monkeypatch_module.setattr(
+        decoder_ops, "_rungs", lambda T, top_k, n_held, E: (T * top_k,))
+    steps["one rung"] = _compile_step_for(one_chip, *_expert_stack(True))
+    return steps
+
+
+def test_training_step_holds_one_conditional_a_layer_and_pass(
+        expert_stack_steps):
+    """An expert layer with two rungs compiles to ONE forward and ONE
+    backward conditional: the forward op hands its first rung's rows to
+    the grad op through ``Kept``.  A grad op that replays the forward
+    compiles the forward conditional a second time (XLA merges replayed
+    HLO, never two conditionals); one rung compiles none."""
+    assert _conditionals(expert_stack_steps[True]) == 2 * EXPERT_LAYERS
+    assert _conditionals(expert_stack_steps[False]) == 3 * EXPERT_LAYERS
+    assert _conditionals(expert_stack_steps["one rung"]) == 0
+
+
+def test_rungs_cost_the_step_no_temporaries(expert_stack_steps):
+    """The step's temporaries with two rungs are not above those of the
+    layer with worst-case buffers alone.  What is kept from forward to
+    backward falls to a quarter (3072 rows of 12288); the last rung's own
+    buffers still have their place in the allocation, whichever rung
+    runs, so two expert layers gain little and four gain a quarter of the
+    step (the Moonlight cell: 4.9 GB against 6.5)."""
+    new, old = (expert_stack_steps[w].memory_analysis().temp_size_in_bytes
+                for w in (True, "one rung"))
+    assert new <= old, (new, old)
+
+
 # -- latent attention's kernels at the cell's shapes, compiled for a v5e ------
 
 def test_latent_attention_kernels_fit_the_v5e_at_s4096(one_chip):
