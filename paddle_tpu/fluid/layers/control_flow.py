@@ -385,11 +385,18 @@ class StaticRNN:
 
     Inputs are time-major ``[T, batch, ...]``; the step sub-block sees one
     time slice; memories carry state across steps; outputs are re-stacked
-    time-major.  Lowered to a single ``lax.scan``; fully differentiable.
+    time-major.  Lowered to a single ``lax.scan``; fully differentiable
+    (``recurrent_grad`` is one reverse scan over the carries the forward
+    op saved: ops/control_flow_ops.py).
+
+    ``steps``: the step count of a loop with NO step input, a block of
+    layers applied ``steps`` times to its memories (weight-tied passes);
+    with step inputs their leading dimension is the count, as ever.
     """
 
-    def __init__(self, name=None):
+    def __init__(self, name=None, steps=None):
         self.helper = LayerHelper("static_rnn", name=name)
+        self._steps = None if steps is None else int(steps)
         self._sub = None
         self._parent = None
         self._step_inputs = []   # (outer Variable, inner Variable)
@@ -486,9 +493,15 @@ class StaticRNN:
         params = [n for n in _external_reads(self._sub, prog.blocks)
                   if n not in inner_names]
 
-        n_steps = None
+        n_steps = self._steps
         if self._step_inputs and self._step_inputs[0][0].shape:
             n_steps = self._step_inputs[0][0].shape[0]
+            if self._steps not in (None, n_steps):
+                raise ValueError(
+                    "StaticRNN(steps=%d) over step inputs of %d steps"
+                    % (self._steps, n_steps))
+        elif not self._step_inputs and not n_steps:
+            raise ValueError("StaticRNN without a step input needs steps=")
         outs = []
         for o in self._outputs:
             ov = self._parent.create_var(
@@ -504,6 +517,18 @@ class StaticRNN:
                 dtype=link.mem.dtype,
                 shape=tuple(link.mem.shape) if link.mem.shape else None)
             finals.append(fv)
+        # each memory at the ENTRY of every step, for recurrent_grad (the
+        # op's residual, as fused_attention hands on its LSE; unwritten in
+        # a program that is not differentiated)
+        carries = []
+        for link, init in zip(self._memories, self._mem_inits):
+            cv = self._parent.create_var(
+                name=self.helper.name + ".carries." + link.pre_mem.name,
+                dtype=init.dtype,
+                shape=((n_steps,) + tuple(init.shape)
+                       if init.shape and n_steps is not None else None))
+            cv.stop_gradient = True
+            carries.append(cv)
 
         self._parent.append_op(
             "recurrent",
@@ -511,8 +536,10 @@ class StaticRNN:
                     "Initials": [v.name for v in self._mem_inits],
                     "Params": params},
             outputs={"Outputs": [v.name for v in outs],
-                     "FinalStates": [v.name for v in finals]},
+                     "FinalStates": [v.name for v in finals],
+                     "Carries": [v.name for v in carries]},
             attrs={"sub_block": self._sub.idx,
+                   "n_steps": int(n_steps or 0),
                    "step_input_vars": [iv.name for _, iv in self._step_inputs],
                    "pre_state_vars": [l.pre_mem.name for l in self._memories],
                    "state_vars": [l.mem.name for l in self._memories],

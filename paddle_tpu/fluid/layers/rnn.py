@@ -385,17 +385,20 @@ def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
     return out
 
 
-def rotary_embedding(x, theta=10000.0, name=None):
+def rotary_embedding(x, theta=10000.0, interleaved=True, name=None):
     """Rotary position embedding on the last axis of ``x`` [B, S, heads, D]
     at positions 0..S-1 (the caller slices the rotary part of a head off
-    first).  Lanes are paired (2i, 2i+1) as in the published ``deepseek_v3``
-    weights and de-interleaved before the rotate-half."""
+    first).  ``interleaved=True``: lanes are paired (2i, 2i+1) as in the
+    published ``deepseek_v3`` weights and de-interleaved before the
+    rotate-half.  ``interleaved=False``: lanes are paired (i, i + D/2), the
+    rotate-half layout of the Llama family's published weights."""
     helper = LayerHelper("rotary_embedding", name=name)
     out = helper.create_variable_for_type_inference(x.dtype)
     out.shape = x.shape
     helper.append_op("rotary_embedding", inputs={"X": [x]},
                      outputs={"Out": [out]},
-                     attrs={"theta": float(theta)})
+                     attrs={"theta": float(theta),
+                            "interleaved": bool(interleaved)})
     return out
 
 

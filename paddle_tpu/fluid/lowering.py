@@ -10,6 +10,7 @@ the reference's eager-deletion GC (``framework/garbage_collector.h``) is
 subsumed.
 """
 
+import contextlib
 import types
 
 import jax
@@ -228,6 +229,25 @@ def role_scope(role):
     return "role_fwd"
 
 
+# Ops whose lowering runs a sub-block through ``run_block`` as a loop body:
+# the body's ops carry their own ``role_*`` / ``fluid_<op>`` scopes, and a
+# ``fluid_recurrent`` around them would be every body instruction's FIRST
+# ``fluid_*`` match, one line holding the whole loop in every reader.  The
+# lowering names the body itself (``ut_loop``).
+_LOOP_OPS = frozenset(["recurrent", "recurrent_grad"])
+
+
+def op_scopes(op):
+    """The named scopes an op lowers inside, outermost first: its role,
+    the ``fluid.name_scope`` it was built under (``op_namescope``, one
+    scope a path segment) and ``fluid_<type>``."""
+    names = [role_scope(op.op_role)]
+    names += [s for s in (op.attr("op_namescope", "") or "").split("/") if s]
+    if op.type not in _LOOP_OPS:
+        names.append("fluid_" + op.type)
+    return names
+
+
 def dispatch(op, env, state, block):
     if op.type in _STRUCTURAL_OPS:
         return
@@ -243,8 +263,9 @@ def dispatch(op, env, state, block):
     # the lowered math, so they stay unconditional rather than joining
     # flags.trace_time_key().
     try:
-        with jax.named_scope(role_scope(op.op_role)), \
-                jax.named_scope("fluid_" + op.type):
+        with contextlib.ExitStack() as scopes:
+            for name in op_scopes(op):
+                scopes.enter_context(jax.named_scope(name))
             if op.type.endswith("_grad"):
                 fwd_type = op.type[:-len("_grad")]
                 from .registry import OP_DEFS

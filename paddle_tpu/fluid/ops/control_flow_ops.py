@@ -23,7 +23,8 @@ import jax
 import jax.numpy as jnp
 
 from ..data_types import jnp_dtype
-from ..registry import register_op
+from ..registry import register_grad_lower, register_op
+from .. import telemetry
 
 DEFAULT_ARRAY_CAPACITY = 128
 
@@ -294,49 +295,200 @@ def _conditional_block(ctx, op):
 # recurrent op — lax.scan; the training path for RNNs
 # ---------------------------------------------------------------------------
 
-@register_op("recurrent")
-def _recurrent(ctx, op):
-    """Scan the sub-block over the leading (time) axis of every step input.
+_m_recurrent = telemetry.counter(
+    "recurrent_lowered_total",
+    "recurrent (StaticRNN / DynamicRNN) forward scans traced, by step "
+    "count and by whether the carries at each step's entry were saved for "
+    "recurrent_grad ('saves': 1 or 0)")
 
-    Slots: Inputs (time-major [T, ...] outer arrays), Initials (initial
-    memory values), Params (closure reads — weights — declared so autodiff
-    reaches them); Outputs (stacked [T, ...]), FinalStates.
-    Attrs map outer slots to inner sub-block var names.  Reference analogue:
-    the StaticRNN machinery of ``layers/control_flow.py`` over
-    ``recurrent_op.cc``, re-founded on lax.scan.
-    """
+_m_recurrent_grad = telemetry.counter(
+    "recurrent_grad_lowered_total",
+    "recurrent_grad ops lowered: one reverse scan each, which "
+    "rematerialises every step from the forward op's saved carry")
+
+
+def _scan_operands(ctx, op):
+    """``(step_fn, init, xs, n_steps, reverse)`` of a ``recurrent`` op or
+    its grad op (which carries the same slots and attributes).
+    ``step_fn(carry, x_t, closure)`` runs the sub-block once: ``closure``
+    ({outer name: value}) overrides what the body reads from outside, the
+    differentiated ``Params`` in the grad op."""
     state = ctx.state
     sub = state.blocks[ctx.attr("sub_block")]
     env = ctx.env
-
     in_vars = ctx.attr("step_input_vars", [])     # inner names, one per Inputs
     pre_vars = ctx.attr("pre_state_vars", [])     # inner names, one per Initials
     post_vars = ctx.attr("state_vars", [])        # inner names (new state)
     out_vars = ctx.attr("step_output_vars", [])   # inner names, one per Outputs
-    reverse = ctx.attr("reverse", False)
 
     xs = tuple(env[n] for n in op.input("Inputs"))
     init = tuple(env[n] for n in op.input("Initials"))
+    # a loop with no sequence to slice (a block applied n times) gives its
+    # count itself; where there are step inputs their leading axis does
+    n_steps = xs[0].shape[0] if xs else int(ctx.attr("n_steps", 0) or 0)
+    if not n_steps and not xs:
+        raise ValueError("recurrent: no step input and no n_steps")
 
     from ..lowering import run_block
 
-    def body(carry, x_t):
+    def step_fn(carry, x_t, closure=None):
         e2 = dict(env)
+        if closure:
+            e2.update(closure)
         for name, v in zip(in_vars, x_t):
             e2[name] = v
         for name, v in zip(pre_vars, carry):
             e2[name] = v
-        run_block(sub, e2, state)
+        with jax.named_scope("ut_loop"):
+            run_block(sub, e2, state)
         new_carry = tuple(e2[n].astype(c.dtype) if e2[n].dtype != c.dtype
                           else e2[n] for n, c in zip(post_vars, carry))
         ys = tuple(e2[n] for n in out_vars)
         return new_carry, ys
 
-    final, ys = jax.lax.scan(body, init, xs, reverse=reverse)
+    return step_fn, init, xs, n_steps, bool(ctx.attr("reverse", False))
+
+
+def _saves_carries(ctx, op):
+    """The forward op writes ``Carries`` only where a ``recurrent_grad`` of
+    the same block reads them: a program that is not differentiated keeps
+    nothing."""
+    names = [n for n in op.output("Carries") if n]
+    return bool(names) and any(
+        o.type == "recurrent_grad" and o.input("Carries") == names
+        for o in ctx.block.ops)
+
+
+@register_op("recurrent")
+def _recurrent(ctx, op):
+    """Scan the sub-block over the leading (time) axis of every step input,
+    or ``n_steps`` times where it has none.
+
+    Slots: Inputs (time-major [T, ...] outer arrays), Initials (initial
+    memory values), Params (closure reads — weights — declared so autodiff
+    reaches them); Outputs (stacked [T, ...]), FinalStates, and Carries
+    ([T, ...] a memory: its value at the ENTRY of every step, no gradient;
+    an intermediate output like ``fused_attention``'s ``LSE``, written
+    only where ``recurrent_grad`` reads it).
+    Attrs map outer slots to inner sub-block var names.  Reference analogue:
+    the StaticRNN machinery of ``layers/control_flow.py`` over
+    ``recurrent_op.cc``, re-founded on lax.scan.
+    """
+    env = ctx.env
+    step_fn, init, xs, n_steps, reverse = _scan_operands(ctx, op)
+    saves = _saves_carries(ctx, op)
+    _m_recurrent.inc(steps=str(n_steps), saves=str(int(saves)))
+
+    def body(carry, x_t):
+        new_carry, ys = step_fn(carry, x_t)
+        return new_carry, (ys, carry if saves else ())
+
+    final, (ys, carries) = jax.lax.scan(body, init, xs, length=n_steps,
+                                        reverse=reverse)
     for n, v in zip(op.output("Outputs"), ys):
         env[n] = v
     for n, v in zip(op.output("FinalStates"), final):
         env[n] = v
+    if saves:
+        ctx.set_all("Carries", carries)
+
+
+def _floating(v):
+    return jnp.issubdtype(v.dtype, jnp.floating)
+
+
+@register_grad_lower("recurrent")
+def _recurrent_grad(ctx, op):
+    """ONE reverse ``lax.scan`` over the forward op's saved ``Carries``.  A
+    step takes the carry its forward step entered with, rematerialises the
+    sub-block under ``jax.vjp`` (as a function of the floating carries,
+    step inputs and ``Params`` that want a gradient) and pulls back the
+    step's output cotangents and the running carry cotangent.  The
+    ``Params``' cotangents are summed in the scan's carry, one float32
+    buffer a parameter whatever the step count: a weight applied ``T``
+    times gets its whole gradient from here, not ``T`` stacked
+    contributions.  The forward scan is never run again, and nothing but
+    the carries crosses from the forward op to this one."""
+    env = ctx.env
+    carries = tuple(env.get(n) for n in op.input("Carries"))
+    if len(carries) != len(op.input("Initials")) or \
+            any(c is None for c in carries):
+        raise ValueError("recurrent_grad: the forward op's Carries are not "
+                         "among its inputs (StaticRNN writes the slot)")
+    _m_recurrent_grad.inc()
+    step_fn, init, xs, n_steps, reverse = _scan_operands(ctx, op)
+
+    def wanted(slot, values):
+        """Per entry of ``slot``: the name its gradient goes to, where the
+        grad op asks for one and the value is floating."""
+        names = op.output(slot + "@GRAD")
+        return [names[i] if i < len(names) and names[i] and _floating(v)
+                else "" for i, v in enumerate(values)]
+
+    param_names = op.input("Params")
+    want_p = {n: g for n, g in zip(
+        param_names, wanted("Params", [env[n] for n in param_names])) if g}
+    diff_p = list(want_p)
+    want_x, want_c = wanted("Inputs", xs), wanted("Initials", init)
+    diff_x = [i for i, g in enumerate(want_x) if g]
+    # the carry's cotangent runs through every floating memory, asked for
+    # at the loop's entry or not
+    diff_c = [i for i, v in enumerate(init) if _floating(v)]
+
+    def cotangent(slot, i, like):
+        names = op.input(slot + "@GRAD")
+        name = names[i] if i < len(names) else ""
+        g = env.get(name) if name else None
+        return jnp.zeros(like.shape, like.dtype) if g is None \
+            else jnp.asarray(g, like.dtype).reshape(like.shape)
+
+    # what the forward step returns, for the shapes of absent cotangents
+    _, spec_y = jax.eval_shape(step_fn, init, tuple(x[0] for x in xs))
+    diff_y = [i for i, s in enumerate(spec_y) if _floating(s)]
+    d_ys = tuple(cotangent("Outputs", i, jax.ShapeDtypeStruct(
+        (n_steps,) + spec_y[i].shape, spec_y[i].dtype)) for i in diff_y)
+    d_final = tuple(cotangent("FinalStates", i, init[i]) for i in diff_c)
+    acc0 = tuple(jnp.zeros(env[n].shape, jnp.float32) for n in diff_p)
+
+    def back_step(state, saved):
+        d_carry, acc = state
+        carry_t, x_t, d_y_t = saved
+
+        def floating_part(c_diff, x_diff, p_diff):
+            carry = list(carry_t)
+            for i, v in zip(diff_c, c_diff):
+                carry[i] = v
+            x = list(x_t)
+            for i, v in zip(diff_x, x_diff):
+                x[i] = v
+            new_carry, ys = step_fn(tuple(carry), tuple(x),
+                                    dict(zip(diff_p, p_diff)))
+            return tuple(new_carry[i] for i in diff_c), \
+                tuple(ys[i] for i in diff_y)
+
+        with jax.named_scope("ut_remat"):
+            _, vjp = jax.vjp(floating_part,
+                             tuple(carry_t[i] for i in diff_c),
+                             tuple(x_t[i] for i in diff_x),
+                             tuple(env[n] for n in diff_p))
+        d_c, d_x, d_p = vjp((d_carry, d_y_t))
+        # the op's own work, named as every op's is (its body's ops carry
+        # their own fluid_<op> scopes)
+        with jax.named_scope("fluid_recurrent_grad"):
+            acc = tuple(a + g.astype(jnp.float32)
+                        for a, g in zip(acc, d_p))
+        return (d_c, acc), d_x
+
+    (d_init, acc), d_xs = jax.lax.scan(
+        back_step, (d_final, acc0), (carries, xs, d_ys), length=n_steps,
+        reverse=not reverse)
+    for i, g in zip(diff_c, d_init):
+        if want_c[i]:
+            env[want_c[i]] = g
+    for i, g in zip(diff_x, d_xs):
+        env[want_x[i]] = g
+    for n, g in zip(diff_p, acc):
+        env[want_p[n]] = g.astype(env[n].dtype)
 
 
 @register_op("print", stop_gradient=True)

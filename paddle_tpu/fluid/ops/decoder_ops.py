@@ -74,17 +74,23 @@ def _rms_norm(ctx, op):
     ctx.set("Y", y.astype(x.dtype))
 
 
-def rotary(x, theta):
+def rotary(x, theta, interleaved=True):
     """Rotary position embedding over the last axis of ``x`` [B, S, heads,
-    D], positions 0..S-1 (full sequences, packed from 0).  The published
-    weights pair lane 2i with lane 2i+1, and the source
-    (``modeling_deepseek.py: apply_rotary_pos_emb``) de-interleaves the
-    lanes (evens first, then odds) before the usual rotate-half; the result
-    stays in that de-interleaved order, for Q and K alike, so the scores are
-    unchanged."""
+    D], positions 0..S-1 (full sequences, packed from 0).
+
+    ``interleaved`` (the ``deepseek_v3`` layout): the published weights
+    pair lane 2i with lane 2i+1, and the source (``modeling_deepseek.py:
+    apply_rotary_pos_emb``) de-interleaves the lanes (evens first, then
+    odds) before the usual rotate-half; the result stays in that
+    de-interleaved order, for Q and K alike, so the scores are unchanged.
+    Not ``interleaved`` (the Llama layout): the weights pair lane i with
+    lane i + D/2 already, and the rotate-half runs on the lanes as they
+    are."""
     B, S, N, D = x.shape
-    xf = x.astype(jnp.float32).reshape(B, S, N, D // 2, 2).swapaxes(-1, -2) \
-        .reshape(B, S, N, D)
+    xf = x.astype(jnp.float32)
+    if interleaved:
+        xf = xf.reshape(B, S, N, D // 2, 2).swapaxes(-1, -2) \
+            .reshape(B, S, N, D)
     inv_freq = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
     angles = jnp.arange(S, dtype=jnp.float32)[:, None] * inv_freq[None, :]
     angles = jnp.concatenate([angles, angles], axis=-1)[None, :, None, :]
@@ -96,8 +102,9 @@ def rotary(x, theta):
 def _rotary_embedding(ctx, op):
     """X [B, S, heads, D] -> Out, the same shape: rotary embedding with
     base ``theta`` on the whole last axis (the caller hands over the
-    rotary slice of the head)."""
-    ctx.set("Out", rotary(ctx.i("X"), float(ctx.attr("theta", 10000.0))))
+    rotary slice of the head); ``interleaved`` says how the lanes pair."""
+    ctx.set("Out", rotary(ctx.i("X"), float(ctx.attr("theta", 10000.0)),
+                          bool(ctx.attr("interleaved", True))))
 
 
 # -- the routed-expert layer ---------------------------------------------------

@@ -18,3 +18,5 @@ from . import vgg
 from . import se_resnext
 from . import deepseek_v3
 from . import deepseek_v3_reference
+from . import ouro
+from . import ouro_reference

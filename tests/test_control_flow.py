@@ -234,3 +234,177 @@ def test_dynamic_rnn_masks_past_lengths():
     oracle = np.stack(ys, axis=1)
     np.testing.assert_allclose(out_v, oracle, rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(out_v[1, 2:], 0.0)
+
+
+# -- recurrent's own grad lowering (one reverse scan over saved carries) -----
+
+def _rnn_program(kind, differentiate=True):
+    """A small recurrent training program of each kind the lowering has to
+    keep whole: stacked outputs, final states, ``reverse``, an integer
+    memory and step input that take no gradient, a loop inside the loop,
+    and a loop with a step count and no step input.  Returns what to
+    fetch: outputs, final states and the gradient of every input."""
+    T, B, D, H = 4, 2, 3, 5
+    rng = np.random.default_rng(3)
+    feed = {"x": rng.normal(size=(T, B, D)).astype(np.float32)}
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 5
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        x = layers.data(name="x", shape=[T, B, D], dtype="float32",
+                        append_batch_size=False)
+        x.stop_gradient = False
+        wrt, fetch = [x], []
+
+        def cell(inp, pre, name):
+            return layers.fc(input=layers.concat([inp, pre], axis=1),
+                             size=H, act="tanh", bias_attr=False,
+                             param_attr=fluid.ParamAttr(name=name))
+
+        if kind == "dynamic":
+            feed["x"] = np.ascontiguousarray(feed["x"].transpose(1, 0, 2))
+            feed["lens"] = np.array([4, 2], np.int64)
+            main.global_block().vars["x"].shape = (B, T, D)
+            lens = layers.data(name="lens", shape=[B], dtype="int64",
+                               append_batch_size=False)
+            rnn = layers.DynamicRNN()
+            with rnn.block():
+                x_t = rnn.step_input(x, lengths=lens)
+                pre = rnn.memory(shape=[H], batch_ref=x_t, dtype="float32")
+                h = cell(x_t, pre, "w")
+                rnn.update_memory(pre, h)
+                rnn.output(h)
+            out, inner = rnn(), rnn._rnn
+        elif kind == "counted":
+            h0 = layers.fc(layers.reshape(x, [T * B, D]), H, bias_attr=False,
+                           param_attr=fluid.ParamAttr(name="w_in"))
+            rnn = inner = layers.StaticRNN(steps=3)
+            with rnn.step():
+                pre = rnn.memory(init=h0)
+                h = layers.fc(pre, H, act="tanh", bias_attr=False,
+                              param_attr=fluid.ParamAttr(name="w"))
+                rnn.update_memory(pre, h)
+                rnn.step_output(layers.reduce_sum(h * h, dim=-1))
+            out = rnn()
+        else:
+            rnn = inner = layers.StaticRNN()
+            with rnn.step():
+                x_t = rnn.step_input(x)
+                pre = rnn.memory(shape=[H], batch_ref=x_t, dtype="float32")
+                if kind == "integers":
+                    count = rnn.memory(shape=[1], batch_ref=x_t,
+                                       dtype="int64")
+                    rnn.update_memory(count, count + 1)
+                    h = cell(x_t * layers.cast(count + 1, "float32"), pre,
+                             "w")
+                elif kind == "nested":
+                    sub = layers.StaticRNN(steps=2)
+                    with sub.step():
+                        s_pre = sub.memory(init=pre)
+                        s_h = cell(x_t, s_pre, "w")
+                        sub.update_memory(s_pre, s_h)
+                        sub.step_output(s_h)
+                    h = layers.reduce_sum(sub(), dim=0)
+                else:
+                    h = cell(x_t, pre, "w")
+                rnn.update_memory(pre, h)
+                rnn.step_output(h)
+            out = rnn()
+            if kind == "reverse":
+                main.global_block().ops[-1].attrs["reverse"] = True
+        finals = [f for f in inner._final_vars if f.dtype == "float32"
+                  or "float" in str(f.dtype)]
+        loss = layers.reduce_mean(out * out)
+        for f in finals:
+            loss = loss + layers.reduce_sum(f * f)
+        if not differentiate:
+            return main, startup, feed, [out]
+        params_grads = fluid.backward.append_backward(loss)
+        fetch = [out] + list(inner._final_vars) + \
+            [main.global_block().var(main._grad_name_map[v.name])
+             for v in wrt] + [g for _, g in params_grads]
+    return main, startup, feed, fetch
+
+
+def _close(got, want, tol=2e-5):
+    """Float32 on both sides, another order of summation: ``tol`` of the
+    tensor's largest entry."""
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=tol * max(np.abs(want).max(), 1e-30))
+
+
+@pytest.mark.parametrize("kind", ["plain", "reverse", "dynamic", "integers",
+                                  "nested", "counted"])
+def test_recurrent_grad_lowering_equals_the_replay(kind, monkeypatch):
+    """``recurrent_grad``'s own lowering (a reverse scan that
+    rematerialises each step from the saved carry) against
+    ``generic_grad_lower`` (``jax.vjp`` of a second run of the whole scan)
+    on the same program: outputs, final states, and the gradient of the
+    step inputs and of every parameter."""
+    from paddle_tpu.fluid import telemetry
+    from paddle_tpu.fluid.registry import OP_DEFS
+
+    counted = telemetry.registry().counter("recurrent_grad_lowered_total")
+    before = counted.value()
+    own = _run(*_rnn_program(kind))
+    assert counted.value() == before + 1
+    monkeypatch.setattr(OP_DEFS["recurrent"], "grad_lower", None)
+    replayed = _run(*_rnn_program(kind))
+    assert counted.value() == before + 1                 # generic: uncounted
+    assert len(own) == len(replayed) >= 4
+    for got, want in zip(own, replayed):
+        _close(got, want)
+    assert all(np.abs(g).max() > 0 for g in own[-2:])
+
+
+def test_recurrent_grad_without_the_carries_says_so():
+    """``StaticRNN`` always writes the ``Carries`` slot; a grad op that lost
+    it (a program description edited by hand) is refused, not guessed at."""
+    main, startup, feed, fetch = _rnn_program("plain")
+    for op in main.global_block().ops:
+        if op.type == "recurrent_grad":
+            del op.inputs["Carries"]
+    main._bump_version()
+    with pytest.raises(Exception, match="Carries"):
+        _run(main, startup, feed, fetch)
+
+
+def test_carries_are_written_only_where_a_grad_op_reads_them():
+    from paddle_tpu.fluid import telemetry
+
+    counted = telemetry.registry().counter("recurrent_lowered_total")
+    main, startup, feed, fetch = _rnn_program("plain")
+    before = counted.value(steps="4", saves="1"), \
+        counted.value(steps="4", saves="0")
+    _run(main, startup, feed, fetch)
+    assert counted.value(steps="4", saves="1") == before[0] + 1
+    _run(*_rnn_program("plain", differentiate=False))
+    assert counted.value(steps="4", saves="0") == before[1] + 1
+
+
+def test_static_rnn_takes_a_step_count_where_it_has_no_step_input():
+    B, H = 2, 3
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        h0 = layers.data(name="h0", shape=[B, H], dtype="float32",
+                         append_batch_size=False)
+        rnn = layers.StaticRNN(steps=5)
+        with rnn.step():
+            pre = rnn.memory(init=h0)
+            nxt = pre * 0.5 + 1.0
+            rnn.update_memory(pre, nxt)
+            rnn.step_output(nxt)
+        out = rnn()
+        assert tuple(out.shape) == (5, B, H)
+        with pytest.raises(ValueError, match="steps"):
+            bad = layers.StaticRNN()
+            with bad.step():
+                pre = bad.memory(init=h0)
+                bad.update_memory(pre, pre * 2.0)
+                bad.step_output(pre)
+    h = np.arange(B * H, dtype=np.float32).reshape(B, H)
+    got, = _run(main, startup, {"h0": h}, [out])
+    want = []
+    for _ in range(5):
+        h = h * 0.5 + 1.0
+        want.append(h)
+    np.testing.assert_allclose(got, np.stack(want), rtol=1e-6)
