@@ -452,7 +452,9 @@ def phase_kernels(device, dry_run):
             jnp.asarray(rng.normal(0, scale, shape), dtype), device)
 
     BH, D = (1, 64) if dry_run else (16 * 12, 64)
-    seqs = (128,) if dry_run else (128, 512)
+    # 128 and 512 are one tile a head (the fused backward, flash_bwd);
+    # 1024 is two tiles a side (the dQ pass and the dK/dV pass)
+    seqs = (128,) if dry_run else (128, 512, 1024)
     # the interpreter is slow: the dry run takes the one case that reaches
     # every kernel (a bias brings in dbias) under the causal mask
     cases = [(True, True)] if dry_run else \

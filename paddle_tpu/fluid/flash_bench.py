@@ -1,8 +1,8 @@
-"""Flash-attention tile sweep: ms a call of each of the three kernels
-(``flash_fwd``, ``flash_dq``, ``flash_dkv``) on the attached chip, at the
-tile the chooser picks (``pallas_ops._tiles``) and at every forced square
-and mixed tile from 128 to 512, at the shapes of the benchmark's two kernel
-cells:
+"""Flash-attention tile sweep: ms a call of each kernel (``flash_fwd``,
+``flash_dq``, ``flash_dkv``, and ``flash_bwd`` where the backward is one
+kernel) on the attached chip, at the tile the chooser picks
+(``pallas_ops._tiles``) and at every forced square and mixed tile from 128
+to 512, at the shapes of the benchmark's two kernel cells:
 
 * ``bert``: BH=384 (batch 32 x 12 heads), S=512, D=64, bf16, with a bias,
   non-causal — the unrolled form (``bert_base_s512_flash``);
@@ -10,10 +10,14 @@ cells:
   rotary key head — the looped form (``moonlight_ep8share_s4096_train``).
 
 Run: python -m paddle_tpu.fluid.flash_bench [bert|moonlight ...]
-Prints one JSON line per shape, kernel and tile.  Each kernel is timed
-alone (the dQ call is dead code in the dK/dV timing: delta is passed in),
-by the bench.py fence (async dispatch, one scalar fetch, RTT subtracted).
-A time is a chip's: off a TPU the module refuses to run.
+Prints one JSON line per shape, kernel and tile; ``form`` says which form
+of the backward the lowering takes at the shape (``fused``: ``bwd`` alone
+runs in a step, ``dq`` and ``dkv`` are what it replaced; ``two_pass``: no
+``bwd`` record), and a ``bwd`` record exists at the chooser's pick only
+(its one tile is the head).  Each kernel is timed alone (delta is passed
+to the dK/dV pass), by the bench.py fence (async dispatch, one scalar
+fetch, RTT subtracted).  A time is a chip's: off a TPU the module refuses
+to run.
 """
 
 import json
@@ -73,9 +77,12 @@ def _kernel_calls(ops):
                             a["rope"])[0]
 
     def dkv(a, lse, delta):
-        return po._flash_backward(a["q"], a["k"], a["v"], a["bias"], scale,
-                                  lse, a["g"], causal, delta,
-                                  bias_grad=False, rope=a["rope"])[1:3]
+        return po._flash_dkv(a["q"], a["k"], a["v"], a["bias"], scale, lse,
+                             a["g"], causal, delta, a["rope"])
+
+    def bwd(a, lse, delta):
+        return po._flash_bwd(a["q"], a["k"], a["v"], a["bias"], scale, lse,
+                             a["g"], causal, None if in_kernel else delta)[:3]
 
     def corner(fn):
         """One scalar that needs every output, for the fence."""
@@ -86,7 +93,8 @@ def _kernel_calls(ops):
     delta = po._row_delta(ops["g"], out)
     return {"fwd": functools.partial(corner(forward), arrays),
             "dq": functools.partial(corner(dq), arrays, lse, delta),
-            "dkv": functools.partial(corner(dkv), arrays, lse, delta)}
+            "dkv": functools.partial(corner(dkv), arrays, lse, delta),
+            "bwd": functools.partial(corner(bwd), arrays, lse, delta)}
 
 
 def sweep(shape, steps=30):
@@ -101,13 +109,16 @@ def sweep(shape, steps=30):
                            "here (%s)" % jax.default_backend())
     ops = _operands(shape)
     chooser = po._tiles
+    key = po._shape_key(ops["q"], ops["k"], ops["v"], ops["bias"],
+                        ops["causal"], ops["rope"])
+    fused = po._fused_backward(*key)
     try:
         for forced in (None,) + TILES:
             po._tiles = chooser if forced is None else \
                 (lambda kernel, *shape: (True,) + forced)
-            key = po._shape_key(ops["q"], ops["k"], ops["v"], ops["bias"],
-                                ops["causal"], ops["rope"])
             for kernel, call in _kernel_calls(ops).items():
+                if kernel == "bwd" and not (fused and forced is None):
+                    continue
                 _, block_q, block_k = po._tiles(kernel, *key)
                 try:
                     dt, _ = timed_steps(lambda i: call(), steps, warmup=3,
@@ -116,7 +127,8 @@ def sweep(shape, steps=30):
                 except Exception as e:      # a tile Mosaic refuses
                     rec = {"error": str(e)[:200]}
                 yield dict(shape=shape, kernel=kernel, block_q=block_q,
-                           block_k=block_k, chosen=forced is None, **rec)
+                           block_k=block_k, chosen=forced is None,
+                           form="fused" if fused else "two_pass", **rec)
     finally:
         po._tiles = chooser
 

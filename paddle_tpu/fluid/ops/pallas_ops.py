@@ -7,19 +7,21 @@ matmul + softmax + matmul ops through cuDNN/cuBLAS; the TPU-native hot
 path is one fused kernel).  Backward is the tiled FlashAttention-2 pair
 (dQ pass + dK/dV pass) recomputing probabilities from the forward's
 saved logsumexp — [S, S] never exists in HBM in either direction for
-dq/dk/dv.  Bias gradients are exact too, via a separate tiled pass whose
-[S, S]-sized output is inherent to d(bias) itself; when the bias is a
-non-trainable mask the op's grad lowering skips that pass (and under
-``jax.grad`` XLA dead-code-eliminates it).  Non-tileable shapes fall
-back to differentiating the identical XLA composition.
+dq/dk/dv — and ONE kernel for all three where a head is one tile ("One
+backward kernel" below).  Bias gradients are exact too, via a separate
+tiled pass whose [S, S]-sized output is inherent to d(bias) itself; when
+the bias is a non-trainable mask the op's grad lowering skips that pass
+(and under ``jax.grad`` XLA dead-code-eliminates it).  Non-tileable shapes
+fall back to differentiating the identical XLA composition.
 
 What goes from forward to backward is the logsumexp alone, lane-dense
 (``[BH, S_q]``; the op's ``LSE`` output, ``[B, H, S_q]``): the dQ pass
-forms delta = sum_d dO * O = sum_j P * dP from the P and dP tiles it
-computes anyway, so ``out`` is no residual, and ``fused_attention_grad``
-has a lowering of its own that runs the backward kernels on the forward
-op's ``LSE`` (the generic replay of the forward lowering would trace a
-second forward kernel: XLA merges replayed HLO, never two custom calls).
+(or the fused backward) forms delta = sum_d dO * O = sum_j P * dP from the
+P and dP tiles it computes anyway, so ``out`` is no residual, and
+``fused_attention_grad`` has a lowering of its own that runs the backward
+kernels on the forward op's ``LSE`` (the generic replay of the forward
+lowering would trace a second forward kernel: XLA merges replayed HLO,
+never two custom calls).
 
 Every kernel goes through ``_pallas_call``: Mosaic compiles it when the
 computation is lowered for a TPU, and Pallas interpret mode runs it on any
@@ -40,18 +42,19 @@ bias tile double-buffered, the other side double- or single-buffered as
 ``_whole_seq`` decides, lane-padded row statistics, the float32 score / P
 / dP / dS tiles, accumulators, the dQ pass's scratches) fits
 ``_VMEM_BUDGET_BYTES`` = 32 MiB, a quarter of a v5e core's VMEM.  At
-S=512, D=64 with a bias that is ONE 512 x 512 tile a head in all three
-kernels (grid ``(BH, 1)``; the forward is a plain softmax with no rescale,
-the dQ pass holds the row's P and dP as values); at 16 heads, S=4096,
-128 + 64 | 128 causal it is 512 x 512 too.  The compiler's scoped default
+S=512, D=64 with a bias that is ONE 512 x 512 tile a head in every
+kernel (grid ``(BH, 1)``; the forward is a plain softmax with no rescale,
+the backward is the fused kernel); at 16 heads, S=4096, 128 + 64 | 128
+causal it is 512 x 512 too.  The compiler's scoped default
 is 16 MiB: where the estimate and an eighth over it pass that,
 ``_pallas_call`` hands Mosaic ``vmem_limit_bytes`` = that sum
 (``_vmem_limit``; the Moonlight dK/dV pass: 15.5 -> 17.4 MiB), and where no
 tile fits the budget the op composes (``_flash_fits``: S=32768 at D=128).
 Mosaic's own count is lower than the estimate (compiled for a v5e, libtpu
-0.0.34: 3.0 / 4.2 / 4.2 MiB for the flash cell's three kernels against
-6.8 / 9.0 / 11.0 estimated; 7.9 / 9.7 / 7.7 against 10.5 / 13.3 / 15.5
-for Moonlight's), which leaves room for what XLA parks in VMEM inside a
+0.0.34: 3.0 / 4.2 / 4.2 MiB for forward, dQ and dK/dV at the flash cell's
+shape against 6.8 / 9.0 / 11.0 estimated, 2.75 against 11.5 for the fused
+backward that runs there; 7.9 / 9.7 / 7.7 against 10.5 / 13.3 / 15.5 for
+Moonlight's), which leaves room for what XLA parks in VMEM inside a
 step.  Past a budget's worth of K/V the sequence has to be streamed block
 by block through the grid.  The dQ pass that forms delta over more than
 one tile holds the row's P and dP in two ``[block_q, S_kv]`` float32
@@ -59,6 +62,32 @@ scratches (counted by the chooser, which gives such a pass fewer rows:
 256 x 512 at S=4096 with a bias); past ``_DELTA_IN_KERNEL_MAX_SKV`` = 4096
 the forward keeps ``out`` and the backward passes delta in, as every
 caller did before.
+
+One backward kernel (PR 33): where ``_tiles`` gives BOTH the dQ and the
+dK/dV pass a tile that covers the whole of ``S_q`` and ``S_kv`` (each a
+grid of ``(BH, 1)``: neither gradient is accumulated across grid cells, so
+nothing forces two passes), there is no rotary pair and the kernel's own
+estimate fits the budget, the backward is ``flash_bwd``
+(``_fused_backward``, from the call's shapes alone; ``_bwd_kernel``): S, P,
+dP and dS are formed once a head and feed ``dQ = dS K``, ``dK = dS^T Q``
+and ``dV = P^T dO`` — the same five products in the same dtypes as the
+pair, which forms the scores and dP twice.  A cell holds Q, K, V, dO, the
+bias tile, both row statistics and the three outputs double-buffered, the
+float32 S / P, dP, dS and widened bias, the input-dtype copies of P and dS
+with their transposes and the products' float32 results: 11.5 MiB by the
+estimate at S=512, D=64 in bfloat16 with a bias.  It takes a passed delta
+(ring attention's K/V are a shard of the row) or forms its own, and writes
+it out only where the dbias pass reads it.  S <= 512 at the BERT widths,
+causal or not (the mask on the one tile); S=1024 and beyond, 384 or 640
+(three and five 128-row tiles) and every rotary pair keep the pair of
+passes.  On the chip at the flash cell's shape a head costs 3.81 us in
+``flash_bwd`` against 3.65 + 4.25 in the pair, and the same with the exp,
+both transposes or the scale taken out of the body: at one tile a head
+the cell's copies bound it, not its vector work — the lane-padded
+``[S, 1]`` statistics first (0.73 us more with delta passed in too, 0.78
+less with the logsumexp read as a lane-dense ``[1, S]`` row), then the
+512 KB bias tile (0.29 less read once a sequence at block row ``i // H``,
+nothing once the statistics are rows) (PERF.md, PR 33).
 
 Latent attention (PR 28): V's head size may differ from Q's and K's, and
 a head may have a second, rotary part whose keys are ONE head shared by
@@ -451,6 +480,40 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
+def _bwd_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, delta_out_ref, *, scale, causal=False):
+    """The whole backward of a head that is ONE tile (``_fused_backward``):
+    S, P, dP and dS are formed once and feed all three gradients, where
+    the dQ and dK/dV passes each rebuild them.  The same five products in
+    the same dtypes as that pair: MXU operands in the input dtype, float32
+    scores, exp and accumulation.
+
+    delta comes from ``delta_ref`` where the caller passed it (it MUST pass
+    it where K/V are a shard of the row), else it is the row sum of P * dP
+    over the tile, which is the whole row; ``delta_out_ref`` takes it for
+    the dbias pass."""
+    q, ks, vs = q_ref[0], k_ref[0], v_ref[0]       # [S_q, D], [S_kv, D | D_v]
+    do = do_ref[0].astype(q.dtype)                 # [S_q, D_v]
+    s = _add_bias(_scores(q, ks, scale), bias_ref, 0, q.shape[0], 0,
+                  ks.shape[0])
+    if causal:
+        s = _causal_mask(s, 0, 0)
+    p = jnp.exp(s - lse_ref[0])
+    dp = jnp.dot(do, vs.T, preferred_element_type=jnp.float32)
+    delta = (p * dp).sum(axis=-1, keepdims=True) if delta_ref is None \
+        else delta_ref[0]
+    if delta_out_ref is not None:
+        delta_out_ref[0] = delta
+    ds = (p * (dp - delta) * scale).astype(q.dtype)
+    dq_ref[0] = jnp.dot(ds, ks, preferred_element_type=jnp.float32) \
+        .astype(dq_ref.dtype)
+    dk_ref[0] = jnp.dot(ds.T, q, preferred_element_type=jnp.float32) \
+        .astype(dk_ref.dtype)
+    dv_ref[0] = jnp.dot(p.astype(q.dtype).T, do,
+                        preferred_element_type=jnp.float32) \
+        .astype(dv_ref.dtype)
+
+
 def _dbias_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                   delta_ref, db_ref, *, scale, block_k, causal=False):
     """d(bias) = ds, recomputed tile-wise.  Its output is [S, S]-sized by
@@ -564,7 +627,10 @@ def _whole_side(kernel, S_q, S_kv, D, D_v, R, itemsize):
     """The whole-sequence operands of one grid cell, ``(rows, cols,
     itemsize)`` each: K, V and the shared rotary keys for the passes over
     q blocks; Q, dO, Q's rotary part and both row statistics for the dK/dV
-    pass.  (No rotary part: R = 0, an operand of no bytes.)"""
+    pass; none for the fused backward, whose one cell owns the head.  (No
+    rotary part: R = 0, an operand of no bytes.)"""
+    if kernel == "bwd":
+        return []
     if kernel == "dkv":
         return [(S_q, D, itemsize), (S_q, D_v, itemsize), (S_q, R, itemsize),
                 (S_q, 1, 4), (S_q, 1, 4)]
@@ -573,15 +639,17 @@ def _whole_side(kernel, S_q, S_kv, D, D_v, R, itemsize):
 
 def _vmem_bytes(kernel, block_q, block_k, S_q, S_kv, D, D_v, R, has_bias,
                 causal, itemsize):
-    """VMEM one grid cell of ``kernel`` ('fwd', 'dq', 'dkv' or 'dbias')
-    asks for at tiles of ``block_q x block_k``, from shapes alone: what the
-    chooser holds against ``_VMEM_BUDGET_BYTES`` and ``_vmem_limit`` hands
-    the compiler.  Every operand is lane-padded (``_lane_padded_bytes``).
+    """VMEM one grid cell of ``kernel`` ('fwd', 'dq', 'dkv', 'bwd' or
+    'dbias') asks for at tiles of ``block_q x block_k``, from shapes alone:
+    what the chooser holds against ``_VMEM_BUDGET_BYTES`` and
+    ``_vmem_limit`` hands the compiler.  Every operand is lane-padded (``_lane_padded_bytes``).
 
     * the cell's own blocks, in and out, twice (the pipeline's two
       buffers): ``block_q`` rows of Q, its rotary part, dO, the row
       statistics and the outputs; in the dK/dV pass ``block_k`` rows of K,
-      V, the rotary keys and dK, dV, and the per-head float32 dKr;
+      V, the rotary keys and dK, dV, and the per-head float32 dKr; in the
+      fused backward ('bwd': ``block_q`` = S_q, ``block_k`` = S_kv) Q, K,
+      V, dO, both row statistics and dQ, dK, dV;
     * the bias tile, twice: ``[block_q, S_kv]``, ``[S_q, block_k]`` in the
       dK/dV pass, and as much again for the dbias pass's output;
     * the whole other side (``_whole_side``), twice or once as
@@ -589,11 +657,20 @@ def _vmem_bytes(kernel, block_q, block_k, S_q, S_kv, D, D_v, R, has_bias,
     * the float32 tiles a step of the sweep holds at once — scores, P, dP
       and dS, the widened bias tile — at ``block_q x block_k x 4`` each,
       and the copies in the input dtype that feed the MXU (P, dS, and in
-      the dK/dV pass their transposes);
+      the dK/dV pass and the fused backward their transposes);
     * the float32 accumulators, and the forward's two running statistics;
     * the dQ pass's two ``[block_q, S_kv]`` float32 scratches, where it
       forms delta over more than one tile (``_delta_in_kernel``)."""
-    if kernel == "dkv":
+    if kernel == "bwd":
+        own = [(block_q, D, itemsize), (block_q, D_v, itemsize),   # Q, dO
+               (block_q, 1, 4), (block_q, 1, 4),                   # lse, delta
+               (block_k, D, itemsize), (block_k, D_v, itemsize),   # K, V
+               (block_q, D, itemsize), (block_k, D, itemsize),     # dQ, dK
+               (block_k, D_v, itemsize)]                           # dV
+        bias_tile = (block_q, block_k, itemsize)
+        acc = [(block_q, D, 4), (block_k, D, 4), (block_k, D_v, 4)]
+        wide, narrow = 4, 4
+    elif kernel == "dkv":
         b = block_k
         own = [(b, D, itemsize), (b, D_v, itemsize), (b, R, itemsize),  # in
                (b, D, itemsize), (b, D_v, itemsize), (b, R, 4)]        # out
@@ -644,14 +721,21 @@ def _vmem_limit(need):
 
 def _tiles(kernel, S_q, S_kv, D, D_v, R, has_bias, causal, itemsize):
     """``(ok, block_q, block_k)`` of one kernel ('fwd', 'dq', 'dkv',
-    'dbias') at one shape: the largest tile, sides from ``_TILE_SIDES``
-    that divide the sequence, whose ``_vmem_bytes`` fits
+    'bwd', 'dbias') at one shape: the largest tile, sides from
+    ``_TILE_SIDES`` that divide the sequence, whose ``_vmem_bytes`` fits
     ``_VMEM_BUDGET_BYTES``.  A grid cell's fixed cost (the pipeline's step,
     the serial matmul -> row max -> exp -> row sum -> matmul round, MXU
     weights loaded for the streamed rows) is paid per tile, not per FLOP,
     so the largest tile wins; of two equal areas the one with more rows of
     the grid's own side, which cuts the cells.  ``ok`` False: no side
-    divides a sequence (the caller composes), or nothing fits."""
+    divides a sequence (the caller composes), or nothing fits.
+
+    'bwd' is the fused backward, whose one tile is the whole head where
+    ``_fused_backward`` lets it run."""
+    if kernel == "bwd":
+        return _fused_backward(S_q, S_kv, D, D_v, R, has_bias, causal,
+                               itemsize), S_q, S_kv
+
     def sides(S):
         # a sequence shorter than the least side is one block
         return [S] if S < _TILE_SIDES[-1] else \
@@ -666,6 +750,23 @@ def _tiles(kernel, S_q, S_kv, D, D_v, R, has_bias, causal, itemsize):
                        has_bias, causal, itemsize) <= _VMEM_BUDGET_BYTES:
             return True, block_q, block_k
     return False, min(_TILE_SIDES[-1], S_q), min(_TILE_SIDES[-1], S_kv)
+
+
+def _fused_backward(*shape):
+    """Whether the backward at this shape (``_shape_key``) is ONE kernel
+    (``_bwd_kernel``) and not the dQ pass followed by the dK/dV pass, from
+    the shape alone: where the chooser gives BOTH passes a tile that covers
+    the whole of ``S_q`` and ``S_kv`` (each a grid of ``(BH, 1)``: neither
+    gradient is accumulated across cells, so nothing forces two passes that
+    each rebuild S, P, dP and dS), there is no rotary pair (it exists in
+    the looped sweeps alone) and the kernel's own estimate fits the budget.
+    S <= 512 at the BERT widths, bias or none, causal or not (the mask on
+    the one tile: there is no diagonal to skip)."""
+    S_q, S_kv, _, _, R = shape[:5]
+    return not R and \
+        all(_tiles(kernel, *shape) == (True, S_q, S_kv)
+            for kernel in ("dq", "dkv")) and \
+        _vmem_bytes("bwd", S_q, S_kv, *shape) <= _VMEM_BUDGET_BYTES
 
 
 def _shape_key(q, k, v, bias, causal, rope):
@@ -697,8 +798,9 @@ def _plan(kernel, *shape):
 
 _m_tiles = telemetry.counter(
     "flash_tiles_total",
-    "flash kernel calls traced, by kernel ('fwd', 'dq', 'dkv', 'dbias') and "
-    "the tile the chooser picked for the call's shape: block_q rows of Q by "
+    "flash kernel calls traced, by kernel ('fwd', 'dq', 'dkv', 'dbias'; "
+    "'bwd': dQ, dK and dV in one call, where a head is one tile) and the "
+    "tile the chooser picked for the call's shape: block_q rows of Q by "
     "block_k rows of K a step of the sweep")
 
 
@@ -884,44 +986,30 @@ def _flash_dq(q, k, v, bias, scale, lse, g, causal, delta, rope=None):
         (res[-1] if in_kernel else delta)
 
 
-def _flash_backward(q, k, v, bias, scale, lse, g, causal=False, delta=None,
-                    bias_grad=True, rope=None):
-    """Tiled dQ/dK/dV — recomputes p blockwise from the saved LSE
-    ([BH, S_q, 1]); the [S, S] score matrix never exists in HBM
-    (FlashAttention-2 backward).
-
-    ``delta`` ([BH, S_q, 1] float32, ``_row_delta``) is passed by a caller
-    that holds ``out``; it MUST be by one whose K/V are a shard of the
-    row (ring attention: a delta summed over one device's K/V is wrong).
-    With ``delta=None`` the dQ kernel forms it over the whole row and
-    hands it to the other passes, so no ``out`` is needed at all.
-    ``bias_grad=False`` skips the dbias pass.  With a rotary pair ``dq`` and
-    ``dk`` are pairs, ``(dq, dqr)`` and ``(dk, dkr)``, ``dkr`` summed over
-    the heads that share the rotary keys (``_rope_runs_looped``: never
-    beside a bias)."""
+def _flash_dkv(q, k, v, bias, scale, lse, g, causal, delta, rope=None):
+    """The dK/dV pass (grid over k blocks, the whole Q side of a head in
+    VMEM): ``(dk, dv)``.  With a rotary pair ``dk`` is the pair ``(dk,
+    dkr)``, ``dkr`` summed over the heads that share the rotary keys."""
     BH, S_q, D = q.shape
     S_kv = k.shape[1]
     D_v = v.shape[2]
-    shape = _shape_key(q, k, v, bias, causal, rope)
-    dq, delta = _flash_dq(q, k, v, bias, scale, lse, g, causal, delta, rope)
-
-    # dK/dV pass: grid over k blocks, the whole Q side of a head in VMEM
-    block_q, block_k, whole, vmem = _plan("dkv", *shape)
-    dkv_specs = [
+    block_q, block_k, whole, vmem = _plan(
+        "dkv", *_shape_key(q, k, v, bias, causal, rope))
+    in_specs = [
         pl.BlockSpec((1, S_q, D), lambda i, j: (i, 0, 0), **whole),  # q
         pl.BlockSpec((1, block_k, D), lambda i, j: (i, j, 0)),  # k
         pl.BlockSpec((1, block_k, D_v), lambda i, j: (i, j, 0)),  # v
     ]
-    dkv_args = [q, k, v]
+    args = [q, k, v]
     if bias is not None:
-        dkv_specs.append(pl.BlockSpec((1, S_q, block_k),
-                                      lambda i, j: (i, 0, j)))
-        dkv_args.append(bias)
+        in_specs.append(pl.BlockSpec((1, S_q, block_k),
+                                     lambda i, j: (i, 0, j)))
+        args.append(bias)
     if rope is not None:
-        dkv_specs += _rope_specs(rope, None, block_k, whole)
-        dkv_args += list(rope)
+        in_specs += _rope_specs(rope, None, block_k, whole)
+        args += list(rope)
 
-    def dkv_kern(q_ref, k_ref, v_ref, *refs):
+    def kern(q_ref, k_ref, v_ref, *refs):
         refs = list(refs)
         bias_ref = refs.pop(0) if bias is not None else None
         qr_ref, kr_ref = (refs.pop(0), refs.pop(0)) if rope is not None \
@@ -930,37 +1018,123 @@ def _flash_backward(q, k, v, bias, scale, lse, g, causal=False, delta=None,
                     scale=scale, block_q=block_q, causal=causal,
                     qr_ref=qr_ref, kr_ref=kr_ref,
                     dkr_ref=refs[5] if rope is not None else None)
-    dkv_specs += [
+    in_specs += [
         pl.BlockSpec((1, S_q, D_v), lambda i, j: (i, 0, 0), **whole),  # dO
         pl.BlockSpec((1, S_q, 1), lambda i, j: (i, 0, 0), **whole),   # lse
         pl.BlockSpec((1, S_q, 1), lambda i, j: (i, 0, 0), **whole),   # delta
     ]
-    dkv_out_specs = [
+    out_specs = [
         pl.BlockSpec((1, block_k, D), lambda i, j: (i, j, 0)),
         pl.BlockSpec((1, block_k, D_v), lambda i, j: (i, j, 0))]
-    dkv_out_shape = [jax.ShapeDtypeStruct((BH, S_kv, D), k.dtype),
-                     jax.ShapeDtypeStruct((BH, S_kv, D_v), v.dtype)]
+    out_shape = [jax.ShapeDtypeStruct((BH, S_kv, D), k.dtype),
+                 jax.ShapeDtypeStruct((BH, S_kv, D_v), v.dtype)]
     if rope is not None:
         R = rope[1].shape[2]
-        dkv_out_specs.append(
+        out_specs.append(
             pl.BlockSpec((1, block_k, R), lambda i, j: (i, j, 0)))
-        dkv_out_shape.append(
+        out_shape.append(
             jax.ShapeDtypeStruct((BH, S_kv, R), jnp.float32))
     dk, dv, *dkr = _pallas_call(
-        dkv_kern, "flash_dkv", vmem_limit_bytes=vmem,
+        kern, "flash_dkv", vmem_limit_bytes=vmem,
         grid=(BH, S_kv // block_k),
-        in_specs=dkv_specs,
-        out_specs=dkv_out_specs,
-        out_shape=dkv_out_shape,
-    )(*dkv_args, g, lse, delta)
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+    )(*args, g, lse, delta)
     if rope is not None:
         kr = rope[1]
-        dkr = dkr[0].reshape(kr.shape[0], -1, S_kv, kr.shape[2]) \
-            .sum(axis=1).astype(kr.dtype)
-        return dq, (dk, dkr), dv, None
+        dk = (dk, dkr[0].reshape(kr.shape[0], -1, S_kv, kr.shape[2])
+              .sum(axis=1).astype(kr.dtype))
+    return dk, dv
+
+
+def _flash_bwd(q, k, v, bias, scale, lse, g, causal, delta,
+               delta_out=False):
+    """The fused backward (``_bwd_kernel``; where ``_fused_backward`` says a
+    head is one tile): ``(dq, dk, dv, delta)`` from one call, a grid cell a
+    head.  A passed delta is used and comes back as it went in; with
+    ``delta=None`` the kernel forms it, and writes it out only where
+    ``delta_out`` asks (the dbias pass reads it), else None comes back."""
+    BH, S_q, D = q.shape
+    S_kv = k.shape[1]
+    D_v = v.shape[2]
+    vmem = _plan("bwd", *_shape_key(q, k, v, bias, causal, None))[3]
+    delta_out = delta_out and delta is None
+
+    def rows(n, d):
+        return pl.BlockSpec((1, n, d), lambda i: (i, 0, 0))
+    in_specs = [rows(S_q, D), rows(S_kv, D), rows(S_kv, D_v)]
+    args = [q, k, v]
+    if bias is not None:
+        in_specs.append(rows(S_q, S_kv))
+        args.append(bias)
+    in_specs += [rows(S_q, D_v), rows(S_q, 1)]          # dO, lse
+    args += [g, lse]
+    if delta is not None:
+        in_specs.append(rows(S_q, 1))
+        args.append(delta)
+    out_specs = [rows(S_q, D), rows(S_kv, D), rows(S_kv, D_v)]
+    out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype),
+                 jax.ShapeDtypeStruct(k.shape, k.dtype),
+                 jax.ShapeDtypeStruct(v.shape, v.dtype)]
+    if delta_out:
+        out_specs.append(rows(S_q, 1))
+        out_shape.append(jax.ShapeDtypeStruct((BH, S_q, 1), jnp.float32))
+
+    def kern(q_ref, k_ref, v_ref, *refs):
+        refs = list(refs)
+        bias_ref = refs.pop(0) if bias is not None else None
+        do_ref, lse_ref = refs.pop(0), refs.pop(0)
+        delta_ref = None if delta is None else refs.pop(0)
+        _bwd_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
+                    delta_ref, *refs[:3], refs[3] if delta_out else None,
+                    scale=scale, causal=causal)
+
+    dq, dk, dv, *formed = _pallas_call(
+        kern, "flash_bwd", vmem_limit_bytes=vmem,
+        grid=(BH,),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+    )(*args)
+    return dq, dk, dv, (formed[0] if delta_out else delta)
+
+
+def _flash_backward(q, k, v, bias, scale, lse, g, causal=False, delta=None,
+                    bias_grad=True, rope=None):
+    """Tiled dQ/dK/dV — recomputes p blockwise from the saved LSE
+    ([BH, S_q, 1]); the [S, S] score matrix never exists in HBM
+    (FlashAttention-2 backward).  Where a head is one tile in both passes
+    (``_fused_backward``, from the call's shapes alone: S <= 512 at the BERT
+    widths) ONE kernel, ``flash_bwd``, forms S, P, dP and dS once for all
+    three gradients; every other shape runs the dQ pass and then the dK/dV
+    pass, which each rebuild them.
+
+    ``delta`` ([BH, S_q, 1] float32, ``_row_delta``) is passed by a caller
+    that holds ``out``; it MUST be by one whose K/V are a shard of the
+    row (ring attention: a delta summed over one device's K/V is wrong).
+    With ``delta=None`` the fused kernel or the dQ kernel forms it over the
+    whole row and hands it to the other passes, so no ``out`` is needed at
+    all.  ``bias_grad=False`` skips the dbias pass.  With a rotary pair
+    ``dq`` and ``dk`` are pairs, ``(dq, dqr)`` and ``(dk, dkr)``, ``dkr``
+    summed over the heads that share the rotary keys
+    (``_rope_runs_looped``: never beside a bias)."""
+    BH, S_q, D = q.shape
+    S_kv = k.shape[1]
+    D_v = v.shape[2]
+    shape = _shape_key(q, k, v, bias, causal, rope)
+    want_dbias = bias is not None and bias_grad
+    if _fused_backward(*shape):
+        dq, dk, dv, delta = _flash_bwd(q, k, v, bias, scale, lse, g, causal,
+                                       delta, delta_out=want_dbias)
+    else:
+        dq, delta = _flash_dq(q, k, v, bias, scale, lse, g, causal, delta,
+                              rope)
+        dk, dv = _flash_dkv(q, k, v, bias, scale, lse, g, causal, delta,
+                            rope)
 
     dbias = None
-    if bias is not None and bias_grad:
+    if want_dbias:
         block_q, block_k, whole, vmem = _plan("dbias", *shape)
         db_specs = [
             pl.BlockSpec((1, block_q, D), lambda i, j: (i, j, 0)),  # q

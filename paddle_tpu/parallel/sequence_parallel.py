@@ -136,7 +136,7 @@ def _ring_flash_bwd(axis_name, scale, res, g):
     perm = [(j, (j + 1) % P) for j in range(P)]
     qf, gf = _bhsd(q), _bhsd(g.astype(q.dtype))
     # delta belongs to the GLOBAL row: formed here from the merged output,
-    # never by the dQ kernel over one ring step's K/V shard
+    # never by a backward kernel over one ring step's K/V shard
     delta = _row_delta(gf, _bhsd(out))
     lsef = lse.reshape(B * H, Tl, 1)
     kb, vb = k, v

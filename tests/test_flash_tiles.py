@@ -196,11 +196,13 @@ def test_looped_sweeps_with_unequal_sides(monkeypatch, block_q, block_k):
 # -- the counter --------------------------------------------------------------
 
 @pytest.mark.parametrize("form,causal,with_bias,S", [
-    ("unrolled", False, True, 512), ("looped", True, False, 256)])
+    ("unrolled", False, True, 512), ("looped", True, False, 256),
+    ("unrolled", False, True, 1024), ("looped", True, False, 1024)])
 def test_flash_tiles_total_counts_each_kernel_of_a_lowering(form, causal,
                                                             with_bias, S):
-    """One lowering of forward and backward counts one ``fwd``, one ``dq``
-    and one ``dkv`` call at the tile the chooser picked (trace-time, like
+    """One lowering of forward and backward counts one ``fwd`` call and,
+    where a head is one tile, one ``bwd`` call, else one ``dq`` and one
+    ``dkv``, at the tile the chooser picked (trace-time, like
     ``fused_attention_lowered_total``), in either form."""
     counter = telemetry.registry().get("flash_tiles_total")
     x = jax.ShapeDtypeStruct((2, S, 16), jnp.float32)
@@ -208,12 +210,67 @@ def test_flash_tiles_total_counts_each_kernel_of_a_lowering(form, causal,
         else None
     block = min(S, 512)
     labels = [dict(kernel=k, block_q=block, block_k=block)
-              for k in KERNELS + ("dbias",)]
+              for k in KERNELS + ("bwd", "dbias")]
     before = [counter.value(**lb) for lb in labels]
     jax.jit(jax.grad(
         lambda q, k, v, b: flash_attention(q, k, v, b, 0.25, causal).sum(),
         argnums=(0, 1, 2))).lower(x, x, x, bias)
+    fused = int(S <= 512)
     # the dbias pass is traced wherever there is a bias (XLA drops it when
     # nothing reads its output)
     assert [counter.value(**lb) - b for lb, b in zip(labels, before)] == \
-        [1, 1, 1, int(with_bias)]
+        [1, 1 - fused, 1 - fused, fused, int(with_bias)]
+
+
+# -- one backward kernel or two: the rule, from shapes alone -----------------
+
+# (S_q, S_kv, D, D_v, R, has_bias, causal, itemsize) -> fused
+FUSED = {
+    "flash_cell": True,                 # S=512, D=64, bf16, a bias
+    "s128_d16": True,
+    "cross_128x256": True,              # a tile need not be square
+    "s64": True,                        # shorter than the least side
+    "s512_causal_d128": True,
+    "s512_f32_bias": True,
+    "s384": False,                      # 3 x 128: three tiles a side
+    "s640": False,
+    "s1024_bias": False,                # two 512-row tiles a side
+    "cross_512x1024": False,            # dQ is summed over two k tiles
+    "moonlight_cell": False,            # S=4096, and a rotary pair
+    "ouro_cell": False,                 # S=4096, D=128, causal
+    "s512_rotary": False,               # one tile, but a rotary pair
+    "s8192_bias": False,
+    # one tile in both passes, yet the fused kernel holds both sides and
+    # all three outputs at once: past the budget the pair of passes runs
+    "s512_d1536": False,
+}
+RULE_SHAPES = dict(
+    SHAPES,
+    s512_causal_d128=(512, 512, 128, 128, 0, False, True, 2),
+    s512_f32_bias=(512, 512, 64, 64, 0, True, False, 4),
+    s1024_bias=(1024, 1024, 64, 64, 0, True, False, 2),
+    cross_512x1024=(512, 1024, 64, 64, 0, False, False, 2),
+    ouro_cell=(4096, 4096, 128, 128, 0, False, True, 2),
+    s512_rotary=(512, 512, 128, 128, 64, False, True, 2),
+    s512_d1536=(512, 512, 1536, 1536, 0, False, False, 2),
+)
+
+
+@pytest.mark.parametrize("shape", sorted(FUSED))
+def test_backward_is_one_kernel_where_a_head_is_one_tile(shape):
+    key = RULE_SHAPES[shape]
+    assert pallas_ops._fused_backward(*key) is FUSED[shape]
+    assert pallas_ops._tiles("bwd", *key) == (FUSED[shape],) + key[:2]
+    if FUSED[shape]:
+        for kernel in ("dq", "dkv"):
+            assert pallas_ops._tiles(kernel, *key) == (True,) + key[:2]
+        assert pallas_ops._vmem_bytes("bwd", *key[:2], *key) <= \
+            pallas_ops._VMEM_BUDGET_BYTES
+
+
+def test_a_wide_head_of_one_tile_keeps_the_pair_for_the_budget_alone():
+    key = RULE_SHAPES["s512_d1536"]
+    for kernel in ("dq", "dkv"):
+        assert pallas_ops._tiles(kernel, *key) == (True, 512, 512)
+    assert pallas_ops._vmem_bytes("bwd", 512, 512, *key) > \
+        pallas_ops._VMEM_BUDGET_BYTES

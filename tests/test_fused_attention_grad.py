@@ -108,16 +108,18 @@ def _paths_taken(before):
     return after[0] - before[0], after[1] - before[1]
 
 
-@pytest.mark.parametrize("n_ops", [1, 3])
+@pytest.mark.parametrize("n_ops,S", [(1, 128), (3, 128), (1, 1024)])
 def test_training_step_runs_each_flash_kernel_once_per_op(kernel_calls,
-                                                          n_ops):
+                                                          n_ops, S):
+    """A head of 128 rows is one tile: one backward kernel an op.  At
+    S=1024 (two 512-row tiles a side) the dQ pass and the dK/dV pass."""
     before = _grad_paths()
-    _run(*_program(n_ops=n_ops), _feed())
-    assert {n: len(c) for n, c in kernel_calls.items()} == {
-        "flash_fwd": n_ops, "flash_dq": n_ops, "flash_dkv": n_ops}
-    # the forward kernel writes (out, LSE); the dQ kernel (dq, delta)
-    assert kernel_calls["flash_fwd"] == [2] * n_ops
-    assert kernel_calls["flash_dq"] == [2] * n_ops
+    _run(*_program(S_q=S, S_kv=S, n_ops=n_ops), _feed(S, S))
+    # the forward kernel writes (out, LSE); the fused backward (dq, dk, dv)
+    # and no delta, which nothing reads; the dQ kernel (dq, delta)
+    backward = {"flash_bwd": [3] * n_ops} if S == 128 else \
+        {"flash_dq": [2] * n_ops, "flash_dkv": [2] * n_ops}
+    assert dict(kernel_calls) == dict(backward, flash_fwd=[2] * n_ops)
     assert _paths_taken(before) == (n_ops, 0)
 
 
@@ -128,7 +130,7 @@ def test_program_without_the_lse_slot_replays_the_forward(kernel_calls):
     feed = _feed()
     old = _run(*_program(n_ops=2, with_lse=False), feed)
     assert {n: len(c) for n, c in kernel_calls.items()} == {
-        "flash_fwd": 4, "flash_dq": 2, "flash_dkv": 2}
+        "flash_fwd": 4, "flash_bwd": 2}
     assert _paths_taken(before) == (0, 2)
     new = _run(*_program(n_ops=2), feed)
     for a, b in zip(old, new):
@@ -231,17 +233,104 @@ def test_dq_kernel_forms_delta(causal, with_bias):
                                rtol=2e-4, atol=2e-5)
 
 
-def test_long_kv_keeps_out_and_passes_delta(monkeypatch, kernel_calls):
+FUSED_CASES = {
+    "plain": dict(),
+    "bias": dict(with_bias=True),
+    "causal": dict(causal=True),
+    "causal_bias": dict(causal=True, with_bias=True),
+    "dv_wider": dict(D_v=32, with_bias=True),
+    "cross": dict(S_kv=256, D_v=8),
+    "short": dict(S_q=64, S_kv=64, causal=True),
+}
+
+
+def _fused_case(S_q=128, S_kv=128, D_v=D, with_bias=False, causal=False,
+                seed=11):
+    rng = np.random.RandomState(seed)
+
+    def arr(*dims, scale=0.5):
+        return jnp.asarray(rng.randn(*dims).astype(np.float32) * scale)
+    q, k, v, g = arr(3, S_q, D), arr(3, S_kv, D), arr(3, S_kv, D_v), \
+        arr(3, S_q, D_v)
+    bias = arr(3, S_q, S_kv, scale=0.3) if with_bias else None
+    assert pallas_ops._fused_backward(
+        *pallas_ops._shape_key(q, k, v, bias, causal, None))
+    return q, k, v, bias, g, causal
+
+
+@pytest.mark.parametrize("passed", [False, True],
+                         ids=["delta_formed", "delta_passed"])
+@pytest.mark.parametrize("case", sorted(FUSED_CASES))
+def test_fused_backward_is_the_pair_of_passes_in_one_kernel(case, passed):
+    """``flash_bwd``'s dQ, dK, dV and delta against ``flash_dq`` then
+    ``flash_dkv`` on the same inputs: the same five products in the same
+    dtypes, so they agree to rounding's last bits, with delta formed by
+    the kernel or passed in."""
+    q, k, v, bias, g, causal = _fused_case(**FUSED_CASES[case])
+    out, lse = pallas_ops._flash_forward(q, k, v, bias, 0.25, with_lse=True,
+                                         causal=causal)
+    delta = pallas_ops._row_delta(g, out) if passed else None
+    dq, dk, dv, formed = pallas_ops._flash_bwd(
+        q, k, v, bias, 0.25, lse, g, causal, delta, delta_out=True)
+    want_dq, want_delta = pallas_ops._flash_dq(q, k, v, bias, 0.25, lse, g,
+                                               causal, delta)
+    want_dk, want_dv = pallas_ops._flash_dkv(q, k, v, bias, 0.25, lse, g,
+                                             causal, want_delta)
+    if passed:
+        assert formed is delta
+    for name, a, b in (("dq", dq, want_dq), ("dk", dk, want_dk),
+                       ("dv", dv, want_dv), ("delta", formed, want_delta)):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6,
+                                   atol=1e-7, err_msg=name)
+    # a delta nothing reads is not written
+    assert pallas_ops._flash_bwd(q, k, v, bias, 0.25, lse, g, causal,
+                                 None)[3] is None
+
+
+@pytest.mark.parametrize("bias_grad", [False, True])
+@pytest.mark.parametrize("case", sorted(FUSED_CASES))
+def test_fused_backward_matches_the_reference(kernel_calls, case, bias_grad):
+    """Through ``_flash_backward``, which takes the fused form from the
+    shape alone, against ``_reference_attention``'s vjp; with
+    ``bias_grad`` the dbias pass still gets its delta, from the fused
+    kernel's fourth output."""
+    q, k, v, bias, g, causal = _fused_case(**FUSED_CASES[case])
+    out, lse = pallas_ops._flash_forward(q, k, v, bias, 0.25, with_lse=True,
+                                         causal=causal)
+    got = pallas_ops._flash_backward(q, k, v, bias, 0.25, lse, g,
+                                     causal=causal, bias_grad=bias_grad)
+    want_dbias = bias is not None and bias_grad
+    assert dict(kernel_calls) == dict(
+        {"flash_fwd": [2], "flash_bwd": [3 + want_dbias]},
+        **({"flash_dbias": [1]} if want_dbias else {}))
+    _, vjp = jax.vjp(lambda *a: _reference_attention(*a, 0.25,
+                                                     causal=causal),
+                     q, k, v, bias)
+    want = vjp(g)
+    assert (got[3] is None) == (not want_dbias)
+    for name, a, b in zip(("dq", "dk", "dv", "dbias"), got, want):
+        if a is not None:
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=2e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("S_kv,backward", [
+    (256, {"flash_bwd": [3]}),                      # one 128 x 256 tile
+    (1024, {"flash_dq": [1], "flash_dkv": [2]})])   # two k tiles of 512
+def test_long_kv_keeps_out_and_passes_delta(monkeypatch, kernel_calls, S_kv,
+                                            backward):
     """Past ``_DELTA_IN_KERNEL_MAX_SKV`` the held tiles would not fit in
-    VMEM beside K/V: the same lowering passes delta in, from ``Out``."""
+    VMEM beside K/V: the same lowering passes delta in, from ``Out``, and
+    neither the fused kernel nor the dQ pass writes one."""
     monkeypatch.setattr(pallas_ops, "_DELTA_IN_KERNEL_MAX_SKV", 128)
-    kw = CASES["cross"]                     # S_kv = 256 > 128
+    kw = dict(S_q=128, S_kv=S_kv, bias_shape=(B, H, 128, S_kv))
     feed = _feed(kw["S_q"], kw["S_kv"], kw["bias_shape"], seed=4)
     before = _grad_paths()
     got = _run(*_program(**kw), feed)
     assert _paths_taken(before) == (1, 0)
-    assert kernel_calls["flash_fwd"] == [2]
-    assert kernel_calls["flash_dq"] == [1]          # dq alone, no delta
+    assert dict(kernel_calls) == dict(backward, flash_fwd=[2],
+                                      flash_dbias=[1])
     for name, a, b in zip(("loss", "dq", "dk", "dv", "dbias"), got,
                           _reference_grads(feed, False)):
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4, err_msg=name)
@@ -285,22 +374,25 @@ def test_sequence_parallel_island_keeps_the_replay():
 
 
 def test_ring_attention_passes_its_global_delta(monkeypatch):
-    """Each ring step sees one shard of K/V: a delta summed there by the
-    dQ kernel would be wrong, so the ring forms it from the merged output
-    and every step's dQ pass takes it as an input."""
+    """Each ring step sees one shard of K/V: a delta summed there by a
+    kernel would be wrong, so the ring forms it from the merged output and
+    every step's backward takes it as an input — the fused kernel here,
+    whose shard of four rows is one tile and which would else form its
+    own."""
     from jax.sharding import Mesh, PartitionSpec as P
     from paddle_tpu.fluid.mesh_utils import shard_map
     from paddle_tpu.parallel.sequence_parallel import (ring_attention,
                                                        local_attention)
 
     passed = []
-    real = pallas_ops._flash_dq
+    real = pallas_ops._flash_bwd
 
-    def spy(q, k, v, bias, scale, lse, g, causal, delta, rope=None):
+    def spy(q, k, v, bias, scale, lse, g, causal, delta, **kwargs):
         passed.append(delta is not None)
-        return real(q, k, v, bias, scale, lse, g, causal, delta, rope)
+        return real(q, k, v, bias, scale, lse, g, causal, delta, **kwargs)
 
-    monkeypatch.setattr(pallas_ops, "_flash_dq", spy)
+    monkeypatch.setattr(pallas_ops, "_flash_bwd", spy)
+    monkeypatch.setattr(pallas_ops, "_flash_dq", None)      # never reached
     sp = 4
     rng = np.random.RandomState(5)
     q, k, v = (jnp.asarray(rng.randn(1, 4 * sp, 2, 8).astype(np.float32)
@@ -358,7 +450,7 @@ def test_op_inside_a_recompute_span_is_differentiated_by_jax(kernel_calls):
 
     plain = losses(False)
     assert {n: len(c) for n, c in kernel_calls.items()} == {
-        "flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1}
+        "flash_fwd": 1, "flash_bwd": 1}
     remat = losses(True)
     np.testing.assert_allclose(remat, plain, rtol=1e-6)
     assert plain[-1] < plain[0]

@@ -375,53 +375,59 @@ def test_the_loops_ops_keep_their_own_names_in_the_compiled_step():
 LAYERS, PASSES = 2, 3
 
 
-def _mid_config():
-    """Shapes at which the flash kernels are on the path for a v5e."""
+def _mid_config(seq=512):
+    """Shapes at which the flash kernels are on the path for a v5e; at the
+    default length a head is ONE tile of them."""
     return ouro.OuroConfig(
         vocab_size=512, hidden_size=128, num_hidden_layers=LAYERS,
         num_attention_heads=2, num_key_value_heads=2, head_dim=64,
-        intermediate_size=256, total_ut_steps=PASSES, max_seq_len=512)
+        intermediate_size=256, total_ut_steps=PASSES, max_seq_len=seq)
 
 
 @pytest.fixture(scope="module")
-def compiled_for_v5e():
-    """The pure-bf16 training step compiled for a described v5e (no chip
-    needed: XLA:TPU and Mosaic run here), with ``recurrent_grad``'s own
-    lowering and, ``"replay"``, with ``generic_grad_lower``."""
+def one_chip():
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
-    from paddle_tpu.fluid import executor
-    from paddle_tpu.fluid.registry import OP_DEFS
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
-    chip = SingleDeviceSharding(topo.devices[0])
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile_step(cfg, chip):
+    """The pure-bf16 training step of ``cfg`` compiled for ``chip``."""
+    from paddle_tpu.fluid import executor
+    opt = fluid.contrib.mixed_precision.decorate(
+        fluid.optimizer.SGD(0.01), use_pure_bf16=True)
+    main, startup, handles = build(cfg, optimizer=opt)
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        compiled, feed_vals = exe._resolve_compiled(
+            main, batch(cfg, n=1), [handles["loss"]], scope, None)
+        args = (executor._scope_state(scope, compiled.state_mut),
+                executor._scope_state(scope, compiled.state_ro),
+                tuple(feed_vals), np.int32(0))
+        shapes = jax.tree.map(lambda v: jax.ShapeDtypeStruct(
+            v.shape, v.dtype, sharding=chip), args)
+        return compiled._jitted.lower(*shapes).compile()
+
+
+@pytest.fixture(scope="module")
+def compiled_for_v5e(one_chip):
+    """The pure-bf16 training step compiled for a described v5e (no chip
+    needed: XLA:TPU and Mosaic run here), with ``recurrent_grad``'s own
+    lowering and, ``"replay"``, with ``generic_grad_lower``."""
+    from paddle_tpu.fluid.registry import OP_DEFS
     cfg = _mid_config()
-
-    def compile_step():
-        opt = fluid.contrib.mixed_precision.decorate(
-            fluid.optimizer.SGD(0.01), use_pure_bf16=True)
-        main, startup, handles = build(cfg, optimizer=opt)
-        scope = fluid.Scope()
-        with fluid.scope_guard(scope):
-            exe = fluid.Executor(fluid.CPUPlace())
-            exe.run(startup)
-            compiled, feed_vals = exe._resolve_compiled(
-                main, batch(cfg, n=1), [handles["loss"]], scope, None)
-            args = (executor._scope_state(scope, compiled.state_mut),
-                    executor._scope_state(scope, compiled.state_ro),
-                    tuple(feed_vals), np.int32(0))
-            shapes = jax.tree.map(lambda v: jax.ShapeDtypeStruct(
-                v.shape, v.dtype, sharding=chip), args)
-            return compiled._jitted.lower(*shapes).compile()
-
-    steps = {"remat": compile_step()}
+    steps = {"remat": _compile_step(cfg, one_chip)}
     own = OP_DEFS["recurrent"].grad_lower
     OP_DEFS["recurrent"].grad_lower = None
     try:
-        steps["replay"] = compile_step()
+        steps["replay"] = _compile_step(cfg, one_chip)
     finally:
         OP_DEFS["recurrent"].grad_lower = own
     return steps
@@ -436,11 +442,20 @@ def _mosaic_calls(executable):
 def test_the_step_holds_one_forward_and_one_backward_loop(compiled_for_v5e):
     """One ``while`` for the passes and one for their backward; in them 2L
     ``flash_fwd`` calls (the forward scan's and the rematerialised
-    forward's) and L each of ``flash_dq`` / ``flash_dkv``: the forward scan
-    is not run a second time, which would make it 3L."""
+    forward's) and L of ``flash_bwd`` (S=512: a head is one tile, so dQ,
+    dK and dV come from one kernel): the forward scan is not run a second
+    time, which would make it 3L."""
     text = compiled_for_v5e["remat"].as_text()
     assert len(re.findall(r" while\(", text)) == 2
     calls = _mosaic_calls(compiled_for_v5e["remat"])
+    assert calls == {"flash_fwd": 2 * LAYERS, "flash_bwd": LAYERS}, calls
+
+
+def test_a_longer_sequence_keeps_the_two_backward_passes(one_chip):
+    """S=1024 is two 512-row tiles a side: dQ is summed over k tiles and
+    dK/dV over q tiles, so the backward stays the pair of passes, as at
+    the cell's S=4096."""
+    calls = _mosaic_calls(_compile_step(_mid_config(seq=1024), one_chip))
     assert calls == {"flash_fwd": 2 * LAYERS, "flash_dq": LAYERS,
                      "flash_dkv": LAYERS}, calls
 

@@ -280,16 +280,22 @@ def _flash_loss(q, k, v):
     return pallas_ops.flash_attention(q, k, v, None, 0.125).sum()
 
 
-def _flash_args(sharding=None):
-    shape = jax.ShapeDtypeStruct((2, 256, 64), jnp.bfloat16,
+def _flash_args(sharding=None, seq=256):
+    shape = jax.ShapeDtypeStruct((2, seq, 64), jnp.bfloat16,
                                  sharding=sharding)
     return shape, shape, shape
 
 
-def test_flash_kernels_are_named_in_op_names():
+# a head of 256 rows is one tile (one backward kernel), one of 1024 two tiles
+# a side (the dQ pass, then the dK/dV pass)
+BACKWARD_KERNELS = {256: ["flash_bwd"], 1024: ["flash_dkv", "flash_dq"]}
+
+
+@pytest.mark.parametrize("seq", sorted(BACKWARD_KERNELS))
+def test_flash_kernels_are_named_in_op_names(seq):
     text = jax.jit(jax.grad(_flash_loss, argnums=(0, 1, 2))).lower(
-        *_flash_args()).as_text(debug_info=True)
-    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+        *_flash_args(seq=seq)).as_text(debug_info=True)
+    for name in ["flash_fwd"] + BACKWARD_KERNELS[seq]:
         assert "/%s/" % name in text, name
 
 
@@ -305,15 +311,16 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def test_flash_kernels_name_their_tpu_instructions(one_chip):
+@pytest.mark.parametrize("seq", sorted(BACKWARD_KERNELS))
+def test_flash_kernels_name_their_tpu_instructions(one_chip, seq):
     """Compiled for a v5e (no chip needed): XLA:TPU names each Mosaic
     custom call's instruction after the kernel, which is what a device
     trace's ``XLA Ops`` events show."""
     text = jax.jit(jax.grad(_flash_loss, argnums=(0, 1, 2))).lower(
-        *_flash_args(one_chip)).compile().as_text()
+        *_flash_args(one_chip, seq)).compile().as_text()
     calls = re.findall(r"%(\w+?)(?:\.\d+)? = [^\n]*"
                        r'custom_call_target="tpu_custom_call"', text)
-    assert sorted(calls) == ["flash_dkv", "flash_dq", "flash_fwd"], calls
+    assert sorted(calls) == BACKWARD_KERNELS[seq] + ["flash_fwd"], calls
 
 
 # -- the flash residual path, compiled for a v5e (no chip needed) -----------
@@ -414,10 +421,11 @@ def bert_stack_steps(one_chip):
 
 
 def test_training_step_holds_one_flash_forward_per_layer(bert_stack_steps):
-    """N attention layers compile to N ``flash_fwd``, N ``flash_dq`` and N
-    ``flash_dkv`` Mosaic calls; the replay compiled 2N / N / N, because
-    XLA cannot merge two custom calls as it merges replayed HLO."""
-    kernels = ["flash_dkv", "flash_dq", "flash_fwd"]
+    """N attention layers compile to N ``flash_fwd`` and N ``flash_bwd``
+    Mosaic calls (S=512: a head is one tile, dQ, dK and dV come from one
+    kernel); the replay compiled 2N / N, because XLA cannot merge two
+    custom calls as it merges replayed HLO."""
+    kernels = ["flash_bwd", "flash_fwd"]
     assert _mosaic_calls(bert_stack_steps[True]) == sorted(kernels * LAYERS)
     assert _mosaic_calls(bert_stack_steps[False]) == sorted(
         kernels * LAYERS + ["flash_fwd"] * LAYERS)
@@ -426,11 +434,12 @@ def test_training_step_holds_one_flash_forward_per_layer(bert_stack_steps):
 def test_flash_cell_shape_compiles_with_one_tile_a_head(bert_stack_steps):
     """At the flash cell's attention shape (S=512, D=64, bf16, the padding
     mask as a bias) the chooser gives every kernel ONE 512 x 512 tile a
-    head, and the STEP compiles for the v5e with them (inside a step XLA
+    head, so the backward is the fused kernel and neither pass of the
+    pair, and the STEP compiles for the v5e with them (inside a step XLA
     may park a kernel's operand in VMEM, so a kernel that compiles alone
     proves nothing): one traced call a layer and kernel."""
     assert bert_stack_steps["tiles"] == {
-        (kernel, 512, 512): LAYERS for kernel in ("fwd", "dq", "dkv")}
+        (kernel, 512, 512): LAYERS for kernel in ("fwd", "bwd")}
 
 
 def test_lse_residual_costs_its_own_bytes_and_no_more(bert_stack_steps):
