@@ -500,6 +500,30 @@ def phase_kernels(device, dry_run):
         "<= %.2g)" % (BH, seqs, D, worst["fwd"], FWD_TOL, worst["bwd"],
                       BWD_TOL))
 
+    # grouped key/value heads: 32 query heads read 8 key/value heads where
+    # they lie (block row i // 4), dK and dV summed over each group; against
+    # the float32 composition on K and V repeated to 32 heads
+    heads, kv_heads, S = (4, 2, 128) if dry_run else (32, 8, 1024)
+    q, g = arr((heads, S, D)), arr((heads, S, D))
+    k, v = arr((kv_heads, S, D)), arr((kv_heads, S, D))
+
+    def ref_grouped(causal, q, k, v):
+        return ref(causal, q, *(jnp.repeat(x, heads // kv_heads, axis=0)
+                                for x in (k, v)))
+    what = "flash H=%d H_kv=%d S=%d causal" % (heads, kv_heads, S)
+    _check_lowering(functools.partial(kern, True), (q, k, v), on_tpu, what)
+    e_gqa = _rel_err(jax.jit(functools.partial(kern, True))(q, k, v),
+                     jax.jit(functools.partial(ref_grouped, True))(q, k, v))
+    require(e_gqa <= FWD_TOL, "%s: fwd err %.4f", what, e_gqa)
+    for nm, a, w in zip(("dq", "dk", "dv"), grads(kern, True, 3)(g, q, k, v),
+                        grads(ref_grouped, True, 3)(g, q, k, v)):
+        require(a.shape == w.shape, "%s: %s is %s", what, nm, a.shape)
+        e = _rel_err(a, w)
+        require(e <= BWD_TOL, "%s: %s err %.4f", what, nm, e)
+        e_gqa = max(e_gqa, e)
+    log("%s D=%d bf16: fwd + dq/dk/dv match the composition on repeated "
+        "K/V (worst rel err %.4f)" % (what, D, e_gqa))
+
     M, H = (64, 128) if dry_run else (64 * 128, 768)
     x, sc, sh = arr((M, H)), arr((H,), f32), arr((H,), f32)
 
@@ -513,6 +537,7 @@ def phase_kernels(device, dry_run):
 
     return {"flash_fwd_err": round(worst["fwd"], 5),
             "flash_bwd_err": round(worst["bwd"], 5),
+            "flash_grouped_err": round(e_gqa, 5),
             "layer_norm_err": round(e_ln, 5)}
 
 

@@ -20,7 +20,8 @@ gray_list = {"elementwise_add", "elementwise_sub", "elementwise_mul",
              "elementwise_pow", "batch_norm", "tanh", "sigmoid",
              "lookup_table", "relu", "layer_norm", "slice", "concat",
              "dropout", "reshape2", "transpose2", "pool2d", "top_k",
-             "scale", "gelu", "rms_norm", "rotary_embedding", "swish"}
+             "scale", "gelu", "rms_norm", "rotary_embedding", "swish",
+             "gated_short_conv"}
 
 
 class AutoMixedPrecisionLists:

@@ -15,7 +15,7 @@ __all__ = [
     "dynamic_lstm", "dynamic_gru", "linear_chain_crf", "crf_decoding",
     "nce", "hsigmoid", "cos_sim", "beam_search", "beam_search_decode",
     "fused_attention", "switch_moe", "rms_norm", "rotary_embedding",
-    "routed_experts", "moe_bias_update",
+    "gated_short_conv", "routed_experts", "moe_bias_update",
 ]
 
 
@@ -276,7 +276,11 @@ def fused_attention(q, k, v, attn_bias=None, scale=1.0, causal=False,
                     q_rope=None, k_rope=None):
     """Fused attention core (ops/pallas_ops.py flash-attention kernel):
     q [B, H, S_q, D], k/v [B, H, S_kv, D] (cross-attention supported),
-    optional additive bias [B, 1|H, S_q, S_kv].
+    optional additive bias [B, 1|H, S_q, S_kv].  k and v may carry fewer
+    heads, [B, H_kv, S_kv, D] with ``H % H_kv == 0`` (grouped-query
+    attention): query head ``h`` reads key/value head ``h // (H / H_kv)``,
+    the flash kernels read K and V where they lie, and no other path
+    refuses them (they repeat K and V).
     ``causal=True`` applies the decoder triangular mask inside the kernel
     (static block indices — no [S, S] mask tensor).  ``dropout_prob``
     applies upscale_in_train dropout to the attention probabilities
@@ -399,6 +403,28 @@ def rotary_embedding(x, theta=10000.0, interleaved=True, name=None):
                      outputs={"Out": [out]},
                      attrs={"theta": float(theta),
                             "interleaved": bool(interleaved)})
+    return out
+
+
+def gated_short_conv(bcx, kernel_size=3, param_attr=None, name=None):
+    """Gated causal depthwise convolution of length ``kernel_size``
+    (ops/decoder_ops.py).  ``bcx`` [B, S, 3C] holds the gates B and C and
+    the signal x in that order along the last axis (one projection's
+    output); the result [B, S, C] is ``C_t * sum_j w[:, j] * (B * x)_{t -
+    (kernel_size - 1) + j}``, zero before position 0.  The taps ``w`` [C,
+    kernel_size] are a float32 parameter."""
+    helper = LayerHelper("gated_short_conv", param_attr=param_attr, name=name)
+    if int(bcx.shape[-1]) % 3:
+        raise ValueError("gated_short_conv: the last axis (%d) holds B, C "
+                         "and x, so it is a multiple of 3" % bcx.shape[-1])
+    C = int(bcx.shape[-1]) // 3
+    w = helper.create_parameter(helper.param_attr, [C, int(kernel_size)],
+                                "float32")
+    out = helper.create_variable_for_type_inference(bcx.dtype)
+    out.shape = tuple(bcx.shape[:-1]) + (C,)
+    helper.append_op("gated_short_conv", inputs={"X": [bcx], "W": [w]},
+                     outputs={"Out": [out]},
+                     attrs={"kernel_size": int(kernel_size)})
     return out
 
 

@@ -1,7 +1,7 @@
-"""Ops of a decoder-only language model with latent attention and routed
-experts (the ``deepseek_v3`` family, ``models/deepseek_v3.py``): RMS norm,
-rotary embedding on a head's slice, the routed-expert layer and the rule
-that moves its selection bias.  The reference predates all of them.
+"""Ops of decoder-only language models (``models/deepseek_v3.py`` and the
+models after it): RMS norm, rotary embedding on a head's slice, the gated
+short convolution, the routed-expert layer and the rule that moves its
+selection bias.  The reference predates all of them.
 
 ``routed_experts`` is an expert layer that is TOLD which experts it holds
 (attributes ``first_expert`` and the leading dimension of its weights): it
@@ -105,6 +105,69 @@ def _rotary_embedding(ctx, op):
     rotary slice of the head); ``interleaved`` says how the lanes pair."""
     ctx.set("Out", rotary(ctx.i("X"), float(ctx.attr("theta", 10000.0)),
                           bool(ctx.attr("interleaved", True))))
+
+
+# -- the gated short convolution -------------------------------------------------
+
+_m_short_conv_lowered = telemetry.counter(
+    "gated_short_conv_lowered_total",
+    "gated_short_conv lowerings traced, by kernel_size (a training step "
+    "traces each op twice: the forward op and its replay inside the grad "
+    "op)")
+
+
+def gated_short_conv(bcx, w):
+    """``bcx`` [B, S, 3C] (the gates B and C and the signal x, in that
+    order along the last axis), ``w`` [C, L] float32 -> [B, S, C]:
+
+        z_t = B_t * x_t
+        c_t = sum_{j < L} w[:, j] * z_{t - (L - 1) + j}     (z = 0 before 0)
+        out_t = C_t * c_t
+
+    a causal depthwise cross-correlation of length L, padded on the left,
+    between two gates.  L shifted multiply-adds: the products ``B * x`` and
+    their shifts in the activations' dtype, the taps and the sum over them
+    in float32.  No ``conv_general_dilated`` (a C-group convolution for L
+    multiply-adds a channel) and nothing but elementwise work, pads and
+    slices, so the ``jax.vjp`` of this body is elementwise too; all of it
+    under ONE ``short_conv`` scope, which XLA's fusions carry forward and
+    backward.
+
+    The body is a ``jax.checkpoint``: what goes from forward to backward is
+    ``bcx`` and the taps alone.  A shift by one or two rows is no view of a
+    tiled array, so XLA materialises each shifted product, and left to
+    itself keeps them from the forward for the taps' gradient: L - 1
+    ``[B, S, C]`` arrays a layer (537 MB over four layers at S=8192,
+    C=2048, compiled for a v5e; PERF.md section 6, PR 34)."""
+    @jax.checkpoint
+    def body(bcx, w):
+        C, L = w.shape
+        S = bcx.shape[1]
+        gate_b, gate_c, x = (bcx[..., i * C:(i + 1) * C] for i in range(3))
+        z = gate_b * x
+        taps = w.astype(jnp.float32)
+        conv = z.astype(jnp.float32) * taps[:, L - 1]
+        for j in range(L - 1):
+            back = L - 1 - j                 # tap j reads z_{t - back}
+            if back < S:
+                shifted = jnp.pad(z[:, :S - back],
+                                  ((0, 0), (back, 0), (0, 0)))
+                conv = conv + taps[:, j] * shifted.astype(jnp.float32)
+        return (gate_c.astype(jnp.float32) * conv).astype(bcx.dtype)
+
+    with jax.named_scope("short_conv"):
+        return body(bcx, w)
+
+
+@register_op("gated_short_conv")
+def _gated_short_conv(ctx, op):
+    """X [B, S, 3C] (B, C, x along the last axis); W [C, kernel_size]
+    float32 -> Out [B, S, C] (``gated_short_conv``).  The grad op replays
+    this body under ``jax.vjp``: elementwise work, which XLA merges with the
+    forward op's."""
+    w = ctx.i("W")
+    _m_short_conv_lowered.inc(kernel_size=int(w.shape[1]))
+    ctx.set("Out", gated_short_conv(ctx.i("X"), w))
 
 
 # -- the routed-expert layer ---------------------------------------------------

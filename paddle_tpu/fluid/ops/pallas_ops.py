@@ -638,7 +638,7 @@ def _whole_side(kernel, S_q, S_kv, D, D_v, R, itemsize):
 
 
 def _vmem_bytes(kernel, block_q, block_k, S_q, S_kv, D, D_v, R, has_bias,
-                causal, itemsize):
+                causal, itemsize, group=1):
     """VMEM one grid cell of ``kernel`` ('fwd', 'dq', 'dkv', 'bwd' or
     'dbias') asks for at tiles of ``block_q x block_k``, from shapes alone:
     what the chooser holds against ``_VMEM_BUDGET_BYTES`` and
@@ -647,8 +647,10 @@ def _vmem_bytes(kernel, block_q, block_k, S_q, S_kv, D, D_v, R, has_bias,
     * the cell's own blocks, in and out, twice (the pipeline's two
       buffers): ``block_q`` rows of Q, its rotary part, dO, the row
       statistics and the outputs; in the dK/dV pass ``block_k`` rows of K,
-      V, the rotary keys and dK, dV, and the per-head float32 dKr; in the
-      fused backward ('bwd': ``block_q`` = S_q, ``block_k`` = S_kv) Q, K,
+      V, the rotary keys and dK, dV (float32 where ``group`` query heads
+      share a key/value head: a partial a query head, ``_flash_dkv``), and
+      the per-head float32 dKr; in the fused backward ('bwd': ``block_q``
+      = S_q, ``block_k`` = S_kv) Q, K,
       V, dO, both row statistics and dQ, dK, dV;
     * the bias tile, twice: ``[block_q, S_kv]``, ``[S_q, block_k]`` in the
       dK/dV pass, and as much again for the dbias pass's output;
@@ -672,8 +674,9 @@ def _vmem_bytes(kernel, block_q, block_k, S_q, S_kv, D, D_v, R, has_bias,
         wide, narrow = 4, 4
     elif kernel == "dkv":
         b = block_k
+        out = itemsize if group == 1 else 4
         own = [(b, D, itemsize), (b, D_v, itemsize), (b, R, itemsize),  # in
-               (b, D, itemsize), (b, D_v, itemsize), (b, R, 4)]        # out
+               (b, D, out), (b, D_v, out), (b, R, 4)]                   # out
         bias_tile = (S_q, b, itemsize)
         acc = [(b, D, 4), (b, D_v, 4), (b, R, 4)]
         wide, narrow = 4, 4
@@ -719,7 +722,8 @@ def _vmem_limit(need):
     return None if limit <= _VMEM_SCOPED_DEFAULT_BYTES else limit
 
 
-def _tiles(kernel, S_q, S_kv, D, D_v, R, has_bias, causal, itemsize):
+def _tiles(kernel, S_q, S_kv, D, D_v, R, has_bias, causal, itemsize,
+           group=1):
     """``(ok, block_q, block_k)`` of one kernel ('fwd', 'dq', 'dkv',
     'bwd', 'dbias') at one shape: the largest tile, sides from
     ``_TILE_SIDES`` that divide the sequence, whose ``_vmem_bytes`` fits
@@ -734,7 +738,7 @@ def _tiles(kernel, S_q, S_kv, D, D_v, R, has_bias, causal, itemsize):
     ``_fused_backward`` lets it run."""
     if kernel == "bwd":
         return _fused_backward(S_q, S_kv, D, D_v, R, has_bias, causal,
-                               itemsize), S_q, S_kv
+                               itemsize, group), S_q, S_kv
 
     def sides(S):
         # a sequence shorter than the least side is one block
@@ -747,23 +751,27 @@ def _tiles(kernel, S_q, S_kv, D, D_v, R, has_bias, causal, itemsize):
             ((bq, bk) for bq in sides(S_q) for bk in sides(S_kv)),
             key=size, reverse=True):
         if _vmem_bytes(kernel, block_q, block_k, S_q, S_kv, D, D_v, R,
-                       has_bias, causal, itemsize) <= _VMEM_BUDGET_BYTES:
+                       has_bias, causal, itemsize,
+                       group) <= _VMEM_BUDGET_BYTES:
             return True, block_q, block_k
     return False, min(_TILE_SIDES[-1], S_q), min(_TILE_SIDES[-1], S_kv)
 
 
-def _fused_backward(*shape):
+def _fused_backward(S_q, S_kv, D, D_v, R, has_bias, causal, itemsize,
+                    group=1):
     """Whether the backward at this shape (``_shape_key``) is ONE kernel
     (``_bwd_kernel``) and not the dQ pass followed by the dK/dV pass, from
     the shape alone: where the chooser gives BOTH passes a tile that covers
     the whole of ``S_q`` and ``S_kv`` (each a grid of ``(BH, 1)``: neither
     gradient is accumulated across cells, so nothing forces two passes that
     each rebuild S, P, dP and dS), there is no rotary pair (it exists in
-    the looped sweeps alone) and the kernel's own estimate fits the budget.
-    S <= 512 at the BERT widths, bias or none, causal or not (the mask on
-    the one tile: there is no diagonal to skip)."""
-    S_q, S_kv, _, _, R = shape[:5]
-    return not R and \
+    the looped sweeps alone), no group of query heads over one key/value
+    head (dK and dV are then sums over the group: ``_flash_dkv``) and the
+    kernel's own estimate fits the budget.  S <= 512 at the BERT widths,
+    bias or none, causal or not (the mask on the one tile: there is no
+    diagonal to skip)."""
+    shape = (S_q, S_kv, D, D_v, R, has_bias, causal, itemsize)
+    return not R and group == 1 and \
         all(_tiles(kernel, *shape) == (True, S_q, S_kv)
             for kernel in ("dq", "dkv")) and \
         _vmem_bytes("bwd", S_q, S_kv, *shape) <= _VMEM_BUDGET_BYTES
@@ -771,10 +779,11 @@ def _fused_backward(*shape):
 
 def _shape_key(q, k, v, bias, causal, rope):
     """What the chooser sees of a call: ``_tiles``'s arguments after the
-    kernel's name."""
+    kernel's name (the last: the query heads that share a key/value
+    head)."""
     return (q.shape[1], k.shape[1], q.shape[2], v.shape[2],
             0 if rope is None else rope[0].shape[2], bias is not None,
-            bool(causal), q.dtype.itemsize)
+            bool(causal), q.dtype.itemsize, q.shape[0] // k.shape[0])
 
 
 def _flash_fits(*shape):
@@ -790,7 +799,7 @@ def _plan(kernel, *shape):
     (``_shape_key``), counted in ``flash_tiles_total``."""
     _, block_q, block_k = _tiles(kernel, *shape)
     _m_tiles.inc(kernel=kernel, block_q=block_q, block_k=block_k)
-    S_q, S_kv, D, D_v, R, _, _, itemsize = shape
+    S_q, S_kv, D, D_v, R, _, _, itemsize = shape[:8]
     return (block_q, block_k,
             _whole_seq(_whole_side(kernel, S_q, S_kv, D, D_v, R, itemsize)),
             _vmem_limit(_vmem_bytes(kernel, block_q, block_k, *shape)))
@@ -823,6 +832,25 @@ def _rope_specs(rope, q_block, k_block, whole_mode=None):
             spec(k_block, kr.shape[1], lambda i: i // heads)]
 
 
+def _kv_row(q, k):
+    """Grid row ``i`` (one query head of one sequence, Q ``[B * H, S, D]``)
+    -> the block row of its key/value head in K and V ``[B * H_kv, S, D]``:
+    with ``G = H / H_kv`` query heads to a key/value head, head ``h`` reads
+    head ``h // G``, and ``(i // H) * H_kv + (i % H) // G`` is ``i // G``.
+    K and V are read where they lie, as the shared rotary key head is
+    (``_rope_specs``): no copy at H heads exists in HBM, and a group's G
+    consecutive grid rows name the same block, which the pipeline fetches
+    once.  At ``H_kv == H`` the row is ``i`` itself, with no division in
+    the index map."""
+    group = q.shape[0] // k.shape[0]
+    if group * k.shape[0] != q.shape[0]:
+        raise ValueError("flash attention: %d query heads over %d key/value "
+                         "heads" % (q.shape[0], k.shape[0]))
+    if group == 1:
+        return lambda i: i
+    return lambda i: i // group
+
+
 def _compose_rope(q, k, rope):
     """The one-head-size form of a rotary pair, for the composition paths:
     Q and K with the rotary part appended, the shared key head repeated."""
@@ -853,7 +881,13 @@ def _flash_forward(q, k, v, bias, scale, *, with_lse=False,
             "explicit bias for cross-length causal masking"
             % (S_q, S_kv))
     shape = _shape_key(q, k, v, bias, causal, rope)
+    kv = _kv_row(q, k)
     if not _flash_fits(*shape):
+        if shape[8] != 1:
+            raise ValueError(
+                "flash attention: grouped key/value heads at a shape the "
+                "kernels have no tile for; repeat K and V to the query "
+                "heads first (the fused_attention op does)")
         out = _reference_attention(*_compose_rope(q, k, rope), v, bias,
                                    scale, causal=causal)
         if not with_lse:
@@ -863,12 +897,15 @@ def _flash_forward(q, k, v, bias, scale, *, with_lse=False,
         raise AssertionError("with_lse requested for a non-tileable "
                              "shape — caller bug")
     _rope_runs_looped(rope, causal, bias)
+    if rope is not None and shape[8] != 1:
+        raise ValueError("the flash kernels take a rotary pair or grouped "
+                         "key/value heads, not both")
     block_q, block_k, whole, vmem = _plan("fwd", *shape)
     grid = (BH, S_q // block_q)
     in_specs = [
         pl.BlockSpec((1, block_q, D), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((1, S_kv, D), lambda i, j: (i, 0, 0), **whole),
-        pl.BlockSpec((1, S_kv, D_v), lambda i, j: (i, 0, 0), **whole),
+        pl.BlockSpec((1, S_kv, D), lambda i, j: (kv(i), 0, 0), **whole),
+        pl.BlockSpec((1, S_kv, D_v), lambda i, j: (kv(i), 0, 0), **whole),
     ]
     args = [q, k, v]
     if bias is not None:
@@ -926,10 +963,12 @@ def _flash_dq(q, k, v, bias, scale, lse, g, causal, delta, rope=None):
         "dq", *_shape_key(q, k, v, bias, causal, rope))
     _rope_runs_looped(rope, causal, bias)
     in_kernel = delta is None
+    kv = _kv_row(q, k)
     q_block = pl.BlockSpec((1, block_q, D), lambda i, j: (i, j, 0))
     in_specs = [q_block,
-                pl.BlockSpec((1, S_kv, D), lambda i, j: (i, 0, 0), **whole),
-                pl.BlockSpec((1, S_kv, D_v), lambda i, j: (i, 0, 0),
+                pl.BlockSpec((1, S_kv, D), lambda i, j: (kv(i), 0, 0),
+                             **whole),
+                pl.BlockSpec((1, S_kv, D_v), lambda i, j: (kv(i), 0, 0),
                              **whole)]
     args = [q, k, v]
     if bias is not None:
@@ -989,16 +1028,27 @@ def _flash_dq(q, k, v, bias, scale, lse, g, causal, delta, rope=None):
 def _flash_dkv(q, k, v, bias, scale, lse, g, causal, delta, rope=None):
     """The dK/dV pass (grid over k blocks, the whole Q side of a head in
     VMEM): ``(dk, dv)``.  With a rotary pair ``dk`` is the pair ``(dk,
-    dkr)``, ``dkr`` summed over the heads that share the rotary keys."""
+    dkr)``, ``dkr`` summed over the heads that share the rotary keys.
+
+    Where G query heads share a key/value head (``_kv_row``) the grid still
+    runs over QUERY heads: each reads its key/value head's block and writes
+    its own part of dK and dV as a float32 partial ``[B * H, S_kv, D]``,
+    and the G parts are summed here, outside, as ``dkr`` is.  (A grid over
+    key/value heads that sweeps its group inside needs the group's whole Q
+    side in VMEM, G times 12 MiB at S=8192, or fetches it again for every k
+    block; the partials cost one write and one read of ``2 * 4 * S_kv * D``
+    bytes a query head.  PERF.md section 6, PR 34.)"""
     BH, S_q, D = q.shape
     S_kv = k.shape[1]
     D_v = v.shape[2]
     block_q, block_k, whole, vmem = _plan(
         "dkv", *_shape_key(q, k, v, bias, causal, rope))
+    kv = _kv_row(q, k)
+    group = BH // k.shape[0]
     in_specs = [
         pl.BlockSpec((1, S_q, D), lambda i, j: (i, 0, 0), **whole),  # q
-        pl.BlockSpec((1, block_k, D), lambda i, j: (i, j, 0)),  # k
-        pl.BlockSpec((1, block_k, D_v), lambda i, j: (i, j, 0)),  # v
+        pl.BlockSpec((1, block_k, D), lambda i, j: (kv(i), j, 0)),  # k
+        pl.BlockSpec((1, block_k, D_v), lambda i, j: (kv(i), j, 0)),  # v
     ]
     args = [q, k, v]
     if bias is not None:
@@ -1026,8 +1076,11 @@ def _flash_dkv(q, k, v, bias, scale, lse, g, causal, delta, rope=None):
     out_specs = [
         pl.BlockSpec((1, block_k, D), lambda i, j: (i, j, 0)),
         pl.BlockSpec((1, block_k, D_v), lambda i, j: (i, j, 0))]
-    out_shape = [jax.ShapeDtypeStruct((BH, S_kv, D), k.dtype),
-                 jax.ShapeDtypeStruct((BH, S_kv, D_v), v.dtype)]
+    out_shape = [
+        jax.ShapeDtypeStruct((BH, S_kv, D),
+                             k.dtype if group == 1 else jnp.float32),
+        jax.ShapeDtypeStruct((BH, S_kv, D_v),
+                             v.dtype if group == 1 else jnp.float32)]
     if rope is not None:
         R = rope[1].shape[2]
         out_specs.append(
@@ -1045,6 +1098,10 @@ def _flash_dkv(q, k, v, bias, scale, lse, g, causal, delta, rope=None):
         kr = rope[1]
         dk = (dk, dkr[0].reshape(kr.shape[0], -1, S_kv, kr.shape[2])
               .sum(axis=1).astype(kr.dtype))
+    if group > 1:
+        with jax.named_scope("flash_dkv_group_sum"):
+            dk, dv = (parts.reshape(-1, group, *parts.shape[1:]).sum(axis=1)
+                      .astype(x.dtype) for parts, x in ((dk, k), (dv, v)))
     return dk, dv
 
 
@@ -1136,10 +1193,12 @@ def _flash_backward(q, k, v, bias, scale, lse, g, causal=False, delta=None,
     dbias = None
     if want_dbias:
         block_q, block_k, whole, vmem = _plan("dbias", *shape)
+        kv = _kv_row(q, k)
         db_specs = [
             pl.BlockSpec((1, block_q, D), lambda i, j: (i, j, 0)),  # q
-            pl.BlockSpec((1, S_kv, D), lambda i, j: (i, 0, 0), **whole),
-            pl.BlockSpec((1, S_kv, D_v), lambda i, j: (i, 0, 0), **whole),
+            pl.BlockSpec((1, S_kv, D), lambda i, j: (kv(i), 0, 0), **whole),
+            pl.BlockSpec((1, S_kv, D_v), lambda i, j: (kv(i), 0, 0),
+                         **whole),
             pl.BlockSpec((1, block_q, S_kv), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, block_q, D_v), lambda i, j: (i, j, 0)),  # dO
             _row_stat_spec(block_q),                                # lse
@@ -1423,7 +1482,8 @@ def _attention_route(ctx, q, k, v):
     that axis; ``dropout``: the attention-probability rate in effect;
     ``flash``: neither, and every kernel has a tile at the shape
     (``_flash_fits``), so the Pallas kernels run on the op's operands (Q,
-    K, V ``[B, H, S, D]``) as they are.  A rotary pair is among them only under
+    K, V ``[B, H | H_kv, S, D]``) as they are, grouped key/value heads
+    included (``_kv_row``).  A rotary pair is among them only under
     the causal mask and without a bias (``_rope_runs_looped``); any other
     op with a pair composes one head size first."""
     dropout = 0.0 if _is_test(ctx) else \
@@ -1441,8 +1501,23 @@ def _attention_route(ctx, q, k, v):
         not (qr is not None and (has_bias or not causal)) and \
         _flash_fits(S_q, k.shape[2], q.shape[3], v.shape[3],
                     0 if qr is None else qr.shape[3], has_bias, causal,
-                    q.dtype.itemsize)
+                    q.dtype.itemsize, q.shape[1] // k.shape[1])
     return sp_active, dropout, flash
+
+
+def _kv_group(q, k, v, qr=None):
+    """Query heads to a key/value head, from the op's operands ``[B, H |
+    H_kv, S, D]``; refuses head counts that do not divide, K and V that
+    differ, and grouped heads beside a rotary pair (whose keys are one
+    shared head already)."""
+    H, H_kv = q.shape[1], k.shape[1]
+    if v.shape[1] != H_kv or H % H_kv:
+        raise ValueError("fused_attention: %d query heads over %d key and "
+                         "%d value heads" % (H, H_kv, v.shape[1]))
+    if H != H_kv and qr is not None:
+        raise NotImplementedError("fused_attention: grouped key/value heads "
+                                  "with a rotary pair")
+    return H // H_kv
 
 
 def _norm_bias(spb, q, S_kv):
@@ -1495,6 +1570,14 @@ def _fused_attention(ctx, op):
     every other path, and under ``is_test``, it stays unwritten and the
     kernel computes no statistic.
 
+    Grouped key/value heads: K and V may carry ``H_kv`` heads with ``H %
+    H_kv == 0``; query head ``h`` then reads key/value head ``h // (H /
+    H_kv)``.  The flash kernels read K and V where they lie (``_kv_row``:
+    no copy at H heads exists) and sum dK / dV over a group from float32
+    parts (``_flash_dkv``); every other path — the composition, dropout,
+    the sequence-parallel islands — repeats K and V to H heads first, and
+    the repeat's transpose sums the gradient.
+
     V [B, H, S_kv, D_v] may have another head size than Q and K; Out has
     V's.  ``QRope`` [B, H, S_q, R] / ``KRope`` [B, 1, S_kv, R] (latent
     attention: the rotary part of each head, its keys ONE head shared by
@@ -1520,12 +1603,16 @@ def _fused_attention(ctx, op):
             "%d) — the causal alignment for cross-length attention is "
             "ambiguous; pass an explicit additive bias instead"
             % (S_q, S_kv))
+    group = _kv_group(q, k, v, qr)
     sp_active, dropout, flash = _attention_route(ctx, q, k, v)
     sp_axis = ctx.attr("sp_axis", None)
     mesh = getattr(ctx.state, "mesh", None)
-    _m_lowered.inc(shape="mha" if qr is None else "mla",
+    _m_lowered.inc(shape="mla" if qr is not None
+                   else "gqa" if group > 1 else "mha",
                    path="sequence_parallel" if sp_active
                    else "flash" if flash else "composition")
+    if group > 1 and not flash:
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     rope = None
     if qr is not None and flash:
         rope = (_flat(qr), kr.reshape(B, S_kv, -1))
@@ -1573,15 +1660,20 @@ def _fused_attention(ctx, op):
 _m_lowered = telemetry.counter(
     "fused_attention_lowered_total",
     "fused_attention lowerings traced (a grad op that replays the forward "
-    "counts again), by shape ('mla': with a rotary pair, 'mha': without) "
+    "counts again), by shape ('mla': with a rotary pair, 'gqa': fewer "
+    "key/value heads than query heads, 'mha': neither) "
     "and path ('flash': the Pallas kernels, 'composition': XLA, "
     "'sequence_parallel': a shard_map island)")
 
 _m_grad_lowered = telemetry.counter(
     "fused_attention_grad_lowered_total",
-    "fused_attention_grad ops lowered, by path: 'residual' runs the flash "
+    "fused_attention_grad ops lowered, by path ('residual' runs the flash "
     "backward kernels on the forward op's LSE, 'replay' differentiates a "
-    "second run of the forward lowering")
+    "second run of the forward lowering) and by how dK / dV are summed "
+    "over the query heads of a key/value head (kv_sum: 'none' at H_kv == "
+    "H, 'partials': float32 parts a query head out of the dK/dV pass, "
+    "summed outside it, 'repeat': the transpose of the composition's "
+    "repeat)")
 
 
 @register_grad_lower("fused_attention")
@@ -1599,11 +1691,14 @@ def _fused_attention_grad(ctx, op):
     q, k, v = ctx.i("Q"), ctx.i("K"), ctx.i("V")
     lse, g = ctx.i_opt("LSE"), ctx.i_opt("Out@GRAD")
     S_q, S_kv = q.shape[2], k.shape[2]
+    grouped = q.shape[1] != k.shape[1]
     if lse is None or g is None or not _attention_route(ctx, q, k, v)[2]:
-        _m_grad_lowered.inc(path="replay")
+        _m_grad_lowered.inc(path="replay",
+                            kv_sum="repeat" if grouped else "none")
         generic_grad_lower(ctx, op, residual_slots=("LSE",))
         return
-    _m_grad_lowered.inc(path="residual")
+    _m_grad_lowered.inc(path="residual",
+                        kv_sum="partials" if grouped else "none")
     want = {slot: (op.output(slot + "@GRAD") or [""])[0]
             for slot in ("Q", "K", "V", "BiasQK", "QRope", "KRope")}
     bias = ctx.i_opt("BiasQK")

@@ -20,3 +20,5 @@ from . import deepseek_v3
 from . import deepseek_v3_reference
 from . import ouro
 from . import ouro_reference
+from . import lfm2_moe
+from . import lfm2_moe_reference

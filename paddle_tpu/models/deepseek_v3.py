@@ -181,23 +181,29 @@ def decoder(ids, cfg, loads):
     return _norm(h, cfg, "norm")
 
 
-def build_train(cfg=None, lr=1e-4, optimizer=None):
-    """The training program: ``ids`` and ``labels`` (int64 [B, S, 1]; the
-    labels are the ids shifted by one, the caller's business) -> mean
-    next-token cross-entropy over the vocabulary held, minimised by Adam or
-    the caller's ``optimizer``; then one ``moe_bias_update`` per expert
-    layer in the optimizer role (the selection bias has no gradient)."""
-    cfg = cfg or DeepseekV3Config()
+def token_feeds(cfg):
+    """``ids`` and ``labels``, int64 [B, S, 1]; the labels are the ids
+    shifted by one, the caller's business."""
     S = cfg.max_seq_len
-    ids = fluid.layers.data(name="ids", shape=[S, 1], dtype="int64")
-    labels = fluid.layers.data(name="labels", shape=[S, 1], dtype="int64")
-    loads = []
-    hidden = decoder(ids, cfg, loads)
-    logits = _linear(hidden, cfg.vocab_size, cfg, "lm_head")
+    return (fluid.layers.data(name="ids", shape=[S, 1], dtype="int64"),
+            fluid.layers.data(name="labels", shape=[S, 1], dtype="int64"))
+
+
+def train_on_next_token(ids, labels, logits, loads, cfg, lr, optimizer,
+                        keep_token_loss=False):
+    """Mean next-token cross-entropy of ``logits`` over the vocabulary
+    held, minimised by Adam or the caller's ``optimizer``; then one
+    ``moe_bias_update`` per expert layer (``loads``: ``(selection bias,
+    expert load)`` pairs) in the optimizer role: the bias has no gradient.
+    Returns ``build_train``'s handles.  ``keep_token_loss``: a step leaves
+    every position's loss in the scope (float32 [B, S, 1], written and
+    never read, as ``routed_experts`` leaves its load)."""
     # the loss is float32 whatever the logits are: under pure-bf16 AMP a
     # per-token loss in bf16 has steps of 0.03 at ln(vocabulary)
-    loss = fluid.layers.mean(fluid.layers.softmax_with_cross_entropy(
-        fluid.layers.cast(logits, "float32"), labels))
+    token_loss = fluid.layers.softmax_with_cross_entropy(
+        fluid.layers.cast(logits, "float32"), labels)
+    token_loss.persistable = bool(keep_token_loss)
+    loss = fluid.layers.mean(token_loss)
     opt = optimizer or fluid.optimizer.AdamOptimizer(learning_rate=lr)
     opt.minimize(loss)
     program = fluid.default_main_program()
@@ -205,7 +211,19 @@ def build_train(cfg=None, lr=1e-4, optimizer=None):
         for bias, load in loads:
             fluid.layers.moe_bias_update(bias, load,
                                          gamma=cfg.bias_update_speed)
-    return {"loss": loss, "logits": logits, "feeds": [ids, labels],
+    return {"loss": loss, "logits": logits, "token_loss": token_loss,
+            "feeds": [ids, labels],
             "expert_loads": [load for _, load in loads],
             "select_biases": [bias for bias, _ in loads],
             "optimizer": opt, "config": cfg}
+
+
+def build_train(cfg=None, lr=1e-4, optimizer=None):
+    """The training program: ``ids`` and ``labels`` -> mean next-token
+    cross-entropy over the vocabulary held (``train_on_next_token``)."""
+    cfg = cfg or DeepseekV3Config()
+    ids, labels = token_feeds(cfg)
+    loads = []
+    hidden = decoder(ids, cfg, loads)
+    logits = _linear(hidden, cfg.vocab_size, cfg, "lm_head")
+    return train_on_next_token(ids, labels, logits, loads, cfg, lr, optimizer)

@@ -323,6 +323,36 @@ def test_flash_kernels_name_their_tpu_instructions(one_chip, seq):
     assert sorted(calls) == BACKWARD_KERNELS[seq] + ["flash_fwd"], calls
 
 
+def test_grouped_flash_kernels_compile_at_published_widths(one_chip):
+    """32 query heads over 8 key/value heads of 64 at S=8192, causal, bf16
+    (the grouped-query cell's attention layer), compiled for a v5e: Mosaic
+    takes the three kernels at the tiles the chooser picks, K and V enter
+    them at 8 heads, and dK / dV leave the dK/dV pass as float32 parts a
+    query head, summed outside it."""
+    def loss(q, k, v):
+        return pallas_ops.flash_attention(q, k, v, None, 0.125, True) \
+            .astype(jnp.float32).sum()
+
+    def arg(heads):
+        return jax.ShapeDtypeStruct((heads, 8192, 64), jnp.bfloat16,
+                                    sharding=one_chip)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        arg(32), arg(8), arg(8)).compile()
+    text = compiled.as_text()
+    calls = {name: line for name, line in re.findall(
+        r"%(\w+?)(?:\.\d+)? = ([^\n]*custom_call_target="
+        r'"tpu_custom_call"[^\n]*)', text)}
+    assert sorted(calls) == ["flash_dkv", "flash_dq", "flash_fwd"]
+    for name, line in calls.items():
+        layouts = line.split("operand_layout_constraints={")[1]
+        assert layouts.count("bf16[8,8192,64]") == 2, (name, layouts[:400])
+    assert calls["flash_dkv"].startswith(
+        "(f32[32,8192,64]") and calls["flash_dkv"].count(
+            "f32[32,8192,64]") >= 2
+    assert [tuple(o.shape) for o in jax.tree.leaves(compiled.out_info)] == \
+        [(32, 8192, 64), (8, 8192, 64), (8, 8192, 64)]
+
+
 # -- the flash residual path, compiled for a v5e (no chip needed) -----------
 
 LAYERS, BATCH, SEQ, HEADS = 3, 8, 512, 4
