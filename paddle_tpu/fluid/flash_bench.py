@@ -4,8 +4,11 @@ kernel) on the attached chip, at the tile the chooser picks
 (``pallas_ops._tiles``) and at every forced square and mixed tile from 128
 to 512, at the shapes of the benchmark's two kernel cells:
 
-* ``bert``: BH=384 (batch 32 x 12 heads), S=512, D=64, bf16, with a bias,
-  non-causal — the unrolled form (``bert_base_s512_flash``);
+* ``bert``: BH=384 (batch 32 x 12 heads), S=512, D=64, bf16, non-causal,
+  with the padding mask as the op hands it to the kernels: ``[B, 1, S,
+  S]`` through ``_kernel_bias``, so ONE ``[S, S]`` a sequence read at
+  block row ``i // H``, and ``fwd`` and ``bwd`` with the row statistics as
+  lane-dense rows — the unrolled form (``bert_base_s512_flash``);
 * ``moonlight``: 16 heads, S=4096, 128 + 64 | 128, bf16, causal, a shared
   rotary key head — the looped form (``moonlight_ep8share_s4096_train``).
 
@@ -15,9 +18,10 @@ of the backward the lowering takes at the shape (``fused``: ``bwd`` alone
 runs in a step, ``dq`` and ``dkv`` are what it replaced; ``two_pass``: no
 ``bwd`` record), and a ``bwd`` record exists at the chooser's pick only
 (its one tile is the head).  Each kernel is timed alone (delta is passed
-to the dK/dV pass), by the bench.py fence (async dispatch, one scalar
-fetch, RTT subtracted).  A time is a chip's: off a TPU the module refuses
-to run.
+to the dK/dV pass; the pair of passes reads column statistics at every
+shape, ``pallas_ops._row_stats``), by the bench.py fence (async dispatch,
+one scalar fetch, RTT subtracted).  A time is a chip's: off a TPU the
+module refuses to run.
 """
 
 import json
@@ -42,10 +46,10 @@ def _operands(shape):
             (rng.standard_normal(dims, dtype=np.float32) * scale)
             .astype(dtype), dev)
     if shape == "bert":
-        BH, S, D = 384, 512, 64
-        q, k, v, g = (arr(BH, S, D) for _ in range(4))
-        return dict(q=q, k=k, v=v, g=g, bias=arr(BH, S, S, scale=0.1),
-                    rope=None, causal=False, scale=D ** -0.5)
+        B, H, S, D = 32, 12, 512, 64
+        q, k, v, g = (arr(B * H, S, D) for _ in range(4))
+        return dict(q=q, k=k, v=v, g=g, bias=arr(B, 1, S, S, scale=0.1),
+                    heads=H, rope=None, causal=False, scale=D ** -0.5)
     H, S = 16, 4096
     q, k, v, g = (arr(H, S, 128) for _ in range(4))
     return dict(q=q, k=k, v=v, g=g, bias=None, causal=True,
@@ -62,6 +66,10 @@ def _kernel_calls(ops):
 
     scale, causal = float(ops["scale"]), ops["causal"]
     arrays = {n: ops[n] for n in ("q", "k", "v", "g", "bias", "rope")}
+    if ops["bias"] is not None:
+        # the mask as the fused_attention op hands it to the kernels
+        q4 = ops["q"].reshape(-1, ops["heads"], *ops["q"].shape[1:])
+        arrays["bias"] = po._kernel_bias(ops["bias"], q4, ops["k"].shape[1])
     # the dQ pass forms delta itself where the lowering lets it
     in_kernel = po._delta_in_kernel(ops["k"].shape[1], causal,
                                     ops["bias"] is not None)
@@ -91,10 +99,14 @@ def _kernel_calls(ops):
             for x in jax.tree.leaves(fn(*args))))
     out, lse = jax.jit(forward)(arrays)
     delta = po._row_delta(ops["g"], out)
+    # the two passes read their statistics as columns, the fused kernel as
+    # rows (``pallas_ops._row_stats``)
+    column = [po._kernel_stat(stat, False) for stat in (lse, delta)]
+    row = [po._kernel_stat(stat, True) for stat in (lse, delta)]
     return {"fwd": functools.partial(corner(forward), arrays),
-            "dq": functools.partial(corner(dq), arrays, lse, delta),
-            "dkv": functools.partial(corner(dkv), arrays, lse, delta),
-            "bwd": functools.partial(corner(bwd), arrays, lse, delta)}
+            "dq": functools.partial(corner(dq), arrays, *column),
+            "dkv": functools.partial(corner(dkv), arrays, *column),
+            "bwd": functools.partial(corner(bwd), arrays, *row)}
 
 
 def sweep(shape, steps=30):
@@ -110,7 +122,7 @@ def sweep(shape, steps=30):
     ops = _operands(shape)
     chooser = po._tiles
     key = po._shape_key(ops["q"], ops["k"], ops["v"], ops["bias"],
-                        ops["causal"], ops["rope"])
+                        ops["causal"], ops["rope"])     # a bias or none
     fused = po._fused_backward(*key)
     try:
         for forced in (None,) + TILES:

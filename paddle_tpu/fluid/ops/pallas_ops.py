@@ -15,7 +15,8 @@ the bias is a non-trainable mask the op's grad lowering skips that pass
 fall back to differentiating the identical XLA composition.
 
 What goes from forward to backward is the logsumexp alone, lane-dense
-(``[BH, S_q]``; the op's ``LSE`` output, ``[B, H, S_q]``): the dQ pass
+(``[BH, S_q]``; the op's ``LSE`` output, ``[B, H, S_q]``; how it enters
+and leaves the kernels is "Small operands" below): the dQ pass
 (or the fused backward) forms delta = sum_d dO * O = sum_j P * dP from the
 P and dP tiles it computes anyway, so ``out`` is no residual, and
 ``fused_attention_grad`` has a lowering of its own that runs the backward
@@ -39,7 +40,7 @@ streamed), so ``_tiles`` picks, per kernel and from the call's shapes
 alone, the largest ``block_q x block_k`` from {512, 256, 128} that divides
 the sequences and whose VMEM estimate (``_vmem_bytes``: own blocks and
 bias tile double-buffered, the other side double- or single-buffered as
-``_whole_seq`` decides, lane-padded row statistics, the float32 score / P
+``_whole_seq`` decides, the row statistics as they lie, the float32 score / P
 / dP / dS tiles, accumulators, the dQ pass's scratches) fits
 ``_VMEM_BUDGET_BYTES`` = 32 MiB, a quarter of a v5e core's VMEM.  At
 S=512, D=64 with a bias that is ONE 512 x 512 tile a head in every
@@ -89,6 +90,36 @@ less with the logsumexp read as a lane-dense ``[1, S]`` row), then the
 512 KB bias tile (0.29 less read once a sequence at block row ``i // H``,
 nothing once the statistics are rows) (PERF.md, PR 33).
 
+Small operands (PR 36): what the kernels' row statistics and a shared mask
+cost to cross HBM.  The logsumexp the forward writes and the delta the
+backward forms, is handed or hands to the dbias pass are one float32 a
+row.  As ``[BH, S_q, 1]`` (rows on sublanes, what a ``[bq, bk]`` score tile
+broadcasts against as it lies) XLA:TPU pads the size-1 minor dimension to
+128 lanes: 100.7 MB for 0.79 MB of numbers at BH=384, S=512, a layer.
+Where a head is one tile (``_row_stats``: the shapes of ``_fused_backward``,
+the ones the copies bound; ``flash_fwd``, ``flash_bwd`` and ``flash_dbias``)
+they are ``[BH, 1, S_q]``, a block of ``(1, 1, block_q)`` that Mosaic takes
+because its second-to-last dimension is the whole dimension, laid out in
+(1, 128) tiles at the size of its numbers; the kernel turns the row to a
+column once a tile (``_stat_column``) and a column it formed to a row
+before it writes it (``_stat_store``).  Every multi-pass call keeps the
+columns, and the dQ and dK/dV passes know nothing else: such a call's
+traced program is the one it was, so the steps of the long-sequence cells
+keep their schedule and their memory (with rows there too the kernels gain
+under a millisecond of 18.9 and XLA holds 254 MB more of the Moonlight
+step's temporaries: ``_row_stats``).  Which layout a call takes is the
+kernels' own business: ``_flash_forward`` hands the logsumexp out and
+``_flash_backward`` takes it and a delta in as ``[BH, S_q]``, and the op's
+``LSE`` is ``[B, H, S_q]`` either way.  And a bias that is the same for
+every head of a sequence (a padding mask ``[B, 1, S_q, S_kv]``) whose
+gradient nobody wants enters as ``[B, S_q, S_kv]`` and is read at block row
+``i // H`` (``_kernel_bias``, ``_bias_row``), as the shared rotary key head
+and a grouped key/value head are: no ``[BH, S_q, S_kv]`` copy (201 MB a
+layer in the flash cell) is built or read, at any shape; where no kernel
+has a tile the composition repeats it (``_reference_attention``).  A bias
+with a head dimension, and one whose gradient is wanted (``flash_dbias``
+writes a head's), go a block a head.
+
 Latent attention (PR 28): V's head size may differ from Q's and K's, and
 a head may have a second, rotary part whose keys are ONE head shared by
 the sequence's heads (``rope``: the kernels add ``qr kr^T`` to the scores
@@ -96,7 +127,7 @@ and read ``kr`` at block row ``i // H``, so no per-head copy of it and no
 padding of 128 + 64 into one head size exists in HBM; the dK/dV pass
 writes each head's ``dkr`` and the caller sums them).  At the Moonlight
 cell's shapes (16 heads, S=4096, 128 + 64 | 128, bf16, causal) the dK/dV
-pass's Q side — Q, its rotary part, dO and the two lane-padded row
+pass's Q side — Q, its rotary part, dO and the two lane-padded column
 statistics — is 7 MiB and asked for 16.4 MiB double-buffered inside the
 step, so whole-sequence operands beyond ``_DOUBLE_BUFFER_MAX_BYTES`` take
 one buffer (``_whole_seq``).  Under the causal mask without a bias the
@@ -164,9 +195,14 @@ def _pallas_call(kernel, name, vmem_limit_bytes=None, **kwargs):
 
 
 def _reference_attention(q, k, v, bias, scale, causal=False):
-    """[BH, S, D] composition — the oracle and the vjp target."""
+    """[BH, S, D] composition — the oracle and the vjp target.  A bias of
+    fewer rows than ``q`` is one the heads of a sequence share
+    (``_kernel_bias``: ``[B, S_q, S_kv]``), repeated here, so its
+    cotangent comes back summed over a sequence's heads."""
     s = jnp.einsum("bqd,bkd->bqk", q, k) * scale
     if bias is not None:
+        if bias.shape[0] != q.shape[0]:
+            bias = jnp.repeat(bias, q.shape[0] // bias.shape[0], axis=0)
         s = s + bias
     if causal:
         S = q.shape[1]
@@ -261,7 +297,7 @@ def _attention_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
     # it pays neither the in-kernel log nor the fp32 per-row HBM write
     # (an unused output of a pallas_call is still computed)
     if lse_ref is not None:
-        lse_ref[0] = m + jnp.log(l)
+        _stat_store(lse_ref, m + jnp.log(l))
 
 
 def _rope_runs_looped(rope, causal, bias):
@@ -498,12 +534,12 @@ def _bwd_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
                   ks.shape[0])
     if causal:
         s = _causal_mask(s, 0, 0)
-    p = jnp.exp(s - lse_ref[0])
+    p = jnp.exp(s - _stat_column(lse_ref))
     dp = jnp.dot(do, vs.T, preferred_element_type=jnp.float32)
     delta = (p * dp).sum(axis=-1, keepdims=True) if delta_ref is None \
-        else delta_ref[0]
+        else _stat_column(delta_ref)
     if delta_out_ref is not None:
-        delta_out_ref[0] = delta
+        _stat_store(delta_out_ref, delta)
     ds = (p * (dp - delta) * scale).astype(q.dtype)
     dq_ref[0] = jnp.dot(ds, ks, preferred_element_type=jnp.float32) \
         .astype(dq_ref.dtype)
@@ -521,8 +557,8 @@ def _dbias_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
     so XLA drops the whole pass when the bias is not trainable."""
     q = q_ref[0]
     do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]
-    delta = delta_ref[0]
+    lse = _stat_column(lse_ref)
+    delta = _stat_column(delta_ref)
     S = k_ref.shape[1]
     bq, D = q.shape
     pid = pl.program_id(1)
@@ -552,13 +588,65 @@ def _dbias_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
             ds.astype(db_ref.dtype)
 
 
-def _row_stat_spec(block_q):
-    """Block of a per-row statistic (logsumexp, delta), held as
-    [BH, S_q, 1]: rows on sublanes, so the kernels broadcast it against a
-    [bq, bk] score tile without a relayout.  (A [BH, S_q] array cut into
+def _row_stats(kernel, *shape):
+    """Whether ``kernel``'s row statistics (logsumexp, delta) at this shape
+    (``_shape_key``) cross HBM as lane-dense rows ``[BH, 1, S_q]`` or as
+    the columns ``[BH, S_q, 1]`` the kernels broadcast against a score tile
+    as they are: rows in ``flash_fwd``, ``flash_bwd`` and ``flash_dbias``
+    where a head is one tile in both backward passes (``_fused_backward``:
+    one statistic block a head), columns in every multi-pass call.  The dQ
+    and dK/dV passes know columns alone: no op runs them where a head is
+    one tile.  The layout is these kernels' own: ``_flash_forward`` hands a
+    statistic out and ``_flash_backward`` takes one in as ``[BH, S_q]``.
+
+    XLA:TPU pads a column's size-1 minor dimension to 128 lanes: 100.7 MB
+    for 0.79 MB of numbers at BH=384, S=512, written by the forward and
+    read by the backward in every layer, which at one tile a head bounds
+    the kernels (PERF.md section 6, PRs 33 and 36); a row is laid out in
+    (1, 128) tiles at the size of its numbers.  The multi-pass calls keep
+    the columns because their steps must keep their memory: with rows
+    everywhere the long-sequence kernels gain little (-0.84 of 18.9 ms in
+    the Moonlight cell) while XLA schedules the SAME step otherwise around
+    the changed custom calls, 254 MB more of temporaries there (ledger, PR
+    35; the same compile here, with or without the barriers of
+    ``_forward_keeping_lse``: PERF.md section 6, PR 36).  From the shape
+    alone: the two schedules of the backward already part there."""
+    return kernel in ("fwd", "bwd", "dbias") and _fused_backward(*shape)
+
+
+def _row_stat_spec(block_q, rows=False):
+    """Block of a per-row statistic: ``(1, block_q, 1)`` of ``[BH, S_q,
+    1]``, rows on sublanes, which a ``[bq, bk]`` score tile broadcasts
+    against without a relayout; with ``rows`` (``_row_stats``) ``(1, 1,
+    block_q)`` of ``[BH, 1, S_q]``.  (A [BH, S_q] array cut into
     (1, block_q) blocks is refused by the Mosaic lowering: the
-    second-to-last block dim must be a multiple of 8 or the whole dim.)"""
+    second-to-last block dim must be a multiple of 8 or the whole dim; in
+    the row layout it is the whole dim, 1.)"""
+    if rows:
+        return pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j))
     return pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0))
+
+
+def _kernel_stat(stat, rows):
+    """A statistic ``[BH, S_q]`` (or None) as a kernel takes it: ``[BH, 1,
+    S_q]`` with ``rows`` (``_row_stats``), else ``[BH, S_q, 1]``."""
+    if stat is None:
+        return None
+    return stat[:, None] if rows else stat[..., None]
+
+
+def _stat_column(ref):
+    """A statistic's block as the float32 column ``[bq, 1]`` a ``[bq,
+    bk]`` score tile broadcasts against: read as it lies from a column
+    block ``[1, bq, 1]``; a row block ``[1, 1, bq]`` is turned once a tile,
+    one number a row against the tile's ``bq x bk`` scores."""
+    return ref[0].T if ref.shape[1] == 1 else ref[0]
+
+
+def _stat_store(ref, column):
+    """Write a ``[bq, 1]`` column the kernel formed to its statistic's
+    block, turned to a row where the block is one."""
+    ref[0] = column.T if ref.shape[1] == 1 else column
 
 
 # Tile sides the chooser tries, largest first.  128 is the least the
@@ -586,7 +674,7 @@ _DELTA_IN_KERNEL_MAX_SKV = 4096
 def _lane_padded_bytes(rows, cols, itemsize):
     """VMEM bytes of a [rows, cols] operand: the minor dimension is laid
     out in whole 128-lane tiles (a [S, 1] float32 statistic costs what a
-    [S, 128] one does)."""
+    [S, 128] one does; as a row it is [1, S]: ``_row_stats``)."""
     return rows * -(-cols // 128) * 128 * itemsize
 
 
@@ -638,11 +726,18 @@ def _whole_side(kernel, S_q, S_kv, D, D_v, R, itemsize):
 
 
 def _vmem_bytes(kernel, block_q, block_k, S_q, S_kv, D, D_v, R, has_bias,
-                causal, itemsize, group=1):
+                causal, itemsize, group=1, rows=False):
     """VMEM one grid cell of ``kernel`` ('fwd', 'dq', 'dkv', 'bwd' or
     'dbias') asks for at tiles of ``block_q x block_k``, from shapes alone:
     what the chooser holds against ``_VMEM_BUDGET_BYTES`` and
-    ``_vmem_limit`` hands the compiler.  Every operand is lane-padded (``_lane_padded_bytes``).
+    ``_vmem_limit`` hands the compiler.  Every operand is lane-padded
+    (``_lane_padded_bytes``); a row statistic is ``[block_q, 1]``.  With
+    ``rows`` (``_row_stats``: 'fwd', 'bwd', 'dbias' where a head is one
+    tile) its block is ``[1, block_q]``, and each one the kernel reads is
+    turned to a ``[block_q, 1]`` column as a value (``_stat_column``).  The
+    chooser counts columns, the larger count, because ``_row_stats`` is
+    read off the chooser's own answer; ``_plan`` counts what the call
+    really holds.
 
     * the cell's own blocks, in and out, twice (the pipeline's two
       buffers): ``block_q`` rows of Q, its rotary part, dO, the row
@@ -663,9 +758,11 @@ def _vmem_bytes(kernel, block_q, block_k, S_q, S_kv, D, D_v, R, has_bias,
     * the float32 accumulators, and the forward's two running statistics;
     * the dQ pass's two ``[block_q, S_kv]`` float32 scratches, where it
       forms delta over more than one tile (``_delta_in_kernel``)."""
+    def stat(b):
+        return (1, b, 4) if rows else (b, 1, 4)
     if kernel == "bwd":
         own = [(block_q, D, itemsize), (block_q, D_v, itemsize),   # Q, dO
-               (block_q, 1, 4), (block_q, 1, 4),                   # lse, delta
+               stat(block_q), stat(block_q),                       # lse, delta
                (block_k, D, itemsize), (block_k, D_v, itemsize),   # K, V
                (block_q, D, itemsize), (block_k, D, itemsize),     # dQ, dK
                (block_k, D_v, itemsize)]                           # dV
@@ -682,14 +779,14 @@ def _vmem_bytes(kernel, block_q, block_k, S_q, S_kv, D, D_v, R, has_bias,
         wide, narrow = 4, 4
     else:
         b = block_q
-        own = [(b, D, itemsize), (b, R, itemsize), (b, 1, 4)]  # Q, Qr, lse
+        own = [(b, D, itemsize), (b, R, itemsize), stat(b)]    # Q, Qr, lse
         bias_tile = (b, S_kv, itemsize)
         if kernel == "fwd":
             own += [(b, D_v, itemsize)]                        # out
             acc = [(b, D_v, 4), (b, 1, 4), (b, 1, 4)]          # acc, m, l
             wide, narrow = 2, 1
         else:
-            own += [(b, D_v, itemsize), (b, 1, 4)]             # dO, delta
+            own += [(b, D_v, itemsize), stat(b)]               # dO, delta
             if kernel == "dq":
                 own += [(b, D, itemsize), (b, R, itemsize)]    # dQ, dQr
                 acc = [(b, D, 4), (b, R, 4)]
@@ -706,6 +803,8 @@ def _vmem_bytes(kernel, block_q, block_k, S_q, S_kv, D, D_v, R, has_bias,
         _lane_padded_bytes(*o) for o in whole)
     need += block_q * block_k * (4 * (wide + has_bias) + itemsize * narrow)
     need += sum(_lane_padded_bytes(*o) for o in acc)
+    if rows and kernel != "fwd":
+        need += 2 * _lane_padded_bytes(block_q, 1, 4)     # lse, delta turned
     if kernel == "dq" and S_kv > block_k and \
             _delta_in_kernel(S_kv, causal, has_bias):
         need += 2 * block_q * S_kv * 4
@@ -793,16 +892,23 @@ def _flash_fits(*shape):
                for kernel in ("fwd", "dq", "dkv") + ("dbias",) * shape[5])
 
 
-def _plan(kernel, *shape):
+def _plan(kernel, q, bias, *shape):
     """One kernel call's ``(block_q, block_k, keywords for the whole-
     sequence BlockSpecs, vmem_limit_bytes)`` at this shape
-    (``_shape_key``), counted in ``flash_tiles_total``."""
+    (``_shape_key``), counted in ``flash_tiles_total`` with the layout of
+    its row statistics (``_row_stats``) and which block row its bias is
+    read at (``_bias_row``)."""
     _, block_q, block_k = _tiles(kernel, *shape)
-    _m_tiles.inc(kernel=kernel, block_q=block_q, block_k=block_k)
+    rows = _row_stats(kernel, *shape)
+    _m_tiles.inc(kernel=kernel, block_q=block_q, block_k=block_k,
+                 stats="row" if rows else "column",
+                 bias="none" if bias is None
+                 else "head" if bias.shape[0] == q.shape[0] else "sequence")
     S_q, S_kv, D, D_v, R, _, _, itemsize = shape[:8]
     return (block_q, block_k,
             _whole_seq(_whole_side(kernel, S_q, S_kv, D, D_v, R, itemsize)),
-            _vmem_limit(_vmem_bytes(kernel, block_q, block_k, *shape)))
+            _vmem_limit(_vmem_bytes(kernel, block_q, block_k, *shape,
+                                    rows=rows)))
 
 
 _m_tiles = telemetry.counter(
@@ -810,7 +916,12 @@ _m_tiles = telemetry.counter(
     "flash kernel calls traced, by kernel ('fwd', 'dq', 'dkv', 'dbias'; "
     "'bwd': dQ, dK and dV in one call, where a head is one tile) and the "
     "tile the chooser picked for the call's shape: block_q rows of Q by "
-    "block_k rows of K a step of the sweep")
+    "block_k rows of K a step of the sweep; stats: how the row statistics "
+    "cross HBM ('row': [BH, 1, S_q], lane-dense, in 'fwd', 'bwd' and "
+    "'dbias' where a head is one tile; 'column': [BH, S_q, 1], every "
+    "multi-pass call, and 'dq' and 'dkv' always); bias: 'sequence' where "
+    "one [S_q, S_kv] bias is read by every head of a sequence at block row "
+    "i // H, 'head' where each head has its own, 'none'")
 
 
 def _rope_specs(rope, q_block, k_block, whole_mode=None):
@@ -842,13 +953,31 @@ def _kv_row(q, k):
     consecutive grid rows name the same block, which the pipeline fetches
     once.  At ``H_kv == H`` the row is ``i`` itself, with no division in
     the index map."""
-    group = q.shape[0] // k.shape[0]
-    if group * k.shape[0] != q.shape[0]:
-        raise ValueError("flash attention: %d query heads over %d key/value "
-                         "heads" % (q.shape[0], k.shape[0]))
+    return _shared_row(q, k, "key/value heads")
+
+
+def _shared_row(q, x, what):
+    """Grid row ``i`` of Q ``[BH, ...]`` -> the block row of an operand
+    ``x`` whose every row serves ``BH / x.shape[0]`` consecutive grid rows
+    (``what``, for the refusal of counts that do not divide)."""
+    group = q.shape[0] // x.shape[0]
+    if group * x.shape[0] != q.shape[0]:
+        raise ValueError("flash attention: %d query heads over %d %s"
+                         % (q.shape[0], x.shape[0], what))
     if group == 1:
         return lambda i: i
     return lambda i: i // group
+
+
+def _bias_row(q, bias):
+    """Grid row ``i`` (one head of one sequence, Q ``[B * H, S_q, D]``) ->
+    the block row of its bias: a bias ``[B * H, S_q, S_kv]`` has a block a
+    head; one of ``[B, S_q, S_kv]`` (a padding mask) is the same for the H
+    heads of a sequence and is read where it lies, at ``i // H``, as the
+    shared rotary key head is (``_rope_specs``): no copy a head exists in
+    HBM, and a sequence's H consecutive grid rows name the same block,
+    which the pipeline fetches once."""
+    return _shared_row(q, bias, "rows of bias")
 
 
 def _compose_rope(q, k, rope):
@@ -866,9 +995,12 @@ def _flash_forward(q, k, v, bias, scale, *, with_lse=False,
                    causal=False, rope=None):
     """q: [BH, S_q, D]; k: [BH, S_kv, D]; v: [BH, S_kv, D_v]
     (cross-attention supported; D_v may differ from D, the output has D_v);
-    bias: [BH, S_q, S_kv] or None; rope: ``(qr [BH, S_q, R], kr [B, S_kv,
-    R])`` or None — a second part of every head whose keys are shared by
-    the H = BH / B heads of a sequence (``_scores``)."""
+    bias: [BH, S_q, S_kv], [B, S_q, S_kv] (the same for the H = BH / B
+    heads of a sequence: ``_bias_row``) or None; rope: ``(qr [BH, S_q, R],
+    kr [B, S_kv, R])`` or None — a second part of every head whose keys
+    are shared by the heads of a sequence (``_scores``).  ``with_lse``:
+    ``(out, logsumexp [BH, S_q] float32)``, whichever way the kernel wrote
+    it (``_row_stats``)."""
     BH, S_q, D = q.shape
     S_kv = k.shape[1]
     D_v = v.shape[2]
@@ -900,7 +1032,8 @@ def _flash_forward(q, k, v, bias, scale, *, with_lse=False,
     if rope is not None and shape[8] != 1:
         raise ValueError("the flash kernels take a rotary pair or grouped "
                          "key/value heads, not both")
-    block_q, block_k, whole, vmem = _plan("fwd", *shape)
+    block_q, block_k, whole, vmem = _plan("fwd", q, bias, *shape)
+    rows = _row_stats("fwd", *shape)
     grid = (BH, S_q // block_q)
     in_specs = [
         pl.BlockSpec((1, block_q, D), lambda i, j: (i, j, 0)),
@@ -909,8 +1042,9 @@ def _flash_forward(q, k, v, bias, scale, *, with_lse=False,
     ]
     args = [q, k, v]
     if bias is not None:
+        row = _bias_row(q, bias)
         in_specs.append(pl.BlockSpec((1, block_q, S_kv),
-                                     lambda i, j: (i, j, 0)))
+                                     lambda i, j: (row(i), j, 0)))
         args.append(bias)
     if rope is not None:
         in_specs += _rope_specs(rope, block_q, None, whole)
@@ -931,8 +1065,9 @@ def _flash_forward(q, k, v, bias, scale, *, with_lse=False,
     out_specs = [pl.BlockSpec((1, block_q, D_v), lambda i, j: (i, j, 0))]
     out_shape = [jax.ShapeDtypeStruct((BH, S_q, D_v), q.dtype)]
     if with_lse:
-        out_specs.append(_row_stat_spec(block_q))
-        out_shape.append(jax.ShapeDtypeStruct((BH, S_q, 1), jnp.float32))
+        out_specs.append(_row_stat_spec(block_q, rows))
+        out_shape.append(jax.ShapeDtypeStruct(
+            (BH, 1, S_q) if rows else (BH, S_q, 1), jnp.float32))
     res = _pallas_call(
         kern, "flash_fwd", vmem_limit_bytes=vmem,
         grid=grid,
@@ -940,15 +1075,16 @@ def _flash_forward(q, k, v, bias, scale, *, with_lse=False,
         out_specs=out_specs,
         out_shape=out_shape,
     )(*args)
-    return (res[0], res[1]) if with_lse else res[0]
+    if not with_lse:
+        return res[0]
+    return res[0], (res[1][:, 0] if rows else res[1][..., 0])
 
 
 def _row_delta(g, out):
-    """delta = sum_d dO * O per row, [BH, S_q, 1] float32: for callers
+    """delta = sum_d dO * O per row, ``[BH, S_q]`` float32: for callers
     that hold ``out`` (ring attention's GLOBAL output; S_kv beyond
-    ``_DELTA_IN_KERNEL_MAX_SKV``)."""
-    return jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                   axis=-1, keepdims=True)
+    ``_DELTA_IN_KERNEL_MAX_SKV``; the looped sweeps)."""
+    return jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
 
 
 def _flash_dq(q, k, v, bias, scale, lse, g, causal, delta, rope=None):
@@ -960,7 +1096,7 @@ def _flash_dq(q, k, v, bias, scale, lse, g, causal, delta, rope=None):
     S_kv = k.shape[1]
     D_v = v.shape[2]
     block_q, block_k, whole, vmem = _plan(
-        "dq", *_shape_key(q, k, v, bias, causal, rope))
+        "dq", q, bias, *_shape_key(q, k, v, bias, causal, rope))
     _rope_runs_looped(rope, causal, bias)
     in_kernel = delta is None
     kv = _kv_row(q, k)
@@ -972,8 +1108,9 @@ def _flash_dq(q, k, v, bias, scale, lse, g, causal, delta, rope=None):
                              **whole)]
     args = [q, k, v]
     if bias is not None:
+        row = _bias_row(q, bias)
         in_specs.append(pl.BlockSpec((1, block_q, S_kv),
-                                     lambda i, j: (i, j, 0)))
+                                     lambda i, j: (row(i), j, 0)))
         args.append(bias)
     if rope is not None:
         in_specs += _rope_specs(rope, block_q, None, whole)
@@ -1042,7 +1179,7 @@ def _flash_dkv(q, k, v, bias, scale, lse, g, causal, delta, rope=None):
     S_kv = k.shape[1]
     D_v = v.shape[2]
     block_q, block_k, whole, vmem = _plan(
-        "dkv", *_shape_key(q, k, v, bias, causal, rope))
+        "dkv", q, bias, *_shape_key(q, k, v, bias, causal, rope))
     kv = _kv_row(q, k)
     group = BH // k.shape[0]
     in_specs = [
@@ -1052,8 +1189,9 @@ def _flash_dkv(q, k, v, bias, scale, lse, g, causal, delta, rope=None):
     ]
     args = [q, k, v]
     if bias is not None:
+        row = _bias_row(q, bias)
         in_specs.append(pl.BlockSpec((1, S_q, block_k),
-                                     lambda i, j: (i, 0, j)))
+                                     lambda i, j: (row(i), 0, j)))
         args.append(bias)
     if rope is not None:
         in_specs += _rope_specs(rope, None, block_k, whole)
@@ -1109,34 +1247,36 @@ def _flash_bwd(q, k, v, bias, scale, lse, g, causal, delta,
                delta_out=False):
     """The fused backward (``_bwd_kernel``; where ``_fused_backward`` says a
     head is one tile): ``(dq, dk, dv, delta)`` from one call, a grid cell a
-    head.  A passed delta is used and comes back as it went in; with
+    head.  ``lse`` and delta are rows, ``[BH, 1, S_q]`` (``_row_stats``).
+    A passed delta is used and comes back as it went in; with
     ``delta=None`` the kernel forms it, and writes it out only where
     ``delta_out`` asks (the dbias pass reads it), else None comes back."""
     BH, S_q, D = q.shape
     S_kv = k.shape[1]
     D_v = v.shape[2]
-    vmem = _plan("bwd", *_shape_key(q, k, v, bias, causal, None))[3]
+    vmem = _plan("bwd", q, bias,
+                 *_shape_key(q, k, v, bias, causal, None))[3]
     delta_out = delta_out and delta is None
 
-    def rows(n, d):
-        return pl.BlockSpec((1, n, d), lambda i: (i, 0, 0))
+    def rows(n, d, row=lambda i: i):
+        return pl.BlockSpec((1, n, d), lambda i: (row(i), 0, 0))
     in_specs = [rows(S_q, D), rows(S_kv, D), rows(S_kv, D_v)]
     args = [q, k, v]
     if bias is not None:
-        in_specs.append(rows(S_q, S_kv))
+        in_specs.append(rows(S_q, S_kv, _bias_row(q, bias)))
         args.append(bias)
-    in_specs += [rows(S_q, D_v), rows(S_q, 1)]          # dO, lse
+    in_specs += [rows(S_q, D_v), rows(1, S_q)]          # dO, lse
     args += [g, lse]
     if delta is not None:
-        in_specs.append(rows(S_q, 1))
+        in_specs.append(rows(1, S_q))
         args.append(delta)
     out_specs = [rows(S_q, D), rows(S_kv, D), rows(S_kv, D_v)]
     out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype),
                  jax.ShapeDtypeStruct(k.shape, k.dtype),
                  jax.ShapeDtypeStruct(v.shape, v.dtype)]
     if delta_out:
-        out_specs.append(rows(S_q, 1))
-        out_shape.append(jax.ShapeDtypeStruct((BH, S_q, 1), jnp.float32))
+        out_specs.append(rows(1, S_q))
+        out_shape.append(jax.ShapeDtypeStruct((BH, 1, S_q), jnp.float32))
 
     def kern(q_ref, k_ref, v_ref, *refs):
         refs = list(refs)
@@ -1160,19 +1300,23 @@ def _flash_bwd(q, k, v, bias, scale, lse, g, causal, delta,
 def _flash_backward(q, k, v, bias, scale, lse, g, causal=False, delta=None,
                     bias_grad=True, rope=None):
     """Tiled dQ/dK/dV — recomputes p blockwise from the saved LSE
-    ([BH, S_q, 1]); the [S, S] score matrix never exists in HBM
-    (FlashAttention-2 backward).  Where a head is one tile in both passes
-    (``_fused_backward``, from the call's shapes alone: S <= 512 at the BERT
-    widths) ONE kernel, ``flash_bwd``, forms S, P, dP and dS once for all
-    three gradients; every other shape runs the dQ pass and then the dK/dV
-    pass, which each rebuild them.
+    (``[BH, S_q]`` float32, as ``_flash_forward`` hands it out; laid out
+    here as the shape's kernels take it, ``_row_stats``); the [S, S]
+    score matrix never exists in HBM (FlashAttention-2 backward).  Where a
+    head is one tile in both passes (``_fused_backward``, from the call's
+    shapes alone: S <= 512 at the BERT widths) ONE kernel, ``flash_bwd``,
+    forms S, P, dP and dS once for all three gradients; every other shape
+    runs the dQ pass and then the dK/dV pass, which each rebuild them.
 
-    ``delta`` ([BH, S_q, 1] float32, ``_row_delta``) is passed by a caller
-    that holds ``out``; it MUST be by one whose K/V are a shard of the
-    row (ring attention: a delta summed over one device's K/V is wrong).
+    ``delta`` (``[BH, S_q]`` float32, ``_row_delta``) is passed
+    by a caller that holds ``out``; it MUST be by one whose K/V are a shard
+    of the row (ring attention: a delta summed over one device's K/V is
+    wrong).
     With ``delta=None`` the fused kernel or the dQ kernel forms it over the
     whole row and hands it to the other passes, so no ``out`` is needed at
-    all.  ``bias_grad=False`` skips the dbias pass.  With a rotary pair
+    all.  ``bias_grad=False`` skips the dbias pass, which writes a head's
+    ``[S_q, S_kv]``; for a bias the heads of a sequence share (``_bias_row``)
+    the heads' parts are summed here, outside.  With a rotary pair
     ``dq`` and ``dk`` are pairs, ``(dq, dqr)`` and ``(dk, dkr)``, ``dkr``
     summed over the heads that share the rotary keys
     (``_rope_runs_looped``: never beside a bias)."""
@@ -1181,6 +1325,8 @@ def _flash_backward(q, k, v, bias, scale, lse, g, causal=False, delta=None,
     D_v = v.shape[2]
     shape = _shape_key(q, k, v, bias, causal, rope)
     want_dbias = bias is not None and bias_grad
+    rows = _row_stats("bwd", *shape)
+    lse, delta = _kernel_stat(lse, rows), _kernel_stat(delta, rows)
     if _fused_backward(*shape):
         dq, dk, dv, delta = _flash_bwd(q, k, v, bias, scale, lse, g, causal,
                                        delta, delta_out=want_dbias)
@@ -1192,17 +1338,18 @@ def _flash_backward(q, k, v, bias, scale, lse, g, causal=False, delta=None,
 
     dbias = None
     if want_dbias:
-        block_q, block_k, whole, vmem = _plan("dbias", *shape)
-        kv = _kv_row(q, k)
+        block_q, block_k, whole, vmem = _plan("dbias", q, bias, *shape)
+        kv, row = _kv_row(q, k), _bias_row(q, bias)
         db_specs = [
             pl.BlockSpec((1, block_q, D), lambda i, j: (i, j, 0)),  # q
             pl.BlockSpec((1, S_kv, D), lambda i, j: (kv(i), 0, 0), **whole),
             pl.BlockSpec((1, S_kv, D_v), lambda i, j: (kv(i), 0, 0),
                          **whole),
-            pl.BlockSpec((1, block_q, S_kv), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, block_q, S_kv),
+                         lambda i, j: (row(i), j, 0)),
             pl.BlockSpec((1, block_q, D_v), lambda i, j: (i, j, 0)),  # dO
-            _row_stat_spec(block_q),                                # lse
-            _row_stat_spec(block_q),                                # delta
+            _row_stat_spec(block_q, rows),                          # lse
+            _row_stat_spec(block_q, rows),                          # delta
         ]
         dbias = _pallas_call(
             functools.partial(_dbias_kernel, scale=scale,
@@ -1214,21 +1361,27 @@ def _flash_backward(q, k, v, bias, scale, lse, g, causal=False, delta=None,
                                    lambda i, j: (i, j, 0)),
             out_shape=jax.ShapeDtypeStruct((BH, S_q, S_kv), bias.dtype),
         )(q, k, v, bias, g, lse, delta)
+        if bias.shape[0] != BH:
+            dbias = dbias.reshape(bias.shape[0], -1, S_q, S_kv) \
+                .astype(jnp.float32).sum(axis=1).astype(bias.dtype)
     return dq, dk, dv, dbias
 
 
 def _forward_keeping_lse(q, k, v, bias, scale, causal, rope=None):
     """Training forward on a tileable shape: (out, what the backward
     needs beside its inputs).  The row statistic leaves as ``[BH, S_q]``
-    behind an ``optimization_barrier``: XLA:TPU lays the kernel's
-    ``[BH, S_q, 1]`` out with the size-1 minor dimension padded to 128
-    lanes (100.7 MB for 0.79 MB of numbers at BH=384, S=512), and without
-    the barrier it cancels a squeeze/expand pair and keeps THAT buffer
-    alive from the forward to the backward, in every layer.  ``out`` is
-    kept only where the dQ pass cannot form delta itself."""
+    behind an ``optimization_barrier``.  Where the kernel wrote a column
+    (``_row_stats``: every multi-pass shape) XLA:TPU lays its ``[BH, S_q,
+    1]`` out with the size-1 minor dimension padded to 128 lanes, and
+    without the barrier it cancels a squeeze/expand pair and keeps THAT
+    buffer alive from the forward to the backward, in every layer.  Where
+    it wrote a row the squeeze is a reshape of ``[BH, 1, S_q]`` and no
+    padded buffer exists on either side; the barrier stays, one program
+    for both layouts.  ``out`` is kept only where the dQ pass cannot form
+    delta itself."""
     out, lse = _flash_forward(q, k, v, bias, scale, with_lse=True,
                               causal=causal, rope=rope)
-    lse = jax.lax.optimization_barrier(lse[..., 0])
+    lse = jax.lax.optimization_barrier(lse)
     return out, lse, (None if _delta_in_kernel(k.shape[1], causal,
                                                bias is not None)
                       else out)
@@ -1237,12 +1390,13 @@ def _forward_keeping_lse(q, k, v, bias, scale, causal, rope=None):
 def _backward_from_lse(q, k, v, bias, scale, causal, lse, out, g,
                        bias_grad=True, rope=None):
     """dq, dk, dv, dbias from the residuals of ``_forward_keeping_lse``.
-    ``lse`` and ``g`` pass one barrier together, so the padded
-    ``[BH, S_q, 1]`` expansion cannot be scheduled before the layer's
-    output gradient exists."""
+    ``lse`` (``[BH, S_q]``) and ``g`` pass one barrier together, so
+    ``_flash_backward``'s expansion to its kernels' layout (for a column
+    the lane-padded ``[BH, S_q, 1]``) cannot be scheduled before the
+    layer's output gradient exists."""
     lse, g = jax.lax.optimization_barrier((lse, g))
     return _flash_backward(
-        q, k, v, bias, scale, lse[..., None], g, causal=causal,
+        q, k, v, bias, scale, lse, g, causal=causal,
         delta=None if out is None else _row_delta(g, out),
         bias_grad=bias_grad, rope=rope)
 
@@ -1448,15 +1602,11 @@ def _sp_gather_attention(q, k, v, mesh, axis, scale, causal, bias,
         Skv = kb.shape[2]
         if not dropout and not causal:
             # cross-attention fast path: flash on the local rows
-            bf = None
-            if bb is not None:
-                bf = jnp.broadcast_to(bb.astype(qb.dtype),
-                                      (Bl, Hl, Sl, Skv)) \
-                    .reshape(Bl * Hl, Sl, Skv)
             of = flash_attention(qb.reshape(Bl * Hl, Sl, Dl),
                                  kb.reshape(Bl * Hl, Skv, Dl),
                                  vb.reshape(Bl * Hl, Skv, Dl),
-                                 bf, scale, causal=False)
+                                 _kernel_bias(bb, qb, Skv), scale,
+                                 causal=False)
             return of.reshape(Bl, Hl, Sl, Dl)
         q_off = jax.lax.axis_index(axis) * Sl
         return _attn_core_remat(scale, causal, dropout, rng_axes)(
@@ -1538,14 +1688,21 @@ def _flat(x):
     return x.reshape((-1,) + x.shape[2:])
 
 
-def _flat_bias(bias, q, S_kv):
-    """Any broadcastable bias -> the kernels' [BH, S_q, S_kv] in q's
-    dtype, or None."""
+def _kernel_bias(bias, q, S_kv, per_head=False):
+    """Any broadcastable bias -> what the kernels take, in q's dtype, or
+    None: ``[B, S_q, S_kv]`` where it is the same for every head of a
+    sequence (its head dimension is 1 after ``_norm_bias``: a padding
+    mask), which the kernels read at block row ``i // H`` (``_bias_row``);
+    ``[BH, S_q, S_kv]`` where it has a head dimension, or where
+    ``per_head`` asks for a head's own copy (a bias whose gradient is
+    wanted: ``flash_dbias`` writes a head's)."""
     if bias is None:
         return None
     B, H, S_q, _ = q.shape
-    return _flat(jnp.broadcast_to(_norm_bias(bias, q, S_kv),
-                                  (B, H, S_q, S_kv)))
+    bias = _norm_bias(bias, q, S_kv)
+    if bias.shape[1] == 1 and not per_head:
+        return bias[:, 0]
+    return _flat(jnp.broadcast_to(bias, (B, H, S_q, S_kv)))
 
 
 @register_op("fused_attention")
@@ -1647,7 +1804,7 @@ def _fused_attention(ctx, op):
             q, k, v, _norm_bias(bias, q, S_kv), 0, ctx.rng())
         ctx.set("Out", out)
         return
-    args = (_flat(q), _flat(k), _flat(v), _flat_bias(bias, q, S_kv),
+    args = (_flat(q), _flat(k), _flat(v), _kernel_bias(bias, q, S_kv),
             float(scale), causal, rope)
     if flash and op.output("LSE") and not _is_test(ctx):
         out, lse = flash_attention_lse(*args)
@@ -1707,9 +1864,10 @@ def _fused_attention_grad(ctx, op):
         (_flat(qr), kr.reshape(kr.shape[0], S_kv, -1))
     if want["BiasQK"]:
         # the transpose of the bias's broadcast sums dbias back to its shape
-        bf, bias_vjp = jax.vjp(lambda b: _flat_bias(b, q, S_kv), bias)
+        bf, bias_vjp = jax.vjp(
+            lambda b: _kernel_bias(b, q, S_kv, per_head=True), bias)
     else:
-        bf = _flat_bias(bias, q, S_kv)
+        bf = _kernel_bias(bias, q, S_kv)
     dq, dk, dv, dbias = _backward_from_lse(
         _flat(q), _flat(k), _flat(v), bf, float(ctx.attr("scale", 1.0)),
         bool(ctx.attr("causal", False)), _flat(lse),

@@ -138,7 +138,7 @@ def _ring_flash_bwd(axis_name, scale, res, g):
     # delta belongs to the GLOBAL row: formed here from the merged output,
     # never by a backward kernel over one ring step's K/V shard
     delta = _row_delta(gf, _bhsd(out))
-    lsef = lse.reshape(B * H, Tl, 1)
+    lsef = lse.reshape(B * H, Tl)
     kb, vb = k, v
     dq = jnp.zeros((B * H, Tl, D), jnp.float32)
     dkb = jnp.zeros_like(k, dtype=jnp.float32)

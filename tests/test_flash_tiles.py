@@ -222,6 +222,33 @@ def test_flash_tiles_total_counts_each_kernel_of_a_lowering(form, causal,
         [1, 1 - fused, 1 - fused, fused, int(with_bias)]
 
 
+@pytest.mark.parametrize("S,bias_rows,stats,bias", [
+    (128, 0, "row", "none"), (128, 2, "row", "sequence"),
+    (128, 4, "row", "head"), (1024, 0, "column", "none"),
+    (1024, 2, "column", "sequence"), (1024, 4, "column", "head")])
+def test_flash_tiles_total_says_which_layout_and_bias_a_call_took(
+        S, bias_rows, stats, bias):
+    """``stats``: rows where a head is one tile, columns where the backward
+    is two passes; ``bias``: ``sequence`` for a ``[B, S_q, S_kv]`` bias the
+    H heads of a sequence read at block row ``i // H``, ``head`` for one a
+    head, ``none``.  Every kernel of the lowering carries both."""
+    counter = telemetry.registry().get("flash_tiles_total")
+    x = jax.ShapeDtypeStruct((4, S, 16), jnp.float32)
+    b = jax.ShapeDtypeStruct((bias_rows, S, S), jnp.float32) \
+        if bias_rows else None
+    # the dbias pass is traced wherever there is a bias, labelled alike
+    kernels = (("fwd", "bwd") if stats == "row" else ("fwd", "dq", "dkv")) \
+        + (("dbias",) if bias_rows else ())
+    labels = [dict(kernel=k, stats=stats, bias=bias) for k in kernels]
+    before, total = [counter.value(**lb) for lb in labels], counter.value()
+    jax.jit(jax.grad(
+        lambda q, k, v, b: flash_attention(q, k, v, b, 0.25).sum(),
+        argnums=(0, 1, 2))).lower(x, x, x, b)
+    assert [counter.value(**lb) - n for lb, n in zip(labels, before)] == \
+        [1] * len(kernels)
+    assert counter.value() - total == len(kernels)
+
+
 # -- one backward kernel or two: the rule, from shapes alone -----------------
 
 # (S_q, S_kv, D, D_v, R, has_bias, causal, itemsize) -> fused

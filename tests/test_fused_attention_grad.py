@@ -8,6 +8,7 @@ grad ops take which path, and gradient parity through the Fluid op against
 """
 
 import collections
+import functools
 
 import numpy as np
 import pytest
@@ -31,17 +32,19 @@ def _data(name, shape, dtype="float32", grad=True):
 
 
 def _program(S_q=128, S_kv=128, bias_shape=None, causal=False, dropout=0.0,
-             n_ops=1, with_lse=True, cast=None, backward=True):
+             n_ops=1, with_lse=True, cast=None, backward=True,
+             bias_grad=True):
     """``n_ops`` chained attention ops (each one's output is the next
     one's Q) under the loss ``sum(out * w)``; the fetch list is the loss
-    and the gradients of q, k, v and the bias.  ``with_lse=False`` builds
-    the op as a program from before the ``LSE`` slot existed."""
+    and the gradients of q, k, v and (``bias_grad``; else it is a mask
+    that wants none) the bias.  ``with_lse=False`` builds the op as a
+    program from before the ``LSE`` slot existed."""
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup), fluid.unique_name.guard():
         ins = [_data("q", (B, H, S_q, D)), _data("k", (B, H, S_kv, D)),
                _data("v", (B, H, S_kv, D))]
         if bias_shape is not None:
-            ins.append(_data("b", bias_shape))
+            ins.append(_data("b", bias_shape, grad=bias_grad))
         w = _data("w", (B, H, S_q, D), grad=False)
         q, k, v, b = (ins + [None])[:4]
         if cast:
@@ -59,7 +62,8 @@ def _program(S_q=128, S_kv=128, bias_shape=None, causal=False, dropout=0.0,
         if cast:
             out = layers.cast(out, "float32")
         loss = layers.reduce_sum(out * w)
-        grads = fluid.gradients(loss, ins) if backward else []
+        wanted = [x for x in ins if not x.stop_gradient]
+        grads = fluid.gradients(loss, wanted) if backward else []
     return main, startup, [loss] + grads
 
 
@@ -221,7 +225,8 @@ def test_dq_kernel_forms_delta(causal, with_bias):
         if with_bias else None
     out, lse = pallas_ops._flash_forward(q, k, v, bias, 0.25, with_lse=True,
                                          causal=causal)
-    want = pallas_ops._row_delta(g, out)
+    # the pass reads and writes its statistics as [BH, S_q, 1] columns
+    lse, want = lse[..., None], pallas_ops._row_delta(g, out)[..., None]
     dq, delta = pallas_ops._flash_dq(q, k, v, bias, 0.25, lse, g, causal,
                                      None)
     np.testing.assert_allclose(np.asarray(delta), np.asarray(want),
@@ -270,21 +275,27 @@ def test_fused_backward_is_the_pair_of_passes_in_one_kernel(case, passed):
     out, lse = pallas_ops._flash_forward(q, k, v, bias, 0.25, with_lse=True,
                                          causal=causal)
     delta = pallas_ops._row_delta(g, out) if passed else None
+    # the fused kernel's statistics are [BH, 1, S_q] rows, the passes'
+    # [BH, S_q, 1] columns
+    row, col = (functools.partial(pallas_ops._kernel_stat, rows=rows)
+                for rows in (True, False))
+    as_row = row(delta)
     dq, dk, dv, formed = pallas_ops._flash_bwd(
-        q, k, v, bias, 0.25, lse, g, causal, delta, delta_out=True)
-    want_dq, want_delta = pallas_ops._flash_dq(q, k, v, bias, 0.25, lse, g,
-                                               causal, delta)
-    want_dk, want_dv = pallas_ops._flash_dkv(q, k, v, bias, 0.25, lse, g,
-                                             causal, want_delta)
+        q, k, v, bias, 0.25, row(lse), g, causal, as_row, delta_out=True)
+    want_dq, want_delta = pallas_ops._flash_dq(q, k, v, bias, 0.25, col(lse),
+                                               g, causal, col(delta))
+    want_dk, want_dv = pallas_ops._flash_dkv(q, k, v, bias, 0.25, col(lse),
+                                             g, causal, want_delta)
     if passed:
-        assert formed is delta
+        assert formed is as_row
     for name, a, b in (("dq", dq, want_dq), ("dk", dk, want_dk),
-                       ("dv", dv, want_dv), ("delta", formed, want_delta)):
+                       ("dv", dv, want_dv),
+                       ("delta", formed[:, 0], want_delta[..., 0])):
         assert a.shape == b.shape and a.dtype == b.dtype, name
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6,
                                    atol=1e-7, err_msg=name)
     # a delta nothing reads is not written
-    assert pallas_ops._flash_bwd(q, k, v, bias, 0.25, lse, g, causal,
+    assert pallas_ops._flash_bwd(q, k, v, bias, 0.25, row(lse), g, causal,
                                  None)[3] is None
 
 
@@ -454,3 +465,259 @@ def test_op_inside_a_recompute_span_is_differentiated_by_jax(kernel_calls):
     remat = losses(True)
     np.testing.assert_allclose(remat, plain, rtol=1e-6)
     assert plain[-1] < plain[0]
+
+
+# -- what crosses HBM beside Q, K, V: the statistics' layout and the mask -----
+
+# several tiles: 384 = 3 x 128 rows a side (two passes, column statistics);
+# one tile: 128 (the fused kernel, row statistics); no tile: 192, which no
+# side divides (the composition, handed the same bias as the kernels)
+TILES = {"one_tile": 128, "several_tiles": 384, "no_tile": 192}
+# bias shape by name (S x S filled in), and whether its gradient is wanted:
+# a mask that wants none is what the kernels read once a sequence
+BIASES = {
+    "no_bias": (None, False),
+    "key_mask": ((B, 1, 1, "S"), False),
+    "key_mask_grad": ((B, 1, 1, "S"), True),
+    "sequence_mask": ((B, 1, "S", "S"), False),
+    "sequence_mask_grad": ((B, 1, "S", "S"), True),
+    "head_bias": ((B, H, "S", "S"), True),
+}
+_layout_runs = {}
+
+
+def _layout_case(tiles, bias, causal):
+    """(got, want) of one run through the Fluid op, by name; cached, so the
+    cases below that read one run share it."""
+    key = (tiles, bias, causal)
+    if key not in _layout_runs:
+        S = TILES[tiles]
+        shape, grad = BIASES[bias]
+        shape = shape and tuple(S if d == "S" else d for d in shape)
+        feed = _feed(S, S, shape, seed=len(bias) + S + causal)
+        got = _run(*_program(S_q=S, S_kv=S, bias_shape=shape, causal=causal,
+                             bias_grad=grad), feed)
+        want = _reference_grads(feed, causal)
+        names = ("out", "dq", "dk", "dv") + (("dbias",) if grad else ())
+        _layout_runs[key] = dict(zip(names, got)), dict(zip(names, want))
+    return _layout_runs[key]
+
+
+def _layout_params():
+    for tiles in TILES:
+        for bias, (_, grad) in BIASES.items():
+            for causal in (False, True):
+                for what in ("out", "dq", "dk", "dv") + \
+                        (("dbias",) if grad else ()):
+                    yield pytest.param(
+                        tiles, bias, causal, what,
+                        id="-".join((tiles, bias,
+                                     "causal" if causal else "full", what)))
+
+
+@pytest.mark.parametrize("tiles,bias,causal,what", list(_layout_params()))
+def test_every_layout_matches_the_reference(tiles, bias, causal, what):
+    """Forward (through the loss), dQ, dK, dV and dbias through the op
+    against ``_reference_attention``'s vjp: row statistics where a head is
+    one tile and columns where it is several, with no bias, a bias every
+    head of a sequence shares (read at block row ``i // H`` when no
+    gradient is wanted, a head's own copy when one is) and a bias a head;
+    and at a length the kernels have no tile for, where the composition
+    takes the shared bias as the kernels would."""
+    got, want = _layout_case(tiles, bias, causal)
+    assert got[what].shape == want[what].shape
+    np.testing.assert_allclose(got[what], want[what], rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("S", [128, 192])
+def test_flash_attention_takes_a_bias_a_sequence_shares(S):
+    """``flash_attention`` called as the op (and the sequence-parallel
+    gather island) calls it, with a ``[B, S_q, S_kv]`` bias under ``B * H``
+    heads: forward and every gradient, the bias's summed over a sequence's
+    heads, where the kernels run and where the shape has no tile."""
+    rng = np.random.RandomState(S)
+    q, k, v, w = (jnp.asarray(rng.randn(B * H, S, D).astype(np.float32) * 0.5)
+                  for _ in range(4))
+    bias = jnp.asarray(rng.randn(B, S, S).astype(np.float32) * 0.3)
+
+    def loss(fn, q, k, v, b):
+        return jnp.sum(fn(q, k, v, b) * w)
+    got = jax.value_and_grad(functools.partial(
+        loss, lambda *a: pallas_ops.flash_attention(*a, 0.25)),
+        argnums=(0, 1, 2, 3))(q, k, v, bias)
+    want = jax.value_and_grad(functools.partial(
+        loss, lambda q, k, v, b: _reference_attention(
+            q, k, v, jnp.repeat(b, H, axis=0), 0.25)),
+        argnums=(0, 1, 2, 3))(q, k, v, bias)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
+
+
+def test_for_test_clone_takes_a_shared_mask_at_a_length_without_a_tile():
+    shape = (B, 1, 1, 192)
+    main, startup, fetches = _program(S_q=192, S_kv=192, bias_shape=shape,
+                                      bias_grad=False, backward=False)
+    feed = _feed(192, 192, shape)
+    train, = _run(main, startup, fetches, feed)
+    infer, = _run(main.clone(for_test=True), startup, fetches, feed)
+    np.testing.assert_allclose(infer, train, rtol=1e-6)
+    np.testing.assert_allclose(train, _reference_grads(feed, False)[0],
+                               rtol=2e-4)
+
+
+@pytest.fixture
+def kernel_operands(monkeypatch):
+    """``{kernel name: [shapes of every operand and result of a call]}``
+    of the kernel calls traced."""
+    seen = collections.defaultdict(list)
+    real = pallas_ops._pallas_call
+
+    def spy(kernel, name, **kwargs):
+        call = real(kernel, name, **kwargs)
+        outs = [tuple(o.shape) for o in jax.tree.leaves(kwargs["out_shape"])]
+
+        def traced(*args):
+            seen[name].append([tuple(a.shape) for a in args] + outs)
+            return call(*args)
+        return traced
+
+    monkeypatch.setattr(pallas_ops, "_pallas_call", spy)
+    return seen
+
+
+@pytest.mark.parametrize("bias", ["no_bias", "sequence_mask",
+                                  "sequence_mask_grad", "head_bias"])
+def test_row_layout_calls_carry_no_size_one_minor_dimension(kernel_operands,
+                                                            bias):
+    """Where a head is one tile no operand or result of a kernel call ends
+    in a dimension of size 1 (which XLA:TPU pads to 128 lanes): the
+    statistics are ``[BH, 1, S_q]``; and a mask that wants no gradient
+    enters every call as ``[B, S_q, S_kv]``, never a copy a head."""
+    S = 128
+    shape, grad = BIASES[bias]
+    shape = shape and tuple(S if d == "S" else d for d in shape)
+    _run(*_program(S_q=S, S_kv=S, bias_shape=shape, bias_grad=grad),
+         _feed(S, S, shape))
+    assert set(kernel_operands) == {"flash_fwd", "flash_bwd"} | (
+        {"flash_dbias"} if grad else set())
+    shapes = [s for calls in kernel_operands.values() for c in calls
+              for s in c]
+    assert all(s[-1] != 1 for s in shapes), shapes
+    assert (B * H, 1, S) in kernel_operands["flash_fwd"][0]
+    assert (B * H, 1, S) in kernel_operands["flash_bwd"][0]
+    if bias == "sequence_mask":
+        for calls in kernel_operands.values():
+            assert (B, S, S) in calls[0] and (B * H, S, S) not in calls[0]
+    elif shape:
+        # a gradient is wanted, or the bias has a head dimension: the
+        # backward reads (and flash_dbias writes) a head's own
+        assert (B * H, S, S) in kernel_operands["flash_bwd"][0]
+
+
+@pytest.mark.parametrize("causal", [False, True],
+                         ids=["unrolled", "looped"])
+def test_multi_pass_calls_trace_what_they_traced_before(kernel_operands,
+                                                        causal):
+    """A shape of several tiles keeps the program it had before the row
+    layout existed (its step's memory is the long-sequence cells':
+    ``_row_stats``): the forward writes and both passes read ``[BH, S_q,
+    1]`` columns, and the dQ pass hands its delta to the dK/dV pass as
+    one."""
+    S = 384
+    _run(*_program(S_q=S, S_kv=S, causal=causal), _feed(S, S))
+    BH, col = B * H, (B * H, S, 1)
+    x = (BH, S, D)
+    assert dict(kernel_operands) == {
+        "flash_fwd": [[x, x, x, x, col]],
+        # looped: delta comes in, from ``Out``; unrolled: the pass forms it
+        "flash_dq": [[x, x, x, x, col, col, x] if causal
+                     else [x, x, x, x, col, x, col]],
+        "flash_dkv": [[x, x, x, x, col, col, x, x]]}
+    for kernel in ("fwd", "dq", "dkv", "bwd", "dbias"):
+        assert not pallas_ops._row_stats(kernel, S, S, D, D, 0, False,
+                                         causal, 4, 1)
+        # where a head is one tile: the three kernels that run there
+        assert pallas_ops._row_stats(
+            kernel, 128, 128, D, D, 0, False, causal, 4, 1) == \
+            (kernel not in ("dq", "dkv"))
+
+
+# (query heads, key/value heads, S, D, D_v, rotary R, rows of bias, causal)
+# of a layer's kernels, all bfloat16 -> sha1 of the jaxpr of forward and
+# backward (``flash_attention_lse`` under ``jax.grad``)
+MULTI_PASS_PROGRAMS = {
+    # the three long-sequence cells' kernel shapes
+    "moonlight": ((16, 16, 4096, 128, 128, 64, 0, True),
+                  "2bacd1f5fa16440ef76516f157aa9d4cdb34c25a"),
+    "ouro": ((16, 16, 4096, 128, 128, 0, 0, True),
+             "1b4617ca9bb2d2ec3b124ec595bbcc7dea9f893a"),
+    "lfm2": ((32, 8, 8192, 64, 64, 0, 0, True),
+             "5f72f28afe5da057affb83d70ff7a102f8f644b6"),
+    # unrolled: the dQ pass forms delta; with a bias a head
+    "s384": ((4, 4, 384, 64, 64, 0, 0, False),
+             "7cfe88f16680b390785c11c101ee5a8b0c308c81"),
+    "s1024_head_bias": ((8, 8, 1024, 64, 64, 0, 8, False),
+                        "1de7130c68ff644a9696edfa12abcec6b7e6a159"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(MULTI_PASS_PROGRAMS))
+def test_multi_pass_programs_are_the_ones_pinned(name):
+    """THE PIN for the cells whose backward is two passes: the whole traced
+    program of a layer's attention, forward and backward, kernel bodies,
+    grids, index maps and VMEM limits included, is the text it was when
+    the cells' ``hbm_peak_gb`` was last read on the chip (PERF.md section
+    6, PR 36).  The unrolled two are the text of the tree before the row
+    layout existed; the looped three are that text with the logsumexp's
+    ``broadcast_in_dim`` three equations later, and compile to the same
+    Moonlight step instruction for instruction.  XLA schedules a whole
+    step anew around a changed custom call (254 MB more of temporaries in
+    the Moonlight cell, one bound's worth: ledger, PR 35), so a hash that
+    moves means: compile the step (``tools/step_memory.py``), read the
+    three cells on the chip, then pin the new one."""
+    import hashlib
+    (BH, BH_kv, S, D_qk, D_v, R, bias_rows, causal), want = \
+        MULTI_PASS_PROGRAMS[name]
+
+    def arr(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    bias = arr(bias_rows, S, S) if bias_rows else None
+    rope = (arr(BH, S, R), arr(1, S, R)) if R else None
+
+    def loss(q, k, v, bias, rope):
+        out, lse = pallas_ops.flash_attention_lse(q, k, v, bias, 0.125,
+                                                  causal, rope)
+        return out.astype(jnp.float32).sum() + lse.sum() * 0
+    wanted = (0, 1, 2) + ((3,) if bias_rows else ()) + ((4,) if R else ())
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=wanted))(
+        arr(BH, S, D_qk), arr(BH_kv, S, D_qk), arr(BH_kv, S, D_v), bias,
+        rope))
+    assert "f32[%d,1,%d]" % (BH, S) not in text          # no row layout
+    assert hashlib.sha1(text.encode()).hexdigest() == want
+
+
+def test_a_shared_mask_is_never_copied_a_head():
+    """The step lowered for a mask every head of a sequence shares holds
+    the ``[B, S_q, S_kv]`` mask and no ``[B * H, S_q, S_kv]`` array, in
+    ``fused_attention`` and in its grad op alike."""
+    from paddle_tpu.fluid import executor
+
+    S_q, S_kv = 128, 256
+    shape = (B, 1, S_q, S_kv)
+    main, startup, fetches = _program(S_q=S_q, S_kv=S_kv, bias_shape=shape,
+                                      bias_grad=False)
+    feed = _feed(S_q, S_kv, shape)
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        compiled, feed_vals = exe._resolve_compiled(main, feed, fetches,
+                                                    scope, None)
+        text = compiled._jitted.lower(
+            executor._scope_state(scope, compiled.state_mut),
+            executor._scope_state(scope, compiled.state_ro),
+            tuple(feed_vals), np.int32(0)).as_text()
+    assert "tensor<%dx%dx%dx" % (B, S_q, S_kv) in text
+    assert "tensor<%dx%dx%dx" % (B * H, S_q, S_kv) not in text
+    assert "tensor<%dx%dx%dx%dx" % (B, H, S_q, S_kv) not in text

@@ -431,11 +431,14 @@ def _mosaic_calls(executable):
 
 
 def _tiles_counted():
-    """``{(kernel, block_q, block_k): calls traced}`` so far."""
+    """``{(kernel, block_q, block_k): calls traced}`` so far, summed over
+    the counter's other labels (``stats``, ``bias``)."""
     values = telemetry.registry().snapshot()["flash_tiles_total"]["values"]
-    return collections.Counter({
-        tuple(v["labels"][n] for n in ("kernel", "block_q", "block_k")):
-        v["value"] for v in values})
+    counted = collections.Counter()
+    for v in values:
+        counted[tuple(v["labels"][n]
+                      for n in ("kernel", "block_q", "block_k"))] += v["value"]
+    return counted
 
 
 @pytest.fixture(scope="module")
@@ -474,11 +477,13 @@ def test_flash_cell_shape_compiles_with_one_tile_a_head(bert_stack_steps):
 
 def test_lse_residual_costs_its_own_bytes_and_no_more(bert_stack_steps):
     """Handing the LSE from the forward op to the grad op costs the step's
-    temporaries the statistic's own bytes a layer.  XLA:TPU holds the
-    kernel's ``[BH, S, 1]`` float32 rows with the size-1 dimension padded
-    to 128 lanes (128 times the numbers), and keeps THAT buffer alive
-    from forward to backward unless the lowering hands on a lane-dense
-    ``[BH, S]`` behind ``optimization_barrier``s (pallas_ops
+    temporaries the statistic's own bytes a layer.  At this shape (a head
+    is one tile) the kernels write and read it as a lane-dense ``[BH, 1,
+    S]`` row, the size of its numbers.  A multi-pass shape's ``[BH, S, 1]``
+    column XLA:TPU holds with the size-1 dimension padded to 128 lanes
+    (128 times the numbers), and would keep THAT buffer alive from forward
+    to backward if the lowering did not hand on a lane-dense ``[BH, S]``
+    behind ``optimization_barrier``s (pallas_ops
     ``_forward_keeping_lse``)."""
     new, replay = (bert_stack_steps[w].memory_analysis().temp_size_in_bytes
                    for w in (True, False))
