@@ -2,12 +2,14 @@
 
 Every compiled executable — plain step, K-window, explicit-collective,
 multihost, serving bucket — can be reduced to one normalized record:
-FLOPs, transcendentals, bytes accessed, argument/output/temp/peak memory,
+FLOPs, transcendentals, bytes accessed, argument/output/temp memory,
 instruction + fusion counts, static collective bytes by species/axis, and
 a roofline ``estimated_step_s``.  Records are keyed by the executable
 signature (program fingerprint prefix + window size) and stamped into
 telemetry as ``hlo_*`` gauges plus a ``kind="compile"`` ledger record in
-the metrics JSONL (docs/observability.md "Device-cost ledger").
+the metrics JSONL (docs/observability.md "Device-cost ledger"); the memory
+of an executable is the ``step_memory_bytes`` / ``step_resident_bytes``
+gauges, stamped by whatever introspection call produced it.
 
 Two capture depths, by cost:
 
@@ -39,10 +41,15 @@ _m_flops = telemetry.gauge(
     "hlo_flops_total",
     "static XLA FLOP count of a compiled executable, per inner step, "
     "by signature")
-_m_peak = telemetry.gauge(
-    "hlo_peak_bytes",
-    "static peak device memory (argument+output+temp) of a compiled "
-    "executable, by signature")
+_m_step_memory = telemetry.gauge(
+    "step_memory_bytes",
+    "what XLA's memory analysis says a compiled executable takes on one "
+    "device, by signature and kind (argument|output|alias|temp|code, and "
+    "peak where the backend fills it)")
+_m_step_resident = telemetry.gauge(
+    "step_resident_bytes",
+    "bytes on one device of the values a compiled step is called with, "
+    "by signature and kind (parameter|optimizer_state|other_state|feed)")
 _m_fusion = telemetry.gauge(
     "hlo_fusion_count",
     "fusion instruction count in a compiled executable's optimized HLO, "
@@ -264,7 +271,44 @@ def normalize_cost(raw):
     return dict(c) if c else {}
 
 
-def describe(executable, k=1, sig=None, comm=None, tag=None):
+# kind of ``step_memory_bytes`` -> the field of XLA's analysis it reads
+MEMORY_KINDS = {
+    "argument": "argument_size_in_bytes",
+    "output": "output_size_in_bytes",
+    "alias": "alias_size_in_bytes",
+    "temp": "temp_size_in_bytes",
+    "code": "generated_code_size_in_bytes",
+    "peak": "peak_memory_in_bytes",
+}
+
+
+def memory_record(analysis):
+    """``{kind: bytes}`` of one ``memory_analysis()`` result (per device):
+    the ONE reader of XLA's analysis, for ``describe`` and for the
+    executor's stamp.  ``alias`` is the part of the arguments the outputs
+    are written over (donated state), so what a step holds beside its
+    arguments is ``output - alias + temp``.  ``peak`` is left out where
+    the backend does not fill it (XLA:CPU and XLA:TPU of jaxlib 0.9.0
+    give 0).  Attribute reads only: never the serialized buffer
+    assignment, which is as large as the step."""
+    rec = {kind: int(getattr(analysis, field, 0) or 0)
+           for kind, field in MEMORY_KINDS.items()}
+    if not rec["peak"]:
+        del rec["peak"]
+    return rec
+
+
+def stamp_step_memory(sig, memory, resident=None):
+    """Publish one executable's memory record as ``step_memory_bytes{sig,
+    kind}`` and, where the caller holds the values the step takes,
+    ``step_resident_bytes{sig, kind}``."""
+    for kind, nbytes in memory.items():
+        _m_step_memory.set(nbytes, sig=sig, kind=kind)
+    for kind, nbytes in (resident or {}).items():
+        _m_step_resident.set(nbytes, sig=sig, kind=kind)
+
+
+def describe(executable, k=1, sig=None, comm=None, tag=None, memory=None):
     """Normalized ledger record for one jax AOT-compiled executable.
 
     ``k`` is the steps_per_run window size; per the module contract the
@@ -274,16 +318,16 @@ def describe(executable, k=1, sig=None, comm=None, tag=None):
     trace-time ``{(species, precision, axis): bytes_per_step}`` map from
     ``_CompiledBlock.comm_bytes_by_axis()`` — static collective bytes,
     cross-checkable against the runtime ``collective_bytes_total{axis}``
-    counters.
+    counters.  ``memory`` is the executable's ``memory_record`` where the
+    caller has it already.
     """
     k = max(1, int(k or 1))
     ca = normalize_cost(executable.cost_analysis())
-    ma = executable.memory_analysis()
+    if memory is None:
+        memory = memory_record(executable.memory_analysis())
     hlo = executable.as_text()
     stats = instruction_stats(hlo)
-    arg = int(getattr(ma, "argument_size_in_bytes", 0) or 0)
-    out = int(getattr(ma, "output_size_in_bytes", 0) or 0)
-    tmp = int(getattr(ma, "temp_size_in_bytes", 0) or 0)
+    arg, out, tmp = memory["argument"], memory["output"], memory["temp"]
     flops = float(ca.get("flops", 0.0) or 0.0)
     bytes_accessed = float(ca.get("bytes accessed", 0.0) or 0.0)
     rec = {
@@ -296,8 +340,9 @@ def describe(executable, k=1, sig=None, comm=None, tag=None):
         "argument_bytes": arg,
         "output_bytes": out,
         "temp_bytes": tmp,
-        "generated_code_bytes": int(
-            getattr(ma, "generated_code_size_in_bytes", 0) or 0),
+        "generated_code_bytes": memory["code"],
+        # no peak: donated state is in the arguments and in the outputs.
+        # The key stays as tools/cost_ledger.py's baseline pins it
         "peak_bytes": arg + out + tmp,
         "instructions": stats["instructions"],
         "fusions": stats["fusions"],
@@ -317,12 +362,13 @@ def stamp(rec, source="full"):
     """Publish one ledger record: ``hlo_*`` gauges labeled by signature
     (visible in prometheus_text/dump_prometheus and the /aggregate
     endpoint) plus a ``kind="compile"`` lifecycle record in the step-
-    event ring / metrics JSONL for tools/metrics_report.py."""
+    event ring / metrics JSONL for tools/metrics_report.py.  The
+    executable's memory is not published here: ``step_memory_bytes`` was
+    stamped where the executor produced the executable the record
+    describes (``stamp_step_memory``)."""
     sig = rec.get("sig") or "?"
     if "flops" in rec:
         _m_flops.set(float(rec["flops"]), sig=sig)
-    if "peak_bytes" in rec:
-        _m_peak.set(float(rec["peak_bytes"]), sig=sig)
     if "fusions" in rec:
         _m_fusion.set(float(rec["fusions"]), sig=sig)
     _m_records.inc(source=source)

@@ -16,6 +16,7 @@ buffer donation, so in-place optimizer updates stay in-place on device.
 
 import collections
 import itertools
+import math
 import os
 import threading
 import time
@@ -540,6 +541,22 @@ def feed_nbytes(feed):
         if isinstance(feed, dict) else 0
 
 
+def device_nbytes(value, sharding=None):
+    """Bytes ``value`` takes on ONE device: a sharded array counts one
+    shard (``shard_shape``: no shard is touched), a replicated or
+    single-device one counts whole.  A host value counts as it would lie
+    under ``sharding`` (the compiled step's, for a feed not staged yet),
+    whole without one."""
+    shape = np.shape(value)
+    sharding = getattr(value, "sharding", None) or sharding
+    if sharding is not None:
+        shape = sharding.shard_shape(shape)
+    dtype = getattr(value, "dtype", None)
+    if dtype is None:
+        dtype = np.asarray(value).dtype
+    return math.prod(shape) * np.dtype(dtype).itemsize
+
+
 def _prefetch_ahead_sync(put, batches):
     """Depth 0 of ``prefetch_ahead`` (see there).  Each batch is drawn
     from the source and ``put`` inside one ``fluid.feed_stage`` span on
@@ -908,6 +925,29 @@ def _model_parallel_axes(program):
     return axes
 
 
+def _resident_bytes(program, compiled, mut, ro, feed_vals):
+    """``{kind: bytes on one device}`` of the values a step is called
+    with, for ``step_resident_bytes``: the persistables it takes as
+    ``parameter`` (``param_names``), ``optimizer_state`` (the
+    accumulators ``program._opt_state_of`` links to a parameter) or
+    ``other_state`` (the rest: learning rate, batch-norm statistics,
+    selection biases, counters), and its ``feed``."""
+    params = param_names(program)
+    opt_state = getattr(program, "_opt_state_of", None) or {}
+    resident = dict.fromkeys(
+        ("parameter", "optimizer_state", "other_state", "feed"), 0)
+    for name, value in zip(
+            itertools.chain(compiled.state_mut, compiled.state_ro),
+            mut + ro):
+        kind = "optimizer_state" if name in opt_state else \
+            "parameter" if name in params else "other_state"
+        resident[kind] += device_nbytes(value)
+    for value, sharding in zip(
+            feed_vals, compiled.feed_shardings or itertools.repeat(None)):
+        resident["feed"] += device_nbytes(value, sharding)
+    return resident
+
+
 class _CompiledBlock:
     """One jitted executable + its scope-variable signature.
 
@@ -1253,13 +1293,26 @@ class Executor:
         """Compile (or fetch from cache) and return the jax Compiled
         object for this (program, feed-signature, fetches, scope-state
         avals) tuple."""
+        return self._step_executable(program, feed, fetch_list, scope,
+                                     steps_per_run)[0]
+
+    def _step_executable(self, program, feed, fetch_list, scope,
+                         steps_per_run=None):
+        """``(executable, its memory_analysis())`` behind every
+        introspection call, and the one place the step's memory record is
+        stamped (``step_memory_bytes`` / ``step_resident_bytes``, by
+        signature): XLA's analysis is read once an executable, the bytes
+        of the values in hand (attribute reads) at every call.  Only an
+        introspection call comes here; a dispatch never does."""
         scope = scope or global_scope()
-        compiled, feed_vals = self._resolve_compiled(
-            program, feed, fetch_list, scope, steps_per_run)
+        target = self._target(program)
+        compiled, feed_vals = self._lookup_compiled(
+            target, feed or {}, fetch_list, scope, steps_per_run)
         mut = _scope_state(scope, compiled.state_mut)
         ro = _scope_state(scope, compiled.state_ro)
         aval_key = tuple(_aval_sig(v) for v in mut + ro)
-        executable = compiled._xla_executables.get(aval_key)
+        executable, analysis = compiled._xla_executables.get(
+            aval_key, (None, None))
         if executable is None:
             # multi-host feeds carry LOCAL shapes; the executable (on
             # every path) is compiled against GLOBAL avals — globalize
@@ -1298,9 +1351,15 @@ class Executor:
                     executable = lowered.compile()
             _m_compile_s.observe(time.perf_counter() - t0,
                                  kind="introspection")
-            compiled._xla_executables[aval_key] = executable
+            analysis = executable.memory_analysis()
+            compiled._xla_executables[aval_key] = (executable, analysis)
         profiler.note_step_executable(executable)
-        return executable
+        costmodel.stamp_step_memory(
+            costmodel.signature(compiled.program_fingerprint,
+                                k=compiled.steps_per_run),
+            costmodel.memory_record(analysis),
+            _resident_bytes(target.program, compiled, mut, ro, feed_vals))
+        return executable, analysis
 
     def compiled_hlo(self, program=None, feed=None, fetch_list=None,
                      scope=None, steps_per_run=None):
@@ -1326,9 +1385,9 @@ class Executor:
         scaling claims: e.g. a sequence-parallel step's temp bytes must
         shrink vs the replicated step (activations stored S/sp), and a
         remat span must shrink them further."""
-        return self._lowered_executable(
+        return self._step_executable(
             program, feed, fetch_list, scope,
-            steps_per_run=steps_per_run).memory_analysis()
+            steps_per_run=steps_per_run)[1]
 
     def compiled_cost(self, program=None, feed=None, fetch_list=None,
                       scope=None, steps_per_run=None, normalize=True):
@@ -1371,7 +1430,7 @@ class Executor:
         if not costmodel.enabled():
             return None
         scope = scope or global_scope()
-        executable = self._lowered_executable(
+        executable, analysis = self._step_executable(
             program, feed, fetch_list, scope, steps_per_run=steps_per_run)
         compiled, _ = self._resolve_compiled(
             program, feed, fetch_list, scope, steps_per_run)
@@ -1379,7 +1438,8 @@ class Executor:
         rec = costmodel.describe(
             executable, k=k,
             sig=costmodel.signature(compiled.program_fingerprint, k=k),
-            comm=compiled.comm_bytes_by_axis(), tag=tag)
+            comm=compiled.comm_bytes_by_axis(), tag=tag,
+            memory=costmodel.memory_record(analysis))
         if stamp:
             costmodel.stamp(rec, source="full")
         return rec

@@ -26,7 +26,7 @@ from . import framework
 from . import preemption
 from . import telemetry
 from .data_feeder import DataFeeder
-from .executor import _device_for_place, feed_nbytes, TPUPlace
+from .executor import _device_for_place, device_nbytes, feed_nbytes, TPUPlace
 from .core_shim import EOFException
 
 # input-pipeline telemetry (docs/observability.md): batches produced by
@@ -36,6 +36,14 @@ from .core_shim import EOFException
 # TPU-pod writeups profile first.
 _m_loader_batches = telemetry.counter(
     "loader_batches_total", "feed dicts produced by DataLoader/PyReader")
+# what the input layer holds of the device's memory: the batches a
+# program-bound loader's worker has put on the device and no step has
+# taken yet (the capacity queue, the one-batch lookahead, the batch the
+# worker is handing to a full queue); a batch a step holds is the step's
+_m_staged = telemetry.gauge(
+    "feed_staged_bytes",
+    "bytes on one device of the feeds program-bound DataLoaders hold "
+    "staged and not yet handed to a step (stat=now|peak)")
 _m_wait_s = telemetry.counter(
     "data_wait_seconds_total",
     "seconds the consumer blocked on the DataLoader queue")
@@ -351,6 +359,19 @@ class FeedRing:
             pass
 
 
+def _staged_nbytes(feed):
+    """Bytes on one device of a staged feed's arrays.  A value still on
+    the host (no consumer has bound a device yet) holds none."""
+    return sum(device_nbytes(v) for v in feed.values()
+               if isinstance(v, jax.Array))
+
+
+def _note_staged(delta):
+    now = _m_staged.inc(delta, stat="now")
+    if delta > 0:
+        _m_staged.raise_to(now, stat="peak")
+
+
 class GeneratorLoader:
     def __init__(self, feed_list, capacity=8, use_double_buffer=True,
                  iterable=True, return_list=False, steps_per_run=None):
@@ -377,6 +398,10 @@ class GeneratorLoader:
         self._thread = None
         self._stop_event = None
         self._pulled = 0          # batches next_feed handed over since start
+        # feed_staged_bytes, this loader's part: bytes the worker staged
+        # (its thread alone adds) and bytes next_feed handed over (the
+        # consumer's alone); the difference is dropped with the queue
+        self._staged_in = self._staged_out = 0
         # set by Executor.run on the first program-bound pull: when no
         # explicit places were given, the producer thread device_puts
         # subsequent batches to the CONSUMING executor's device, so the
@@ -467,7 +492,15 @@ class GeneratorLoader:
                 shardings = self._consumer_shardings
             if dev is None and not shardings:
                 return d
-            return sharded_put(d, shardings, dev)
+            staged = sharded_put(d, shardings, dev)
+            if stop_when is not None and not stop_when():
+                # a program-bound loader's worker, inside its
+                # fluid.feed_stage span: the batch is the loader's until
+                # next_feed hands it over
+                nbytes = _staged_nbytes(staged)
+                self._staged_in += nbytes
+                _note_staged(nbytes)
+            return staged
 
         src = self._gen()
         if self._steps_per_run > 1:
@@ -577,6 +610,14 @@ class GeneratorLoader:
         self._thread = None
         self._queue = None
         self._stop_event = None
+        self._drop_staged()
+
+    def _drop_staged(self):
+        """The staged batches no step will take (the queue's, the
+        lookahead's) leave the books with the queue."""
+        if self._staged_in != self._staged_out:
+            _note_staged(self._staged_out - self._staged_in)
+        self._staged_in = self._staged_out = 0
 
     def reset(self):
         self._stop_worker()
@@ -600,6 +641,7 @@ class GeneratorLoader:
             self._queue = None
             self._thread = None
             self._stop_event = None
+            self._drop_staged()
             raise EOFException(
                 "preemption stop requested: DataLoader drained")
         wait = time.perf_counter() - t0
@@ -608,6 +650,7 @@ class GeneratorLoader:
             self._queue = None
             self._thread = None
             self._stop_event = None
+            self._drop_staged()
             if item.err is not None:
                 # batch attribution: with the device prefetch (ring or
                 # one-batch lookahead) the generator is ahead of
@@ -627,6 +670,9 @@ class GeneratorLoader:
             raise EOFException(
                 "pass end: there is no data in the DataLoader queue")
         self._pulled += 1
+        nbytes = _staged_nbytes(item)
+        self._staged_out += nbytes
+        _note_staged(-nbytes)
         return item
 
 

@@ -156,9 +156,18 @@ class Gauge(_Metric):
             self._values[_label_key(labels)] = value
 
     def inc(self, amount=1, **labels):
+        """Add ``amount``; returns the new value."""
         key = _label_key(labels)
         with _LOCK:
-            self._values[key] = (self._values.get(key) or 0) + amount
+            new = self._values[key] = (self._values.get(key) or 0) + amount
+        return new
+
+    def raise_to(self, value, **labels):
+        """Keep the larger of ``value`` and what is set (a high-water
+        mark fed from more than one thread)."""
+        key = _label_key(labels)
+        with _LOCK:
+            self._values[key] = max(self._values.get(key) or 0, value)
 
     def value(self, **labels):
         with _LOCK:
@@ -662,12 +671,52 @@ def reset_step_events():
 
 
 # ---------------------------------------------------------------------------
+# Device memory (docs/observability.md "Does it fit")
+# ---------------------------------------------------------------------------
+# The runtime keeps two books a device: the buffers it holds (state, staged
+# feeds, fetches) and the region it reserves for the compiled programs'
+# temporaries.  They are gauges READ ON A PULL: a scrape, a snapshot, a
+# benchmark's reader.  No dispatch reads them.
+
+DEVICE_MEMORY_STATS = ("bytes_in_use", "peak_bytes_in_use", "bytes_reserved",
+                       "peak_bytes_reserved", "bytes_limit")
+
+_m_device_memory = gauge(
+    "device_memory_bytes",
+    "the runtime's memory books of each local device (fluid.core."
+    "get_mem_usage), by device id and stat, as of the last "
+    "sample_device_memory()")
+
+
+def sample_device_memory():
+    """Set ``device_memory_bytes{device, stat}`` from ``fluid.core.
+    get_mem_usage(i)`` of every local device, for the stats the backend
+    gives (``DEVICE_MEMORY_STATS``; XLA:CPU gives none, and nothing is
+    set).  A process that has started no backend has no device memory of
+    its own and is not made to start one: a metrics server beside a
+    training process must not take the trainer's chip."""
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        return
+    from .core_shim import get_mem_usage
+    from .mesh_utils import local_devices
+    for i, device in enumerate(local_devices()):
+        stats = get_mem_usage(i)
+        for stat in DEVICE_MEMORY_STATS:
+            if stat in stats:
+                _m_device_memory.set(int(stats[stat]), device=device.id,
+                                     stat=stat)
+
+
+# ---------------------------------------------------------------------------
 # Exporters
 # ---------------------------------------------------------------------------
 
 def metrics_snapshot():
     """Plain-dict export: the full registry snapshot plus ring stats —
-    the programmatic exporter (no flags, no files)."""
+    the programmatic exporter (no flags, no files).  Samples the devices'
+    memory first (``sample_device_memory``)."""
+    sample_device_memory()
     snap = _REGISTRY.snapshot()
     snap["_step_events"] = {"recorded": step_events_recorded(),
                             "in_ring": len(step_events())}
@@ -746,7 +795,10 @@ def prometheus_text():
     """Registry rendered in the Prometheus text exposition format.  In a
     multi-process world every sample carries a ``process="<idx>"`` label
     so per-process scrapes aggregate without collision; single-process
-    output is byte-identical to the pre-pod format."""
+    output is byte-identical to the pre-pod format.  Samples the
+    devices' memory first (``sample_device_memory``): a scrape shows what
+    the chips hold."""
+    sample_device_memory()
     pidx = _process["index"]
     lines = []
     for m in _REGISTRY.metrics():

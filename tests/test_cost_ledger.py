@@ -110,7 +110,7 @@ def test_window_cost_is_per_inner_step_not_k_times():
 def test_cost_record_fields_attribution_and_gauges(tmp_path):
     """``Executor.cost_record`` produces the full normalized record, the
     HLO attribution names the Fluid ops that produced the cost, and the
-    hlo_* gauges surface through prometheus_text, dump_prometheus, and
+    hlo_* and step_memory_bytes gauges surface through prometheus_text, dump_prometheus, and
     the /aggregate merge with the executable signature as label."""
     main, startup, loss = _build_train()
     exe = fluid.Executor(fluid.CPUPlace())
@@ -136,7 +136,7 @@ def test_cost_record_fields_attribution_and_gauges(tmp_path):
     # gauges, labeled by signature
     txt = telemetry.prometheus_text()
     assert 'hlo_flops_total{sig="%s"}' % rec["sig"] in txt
-    assert 'hlo_peak_bytes{sig="%s"}' % rec["sig"] in txt
+    assert 'step_memory_bytes{kind="temp",sig="%s"}' % rec["sig"] in txt
     assert 'hlo_fusion_count{sig="%s"}' % rec["sig"] in txt
     # dump_prometheus -> /aggregate (tools/metrics_server.py)
     telemetry.dump_prometheus(str(tmp_path / "m.p7.prom"))

@@ -28,3 +28,72 @@ def test_moonlight_step_holds_the_temporaries_it_held():
     assert out.returncode == 0, out.stderr[-2000:]
     record = json.loads(out.stdout.strip().splitlines()[-1])
     assert record["temp_size_in_bytes"] == 4880808448, record
+
+
+HLO = '''HloModule jit_fn
+
+%fused_computation.1 (p: bf16[8,8]) -> bf16[8,8] {
+  %p = bf16[8,8]{1,0} parameter(0)
+  ROOT %t = bf16[8,8]{1,0} tanh(%p)
+}
+
+ENTRY %main (a.1: bf16[8,8], b.1: bf16[8,8]) -> (bf16[8,8], bf16[8,8]) {
+  %a.1 = bf16[8,8]{1,0:T(8,128)(2,1)} parameter(0), metadata={op_name="a"}
+  %b.1 = bf16[8,8]{1,0:T(8,128)(2,1)} parameter(1), metadata={op_name="b"}
+  %copy.3 = bf16[8,8]{1,0:T(8,128)(2,1)S(1)} copy(%a.1), backend_config={}
+  %copy-start.2 = (bf16[8,8]{1,0}, bf16[8,8]{1,0:S(1)}, u32[]{:S(2)}) copy-start(%mul_fusion), cross_program_prefetch_index=0
+  %mul_fusion = bf16[8,8]{1,0} fusion(%copy.3, %b.1), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(fn)/role_fwd/fluid_mul/dot_general" stack_frame_id=4}
+  %loop_body = bf16[8,8]{1,0} fusion(%mul_fusion), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(fn)/role_bwd/ut_loop/fluid_mul_grad/transpose(jvp(fluid_mul))/dot_general"}
+  ROOT %out = (bf16[8,8]{1,0}, bf16[8,8]{1,0}) tuple(%loop_body, %copy.3)
+}
+'''
+
+BUFFERS = '''BufferAssignment:
+allocation 0: size 128, parameter 0, shape |bf16[8,8]| at ShapeIndex {}:
+ value: <1 a.1 @0> (size=128,offset=0): bf16[8,8]{1,0}
+allocation 1: size 512, preallocated-temp:
+ value: <2 mul_fusion @0> (size=128,offset=0): bf16[8,8]{1,0}
+ value: <3 copy-start.2{0} @0> (size=128,offset=0): bf16[8,8]{1,0}
+ value: <4 loop_body @0> (size=256,offset=128): bf16[8,8]{1,0}
+ value: <5 copy.3 @0> (size=128,offset=384): bf16[8,8]{1,0}
+allocation 2: size 4096, color 1, preallocated-temp:
+ value: <6 copy.3 @1> (size=4096,offset=0): bf16[8,8]{1,0}
+
+Total bytes used: 4736 (4.6KiB)
+
+Used values:
+<2 mul_fusion @0>
+ positions:
+  mul_fusion
+'''
+
+
+def test_by_op_groups_the_temp_allocations_buffers_by_scope(tmp_path):
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "step_memory", os.path.join(ROOT, "tools", "step_memory.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    hlo = tmp_path / "module_0001.jit_fn.after_optimizations.txt"
+    buffers = tmp_path / \
+        "module_0001.jit_fn.after_optimizations-buffer-assignment.txt"
+    hlo.write_text(HLO)
+    buffers.write_text(BUFFERS)
+    scopes = tool.instruction_scopes(str(hlo))
+    assert scopes["mul_fusion"] == "role_fwd/fluid_mul"
+    # the first role_* and the first fluid_*: the instruction's own op
+    assert scopes["loop_body"] == "role_bwd/fluid_mul_grad"
+    # what XLA made itself takes the scope of what it copies
+    assert scopes["copy-start.2"] == "role_fwd/fluid_mul (via copy-start)"
+    assert scopes["copy.3"] == "(argument) (via copy)"
+    # the HBM allocation alone: the colored one is on-chip memory
+    allocations = tool.temp_buffers(str(buffers))
+    assert list(allocations) == [1] and allocations[1][0] == 512
+    table = tool.by_scope(allocations, scopes)
+    # two buffers share bytes 0-128: half each; the shares sum to the bytes
+    assert table["role_fwd/fluid_mul"] == [64.0, 128, 1]
+    assert table["role_fwd/fluid_mul (via copy-start)"] == [64.0, 128, 1]
+    assert table["role_bwd/fluid_mul_grad"] == [256.0, 256, 1]
+    assert table["(argument) (via copy)"] == [128.0, 128, 1]
+    assert sum(row[0] for row in table.values()) == 512
