@@ -524,6 +524,50 @@ def phase_kernels(device, dry_run):
     log("%s D=%d bf16: fwd + dq/dk/dv match the composition on repeated "
         "K/V (worst rel err %.4f)" % (what, D, e_gqa))
 
+    # operands as the projections leave them, [B, S, H * D], read and
+    # written in place, a pair of heads of 64 a grid cell (the lane slices
+    # at offset 64 are Mosaic's to get right: the interpreter cannot tell);
+    # float32 as the BERT step hands them over, and bfloat16
+    B_, heads, S = (2, 2, 128) if dry_run else (16, 12, 512)
+    e_in_place = 0.0
+    for dtype in (f32, bf16):
+        q, k, v, g = (arr((B_, S, heads * D), dtype) for _ in range(4))
+        mask = arr((B_, S, S), dtype)
+
+        def in_place(causal, q, k, v, b, g=None):
+            # the calls a training step makes: the forward keeping its
+            # logsumexp, then ONE flash_bwd on it (no bias gradient)
+            out, lse = po._flash_fwd_in_place(q, k, v, b, scale, heads,
+                                              causal)
+            if g is None:
+                return out
+            return po._backward_in_place(q, k, v, b, scale, causal, heads,
+                                         lse, g)
+
+        def ref_in_place(causal, q, k, v, b):
+            split = [po._flat(po._heads_major(x, heads)) for x in (q, k, v)]
+            out = ref(causal, *split, b)
+            return po._heads_minor(out.reshape(B_, heads, S, D))
+        for causal in (False, True):
+            what = "flash in place B=%d H=%d S=%d %s causal=%s" % (
+                B_, heads, S, jnp.dtype(dtype).name, causal)
+            fk = functools.partial(in_place, causal)
+            _check_lowering(fk, (q, k, v, mask, g), on_tpu, what)
+            e = _rel_err(jax.jit(fk)(q, k, v, mask), jax.jit(
+                functools.partial(ref_in_place, causal))(q, k, v, mask))
+            require(e <= FWD_TOL, "%s: fwd err %.4f", what, e)
+            e_in_place = max(e_in_place, e)
+            got = jax.jit(fk)(q, k, v, mask, g)
+            want = grads(ref_in_place, causal, 3)(g, q, k, v, mask)
+            for nm, a, w in zip(("dq", "dk", "dv"), got, want):
+                require(a.shape == q.shape, "%s: %s is %s", what, nm, a.shape)
+                e = _rel_err(a, w)
+                require(e <= BWD_TOL, "%s: %s err %.4f", what, nm, e)
+                e_in_place = max(e_in_place, e)
+    log("flash in place [B=%d, S=%d, %d x %d]: fwd + dq/dk/dv match the "
+        "reference on split heads (worst rel err %.4f)"
+        % (B_, S, heads, D, e_in_place))
+
     M, H = (64, 128) if dry_run else (64 * 128, 768)
     x, sc, sh = arr((M, H)), arr((H,), f32), arr((H,), f32)
 
@@ -538,6 +582,7 @@ def phase_kernels(device, dry_run):
     return {"flash_fwd_err": round(worst["fwd"], 5),
             "flash_bwd_err": round(worst["bwd"], 5),
             "flash_grouped_err": round(e_gqa, 5),
+            "flash_in_place_err": round(e_in_place, 5),
             "layer_norm_err": round(e_ln, 5)}
 
 

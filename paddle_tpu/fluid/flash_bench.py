@@ -12,6 +12,13 @@ to 512, at the shapes of the benchmark's two kernel cells:
 * ``moonlight``: 16 heads, S=4096, 128 + 64 | 128, bf16, causal, a shared
   rotary key head — the looped form (``moonlight_ep8share_s4096_train``).
 
+After the sweep of ``bert`` come the kernels that run in that cell's step
+since PR 38, at the chooser's one tile: ``fwd`` and ``bwd`` on ``[B, S, H *
+D]`` operands read in place (``layout`` ``bshd``: 384 heads in 192 grid
+cells, a pair of heads of 64 a cell, ``pallas_ops._in_place``) beside the
+same calls on ``[BH, S, D]`` (``bhsd``), in float32 as the step hands them
+over (``fused_attention`` is on no AMP list) and in bfloat16.
+
 Run: python -m paddle_tpu.fluid.flash_bench [bert|moonlight ...]
 Prints one JSON line per shape, kernel and tile; ``form`` says which form
 of the backward the lowering takes at the shape (``fused``: ``bwd`` alone
@@ -33,15 +40,16 @@ TILES = ((128, 128), (256, 256), (512, 128), (128, 512), (512, 256),
          (256, 512), (512, 512))
 
 
-def _operands(shape):
+def _operands(shape, dtype=None):
     import jax
     import jax.numpy as jnp
     from .mesh_utils import local_devices
 
     rng = np.random.default_rng(0)
     dev = local_devices()[0]
+    dtype = dtype or jnp.bfloat16
 
-    def arr(*dims, dtype=jnp.bfloat16, scale=1.0):
+    def arr(*dims, dtype=dtype, scale=1.0):
         return jax.device_put(
             (rng.standard_normal(dims, dtype=np.float32) * scale)
             .astype(dtype), dev)
@@ -56,12 +64,21 @@ def _operands(shape):
                 rope=(arr(H, S, 64), arr(1, S, 64)), scale=192 ** -0.5)
 
 
+def _corner(fn):
+    """``fn`` jitted down to one scalar that needs every output, for the
+    fence."""
+    import jax
+    import jax.numpy as jnp
+    return jax.jit(lambda *args: sum(
+        jnp.sum(x[..., :1, :1].astype(jnp.float32))
+        for x in jax.tree.leaves(fn(*args))))
+
+
 def _kernel_calls(ops):
     """``{kernel: zero-argument call}``, each running ONE jitted kernel on
     ``ops``; built anew for every tile, so nothing traced is reused."""
     import functools
     import jax
-    import jax.numpy as jnp
     from .ops import pallas_ops as po
 
     scale, causal = float(ops["scale"]), ops["causal"]
@@ -92,33 +109,82 @@ def _kernel_calls(ops):
         return po._flash_bwd(a["q"], a["k"], a["v"], a["bias"], scale, lse,
                              a["g"], causal, None if in_kernel else delta)[:3]
 
-    def corner(fn):
-        """One scalar that needs every output, for the fence."""
-        return jax.jit(lambda *args: sum(
-            jnp.sum(x[..., :1, :1].astype(jnp.float32))
-            for x in jax.tree.leaves(fn(*args))))
     out, lse = jax.jit(forward)(arrays)
     delta = po._row_delta(ops["g"], out)
     # the two passes read their statistics as columns, the fused kernel as
     # rows (``pallas_ops._row_stats``)
     column = [po._kernel_stat(stat, False) for stat in (lse, delta)]
     row = [po._kernel_stat(stat, True) for stat in (lse, delta)]
-    return {"fwd": functools.partial(corner(forward), arrays),
-            "dq": functools.partial(corner(dq), arrays, *column),
-            "dkv": functools.partial(corner(dkv), arrays, *column),
-            "bwd": functools.partial(corner(bwd), arrays, *row)}
+    return {"fwd": functools.partial(_corner(forward), arrays),
+            "dq": functools.partial(_corner(dq), arrays, *column),
+            "dkv": functools.partial(_corner(dkv), arrays, *column),
+            "bwd": functools.partial(_corner(bwd), arrays, *row)}
+
+
+def _require_tpu():
+    import jax
+    if jax.default_backend() != "tpu":
+        raise RuntimeError("flash_bench times the chip's kernels: no TPU "
+                           "here (%s)" % jax.default_backend())
+
+
+def _layout_calls(ops):
+    """``{(layout, kernel): zero-argument call}`` of the one-tile pair on
+    the ``bert`` operands: ``bhsd`` as ``_kernel_calls`` runs them, ``bshd``
+    the same heads as ``[B, S, H * D]``, read and written in place."""
+    import functools
+    import jax
+    from .ops import pallas_ops as po
+
+    scale, H = float(ops["scale"]), ops["heads"]
+    q4 = ops["q"].reshape(-1, H, *ops["q"].shape[1:])
+    bias = po._kernel_bias(ops["bias"], q4, ops["k"].shape[1])
+    flat = {n: ops[n] for n in ("q", "k", "v", "g")}
+    minor = {n: jax.jit(lambda x: po._heads_minor(x.reshape(q4.shape)))(x)
+             for n, x in flat.items()}
+
+    lse = jax.jit(lambda a: po._flash_forward(
+        a["q"], a["k"], a["v"], bias, scale, with_lse=True)[1])(flat)
+    calls = {
+        ("bhsd", "fwd"): (lambda a: po._flash_forward(
+            a["q"], a["k"], a["v"], bias, scale, with_lse=True), flat),
+        ("bhsd", "bwd"): (lambda a: po._flash_bwd(
+            a["q"], a["k"], a["v"], bias, scale, lse[:, None], a["g"],
+            False, None)[:3], flat),
+        ("bshd", "fwd"): (lambda a: po._flash_fwd_in_place(
+            a["q"], a["k"], a["v"], bias, scale, H), minor),
+        ("bshd", "bwd"): (lambda a: po._backward_in_place(
+            a["q"], a["k"], a["v"], bias, scale, False, H, lse, a["g"]),
+            minor)}
+    return {key: functools.partial(_corner(fn), arrays)
+            for key, (fn, arrays) in calls.items()}
+
+
+def layouts(steps=30):
+    """Yield one record per dtype, layout and kernel of the one-tile pair
+    at the ``bert`` shape (module docstring)."""
+    import jax.numpy as jnp
+    from .timing import timed_steps
+
+    _require_tpu()
+    for dtype in (jnp.float32, jnp.bfloat16):
+        for (layout, kernel), call in _layout_calls(
+                _operands("bert", dtype)).items():
+            dt, _ = timed_steps(lambda i: call(), steps, warmup=3,
+                                fetch=lambda out: float(out))
+            yield dict(shape="bert", kernel=kernel, layout=layout,
+                       dtype=jnp.dtype(dtype).name, block_q=512, block_k=512,
+                       chosen=True, form="fused",
+                       ms=round(dt / steps * 1e3, 4))
 
 
 def sweep(shape, steps=30):
     """Yield one record per kernel and tile of ``shape``; the first of a
     kernel's records is the chooser's own pick."""
-    import jax
     from .ops import pallas_ops as po
     from .timing import timed_steps
 
-    if jax.default_backend() != "tpu":
-        raise RuntimeError("flash_bench times the chip's kernels: no TPU "
-                           "here (%s)" % jax.default_backend())
+    _require_tpu()
     ops = _operands(shape)
     chooser = po._tiles
     key = po._shape_key(ops["q"], ops["k"], ops["v"], ops["bias"],
@@ -148,6 +214,9 @@ def sweep(shape, steps=30):
 def main():
     for shape in sys.argv[1:] or ("bert", "moonlight"):
         for rec in sweep(shape):
+            print(json.dumps(rec))
+            sys.stdout.flush()
+        for rec in layouts() if shape == "bert" else ():
             print(json.dumps(rec))
             sys.stdout.flush()
 

@@ -273,7 +273,7 @@ def beam_search_decode(ids, scores, parent_idx, beam_size, end_id,
 
 def fused_attention(q, k, v, attn_bias=None, scale=1.0, causal=False,
                     dropout_prob=0.0, is_test=False, name=None,
-                    q_rope=None, k_rope=None):
+                    q_rope=None, k_rope=None, num_heads=None):
     """Fused attention core (ops/pallas_ops.py flash-attention kernel):
     q [B, H, S_q, D], k/v [B, H, S_kv, D] (cross-attention supported),
     optional additive bias [B, 1|H, S_q, S_kv].  k and v may carry fewer
@@ -291,15 +291,40 @@ def fused_attention(q, k, v, attn_bias=None, scale=1.0, causal=False,
     logsumexp rows; lane-dense, never [..., 1]) is the flash kernels'
     residual: written by the forward kernel of a training program and read
     by ``fused_attention_grad`` in place of a second forward run, as
-    ``batch_norm`` hands on ``SavedMean`` and ``dropout`` its ``Mask``."""
+    ``batch_norm`` hands on ``SavedMean`` and ``dropout`` its ``Mask``.
+
+    ``num_heads=H``: the heads lie in the MINOR dimension, as a
+    projection's matmul leaves them and the next one reads them: q
+    [B, S_q, H * D], k [B, S_kv, H_kv * D], v [B, S_kv, H_kv * D_v]
+    (q_rope [B, S_q, H * R], k_rope [B, S_kv, R]), and the output is
+    [B, S_q, H * D_v]; the bias and ``LSE`` are what they are without it.
+    No reshape or transpose before the op and none after it.  Where a head
+    is one tile of the flash kernels in forward and backward (S <= 512 at
+    the BERT widths), H_kv = H, D_v = D and the heads pack whole into 128
+    lanes (D = 64: a pair of heads a grid cell, because a block's minor
+    dimension must be a multiple of 128; D = 128: one), the kernels read
+    Q, K, V and the output's gradient in place and write the output and
+    the three gradients in place (``ops/pallas_ops._in_place``): the head
+    split and merge, which XLA runs as copies that pad 64 numbers to 128
+    lanes and keeps for the backward, do not exist.  Every other op in
+    this layout (dropout, sequence parallelism, longer sequences, grouped
+    heads, a rotary pair, a bias whose gradient is wanted) is split and
+    merged inside the lowering and computes what the 4-D op computes."""
     helper = LayerHelper("fused_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
-    out.shape = tuple(q.shape[:3]) + tuple(v.shape[3:]) if q.shape and \
-        v.shape else q.shape
     lse = helper.create_variable_for_type_inference("float32",
                                                     stop_gradient=True)
-    if q.shape:
-        lse.shape = tuple(q.shape[:3])
+    if num_heads:
+        if q.shape and k.shape and v.shape:
+            heads_kv = int(k.shape[2]) // (int(q.shape[2]) // num_heads)
+            out.shape = tuple(q.shape[:2]) + \
+                (int(v.shape[2]) // heads_kv * num_heads,)
+            lse.shape = (q.shape[0], num_heads, q.shape[1])
+    else:
+        out.shape = tuple(q.shape[:3]) + tuple(v.shape[3:]) if q.shape \
+            and v.shape else q.shape
+        if q.shape:
+            lse.shape = tuple(q.shape[:3])
     inputs = {"Q": [q], "K": [k], "V": [v]}
     if attn_bias is not None:
         inputs["BiasQK"] = [attn_bias]
@@ -313,6 +338,8 @@ def fused_attention(q, k, v, attn_bias=None, scale=1.0, causal=False,
                             "causal": bool(causal),
                             "attn_dropout": float(dropout_prob),
                             "is_test": bool(is_test),
+                            **({"num_heads": int(num_heads)} if num_heads
+                               else {}),
                             "__op_seed__":
                                 helper.main_program.next_op_seed()})
     return out

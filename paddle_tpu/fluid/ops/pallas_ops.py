@@ -120,6 +120,31 @@ has a tile the composition repeats it (``_reference_attention``).  A bias
 with a head dimension, and one whose gradient is wanted (``flash_dbias``
 writes a head's), go a block a head.
 
+Operands in place (PR 38): where the kernels' operands lie in HBM.  A
+Mosaic custom call takes its operands as they lie, and a program that
+hands the op ``[B, H, S, D]`` spells ``fc -> reshape -> transpose`` before
+it and the reverse after it: XLA runs each as a copy that writes 64
+numbers on 128 lanes (twice the bytes), keeps the split Q, K and V for the
+backward, and does the same for dO, dQ, dK and dV: 13.3 of the flash cell's
+140 ms and 1.8 GB of its reserve (PERF.md section 6, PR 38).  An op with
+``num_heads`` takes Q ``[B, S_q, H * D]``, K and V ``[B, S_kv, H * D]`` as
+the projections' matmuls left them.  Where a head is one tile in forward
+and backward (``_fused_backward``: a cell owns whole rows, nothing is
+summed across cells) and the heads pack whole into 128 lanes
+(``_in_place``), ``flash_fwd`` and ``flash_bwd`` run over those arrays:
+grid ``(B, H * D // 128)``, blocks ``(1, S, 128)`` at ``(b, 0, g)`` for Q,
+K, V, dO, O, dQ, dK and dV (``_cell_specs``).  A block's minor dimension
+must be a multiple of 128 or the whole array's, and 64 of 768 is neither,
+so a cell takes a PAIR of heads of 64 (one of 128, four of 32) and runs the
+one-tile body on each head's lanes in turn (``_lanes``, a static slice at
+lane 64 that Mosaic takes as written), storing into its lanes, so every
+output leaves as full rows; the mask ``[B, S_q, S_kv]`` is the cell's one
+block at row ``b`` and the statistics stay ``[B * H, 1, S_q]``, a row a
+head.  Every other op in that layout (dropout, a sequence-parallel mesh,
+several tiles a head, heads that do not pack, grouped heads, a rotary
+pair, a wanted bias gradient) is split to ``[B, H, S, D]`` inside the
+lowering and takes the path it took.
+
 Latent attention (PR 28): V's head size may differ from Q's and K's, and
 a head may have a second, rotary part whose keys are ONE head shared by
 the sequence's heads (``rope``: the kernels add ``qr kr^T`` to the scores
@@ -316,12 +341,15 @@ def _rows(block, size):
     return pl.ds(pl.multiple_of(block * size, size), size)
 
 
-def _add_bias(s, bias_ref, rows, row_len, cols, col_len):
-    """Scores plus the bias tile at static offsets, widened to float32."""
+def _add_bias(s, bias_ref, rows, row_len, cols, col_len, h=0):
+    """Scores plus the bias tile at static offsets, widened to float32.
+    ``h``: the head, of those a cell holds (``_lanes``), whose tile it is;
+    a block of one tile serves them all (a mask the heads of a sequence
+    share)."""
     if bias_ref is None:
         return s
-    return s + bias_ref[0, rows:rows + row_len, cols:cols + col_len] \
-        .astype(jnp.float32)
+    return s + bias_ref[min(h, bias_ref.shape[0] - 1), rows:rows + row_len,
+                        cols:cols + col_len].astype(jnp.float32)
 
 
 def _causal_mask(s, q0, k0):
@@ -517,7 +545,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
-                dq_ref, dk_ref, dv_ref, delta_out_ref, *, scale, causal=False):
+                dq_ref, dk_ref, dv_ref, delta_out_ref, *, scale, causal=False,
+                heads=1):
     """The whole backward of a head that is ONE tile (``_fused_backward``):
     S, P, dP and dS are formed once and feed all three gradients, where
     the dQ and dK/dV passes each rebuild them.  The same five products in
@@ -527,27 +556,73 @@ def _bwd_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
     delta comes from ``delta_ref`` where the caller passed it (it MUST pass
     it where K/V are a shard of the row), else it is the row sum of P * dP
     over the tile, which is the whole row; ``delta_out_ref`` takes it for
-    the dbias pass."""
-    q, ks, vs = q_ref[0], k_ref[0], v_ref[0]       # [S_q, D], [S_kv, D | D_v]
-    do = do_ref[0].astype(q.dtype)                 # [S_q, D_v]
-    s = _add_bias(_scores(q, ks, scale), bias_ref, 0, q.shape[0], 0,
-                  ks.shape[0])
-    if causal:
-        s = _causal_mask(s, 0, 0)
-    p = jnp.exp(s - _stat_column(lse_ref))
-    dp = jnp.dot(do, vs.T, preferred_element_type=jnp.float32)
-    delta = (p * dp).sum(axis=-1, keepdims=True) if delta_ref is None \
-        else _stat_column(delta_ref)
-    if delta_out_ref is not None:
-        _stat_store(delta_out_ref, delta)
-    ds = (p * (dp - delta) * scale).astype(q.dtype)
-    dq_ref[0] = jnp.dot(ds, ks, preferred_element_type=jnp.float32) \
-        .astype(dq_ref.dtype)
-    dk_ref[0] = jnp.dot(ds.T, q, preferred_element_type=jnp.float32) \
-        .astype(dk_ref.dtype)
-    dv_ref[0] = jnp.dot(p.astype(q.dtype).T, do,
-                        preferred_element_type=jnp.float32) \
-        .astype(dv_ref.dtype)
+    the dbias pass.
+
+    ``heads``: the heads a cell holds side by side on its blocks' lanes
+    (``_in_place``: operands ``[B, S, H * D]``, a block 128 lanes wide);
+    each runs this body on its lanes and stores into its lanes."""
+    for h in range(heads):
+        q, ks, vs = (_lanes(ref, h, heads)         # [S_q, D], [S_kv, D | D_v]
+                     for ref in (q_ref, k_ref, v_ref))
+        do = _lanes(do_ref, h, heads).astype(q.dtype)          # [S_q, D_v]
+        s = _add_bias(_scores(q, ks, scale), bias_ref, 0, q.shape[0], 0,
+                      ks.shape[0], h)
+        if causal:
+            s = _causal_mask(s, 0, 0)
+        p = jnp.exp(s - _stat_column(lse_ref, h))
+        dp = jnp.dot(do, vs.T, preferred_element_type=jnp.float32)
+        delta = (p * dp).sum(axis=-1, keepdims=True) if delta_ref is None \
+            else _stat_column(delta_ref, h)
+        if delta_out_ref is not None:
+            _stat_store(delta_out_ref, delta, h)
+        ds = (p * (dp - delta) * scale).astype(q.dtype)
+        _store_lanes(dq_ref, h, heads,
+                     jnp.dot(ds, ks, preferred_element_type=jnp.float32))
+        _store_lanes(dk_ref, h, heads,
+                     jnp.dot(ds.T, q, preferred_element_type=jnp.float32))
+        _store_lanes(dv_ref, h, heads,
+                     jnp.dot(p.astype(q.dtype).T, do,
+                             preferred_element_type=jnp.float32))
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *, scale,
+                causal=False, heads=1):
+    """The forward of heads that are ONE tile each and lie side by side on
+    their blocks' lanes (``_in_place``): what ``_attention_kernel`` does
+    at one tile, a plain softmax with nothing to rescale, on each head's
+    lanes in turn, so the output leaves as full rows."""
+    for h in range(heads):
+        q = _lanes(q_ref, h, heads)
+        s = _add_bias(_scores(q, _lanes(k_ref, h, heads), scale), bias_ref,
+                      0, q.shape[0], 0, k_ref.shape[1], h)
+        if causal:
+            s = _causal_mask(s, 0, 0)
+        m, l, acc = _online_softmax(None, s, _lanes(v_ref, h, heads),
+                                    q.dtype)
+        l = jnp.maximum(l, 1e-30)
+        _store_lanes(o_ref, h, heads, acc / l)
+        if lse_ref is not None:
+            _stat_store(lse_ref, m + jnp.log(l), h)
+
+
+def _lanes(ref, h, heads):
+    """Head ``h`` of a block ``[1, S, heads * D]`` whose lanes hold
+    ``heads`` heads side by side: ``[S, D]``.  One head a block is the
+    block."""
+    if heads == 1:
+        return ref[0]
+    D = ref.shape[2] // heads
+    return ref[0, :, h * D:(h + 1) * D]
+
+
+def _store_lanes(ref, h, heads, value):
+    """Write head ``h``'s ``[S, D]`` result into its lanes of the block
+    (``_lanes``), in the block's dtype."""
+    if heads == 1:
+        ref[0] = value.astype(ref.dtype)
+    else:
+        D = ref.shape[2] // heads
+        ref[0, :, h * D:(h + 1) * D] = value.astype(ref.dtype)
 
 
 def _dbias_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
@@ -635,18 +710,19 @@ def _kernel_stat(stat, rows):
     return stat[:, None] if rows else stat[..., None]
 
 
-def _stat_column(ref):
+def _stat_column(ref, h=0):
     """A statistic's block as the float32 column ``[bq, 1]`` a ``[bq,
     bk]`` score tile broadcasts against: read as it lies from a column
-    block ``[1, bq, 1]``; a row block ``[1, 1, bq]`` is turned once a tile,
-    one number a row against the tile's ``bq x bk`` scores."""
-    return ref[0].T if ref.shape[1] == 1 else ref[0]
+    block ``[1, bq, 1]``; a row block ``[heads, 1, bq]`` (head ``h``'s row)
+    is turned once a tile, one number a row against the tile's ``bq x bk``
+    scores."""
+    return ref[h].T if ref.shape[1] == 1 else ref[h]
 
 
-def _stat_store(ref, column):
+def _stat_store(ref, column, h=0):
     """Write a ``[bq, 1]`` column the kernel formed to its statistic's
-    block, turned to a row where the block is one."""
-    ref[0] = column.T if ref.shape[1] == 1 else column
+    block, turned to a row where the block is one (head ``h``'s)."""
+    ref[h] = column.T if ref.shape[1] == 1 else column
 
 
 # Tile sides the chooser tries, largest first.  128 is the least the
@@ -726,7 +802,7 @@ def _whole_side(kernel, S_q, S_kv, D, D_v, R, itemsize):
 
 
 def _vmem_bytes(kernel, block_q, block_k, S_q, S_kv, D, D_v, R, has_bias,
-                causal, itemsize, group=1, rows=False):
+                causal, itemsize, group=1, rows=False, heads=1):
     """VMEM one grid cell of ``kernel`` ('fwd', 'dq', 'dkv', 'bwd' or
     'dbias') asks for at tiles of ``block_q x block_k``, from shapes alone:
     what the chooser holds against ``_VMEM_BUDGET_BYTES`` and
@@ -737,7 +813,11 @@ def _vmem_bytes(kernel, block_q, block_k, S_q, S_kv, D, D_v, R, has_bias,
     turned to a ``[block_q, 1]`` column as a value (``_stat_column``).  The
     chooser counts columns, the larger count, because ``_row_stats`` is
     read off the chooser's own answer; ``_plan`` counts what the call
-    really holds.
+    really holds.  With ``heads`` ('fwd' and 'bwd' reading ``[B, S, H *
+    D]`` in place, ``_in_place``) a cell's blocks hold that many heads
+    side by side: every block of Q, K, V, dO and the outputs is ``heads``
+    times as wide and each statistic has a row a head, while the score
+    tiles below stay one head's, the heads taking their turns.
 
     * the cell's own blocks, in and out, twice (the pipeline's two
       buffers): ``block_q`` rows of Q, its rotary part, dO, the row
@@ -759,7 +839,8 @@ def _vmem_bytes(kernel, block_q, block_k, S_q, S_kv, D, D_v, R, has_bias,
     * the dQ pass's two ``[block_q, S_kv]`` float32 scratches, where it
       forms delta over more than one tile (``_delta_in_kernel``)."""
     def stat(b):
-        return (1, b, 4) if rows else (b, 1, 4)
+        return (heads, b, 4) if rows else (b, 1, 4)
+    D, D_v = D * heads, D_v * heads
     if kernel == "bwd":
         own = [(block_q, D, itemsize), (block_q, D_v, itemsize),   # Q, dO
                stat(block_q), stat(block_q),                       # lse, delta
@@ -868,12 +949,86 @@ def _fused_backward(S_q, S_kv, D, D_v, R, has_bias, causal, itemsize,
     head (dK and dV are then sums over the group: ``_flash_dkv``) and the
     kernel's own estimate fits the budget.  S <= 512 at the BERT widths,
     bias or none, causal or not (the mask on the one tile: there is no
-    diagonal to skip)."""
+    diagonal to skip).  These are also the shapes whose kernels can read
+    ``[B, S, H * D]`` operands in place (``_in_place``): a cell that owns
+    its heads' whole rows may as well own several heads' lanes."""
     shape = (S_q, S_kv, D, D_v, R, has_bias, causal, itemsize)
     return not R and group == 1 and \
         all(_tiles(kernel, *shape) == (True, S_q, S_kv)
             for kernel in ("dq", "dkv")) and \
         _vmem_bytes("bwd", S_q, S_kv, *shape) <= _VMEM_BUDGET_BYTES
+
+
+# The lanes of a vector register, and the least a block's minor dimension
+# may be short of the whole array's.
+_LANES = 128
+
+
+def _in_place(heads, *shape):
+    """Whether the kernels at this shape (``_shape_key``) read Q, K, V and
+    dO as ``[B, S, heads * D]``, where a projection's matmul left them, and
+    write O, dQ, dK and dV the same way, where the next matmul reads them:
+    no head split before the kernels and no merge after them, which XLA
+    runs as copies that pad 64 numbers to 128 lanes and keeps for the
+    backward (PERF.md section 6, PR 38).  Where a head is one tile in
+    forward and backward (``_fused_backward``: a cell owns its heads'
+    whole rows, so nothing is summed across cells) and the heads pack
+    whole into a block of 128 lanes: a block's minor dimension must be a
+    multiple of 128 or the array's own, and 64 of ``H * 64`` is neither,
+    so a grid cell takes ``128 // D`` heads, a PAIR at D = 64, one at D =
+    128, and runs them in turn on their lanes (``_lanes``).  V's head
+    size must be Q's (one block index serves every operand), and the
+    wider blocks must fit the budget."""
+    S_q, S_kv, D, D_v = shape[:4]
+    if not _fused_backward(*shape) or D != D_v or _LANES % D or \
+            heads % (_LANES // D):
+        return False
+    return all(_vmem_bytes(kernel, S_q, S_kv, *shape, rows=True,
+                           heads=_LANES // D) <= _VMEM_BUDGET_BYTES
+               for kernel in ("fwd", "bwd"))
+
+
+def _in_place_shape(q, k, bias, causal, heads):
+    """``_shape_key`` of operands ``[B, S, heads * D]``."""
+    D = q.shape[2] // heads
+    return (q.shape[1], k.shape[1], D, D, 0, bias is not None, bool(causal),
+            q.dtype.itemsize, 1)
+
+
+def _cell_specs(q, k, bias, heads):
+    """``(grid, heads a cell, rows, stat, bias spec)`` of a kernel whose
+    one tile is the head (``flash_bwd``, and ``flash_fwd`` in place):
+    ``rows(n, d)`` the BlockSpec of the cell's ``n`` rows of an operand
+    whose heads are ``d`` wide, ``stat`` that of its row statistics ``[BH,
+    1, S_q]``.
+
+    ``heads`` None: operands ``[BH, S, D]``, grid ``(BH,)``, a head a
+    cell.  Else ``[B, S, heads * D]`` read in place (``_in_place``): grid
+    ``(B, heads * D // 128)``, blocks ``(1, S, 128)`` at ``(b, 0, g)``, the
+    statistics of the cell's ``128 // D`` heads ``128 // D`` consecutive
+    rows, a bias of ``[B, S_q, S_kv]`` the one tile of sequence ``b`` for
+    all of them and one of ``[BH, S_q, S_kv]`` a tile a head."""
+    S_q, S_kv = q.shape[1], k.shape[1]
+    bias_spec = None
+    if heads is None:
+        if bias is not None:
+            row = _bias_row(q, bias)
+            bias_spec = pl.BlockSpec((1, S_q, S_kv), lambda i: (row(i), 0, 0))
+        return ((q.shape[0],), 1,
+                lambda n, d: pl.BlockSpec((1, n, d), lambda i: (i, 0, 0)),
+                pl.BlockSpec((1, 1, S_q), lambda i: (i, 0, 0)), bias_spec)
+    pack = _LANES // (q.shape[2] // heads)
+    cells = heads // pack
+    if bias is not None and bias.shape[0] == q.shape[0]:
+        bias_spec = pl.BlockSpec((1, S_q, S_kv), lambda b, g: (b, 0, 0))
+    elif bias is not None:
+        bias_spec = pl.BlockSpec((pack, S_q, S_kv),
+                                 lambda b, g: (b * cells + g, 0, 0))
+    return ((q.shape[0], cells), pack,
+            lambda n, d: pl.BlockSpec((1, n, d * pack),
+                                      lambda b, g: (b, 0, g)),
+            pl.BlockSpec((pack, 1, S_q), lambda b, g: (b * cells + g, 0, 0)),
+            bias_spec)
 
 
 def _shape_key(q, k, v, bias, causal, rope):
@@ -892,23 +1047,28 @@ def _flash_fits(*shape):
                for kernel in ("fwd", "dq", "dkv") + ("dbias",) * shape[5])
 
 
-def _plan(kernel, q, bias, *shape):
+def _plan(kernel, q, bias, *shape, heads=None):
     """One kernel call's ``(block_q, block_k, keywords for the whole-
     sequence BlockSpecs, vmem_limit_bytes)`` at this shape
     (``_shape_key``), counted in ``flash_tiles_total`` with the layout of
-    its row statistics (``_row_stats``) and which block row its bias is
-    read at (``_bias_row``)."""
+    its operands (``heads``: the H of ``[B, S, H * D]`` operands read in
+    place, ``_in_place``; None for ``[BH, S, D]``), of its row statistics
+    (``_row_stats``) and which block row its bias is read at
+    (``_bias_row``)."""
     _, block_q, block_k = _tiles(kernel, *shape)
     rows = _row_stats(kernel, *shape)
     _m_tiles.inc(kernel=kernel, block_q=block_q, block_k=block_k,
                  stats="row" if rows else "column",
                  bias="none" if bias is None
-                 else "head" if bias.shape[0] == q.shape[0] else "sequence")
+                 else "head" if bias.shape[0] == q.shape[0] * (heads or 1)
+                 else "sequence",
+                 layout="bhsd" if heads is None else "bshd")
     S_q, S_kv, D, D_v, R, _, _, itemsize = shape[:8]
     return (block_q, block_k,
             _whole_seq(_whole_side(kernel, S_q, S_kv, D, D_v, R, itemsize)),
-            _vmem_limit(_vmem_bytes(kernel, block_q, block_k, *shape,
-                                    rows=rows)))
+            _vmem_limit(_vmem_bytes(
+                kernel, block_q, block_k, *shape, rows=rows,
+                heads=1 if heads is None else _LANES // D)))
 
 
 _m_tiles = telemetry.counter(
@@ -921,7 +1081,11 @@ _m_tiles = telemetry.counter(
     "'dbias' where a head is one tile; 'column': [BH, S_q, 1], every "
     "multi-pass call, and 'dq' and 'dkv' always); bias: 'sequence' where "
     "one [S_q, S_kv] bias is read by every head of a sequence at block row "
-    "i // H, 'head' where each head has its own, 'none'")
+    "i // H, 'head' where each head has its own, 'none'; layout: 'bhsd' "
+    "where the operands are [BH, S, D], a head a cell, 'bshd' where 'fwd' "
+    "and 'bwd' read Q, K, V and dO as [B, S, H * D] where the projections "
+    "left them and write O, dQ, dK and dV the same way, 128 // D heads a "
+    "cell")
 
 
 def _rope_specs(rope, q_block, k_block, whole_mode=None):
@@ -1244,39 +1408,42 @@ def _flash_dkv(q, k, v, bias, scale, lse, g, causal, delta, rope=None):
 
 
 def _flash_bwd(q, k, v, bias, scale, lse, g, causal, delta,
-               delta_out=False):
+               delta_out=False, heads=None):
     """The fused backward (``_bwd_kernel``; where ``_fused_backward`` says a
     head is one tile): ``(dq, dk, dv, delta)`` from one call, a grid cell a
     head.  ``lse`` and delta are rows, ``[BH, 1, S_q]`` (``_row_stats``).
     A passed delta is used and comes back as it went in; with
     ``delta=None`` the kernel forms it, and writes it out only where
-    ``delta_out`` asks (the dbias pass reads it), else None comes back."""
-    BH, S_q, D = q.shape
-    S_kv = k.shape[1]
-    D_v = v.shape[2]
-    vmem = _plan("bwd", q, bias,
-                 *_shape_key(q, k, v, bias, causal, None))[3]
-    delta_out = delta_out and delta is None
+    ``delta_out`` asks (the dbias pass reads it), else None comes back.
 
-    def rows(n, d, row=lambda i: i):
-        return pl.BlockSpec((1, n, d), lambda i: (row(i), 0, 0))
+    With ``heads`` (``_in_place``) Q, K, V and dO are ``[B, S, heads * D]``
+    and so are dQ, dK and dV; a cell runs ``128 // D`` heads
+    (``_cell_specs``)."""
+    S_q, S_kv = q.shape[1], k.shape[1]
+    D, D_v = (q.shape[2], v.shape[2]) if heads is None \
+        else (q.shape[2] // heads,) * 2
+    shape = _shape_key(q, k, v, bias, causal, None) if heads is None \
+        else _in_place_shape(q, k, bias, causal, heads)
+    vmem = _plan("bwd", q, bias, *shape, heads=heads)[3]
+    delta_out = delta_out and delta is None
+    grid, pack, rows, stat, bias_spec = _cell_specs(q, k, bias, heads)
     in_specs = [rows(S_q, D), rows(S_kv, D), rows(S_kv, D_v)]
     args = [q, k, v]
     if bias is not None:
-        in_specs.append(rows(S_q, S_kv, _bias_row(q, bias)))
+        in_specs.append(bias_spec)
         args.append(bias)
-    in_specs += [rows(S_q, D_v), rows(1, S_q)]          # dO, lse
+    in_specs += [rows(S_q, D_v), stat]                  # dO, lse
     args += [g, lse]
     if delta is not None:
-        in_specs.append(rows(1, S_q))
+        in_specs.append(stat)
         args.append(delta)
     out_specs = [rows(S_q, D), rows(S_kv, D), rows(S_kv, D_v)]
     out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype),
                  jax.ShapeDtypeStruct(k.shape, k.dtype),
                  jax.ShapeDtypeStruct(v.shape, v.dtype)]
     if delta_out:
-        out_specs.append(rows(1, S_q))
-        out_shape.append(jax.ShapeDtypeStruct((BH, 1, S_q), jnp.float32))
+        out_specs.append(stat)
+        out_shape.append(jax.ShapeDtypeStruct(lse.shape, jnp.float32))
 
     def kern(q_ref, k_ref, v_ref, *refs):
         refs = list(refs)
@@ -1285,16 +1452,59 @@ def _flash_bwd(q, k, v, bias, scale, lse, g, causal, delta,
         delta_ref = None if delta is None else refs.pop(0)
         _bwd_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                     delta_ref, *refs[:3], refs[3] if delta_out else None,
-                    scale=scale, causal=causal)
+                    scale=scale, causal=causal, heads=pack)
 
     dq, dk, dv, *formed = _pallas_call(
         kern, "flash_bwd", vmem_limit_bytes=vmem,
-        grid=(BH,),
+        grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
     )(*args)
     return dq, dk, dv, (formed[0] if delta_out else delta)
+
+
+def _flash_fwd_in_place(q, k, v, bias, scale, heads, causal=False,
+                        with_lse=True):
+    """``flash_fwd`` on operands as the projections left them
+    (``_in_place``): q ``[B, S_q, heads * D]``, k and v ``[B, S_kv, heads *
+    D]``, bias ``[B, S_q, S_kv]`` (the heads of a sequence share it),
+    ``[B * heads, S_q, S_kv]`` or None -> ``(out [B, S_q, heads * D],
+    logsumexp [B * heads, S_q] float32 or None)``: what ``_flash_forward``
+    gives for the same heads as ``[B * heads, S, D]``, with no copy of any
+    of them."""
+    B, S_q, _ = q.shape
+    D = q.shape[2] // heads
+    vmem = _plan("fwd", q, bias,
+                 *_in_place_shape(q, k, bias, causal, heads), heads=heads)[3]
+    grid, pack, rows, stat, bias_spec = _cell_specs(q, k, bias, heads)
+    in_specs = [rows(S_q, D), rows(k.shape[1], D), rows(k.shape[1], D)]
+    args = [q, k, v]
+    if bias is not None:
+        in_specs.append(bias_spec)
+        args.append(bias)
+
+    def kern(q_ref, k_ref, v_ref, *refs):
+        refs = list(refs)
+        bias_ref = refs.pop(0) if bias is not None else None
+        _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, refs[0],
+                    refs[1] if with_lse else None, scale=scale,
+                    causal=causal, heads=pack)
+
+    out_specs = [rows(S_q, D)]
+    out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype)]
+    if with_lse:
+        out_specs.append(stat)
+        out_shape.append(jax.ShapeDtypeStruct((B * heads, 1, S_q),
+                                              jnp.float32))
+    res = _pallas_call(
+        kern, "flash_fwd", vmem_limit_bytes=vmem,
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+    )(*args)
+    return res[0], (res[1][:, 0] if with_lse else None)
 
 
 def _flash_backward(q, k, v, bias, scale, lse, g, causal=False, delta=None,
@@ -1455,6 +1665,61 @@ def _fal_bwd(scale, causal, res, gs):
 
 
 flash_attention_lse.defvjp(_fal_fwd, _fal_bwd)
+
+
+def _heads_major(x, heads):
+    """``[B, S, heads * D]`` -> ``[B, heads, S, D]``: the head split a
+    program used to spell with ``reshape2`` and ``transpose2``."""
+    B, S, width = x.shape
+    return x.reshape(B, S, heads, width // heads).transpose(0, 2, 1, 3)
+
+
+def _heads_minor(x):
+    """``[B, H, S, D]`` -> ``[B, S, H * D]``: the merge after attention."""
+    B, H, S, D = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(B, S, H * D)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def flash_attention_in_place(q, k, v, bias, scale, causal, heads,
+                             with_lse=True):
+    """``flash_attention_lse`` on operands ``[B, S, heads * D]`` at a shape
+    the kernels read in place (``_in_place``): ``(out [B, S_q, heads * D],
+    logsumexp [B * heads, S_q] or None)``.  The statistic is a residual,
+    not a differentiable output."""
+    return _flash_fwd_in_place(q, k, v, bias, scale, heads, causal, with_lse)
+
+
+def _fip_fwd(q, k, v, bias, scale, causal, heads, with_lse):
+    out, lse = _flash_fwd_in_place(q, k, v, bias, scale, heads, causal)
+    return (out, lse if with_lse else None), (q, k, v, bias, lse)
+
+
+def _fip_bwd(scale, causal, heads, with_lse, res, gs):
+    q, k, v, bias, lse = res
+    if bias is None:
+        return _backward_in_place(q, k, v, None, scale, causal, heads, lse,
+                                  gs[0]) + (None,)
+    # a bias under jax's own differentiation may want its gradient, and
+    # the dbias pass writes a head's ``[BH, S_q, S_kv]``: the heads are
+    # split for it, and XLA drops what nothing reads
+    q, k, v, g = (_flat(_heads_major(x, heads)) for x in (q, k, v, gs[0]))
+    dq, dk, dv, dbias = _backward_from_lse(q, k, v, bias, scale, causal, lse,
+                                           None, g)
+    return tuple(_heads_minor(d.reshape(-1, heads, *d.shape[1:]))
+                 for d in (dq, dk, dv)) + (dbias,)
+
+
+flash_attention_in_place.defvjp(_fip_fwd, _fip_bwd)
+
+
+def _backward_in_place(q, k, v, bias, scale, causal, heads, lse, g):
+    """``(dq, dk, dv)`` as ``[B, S, heads * D]`` from operands and ``g`` in
+    that layout and the forward's logsumexp ``[B * heads, S_q]``: ONE
+    ``flash_bwd`` call that forms delta itself (the one tile is the whole
+    row), for a bias whose gradient nobody wants."""
+    return _flash_bwd(q, k, v, bias, scale, lse[:, None], g.astype(q.dtype),
+                      causal, None, heads=heads)[:3]
 
 
 def _sp_attention(q, k, v, mesh, axis, mode, scale, causal, bias=None):
@@ -1624,7 +1889,7 @@ def _is_test(ctx):
     return bool(ctx.attr("is_test", False) or ctx.state.is_test)
 
 
-def _attention_route(ctx, q, k, v):
+def _attention_route(ctx, q, k, v, qr=None):
     """Which path a ``fused_attention`` op — or its grad op, which
     carries the same attributes — takes, from what it can observe:
     ``(sp_active, dropout, flash)``.  ``sp_active``: the sequence-parallel
@@ -1633,9 +1898,10 @@ def _attention_route(ctx, q, k, v):
     ``flash``: neither, and every kernel has a tile at the shape
     (``_flash_fits``), so the Pallas kernels run on the op's operands (Q,
     K, V ``[B, H | H_kv, S, D]``) as they are, grouped key/value heads
-    included (``_kv_row``).  A rotary pair is among them only under
-    the causal mask and without a bias (``_rope_runs_looped``); any other
-    op with a pair composes one head size first."""
+    included (``_kv_row``).  A rotary pair (``qr``: the op's ``QRope`` as
+    ``[B, H, S_q, R]``) is among them only under the causal mask and
+    without a bias (``_rope_runs_looped``); any other op with a pair
+    composes one head size first."""
     dropout = 0.0 if _is_test(ctx) else \
         float(ctx.attr("attn_dropout", 0.0) or 0.0)
     sp_axis = ctx.attr("sp_axis", None)
@@ -1646,7 +1912,6 @@ def _attention_route(ctx, q, k, v):
     sp_active = sp > 1 and S_q % sp == 0 and _axis_is_auto(mesh, sp_axis)
     causal, has_bias = bool(ctx.attr("causal", False)), \
         ctx.has_input("BiasQK")
-    qr = ctx.i_opt("QRope")
     flash = not sp_active and not dropout and \
         not (qr is not None and (has_bias or not causal)) and \
         _flash_fits(S_q, k.shape[2], q.shape[3], v.shape[3],
@@ -1711,6 +1976,19 @@ def _fused_attention(ctx, op):
     [B, H, S_kv, D] (cross-attention supported; + optional additive
     BiasQK [B, 1|H, S_q, S_kv]) → Out [B, H, S_q, D].
 
+    With the attribute ``num_heads`` = H the heads lie in the minor
+    dimension: Q [B, S_q, H * D], K [B, S_kv, H_kv * D], V [B, S_kv, H_kv *
+    D_v] (QRope [B, S_q, H * R], KRope [B, S_kv, R]) → Out [B, S_q, H *
+    D_v]; BiasQK and ``LSE`` are what they are without it.  Where
+    ``_op_in_place`` says so (the flash route, a head one tile, H_kv = H,
+    D_v = D, ``128 // D`` heads to a block, no rotary pair) the kernels
+    read and write those arrays as they lie (``flash_attention_in_place``);
+    every other such op is the 4-D op below between a head split and a
+    merge, traced here and outside any checkpoint, which is the graph the
+    program used to spell with ``transpose2`` ops.  The rule is read from
+    the operands and the attribute alone; an op without ``num_heads``
+    traces what it traced.
+
     When the sequence-parallel transpiler stamped this op (``sp_axis``
     attr) and the step compiles over a mesh carrying that axis, the
     equal-length dropout-free path (with or without an additive
@@ -1748,6 +2026,27 @@ def _fused_attention(ctx, op):
     qr, kr = ctx.i_opt("QRope"), ctx.i_opt("KRope")
     scale = ctx.attr("scale", 1.0)
     causal = bool(ctx.attr("causal", False))
+    heads = int(ctx.attr("num_heads", 0) or 0)
+    if heads:
+        if _op_in_place(ctx, q, k, v, heads):
+            _m_lowered.inc(shape="mha", path="flash", layout="bshd")
+            B, S_q = q.shape[:2]
+            out, lse = flash_attention_in_place(
+                q, k, v, _kernel_bias(bias, _heads_major_shape(q, heads),
+                                      k.shape[1]),
+                float(scale), causal, heads,
+                bool(op.output("LSE")) and not _is_test(ctx))
+            if lse is not None:
+                ctx.set("LSE", lse.reshape(B, heads, S_q))
+            ctx.set("Out", out)
+            return
+        # every other op in this layout is the op on [B, H, S, D] between
+        # a head split and a merge, here and outside any checkpoint: the
+        # graph the program used to spell with transpose2 ops
+        q, k, v, qr, kr = _op_heads_major(q, k, v, qr, kr, heads)
+
+    def put(out):
+        ctx.set("Out", _heads_minor(out) if heads else out)
     B, H, S_q, D = q.shape
     S_kv = k.shape[2]
     if causal and S_q != S_kv:
@@ -1761,13 +2060,13 @@ def _fused_attention(ctx, op):
             "ambiguous; pass an explicit additive bias instead"
             % (S_q, S_kv))
     group = _kv_group(q, k, v, qr)
-    sp_active, dropout, flash = _attention_route(ctx, q, k, v)
+    sp_active, dropout, flash = _attention_route(ctx, q, k, v, qr)
     sp_axis = ctx.attr("sp_axis", None)
     mesh = getattr(ctx.state, "mesh", None)
     _m_lowered.inc(shape="mla" if qr is not None
                    else "gqa" if group > 1 else "mha",
                    path="sequence_parallel" if sp_active
-                   else "flash" if flash else "composition")
+                   else "flash" if flash else "composition", layout="bhsd")
     if group > 1 and not flash:
         k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     rope = None
@@ -1784,25 +2083,22 @@ def _fused_attention(ctx, op):
     if sp_active and (S_q != S_kv or dropout):
         # cross-attention and/or attention dropout: q rows stay sharded,
         # kv all-gathered in-island (VERDICT r4 item 6a/6b)
-        out = _sp_gather_attention(q, k, v, mesh, sp_axis, float(scale),
-                                   causal, _norm_bias(bias, q, S_kv),
-                                   dropout, ctx.rng() if dropout else None)
-        ctx.set("Out", out)
+        put(_sp_gather_attention(q, k, v, mesh, sp_axis, float(scale),
+                                 causal, _norm_bias(bias, q, S_kv),
+                                 dropout, ctx.rng() if dropout else None))
         return
     if sp_active:
-        out = _sp_attention(q, k, v, mesh, sp_axis,
-                            ctx.attr("sp_mode", "ring"), float(scale),
-                            causal, bias=_norm_bias(bias, q, S_kv))
-        ctx.set("Out", out)
+        put(_sp_attention(q, k, v, mesh, sp_axis,
+                          ctx.attr("sp_mode", "ring"), float(scale),
+                          causal, bias=_norm_bias(bias, q, S_kv)))
         return
     if dropout:
         # probability dropout has no in-kernel flash story — exact
         # composition, per-op key (ctx.rng() already folds axis_env +
         # extra axes; replayed identically by the grad op: __op_seed__
         # rides the grad attrs)
-        out = _attn_core_remat(float(scale), causal, dropout)(
-            q, k, v, _norm_bias(bias, q, S_kv), 0, ctx.rng())
-        ctx.set("Out", out)
+        put(_attn_core_remat(float(scale), causal, dropout)(
+            q, k, v, _norm_bias(bias, q, S_kv), 0, ctx.rng()))
         return
     args = (_flat(q), _flat(k), _flat(v), _kernel_bias(bias, q, S_kv),
             float(scale), causal, rope)
@@ -1811,7 +2107,46 @@ def _fused_attention(ctx, op):
         ctx.set("LSE", lse.reshape(B, H, S_q))
     else:
         out = flash_attention(*args)
-    ctx.set("Out", out.reshape(B, H, S_q, v.shape[3]))
+    put(out.reshape(B, H, S_q, v.shape[3]))
+
+
+def _heads_major_shape(x, heads):
+    """The shape and dtype ``_heads_major`` would give, with nothing
+    traced."""
+    return jax.eval_shape(functools.partial(_heads_major, heads=heads), x)
+
+
+def _op_heads_major(q, k, v, qr, kr, heads):
+    """The operands of an op in the heads-minor layout (Q ``[B, S_q, H *
+    D]``, K ``[B, S_kv, H_kv * D]``, V ``[B, S_kv, H_kv * D_v]``, a rotary
+    pair ``[B, S_q, H * R]`` / ``[B, S_kv, R]``) as ``[B, H | H_kv | 1, S,
+    D]``.  The key/value heads are as many as K is wide in heads of Q's
+    size."""
+    D = q.shape[2] // heads
+    if D * heads != q.shape[2] or k.shape[2] % D:
+        raise ValueError(
+            "fused_attention: Q %s and K %s are not whole heads at "
+            "num_heads=%d" % (q.shape, k.shape, heads))
+    heads_kv = k.shape[2] // D
+    return (_heads_major(q, heads), _heads_major(k, heads_kv),
+            _heads_major(v, heads_kv),
+            None if qr is None else _heads_major(qr, heads),
+            None if kr is None else kr[:, None])
+
+
+def _op_in_place(ctx, q, k, v, heads):
+    """Whether an op (or its grad op) whose operands are ``[B, S, heads *
+    D]`` runs the kernels on them as they lie: the flash route of
+    ``_attention_route`` (no sequence-parallel mesh, no dropout) at a
+    shape ``_in_place`` takes, with as many key/value heads as query heads
+    and no rotary pair."""
+    if ctx.has_input("QRope") or k.shape[2] != q.shape[2] or \
+            v.shape[2] != q.shape[2] or q.shape[2] % heads:
+        return False
+    major = [_heads_major_shape(x, heads) for x in (q, k, v)]
+    return _attention_route(ctx, *major)[2] and _in_place(
+        heads, *_in_place_shape(q, k, ctx.i_opt("BiasQK"),
+                                ctx.attr("causal", False), heads))
 
 
 _m_lowered = telemetry.counter(
@@ -1820,7 +2155,10 @@ _m_lowered = telemetry.counter(
     "counts again), by shape ('mla': with a rotary pair, 'gqa': fewer "
     "key/value heads than query heads, 'mha': neither) "
     "and path ('flash': the Pallas kernels, 'composition': XLA, "
-    "'sequence_parallel': a shard_map island)")
+    "'sequence_parallel': a shard_map island) and layout ('bshd': an op "
+    "with num_heads whose [B, S, H * D] operands the kernels read in "
+    "place, 128 // D heads a cell; 'bhsd': [B, H, S, D] operands, the "
+    "op's own or split from [B, S, H * D] inside the lowering)")
 
 _m_grad_lowered = telemetry.counter(
     "fused_attention_grad_lowered_total",
@@ -1842,24 +2180,53 @@ def _fused_attention_grad(ctx, op):
     op's (it folds a replay of plain HLO, never a custom call).
     Everywhere else — sequence-parallel islands, the dropout composition,
     non-tileable shapes, a program built without the ``LSE`` slot — the
-    replay stands."""
+    replay stands.
+
+    An op with ``num_heads`` (operands ``[B, S, H * D]``,
+    ``_fused_attention``) whose shape the kernels read in place runs ONE
+    ``flash_bwd`` on them as they lie and writes dQ, dK and dV the same
+    way, unless the bias wants its gradient (the dbias pass writes a
+    head's ``[S_q, S_kv]`` from split heads); every other one is split,
+    takes the path below and is merged."""
     from ..lowering import generic_grad_lower
 
     q, k, v = ctx.i("Q"), ctx.i("K"), ctx.i("V")
     lse, g = ctx.i_opt("LSE"), ctx.i_opt("Out@GRAD")
+    qr, kr = ctx.i_opt("QRope"), ctx.i_opt("KRope")
+    out = ctx.i_opt("Out")
+    want = {slot: (op.output(slot + "@GRAD") or [""])[0]
+            for slot in ("Q", "K", "V", "BiasQK", "QRope", "KRope")}
+    heads = int(ctx.attr("num_heads", 0) or 0)
+    if heads:
+        if lse is not None and g is not None and not want["BiasQK"] and \
+                _op_in_place(ctx, q, k, v, heads):
+            _m_grad_lowered.inc(path="residual", kv_sum="none")
+            grads = _backward_in_place(
+                q, k, v, _kernel_bias(ctx.i_opt("BiasQK"),
+                                      _heads_major_shape(q, heads),
+                                      k.shape[1]),
+                float(ctx.attr("scale", 1.0)),
+                bool(ctx.attr("causal", False)), heads, _flat(lse), g)
+            for slot, grad in zip(("Q", "K", "V"), grads):
+                if want[slot]:
+                    ctx.env[want[slot]] = grad
+            return
+        # the backward kernels on [B, H, S, D] between a split and a merge
+        # (``_fused_attention``); a replay below splits for itself
+        q, k, v, qr, kr = _op_heads_major(q, k, v, qr, kr, heads)
+        g, out = (x if x is None else _heads_major(x, heads)
+                  for x in (g, out))
     S_q, S_kv = q.shape[2], k.shape[2]
     grouped = q.shape[1] != k.shape[1]
-    if lse is None or g is None or not _attention_route(ctx, q, k, v)[2]:
+    if lse is None or g is None or \
+            not _attention_route(ctx, q, k, v, qr)[2]:
         _m_grad_lowered.inc(path="replay",
                             kv_sum="repeat" if grouped else "none")
         generic_grad_lower(ctx, op, residual_slots=("LSE",))
         return
     _m_grad_lowered.inc(path="residual",
                         kv_sum="partials" if grouped else "none")
-    want = {slot: (op.output(slot + "@GRAD") or [""])[0]
-            for slot in ("Q", "K", "V", "BiasQK", "QRope", "KRope")}
     bias = ctx.i_opt("BiasQK")
-    qr, kr = ctx.i_opt("QRope"), ctx.i_opt("KRope")
     rope = None if qr is None else \
         (_flat(qr), kr.reshape(kr.shape[0], S_kv, -1))
     if want["BiasQK"]:
@@ -1873,7 +2240,7 @@ def _fused_attention_grad(ctx, op):
         bool(ctx.attr("causal", False)), _flat(lse),
         None if _delta_in_kernel(S_kv, bool(ctx.attr("causal", False)),
                                  bias is not None)
-        else _flat(ctx.i("Out")),
+        else _flat(out),
         _flat(g.astype(q.dtype)), bias_grad=bool(want["BiasQK"]), rope=rope)
     grads = {}
     if rope is not None:
@@ -1882,6 +2249,9 @@ def _fused_attention_grad(ctx, op):
                  "KRope": dkr.reshape(kr.shape)}
     grads.update({"Q": dq.reshape(q.shape), "K": dk.reshape(k.shape),
                   "V": dv.reshape(v.shape)})
+    if heads:
+        grads = {slot: grad[:, 0] if slot == "KRope" else _heads_minor(grad)
+                 for slot, grad in grads.items()}
     if dbias is not None:
         grads["BiasQK"], = bias_vjp(dbias)
     for slot, name in want.items():

@@ -86,9 +86,13 @@ class SequenceParallelTranspiler:
                           (op.attrs.get("__fwd_inputs__") or {}).get("Q")
                           or [])
                 qv = blk._find_var_recursive(qnames[0]) if qnames else None
-                if qv is None or not qv.shape or len(qv.shape) != 4:
+                # heads in the minor dimension (``num_heads``): [B, S, H*D]
+                heads = op.attrs.get("num_heads")
+                if qv is None or not qv.shape or \
+                        len(qv.shape) != (3 if heads else 4):
                     continue
-                S, H = qv.shape[2], qv.shape[1]
+                S, H = (qv.shape[1], heads) if heads else \
+                    (qv.shape[2], qv.shape[1])
                 if S is None or S % sp:
                     raise ValueError(
                         "sequence length %s of attention input %r is not "
@@ -114,8 +118,9 @@ class SequenceParallelTranspiler:
                           (op.attrs.get("__fwd_inputs__") or {}).get("K")
                           or [])
                 kv = blk._find_var_recursive(knames[0]) if knames else None
-                if kv is not None and kv.shape and len(kv.shape) == 4:
-                    S_kv = kv.shape[2]
+                if kv is not None and kv.shape and \
+                        len(kv.shape) == (3 if heads else 4):
+                    S_kv = kv.shape[1 if heads else 2]
                     if S_kv and S_kv > 0 and S_kv % sp == 0:
                         seq_lens.add(S_kv)
                 bias_names.update(

@@ -103,8 +103,21 @@ def multi_head_attention(q_in, kv_in, attn_bias, cfg, cache=None,
 
     S_q_in = q_in.shape[1] if q_in.shape else None
     S_kv_in = kv_in.shape[1] if kv_in.shape else None
+    fused = getattr(cfg, "use_fused_attention", False)
+    if fused and cache is None:
+        # the op takes the projections' outputs as they lie, heads in the
+        # minor dimension, and hands the output projection its input: no
+        # head split and no merge in the program (where a head is one
+        # tile the flash kernels read and write in place, two heads of
+        # 64 a grid cell; every other shape splits inside the lowering)
+        ctxs = fluid.layers.fused_attention(
+            q, k, v, attn_bias, scale=1.0 / math.sqrt(d_head),
+            causal=causal, dropout_prob=float(cfg.attn_dropout or 0.0),
+            num_heads=n_head)
+        return fluid.layers.fc(ctxs, h, num_flatten_dims=2,
+                               param_attr=_param("o"))
     q, k, v = heads(q, S_q_in), heads(k, S_kv_in), heads(v, S_kv_in)
-    if getattr(cfg, "use_fused_attention", False):
+    if fused:
         # pallas flash-attention (ops/pallas_ops.py): no [S, S] score
         # matrix in HBM; exact same math as the composition below.
         # Attention dropout routes through the op's composition path

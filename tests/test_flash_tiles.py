@@ -301,3 +301,104 @@ def test_a_wide_head_of_one_tile_keeps_the_pair_for_the_budget_alone():
         assert pallas_ops._tiles(kernel, *key) == (True, 512, 512)
     assert pallas_ops._vmem_bytes("bwd", 512, 512, *key) > \
         pallas_ops._VMEM_BUDGET_BYTES
+
+
+# -- read in place or split: the rule, from the heads and the shape alone ----
+
+# (heads, shape key) -> whether the kernels read [B, S, heads * D] in place
+IN_PLACE = {
+    # bert_base_s512_flash as its step hands it over: float32, the mask
+    "flash_cell_f32": (12, RULE_SHAPES["s512_f32_bias"], True),
+    "flash_cell_bf16": (12, RULE_SHAPES["flash_cell"], True),
+    "one_head_of_128": (5, RULE_SHAPES["s512_causal_d128"], True),
+    "cross_128x256_d16": (8, RULE_SHAPES["cross_128x256"], True),
+    # a pair of heads a block, and an odd head over
+    "odd_heads_of_64": (11, RULE_SHAPES["flash_cell"], False),
+    "four_heads_of_16": (4, RULE_SHAPES["s128_d16"], False),
+    # no one-tile backward: two passes, on split heads
+    "s1024": (12, RULE_SHAPES["s1024_bias"], False),
+    "s384": (12, RULE_SHAPES["s384"], False),
+    "ouro_cell": (16, RULE_SHAPES["ouro_cell"], False),
+    "rotary": (16, RULE_SHAPES["s512_rotary"], False),
+    # V's head is not Q's: one block index cannot serve both
+    "dv_wider": (4, (128, 128, 64, 128, 0, False, False, 4), False),
+    # 96 does not divide 128; 256 is wider than a block
+    "heads_of_96": (4, (128, 128, 96, 96, 0, False, False, 4), False),
+    "heads_of_256": (4, (128, 128, 256, 256, 0, False, False, 2), False),
+    # grouped key/value heads have no one-tile backward
+    "grouped": (4, (128, 128, 64, 64, 0, False, True, 2, 2), False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(IN_PLACE))
+def test_heads_are_read_in_place_where_they_pack_into_a_block(case):
+    heads, key, want = IN_PLACE[case]
+    assert pallas_ops._in_place(heads, *key) is want
+    if want:
+        assert pallas_ops._fused_backward(*key)
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
+@pytest.mark.parametrize("case", sorted(
+    c for c in IN_PLACE if IN_PLACE[c][2]))
+def test_estimate_counts_the_heads_a_cell_holds(case, kernel):
+    """A cell of ``128 // D`` heads holds blocks that many times as wide
+    (Q, K, V, dO and the outputs) and a statistic row a head; the score
+    tiles stay one head's.  Still within the budget, and more than one
+    head's cell asks for."""
+    heads, key, _ = IN_PLACE[case]
+    pack = 128 // key[2]
+    one = pallas_ops._vmem_bytes(kernel, *key[:2], *key, rows=True)
+    cell = pallas_ops._vmem_bytes(kernel, *key[:2], *key, rows=True,
+                                  heads=pack)
+    assert cell <= pallas_ops._VMEM_BUDGET_BYTES
+    if pack == 1:
+        assert cell == one
+    else:
+        # the lanes a narrow head's block is padded to are the cell's
+        # other heads: what grows is the statistics and the accumulators
+        assert one < cell < one * pack
+
+
+def test_flash_cell_pair_in_float32_is_handed_its_own_limit():
+    """The flash cell's cell of two heads in float32: the forward's
+    estimate and its eighth stay under the compiler's 16 MiB scoped
+    default, the backward's (16.5 MB: seven blocks of 256 KB and the mask
+    twice, four float32 score tiles) pass it, so Mosaic is handed
+    ``vmem_limit_bytes`` (compiled for a v5e, test_program_spans.py)."""
+    key = RULE_SHAPES["s512_f32_bias"]
+    need = {kernel: pallas_ops._vmem_bytes(kernel, 512, 512, *key, rows=True,
+                                           heads=2)
+            for kernel in ("fwd", "bwd")}
+    assert pallas_ops._vmem_limit(need["fwd"]) is None, need
+    assert (16 << 20) < pallas_ops._vmem_limit(need["bwd"]) < (20 << 20), need
+
+
+@pytest.mark.parametrize("heads,layout", [(2, "bshd"), (3, "bhsd")])
+def test_flash_tiles_total_says_which_operand_layout_a_call_took(heads,
+                                                                 layout):
+    """``layout``: ``bshd`` for ``fwd`` and ``bwd`` on ``[B, S, H * D]``
+    read in place, ``bhsd`` for every call on ``[BH, S, D]``; the other
+    labels say what they said."""
+    counter = telemetry.registry().get("flash_tiles_total")
+    S, D = 128, 64
+    labels = [dict(kernel=k, layout=layout, stats="row", bias="none",
+                   block_q=S, block_k=S) for k in ("fwd", "bwd")]
+    before, total = [counter.value(**lb) for lb in labels], counter.value()
+    if layout == "bshd":
+        x = jax.ShapeDtypeStruct((2, S, heads * D), jnp.float32)
+
+        def loss(q, k, v):
+            return pallas_ops.flash_attention_in_place(
+                q, k, v, None, 0.125, False, heads)[0].sum()
+    else:
+        x = jax.ShapeDtypeStruct((2 * heads, S, D), jnp.float32)
+
+        def loss(q, k, v):
+            return flash_attention(q, k, v, None, 0.125).sum()
+    jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x)
+    assert [counter.value(**lb) - n for lb, n in zip(labels, before)] == \
+        [1, 1]
+    assert counter.value() - total == 2
+    assert counter.value(layout="bshd") + counter.value(layout="bhsd") == \
+        counter.value()

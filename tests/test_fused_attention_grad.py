@@ -721,3 +721,305 @@ def test_a_shared_mask_is_never_copied_a_head():
     assert "tensor<%dx%dx%dx" % (B, S_q, S_kv) in text
     assert "tensor<%dx%dx%dx" % (B * H, S_q, S_kv) not in text
     assert "tensor<%dx%dx%dx%dx" % (B, H, S_q, S_kv) not in text
+
+
+# -- heads in the minor dimension: ``num_heads`` ------------------------------
+
+def _minor(x):
+    """``[B, H, S, D]`` -> ``[B, S, H * D]``, on the host."""
+    b, h, s, d = x.shape
+    return np.ascontiguousarray(x.transpose(0, 2, 1, 3)).reshape(b, s, h * d)
+
+
+def _layout_program(minor, heads=2, kv_heads=None, head=64, S=128,
+                    bias_shape=None, bias_grad=False, causal=False,
+                    dropout=0.0, rope=0, backward=True, lse=True):
+    """ONE attention op under ``sum(out * w)``, its operands in the
+    heads-minor layout (``minor``: Q ``[B, S, H * D]``, ``num_heads=H``) or
+    as ``[B, H, S, D]``; K and V at ``kv_heads`` heads, with ``rope`` a
+    rotary pair of that size.  Fetches: loss, ``LSE`` (``lse``: the
+    composition writes none), then the gradients of q, k, v, the rotary
+    pair and (``bias_grad``) the bias."""
+    kv_heads = kv_heads or heads
+
+    def operand(name, n, width, grad=True):
+        return _data(name, (B, S, n * width) if minor else (B, n, S, width),
+                     grad=grad)
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        ins = [operand("q", heads, head), operand("k", kv_heads, head),
+               operand("v", kv_heads, head)]
+        pair = {}
+        if rope:
+            pair = dict(q_rope=operand("qr", heads, rope),
+                        k_rope=_data("kr", (B, S, rope) if minor
+                                     else (B, 1, S, rope)))
+            ins += list(pair.values())
+        b = None
+        if bias_shape is not None:
+            b = _data("b", bias_shape, grad=bias_grad)
+            ins.append(b)
+        w = operand("w", heads, head, grad=False)
+        out = layers.fused_attention(
+            *ins[:3], b, scale=head ** -0.5, causal=causal,
+            dropout_prob=dropout, num_heads=heads if minor else None, **pair)
+        assert tuple(out.shape) == tuple(w.shape)
+        loss = layers.reduce_sum(out * w)
+        stat = main.global_block().var(next(
+            op for op in main.global_block().ops
+            if op.type == "fused_attention").output("LSE")[0])
+        assert tuple(stat.shape) == (B, heads, S)
+        wanted = [x for x in ins if not x.stop_gradient]
+        grads = fluid.gradients(loss, wanted) if backward else []
+    return main, startup, [loss] + [stat] * lse + grads
+
+
+def _layout_feed(heads=2, kv_heads=None, head=64, S=128, bias_shape=None,
+                 rope=0, seed=3, **_):
+    """The ``[B, H, S, D]`` feed and the same numbers heads-minor."""
+    kv_heads = kv_heads or heads
+    rng = np.random.RandomState(seed)
+
+    def arr(*dims, scale=0.5):
+        return (rng.randn(*dims) * scale).astype(np.float32)
+    major = {"q": arr(B, heads, S, head), "k": arr(B, kv_heads, S, head),
+             "v": arr(B, kv_heads, S, head), "w": arr(B, heads, S, head)}
+    if rope:
+        major.update(qr=arr(B, heads, S, rope), kr=arr(B, 1, S, rope))
+    minor = {n: _minor(x) for n, x in major.items()}
+    if bias_shape is not None:
+        major["b"] = minor["b"] = arr(*bias_shape, scale=0.3)
+    return major, minor
+
+
+def _tiles_by_layout():
+    c = telemetry.counter("flash_tiles_total")
+    return {(layout, kernel): c.value(layout=layout, kernel=kernel)
+            for layout in ("bshd", "bhsd")
+            for kernel in ("fwd", "bwd", "dq", "dkv", "dbias")}
+
+
+def _lowered_by_layout():
+    c = telemetry.counter("fused_attention_lowered_total")
+    return {(layout, path): c.value(layout=layout, path=path)
+            for layout in ("bshd", "bhsd")
+            for path in ("flash", "composition")}
+
+
+def _moved(before, after):
+    return {k: after[k] - n for k, n in before.items() if after[k] != n}
+
+
+# case -> (program keywords, the kernels its step traces with each call's
+# number of outputs, flash_tiles_total's and fused_attention_lowered_total's
+# increments by (layout, ...))
+ROUTES = {
+    # read in place: a pair of heads of 64 a cell, the mask once a cell
+    "pairs_mask": (
+        dict(bias_shape=(B, 1, 128, 128)),
+        {"flash_fwd": [2], "flash_bwd": [3]},
+        {("bshd", "fwd"): 1, ("bshd", "bwd"): 1}, {("bshd", "flash"): 1}),
+    "pairs_causal": (
+        dict(causal=True),
+        {"flash_fwd": [2], "flash_bwd": [3]},
+        {("bshd", "fwd"): 1, ("bshd", "bwd"): 1}, {("bshd", "flash"): 1}),
+    "one_head_of_128": (
+        dict(heads=3, head=128, bias_shape=(B, 1, 1, 128)),
+        {"flash_fwd": [2], "flash_bwd": [3]},
+        {("bshd", "fwd"): 1, ("bshd", "bwd"): 1}, {("bshd", "flash"): 1}),
+    "head_bias_no_grad": (
+        dict(heads=4, bias_shape=(B, 4, 128, 128)),
+        {"flash_fwd": [2], "flash_bwd": [3]},
+        {("bshd", "fwd"): 1, ("bshd", "bwd"): 1}, {("bshd", "flash"): 1}),
+    # a wanted bias gradient: the forward in place, the backward and its
+    # dbias pass (which writes a head's [BH, S_q, S_kv]) on split heads
+    "head_bias_grad": (
+        dict(bias_shape=(B, 2, 128, 128), bias_grad=True),
+        {"flash_fwd": [2], "flash_bwd": [4], "flash_dbias": [1]},
+        {("bshd", "fwd"): 1, ("bhsd", "bwd"): 1, ("bhsd", "dbias"): 1},
+        {("bshd", "flash"): 1}),
+    # split inside the lowering: what the 4-D op traces
+    "odd_heads_of_64": (
+        dict(heads=3, bias_shape=(B, 1, 128, 128)),
+        {"flash_fwd": [2], "flash_bwd": [3]},
+        {("bhsd", "fwd"): 1, ("bhsd", "bwd"): 1}, {("bhsd", "flash"): 1}),
+    "eight_heads_of_16": (                   # eight heads a cell
+        dict(heads=8, head=16),
+        {"flash_fwd": [2], "flash_bwd": [3]},
+        {("bshd", "fwd"): 1, ("bshd", "bwd"): 1}, {("bshd", "flash"): 1}),
+    "four_heads_of_16": (                    # half a block of 128 lanes
+        dict(heads=4, head=16),
+        {"flash_fwd": [2], "flash_bwd": [3]},
+        {("bhsd", "fwd"): 1, ("bhsd", "bwd"): 1}, {("bhsd", "flash"): 1}),
+    "s1024": (
+        dict(S=1024),
+        {"flash_fwd": [2], "flash_dq": [2], "flash_dkv": [2]},
+        {("bhsd", "fwd"): 1, ("bhsd", "dq"): 1, ("bhsd", "dkv"): 1},
+        {("bhsd", "flash"): 1}),
+    "grouped_heads": (
+        dict(heads=4, kv_heads=2, causal=True),
+        {"flash_fwd": [2], "flash_dq": [1], "flash_dkv": [2]},
+        {("bhsd", "fwd"): 1, ("bhsd", "dq"): 1, ("bhsd", "dkv"): 1},
+        {("bhsd", "flash"): 1}),
+    "rotary_pair": (
+        dict(causal=True, rope=32),
+        {"flash_fwd": [2], "flash_dq": [2], "flash_dkv": [3]},
+        {("bhsd", "fwd"): 1, ("bhsd", "dq"): 1, ("bhsd", "dkv"): 1},
+        {("bhsd", "flash"): 1}),
+    "dropout": (
+        dict(dropout=0.1, bias_shape=(B, 1, 128, 128), lse=False),
+        {}, {}, {("bhsd", "composition"): 2}),      # forward and its replay
+    "no_tile_s192": (
+        dict(S=192, lse=False), {}, {}, {("bhsd", "composition"): 2}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ROUTES))
+def test_heads_minor_op_takes_its_route_and_gives_the_4d_ops_numbers(
+        kernel_calls, case):
+    """An op with ``num_heads`` runs the kernels on its operands in place
+    where a head is one tile and the heads pack whole into 128 lanes, and
+    is the 4-D op between a split and a merge everywhere else; either way
+    the loss, ``LSE`` and every gradient are the 4-D op's on the same
+    numbers (with dropout: the same key, so the same mask)."""
+    kw, kernels, tiles, lowered = ROUTES[case]
+    major, minor = _layout_feed(**kw)
+    want = _run(*_layout_program(False, **kw), major)
+    kernel_calls.clear()
+    before = _tiles_by_layout(), _lowered_by_layout()
+    got = _run(*_layout_program(True, **kw), minor)
+    assert dict(kernel_calls) == kernels
+    assert _moved(before[0], _tiles_by_layout()) == tiles
+    assert _moved(before[1], _lowered_by_layout()) == lowered
+    names = ["loss"] + ["lse"] * kw.get("lse", True) + ["dq", "dk", "dv"] + \
+        (["dqr", "dkr"] if kw.get("rope") else []) + \
+        (["dbias"] if kw.get("bias_grad") else [])
+    assert len(got) == len(want) == len(names)
+    for name, a, b in zip(names, got, want):
+        if name == "dkr":
+            b = b[:, 0]
+        elif name[0] == "d" and name != "dbias":
+            b = _minor(b)
+        assert a.shape == b.shape, name
+        # ``LSE`` is the same array under both layouts
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5, err_msg=name)
+
+
+def test_heads_minor_for_test_clone_reads_in_place_and_asks_for_no_lse(
+        kernel_calls):
+    kw = dict(bias_shape=(B, 1, 128, 128), backward=False)
+    major, minor = _layout_feed(**kw)
+    main, startup, fetches = _layout_program(True, **kw)
+    train = _run(main, startup, fetches[:1], minor)[0]
+    assert kernel_calls.pop("flash_fwd") == [2]
+    before = _tiles_by_layout()
+    infer = _run(main.clone(for_test=True), startup, fetches[:1], minor)[0]
+    assert dict(kernel_calls) == {"flash_fwd": [1]}
+    assert _moved(before, _tiles_by_layout()) == {("bshd", "fwd"): 1}
+    want = _run(*_layout_program(False, **kw)[:2],
+                _layout_program(False, **kw)[2][:1], major)[0]
+    np.testing.assert_allclose(infer, train, rtol=1e-6)
+    np.testing.assert_allclose(infer, want, rtol=1e-5)   # another sum order
+
+
+def test_heads_minor_program_without_the_lse_slot_replays_in_place(
+        kernel_calls):
+    """The grad op of a program built before the ``LSE`` slot existed
+    differentiates a second forward, in place too: ``jax.vjp`` of
+    ``flash_attention_in_place``."""
+    kw = dict()
+    major, minor = _layout_feed(**kw)
+    main, startup, fetches = _layout_program(True, **kw)
+    new = _run(main, startup, [fetches[0]] + fetches[2:], minor)
+    kernel_calls.clear()
+    main, startup, fetches = _layout_program(True, **kw)
+    for op in main.global_block().ops:
+        if op.type in ("fused_attention", "fused_attention_grad"):
+            op.outputs.pop("LSE", None)
+            op.inputs.pop("LSE", None)
+            (op.attrs.get("__fwd_outputs__") or {}).pop("LSE", None)
+    before = _grad_paths()
+    old = _run(main, startup, [fetches[0]] + fetches[2:], minor)
+    assert _paths_taken(before) == (0, 1)
+    assert {n: len(c) for n, c in kernel_calls.items()} == {
+        "flash_fwd": 2, "flash_bwd": 1}
+    for a, b in zip(old, new):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
+def _tiny_fused_bert(attn_dropout=0.0, layers_=2, heads=2, head=64, S=128):
+    from paddle_tpu import models
+
+    cfg = models.bert.BertConfig(
+        vocab_size=128, hidden_size=heads * head, num_layers=layers_,
+        num_heads=heads, ffn_size=128, max_position=S, type_vocab_size=2,
+        hidden_dropout=0.0, attn_dropout=attn_dropout, max_seq_len=S)
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        loss = models.bert.build_pretrain(
+            cfg, optimizer=fluid.optimizer.SGD(0.01),
+            max_pred_per_seq=4)["loss"]
+    rng = np.random.default_rng(0)
+    feed = {
+        "src_ids": rng.integers(0, 128, (B, S, 1), dtype=np.int64),
+        "pos_ids": np.tile(np.arange(S, dtype=np.int64)[None, :, None],
+                           (B, 1, 1)),
+        "sent_ids": np.zeros((B, S, 1), np.int64),
+        "input_mask": np.ones((B, S, 1), np.float32),
+        "mask_pos": (rng.integers(0, S, (B, 4)) + np.arange(B)[:, None] * S)
+        .reshape(-1, 1).astype(np.int32),
+        "mask_label": rng.integers(0, 128, (B * 4, 1), dtype=np.int64),
+        "nsp_label": rng.integers(0, 2, (B, 1), dtype=np.int64),
+    }
+    return main, startup, loss, feed, (heads, head, S)
+
+
+def test_tiny_fused_bert_step_holds_no_head_split_or_merge(kernel_calls):
+    """The program of a fused BERT has no ``transpose2`` around attention,
+    its step traces one ``flash_fwd`` and one ``flash_bwd`` a layer at
+    ``layout=bshd``, and nothing in the lowered step has the shape of a
+    split head (``[B, H, S, D]``, or ``[B, S, H, D]`` on the way there):
+    Q, K, V, the output and their gradients stay ``[B, S, H * D]``."""
+    from paddle_tpu.fluid import executor
+
+    main, startup, loss, feed, (heads, head, S) = _tiny_fused_bert()
+    kinds = [op.type for op in main.global_block().ops]
+    assert kinds.count("fused_attention") == 2
+    assert "transpose2" not in kinds and "transpose2_grad" not in kinds
+    before = _tiles_by_layout(), _lowered_by_layout()
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        compiled, feed_vals = exe._resolve_compiled(main, feed, [loss],
+                                                    scope, None)
+        text = compiled._jitted.lower(
+            executor._scope_state(scope, compiled.state_mut),
+            executor._scope_state(scope, compiled.state_ro),
+            tuple(feed_vals), np.int32(0)).as_text()
+        assert _moved(before[0], _tiles_by_layout()) == {
+            ("bshd", "fwd"): 2, ("bshd", "bwd"): 2}
+        assert _moved(before[1], _lowered_by_layout()) == {
+            ("bshd", "flash"): 2}
+        assert {n: len(c) for n, c in kernel_calls.items()} == {
+            "flash_fwd": 2, "flash_bwd": 2}
+        losses = [float(np.asarray(exe.run(main, feed=feed,
+                                           fetch_list=[loss])[0]).reshape(()))
+                  for _ in range(3)]
+    assert "tensor<%dx%dx%dx%dx" % (B, heads, S, head) not in text
+    assert "tensor<%dx%dx%dx%dx" % (B, S, heads, head) not in text
+    assert "tensor<%dx%dx%dx" % (B * heads, S, head) not in text
+    assert "tensor<%dx%dx%dxf32>" % (B, S, heads * head) in text
+    assert losses[-1] < losses[0]
+
+
+def test_tiny_fused_bert_with_attention_dropout_splits_inside_the_op():
+    """Attention dropout takes the composition: the op splits the heads
+    itself, and the program still spells no transpose."""
+    main, startup, loss, feed, _ = _tiny_fused_bert(attn_dropout=0.1)
+    kinds = [op.type for op in main.global_block().ops]
+    assert "transpose2" not in kinds
+    before = _lowered_by_layout()
+    assert np.isfinite(_run(main, startup, [loss], feed)[0]).all()
+    assert _moved(before, _lowered_by_layout()) == {
+        ("bhsd", "composition"): 4}

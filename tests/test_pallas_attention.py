@@ -422,3 +422,168 @@ def test_cross_attention_distinct_lengths():
     for name, a, b_ in zip(("dq", "dk", "dv", "dbias"), got, refs):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=2e-4, atol=2e-4, err_msg=name)
+
+
+# -- the one-tile kernels on [B, S, H * D], read and written in place --------
+
+import pytest                                               # noqa: E402
+import jax                                                  # noqa: E402
+import jax.numpy as jnp                                     # noqa: E402
+
+from paddle_tpu.fluid.ops import pallas_ops                 # noqa: E402
+
+# (D, H, S, causal, bias: None | "sequence" | "head", dtype)
+IN_PLACE = {
+    "pairs_mask_s128": (64, 4, 128, False, "sequence", "float32"),
+    "pairs_plain_s128": (64, 2, 128, False, None, "float32"),
+    "pairs_causal_s128": (64, 2, 128, True, None, "float32"),
+    "pairs_causal_mask_s128": (64, 2, 128, True, "sequence", "float32"),
+    "pairs_head_bias_s128": (64, 4, 128, False, "head", "float32"),
+    "pairs_mask_s512": (64, 2, 512, False, "sequence", "float32"),
+    "pairs_mask_s512_bf16": (64, 2, 512, False, "sequence", "bfloat16"),
+    "pairs_causal_s128_bf16": (64, 2, 128, True, None, "bfloat16"),
+    "one_head_mask_s128": (128, 2, 128, False, "sequence", "float32"),
+    "one_head_causal_s512": (128, 1, 512, True, None, "float32"),
+    "one_head_plain_s128_bf16": (128, 3, 128, False, None, "bfloat16"),
+    "quads_mask_s128": (32, 4, 128, False, "sequence", "float32"),
+}
+
+
+def _in_place_case(name, seed=5):
+    """Operands ``[B, S, H * D]`` and the same heads as ``[B * H, S, D]``."""
+    D_, H_, S_, causal, bias, dtype = IN_PLACE[name]
+    B_ = 2
+    rng = np.random.RandomState(seed)
+
+    def arr(*dims, scale=0.5):
+        return jnp.asarray(rng.randn(*dims).astype(np.float32) * scale) \
+            .astype(dtype)
+    minor = [arr(B_, S_, H_ * D_) for _ in range(4)]            # q, k, v, g
+    flat = [pallas_ops._flat(pallas_ops._heads_major(x, H_)) for x in minor]
+    b = None if bias is None else \
+        arr(B_ if bias == "sequence" else B_ * H_, S_, S_, scale=0.3)
+    assert pallas_ops._in_place(H_, *pallas_ops._in_place_shape(
+        minor[0], minor[1], b, causal, H_))
+    return minor, flat, b, causal, H_
+
+
+def _as_minor(x, heads):
+    return pallas_ops._heads_minor(x.reshape(-1, heads, *x.shape[1:]))
+
+
+def _f32(x):
+    return np.asarray(x.astype(jnp.float32))
+
+
+@pytest.mark.parametrize("name", sorted(IN_PLACE))
+def test_in_place_kernels_are_the_flat_kernels_bit_for_bit(name):
+    """``flash_fwd`` and ``flash_bwd`` over ``[B, S, H * D]`` (``128 // D``
+    heads a grid cell, each on its lanes) against the same kernels over
+    ``[B * H, S, D]``: the per-head body is the same, so the output, the
+    logsumexp and the three gradients are equal to the bit, in float32
+    and in bfloat16, with the mask of a sequence read once a cell, a bias
+    a head, or none, causal or not."""
+    (q, k, v, g), (qf, kf, vf, gf), bias, causal, heads = \
+        _in_place_case(name)
+    out, lse = pallas_ops._flash_fwd_in_place(q, k, v, bias, 0.125, heads,
+                                              causal)
+    want_out, want_lse = pallas_ops._flash_forward(
+        qf, kf, vf, bias, 0.125, with_lse=True, causal=causal)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    np.testing.assert_array_equal(_f32(out), _f32(_as_minor(want_out, heads)))
+    np.testing.assert_array_equal(np.asarray(lse), np.asarray(want_lse))
+    # inference asks for no statistic, and gets the same output
+    infer, none = pallas_ops._flash_fwd_in_place(q, k, v, bias, 0.125, heads,
+                                                 causal, with_lse=False)
+    assert none is None
+    np.testing.assert_array_equal(_f32(infer), _f32(out))
+    got = pallas_ops._backward_in_place(q, k, v, bias, 0.125, causal, heads,
+                                        lse, g)
+    want = pallas_ops._flash_backward(qf, kf, vf, bias, 0.125, want_lse, gf,
+                                      causal=causal, bias_grad=False)[:3]
+    for what, a, b in zip(("dq", "dk", "dv"), got, want):
+        assert a.shape == q.shape and a.dtype == q.dtype, what
+        np.testing.assert_array_equal(_f32(a), _f32(_as_minor(b, heads)),
+                                      err_msg=what)
+
+
+@pytest.mark.parametrize("name", sorted(
+    n for n in IN_PLACE if IN_PLACE[n][5] == "float32"))
+def test_in_place_kernels_match_the_reference(name):
+    (q, k, v, g), (qf, kf, vf, gf), bias, causal, heads = \
+        _in_place_case(name)
+    out, lse = pallas_ops._flash_fwd_in_place(q, k, v, bias, 0.125, heads,
+                                              causal)
+    want_out, vjp = jax.vjp(
+        lambda *a: _reference_attention(*a, bias, 0.125, causal=causal),
+        qf, kf, vf)
+    np.testing.assert_allclose(_f32(out), _f32(_as_minor(want_out, heads)),
+                               rtol=2e-4, atol=2e-5)
+    got = pallas_ops._backward_in_place(q, k, v, bias, 0.125, causal, heads,
+                                        lse, g)
+    for what, a, b in zip(("dq", "dk", "dv"), got, vjp(gf)):
+        np.testing.assert_allclose(_f32(a), _f32(_as_minor(b, heads)),
+                                   rtol=2e-4, atol=2e-4, err_msg=what)
+
+
+@pytest.mark.parametrize("name", ["pairs_mask_s128", "pairs_causal_s128",
+                                  "one_head_mask_s128",
+                                  "pairs_mask_s512_bf16"])
+def test_in_place_backward_takes_a_passed_delta(name):
+    """With delta passed in (rows ``[B * H, 1, S_q]``, as the logsumexp)
+    the in-place ``flash_bwd`` is the flat one with the same delta, bit
+    for bit, and agrees with the delta it forms itself; asked to, it
+    writes the one it formed, a row a head."""
+    (q, k, v, g), (qf, kf, vf, gf), bias, causal, heads = \
+        _in_place_case(name)
+    out, lse = pallas_ops._flash_forward(qf, kf, vf, bias, 0.125,
+                                         with_lse=True, causal=causal)
+    delta = pallas_ops._row_delta(gf, out)[:, None]
+    rows = lse[:, None]
+    got = pallas_ops._flash_bwd(q, k, v, bias, 0.125, rows, g, causal, delta,
+                                heads=heads)
+    want = pallas_ops._flash_bwd(qf, kf, vf, bias, 0.125, rows, gf, causal,
+                                 delta)
+    assert got[3] is delta
+    formed = pallas_ops._flash_bwd(q, k, v, bias, 0.125, rows, g, causal,
+                                   None, delta_out=True, heads=heads)
+    tol = dict(rtol=2e-2, atol=2e-2) if q.dtype == jnp.bfloat16 \
+        else dict(rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(formed[3]), np.asarray(delta),
+                               **tol)
+    for what, a, b, c in zip(("dq", "dk", "dv"), got, want, formed):
+        np.testing.assert_array_equal(_f32(a), _f32(_as_minor(b, heads)),
+                                      err_msg=what)
+        np.testing.assert_allclose(_f32(a), _f32(c), err_msg=what, **tol)
+
+
+@pytest.mark.parametrize("bias", [None, "sequence", "head"])
+def test_in_place_attention_is_differentiated_by_jax(bias):
+    """``flash_attention_in_place`` under ``jax.grad`` (an op inside a
+    recompute span, a replayed forward): the gradients of Q, K, V and,
+    where there is a bias, of the bias are the reference's."""
+    B_, H_, S_, D_ = 2, 2, 128, 64
+    rng = np.random.RandomState(9)
+
+    def arr(*dims, scale=0.5):
+        return jnp.asarray(rng.randn(*dims).astype(np.float32) * scale)
+    q, k, v, w = (arr(B_, S_, H_ * D_) for _ in range(4))
+    b = None if bias is None else \
+        arr(B_ if bias == "sequence" else B_ * H_, S_, S_, scale=0.3)
+
+    def loss(q, k, v, b):
+        return (pallas_ops.flash_attention_in_place(
+            q, k, v, b, 0.125, False, H_)[0] * w).sum()
+
+    def want_loss(q, k, v, b):
+        flat = [pallas_ops._flat(pallas_ops._heads_major(x, H_))
+                for x in (q, k, v)]
+        return (_as_minor(_reference_attention(*flat, b, 0.125), H_)
+                * w).sum()
+    wrt = (0, 1, 2) + ((3,) if bias else ())
+    for what, a, c in zip(("dq", "dk", "dv", "dbias"),
+                          jax.grad(loss, argnums=wrt)(q, k, v, b),
+                          jax.grad(want_loss, argnums=wrt)(q, k, v, b)):
+        assert a.shape == c.shape, what
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c), rtol=2e-4,
+                                   atol=2e-4, err_msg=what)

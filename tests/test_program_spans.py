@@ -475,6 +475,31 @@ def test_flash_cell_shape_compiles_with_one_tile_a_head(bert_stack_steps):
         (kernel, 512, 512): LAYERS for kernel in ("fwd", "bwd")}
 
 
+def test_flash_step_reads_the_projections_outputs_in_place(bert_stack_steps):
+    """The small flash step compiled by XLA:TPU and Mosaic: every
+    ``flash_fwd`` and ``flash_bwd`` custom call takes Q, K and V as ``[B,
+    S, H * D]`` (Mosaic accepts the blocks of 128 lanes, a pair of heads of
+    64 a cell, and the lane slices inside) and writes its outputs the same
+    way; no instruction sits under a ``fluid_transpose2`` scope and none
+    has the shape of split heads."""
+    text = bert_stack_steps[True].as_text()
+    calls = re.findall(r"%(\w+?)(?:\.\d+)? = ([^\n]*?) custom-call\("
+                       r'[^\n]*custom_call_target="tpu_custom_call", '
+                       r"operand_layout_constraints=\{([^\n]*?)\}, "
+                       r"frontend_attributes", text)
+    assert sorted(c[0] for c in calls) == sorted(
+        ["flash_bwd", "flash_fwd"] * LAYERS)
+    in_place = "[%d,%d,%d]" % (BATCH, SEQ, HEADS * 64)
+    for name, result, operands in calls:
+        assert operands.count(in_place) == (3 if name == "flash_fwd" else 4)
+        assert result.count(in_place) == (1 if name == "flash_fwd" else 3)
+    assert "fluid_transpose2" not in text
+    for split in ("[%d,%d,%d,64]" % (BATCH, HEADS, SEQ),
+                  "[%d,%d,%d,64]" % (BATCH, SEQ, HEADS),
+                  "[%d,%d,64]" % (BATCH * HEADS, SEQ)):
+        assert split not in text, split
+
+
 def test_lse_residual_costs_its_own_bytes_and_no_more(bert_stack_steps):
     """Handing the LSE from the forward op to the grad op costs the step's
     temporaries the statistic's own bytes a layer.  At this shape (a head

@@ -13,21 +13,31 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+# cell -> ``temp_size_in_bytes`` of its step compiled for a described v5e
+STEP_TEMPORARIES = {
+    # PR 34's tree and PR 36's (18.28544 GB of ``hbm_peak_gb`` on the chip
+    # at both); PR 35's tree, the row statistics in the multi-pass kernels
+    # too, compiled to 5,376,560,128 here and read 18.5395 GB there, past
+    # the cell's bound
+    "moonlight_ep8share_s4096_train": 4880808448,
+    # PR 38: the kernels read Q, K, V and dO as [B, S, H * D] in place;
+    # with the head split (36 padded copies kept for the backward) the
+    # step held 9,866,375,680
+    "bert_base_s512_flash": 8172316672,
+}
+
+
 @pytest.mark.slow
-def test_moonlight_step_holds_the_temporaries_it_held():
-    """4,880,808,448 bytes of temporaries at PR 34's tree and at PR 36's
-    (18.28544 GB of ``hbm_peak_gb`` on the chip at both); PR 35's tree,
-    the row statistics in the multi-pass kernels too, compiled to
-    5,376,560,128 here and read 18.5395 GB there, past the cell's bound."""
+@pytest.mark.parametrize("cell", sorted(STEP_TEMPORARIES))
+def test_step_holds_the_temporaries_it_held(cell):
     out = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "tools", "step_memory.py"),
-         "moonlight_ep8share_s4096_train"],
+        [sys.executable, os.path.join(ROOT, "tools", "step_memory.py"), cell],
         capture_output=True, text=True, timeout=900, cwd=ROOT,
         env=dict(os.environ, JAX_PLATFORMS="cpu",
                  ALLOW_MULTIPLE_LIBTPU_LOAD="1"))
     assert out.returncode == 0, out.stderr[-2000:]
     record = json.loads(out.stdout.strip().splitlines()[-1])
-    assert record["temp_size_in_bytes"] == 4880808448, record
+    assert record["temp_size_in_bytes"] == STEP_TEMPORARIES[cell], record
 
 
 HLO = '''HloModule jit_fn
