@@ -524,6 +524,56 @@ def phase_kernels(device, dry_run):
     log("%s D=%d bf16: fwd + dq/dk/dv match the composition on repeated "
         "K/V (worst rel err %.4f)" % (what, D, e_gqa))
 
+    # a sliding window: the looped kernels at the long-context cell's shape,
+    # one head group of it (7 query heads of 128 over one key/value head,
+    # 16384 tokens), with a window of 4096 keys and with none, against the
+    # float32 composition whose band is a boolean mask, a head and 2048
+    # query rows at a time (one head's scores are 1 GB)
+    group, S, Dw, rows = (3, 256, 64, 128) if dry_run \
+        else (7, 16384, 128, 2048)
+    scale_w = 1.0 / np.sqrt(Dw)
+    q, g = arr((group, S, Dw)), arr((group, S, Dw))
+    k, v = arr((1, S, Dw)), arr((1, S, Dw))
+
+    def band(window, q, k, v):
+        return po.flash_attention(q, k, v, None, scale_w, True, None, window)
+
+    def ref_band(window, q, k, v):
+        kf, vf = k[0].astype(f32), v[0].astype(f32)
+
+        @jax.checkpoint
+        def piece(qs, first):
+            s_ = (qs @ kf.T) * scale_w
+            allowed = po._in_band(first + jnp.arange(rows)[:, None],
+                                  jnp.arange(S)[None, :], window)
+            return jax.nn.softmax(jnp.where(allowed, s_, -jnp.inf),
+                                  axis=-1) @ vf
+
+        def head(qh):
+            return jax.lax.map(lambda a: piece(*a), (
+                qh.reshape(S // rows, rows, Dw),
+                jnp.arange(0, S, rows))).reshape(S, Dw)
+        return jax.lax.map(head, q.astype(f32))
+    e_window = 0.0
+    for window in (S // 4, 0):
+        what = "flash H=%d H_kv=1 S=%d window=%d" % (group, S, window)
+        fk = functools.partial(band, window)
+        _check_lowering(fk, (q, k, v), on_tpu, what)
+        e = _rel_err(jax.jit(fk)(q, k, v),
+                     jax.jit(functools.partial(ref_band, window))(q, k, v))
+        require(e <= FWD_TOL, "%s: fwd err %.4f", what, e)
+        e_window = max(e_window, e)
+        for nm, a, w in zip(("dq", "dk", "dv"),
+                            grads(band, window, 3)(g, q, k, v),
+                            grads(ref_band, window, 3)(g, q, k, v)):
+            require(a.shape == w.shape, "%s: %s is %s", what, nm, a.shape)
+            e = _rel_err(a, w)
+            require(e <= BWD_TOL, "%s: %s err %.4f", what, nm, e)
+            e_window = max(e_window, e)
+    log("flash H=%d H_kv=1 S=%d D=%d bf16, a window of %d and none: fwd + "
+        "dq/dk/dv match the masked composition (worst rel err %.4f)"
+        % (group, S, Dw, S // 4, e_window))
+
     # operands as the projections leave them, [B, S, H * D], read and
     # written in place, a pair of heads of 64 a grid cell (the lane slices
     # at offset 64 are Mosaic's to get right: the interpreter cannot tell);
@@ -582,6 +632,7 @@ def phase_kernels(device, dry_run):
     return {"flash_fwd_err": round(worst["fwd"], 5),
             "flash_bwd_err": round(worst["bwd"], 5),
             "flash_grouped_err": round(e_gqa, 5),
+            "flash_window_err": round(e_window, 5),
             "flash_in_place_err": round(e_in_place, 5),
             "layer_norm_err": round(e_ln, 5)}
 

@@ -10,7 +10,12 @@ to 512, at the shapes of the benchmark's two kernel cells:
   block row ``i // H``, and ``fwd`` and ``bwd`` with the row statistics as
   lane-dense rows — the unrolled form (``bert_base_s512_flash``);
 * ``moonlight``: 16 heads, S=4096, 128 + 64 | 128, bf16, causal, a shared
-  rotary key head — the looped form (``moonlight_ep8share_s4096_train``).
+  rotary key head — the looped form (``moonlight_ep8share_s4096_train``);
+* ``smallthinker``: 28 query heads over 4 key/value heads of 128, S=16384,
+  bf16, causal, both layer kinds of ``smallthinker_ep8share_s16384_train``
+  one after the other: the full layer (``window`` 0 in the record) and the
+  layer with a sliding window of 4096 keys, whose looped sweeps visit the
+  band's tiles alone (252 of the 528 tiles of 512).
 
 After the sweep of ``bert`` come the kernels that run in that cell's step
 since PR 38, at the chooser's one tile: ``fwd`` and ``bwd`` on ``[B, S, H *
@@ -58,6 +63,11 @@ def _operands(shape, dtype=None):
         q, k, v, g = (arr(B * H, S, D) for _ in range(4))
         return dict(q=q, k=k, v=v, g=g, bias=arr(B, 1, S, S, scale=0.1),
                     heads=H, rope=None, causal=False, scale=D ** -0.5)
+    if shape == "smallthinker":
+        H, H_kv, S, D = 28, 4, 16384, 128
+        return dict(q=arr(H, S, D), k=arr(H_kv, S, D), v=arr(H_kv, S, D),
+                    g=arr(H, S, D), bias=None, causal=True, rope=None,
+                    scale=D ** -0.5)
     H, S = 16, 4096
     q, k, v, g = (arr(H, S, 128) for _ in range(4))
     return dict(q=q, k=k, v=v, g=g, bias=None, causal=True,
@@ -74,9 +84,14 @@ def _corner(fn):
         for x in jax.tree.leaves(fn(*args))))
 
 
-def _kernel_calls(ops):
+# the sliding windows a shape's sweep runs, one after the other (0: none)
+WINDOWS = {"smallthinker": (0, 4096)}
+
+
+def _kernel_calls(ops, window=0):
     """``{kernel: zero-argument call}``, each running ONE jitted kernel on
-    ``ops``; built anew for every tile, so nothing traced is reused."""
+    ``ops`` (under the sliding ``window``); built anew for every tile, so
+    nothing traced is reused."""
     import functools
     import jax
     from .ops import pallas_ops as po
@@ -94,16 +109,16 @@ def _kernel_calls(ops):
     def forward(a):
         return po._flash_forward(a["q"], a["k"], a["v"], a["bias"], scale,
                                  with_lse=True, causal=causal,
-                                 rope=a["rope"])
+                                 rope=a["rope"], window=window)
 
     def dq(a, lse, delta):
         return po._flash_dq(a["q"], a["k"], a["v"], a["bias"], scale, lse,
                             a["g"], causal, None if in_kernel else delta,
-                            a["rope"])[0]
+                            a["rope"], window)[0]
 
     def dkv(a, lse, delta):
         return po._flash_dkv(a["q"], a["k"], a["v"], a["bias"], scale, lse,
-                             a["g"], causal, delta, a["rope"])
+                             a["g"], causal, delta, a["rope"], window)
 
     def bwd(a, lse, delta):
         return po._flash_bwd(a["q"], a["k"], a["v"], a["bias"], scale, lse,
@@ -179,22 +194,28 @@ def layouts(steps=30):
 
 
 def sweep(shape, steps=30):
-    """Yield one record per kernel and tile of ``shape``; the first of a
-    kernel's records is the chooser's own pick."""
+    """Yield one record per kernel and tile of ``shape`` (and per sliding
+    window, where the shape has layers of several kinds: ``WINDOWS``); the
+    first of a kernel's records is the chooser's own pick."""
+    _require_tpu()
+    ops = _operands(shape)
+    for window in WINDOWS.get(shape, (0,)):
+        yield from _sweep_window(shape, ops, window, steps)
+
+
+def _sweep_window(shape, ops, window, steps):
     from .ops import pallas_ops as po
     from .timing import timed_steps
 
-    _require_tpu()
-    ops = _operands(shape)
     chooser = po._tiles
     key = po._shape_key(ops["q"], ops["k"], ops["v"], ops["bias"],
-                        ops["causal"], ops["rope"])     # a bias or none
+                        ops["causal"], ops["rope"], window)
     fused = po._fused_backward(*key)
     try:
         for forced in (None,) + TILES:
             po._tiles = chooser if forced is None else \
                 (lambda kernel, *shape: (True,) + forced)
-            for kernel, call in _kernel_calls(ops).items():
+            for kernel, call in _kernel_calls(ops, window).items():
                 if kernel == "bwd" and not (fused and forced is None):
                     continue
                 _, block_q, block_k = po._tiles(kernel, *key)
@@ -204,8 +225,9 @@ def sweep(shape, steps=30):
                     rec = {"ms": round(dt / steps * 1e3, 4)}
                 except Exception as e:      # a tile Mosaic refuses
                     rec = {"error": str(e)[:200]}
-                yield dict(shape=shape, kernel=kernel, block_q=block_q,
-                           block_k=block_k, chosen=forced is None,
+                yield dict(shape=shape, kernel=kernel, window=window,
+                           block_q=block_q, block_k=block_k,
+                           chosen=forced is None,
                            form="fused" if fused else "two_pass", **rec)
     finally:
         po._tiles = chooser

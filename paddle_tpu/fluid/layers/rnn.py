@@ -273,7 +273,7 @@ def beam_search_decode(ids, scores, parent_idx, beam_size, end_id,
 
 def fused_attention(q, k, v, attn_bias=None, scale=1.0, causal=False,
                     dropout_prob=0.0, is_test=False, name=None,
-                    q_rope=None, k_rope=None, num_heads=None):
+                    q_rope=None, k_rope=None, num_heads=None, window=0):
     """Fused attention core (ops/pallas_ops.py flash-attention kernel):
     q [B, H, S_q, D], k/v [B, H, S_kv, D] (cross-attention supported),
     optional additive bias [B, 1|H, S_q, S_kv].  k and v may carry fewer
@@ -309,7 +309,15 @@ def fused_attention(q, k, v, attn_bias=None, scale=1.0, causal=False,
     lanes and keeps for the backward, do not exist.  Every other op in
     this layout (dropout, sequence parallelism, longer sequences, grouped
     heads, a rotary pair, a bias whose gradient is wanted) is split and
-    merged inside the lowering and computes what the 4-D op computes."""
+    merged inside the lowering and computes what the 4-D op computes.
+
+    ``window=W`` (with ``causal=True``): a sliding window, query ``i`` sees
+    keys ``j`` with ``i - W < j <= i`` (W keys, itself among them).  The
+    flash kernels visit the tiles of that band alone; 0 is no window, and
+    ``W >= S_kv`` is the causal op."""
+    if window and (window < 0 or not causal):
+        raise ValueError("fused_attention: window=%d needs causal=True and "
+                         "a positive size" % window)
     helper = LayerHelper("fused_attention", name=name)
     out = helper.create_variable_for_type_inference(q.dtype)
     lse = helper.create_variable_for_type_inference("float32",
@@ -340,6 +348,7 @@ def fused_attention(q, k, v, attn_bias=None, scale=1.0, causal=False,
                             "is_test": bool(is_test),
                             **({"num_heads": int(num_heads)} if num_heads
                                else {}),
+                            **({"window": int(window)} if window else {}),
                             "__op_seed__":
                                 helper.main_program.next_op_seed()})
     return out
@@ -457,8 +466,9 @@ def gated_short_conv(bcx, kernel_size=3, param_attr=None, name=None):
 
 def routed_experts(x, num_experts, top_k, ffn_dim, num_held=None,
                    first_expert=0, routed_scaling_factor=1.0,
-                   param_attr=None, name=None):
-    """Routed SwiGLU experts without capacity or drops (ops/decoder_ops.py).
+                   param_attr=None, name=None, router_input=None,
+                   scoring_func="sigmoid", hidden_act="silu"):
+    """Routed gated experts without capacity or drops (ops/decoder_ops.py).
 
     x [..., H] -> (out [..., H], expert_load [num_experts], select_bias
     [num_experts]).  The layer routes over all ``num_experts`` (float32
@@ -471,6 +481,14 @@ def routed_experts(x, num_experts, top_k, ffn_dim, num_held=None,
     which read ``expert_load`` (tokens that chose each expert).  The load is
     persistable too: the last step's count stays in the scope, where a
     monitor reads the balance without a fetch of its own.
+
+    ``router_input`` (the shape of ``x``): what the router reads where that
+    is not the experts' input, e.g. the normed input of the same layer's
+    attention; its gradient comes through the weights alone.
+    ``scoring_func="softmax"``: the ``top_k`` largest LOGITS (plus the
+    bias) are chosen and weighed by the softmax over the chosen logits.
+    ``hidden_act="relu"``: the experts gate with ReLU (ReGLU), not SiLU.
+    (The lowering refuses any other value of either.)
 
     The op's ``Kept`` outputs (four variables without a gradient) are the
     rows its backward reads, handed from the forward op to
@@ -508,16 +526,21 @@ def routed_experts(x, num_experts, top_k, ffn_dim, num_held=None,
     kept = [helper.create_variable_for_type_inference(x.dtype,
                                                       stop_gradient=True)
             for _ in range(4)]
+    attrs = {"top_k": int(top_k), "first_expert": int(first_expert),
+             "routed_scaling_factor": float(routed_scaling_factor)}
+    if scoring_func != "sigmoid":
+        attrs["scoring_func"] = scoring_func
+    if hidden_act != "silu":
+        attrs["hidden_act"] = hidden_act
     helper.append_op("routed_experts",
                      inputs={"X": [x], "RouterW": [router_w],
                              "SelectBias": [bias], "WGate": [w_gate],
-                             "WUp": [w_up], "WDown": [w_down]},
+                             "WUp": [w_up], "WDown": [w_down],
+                             **({} if router_input is None
+                                else {"RouterX": [router_input]})},
                      outputs={"Out": [out], "ExpertLoad": [load],
                               "Kept": kept},
-                     attrs={"top_k": int(top_k),
-                            "first_expert": int(first_expert),
-                            "routed_scaling_factor":
-                                float(routed_scaling_factor)})
+                     attrs=attrs)
     return out, load, bias
 
 

@@ -229,12 +229,15 @@ def role_scope(role):
     return "role_fwd"
 
 
-# Ops whose lowering runs a sub-block through ``run_block`` as a loop body:
-# the body's ops carry their own ``role_*`` / ``fluid_<op>`` scopes, and a
-# ``fluid_recurrent`` around them would be every body instruction's FIRST
-# ``fluid_*`` match, one line holding the whole loop in every reader.  The
-# lowering names the body itself (``ut_loop``).
-_LOOP_OPS = frozenset(["recurrent", "recurrent_grad"])
+# Ops whose lowering runs a sub-block's ops through ``dispatch`` (a loop
+# body; a ``recompute`` span): the inner ops carry their own ``role_*`` /
+# ``fluid_<op>`` scopes, and a ``fluid_recurrent`` or ``fluid_recompute``
+# around them would be every inner instruction's FIRST ``fluid_*`` match, one
+# line holding the whole loop (or every layer of a checkpointed model) in
+# every reader.  The loop's lowering names the body itself (``ut_loop``); a
+# span's replay is named by ``jax.checkpoint`` (``rematted_computation``).
+_BODY_OPS = frozenset(["recurrent", "recurrent_grad", "recompute",
+                       "recompute_grad"])
 
 
 def op_scopes(op):
@@ -243,7 +246,7 @@ def op_scopes(op):
     scope a path segment) and ``fluid_<type>``."""
     names = [role_scope(op.op_role)]
     names += [s for s in (op.attr("op_namescope", "") or "").split("/") if s]
-    if op.type not in _LOOP_OPS:
+    if op.type not in _BODY_OPS:
         names.append("fluid_" + op.type)
     return names
 

@@ -499,6 +499,21 @@ def _print(ctx, op):
     ctx.set("Out", x)
 
 
+# The scope ``jax.checkpoint`` itself puts around the forward it builds
+# again inside a backward: every operation of a ``recompute`` span's replay
+# carries ``.../checkpoint/rematted_computation/...`` in its ``op_name``
+# (the span's transposes carry ``checkpoint/`` alone), so a device trace
+# tells the price of fitting from the backward proper with no scope of ours.
+REPLAY_SCOPE = "rematted_computation"
+
+_m_recompute_lowered = telemetry.counter(
+    "recompute_lowered_total",
+    "recompute spans lowered, by the number of ops in the span (a training "
+    "step traces each span twice: the forward op, and its grad op, which "
+    "differentiates a second run of the span and replays its forward under "
+    "'rematted_computation')")
+
+
 @register_op("recompute")
 def _recompute(ctx, op):
     """Rematerialized forward segment (``jax.checkpoint``): run the
@@ -507,9 +522,19 @@ def _recompute(ctx, op):
     backward pass instead of keeping them live in HBM — the
     memory-for-FLOPs trade of the reference's (1.6+) RecomputeOptimizer,
     re-founded on jax.checkpoint.  RNG ops inside the segment replay
-    identically on recompute (per-op counter keys, lowering.py rng)."""
+    identically on recompute (per-op counter keys, lowering.py rng).
+
+    Inside a span the backward is the generic vjp of the span, not the grad
+    ops' own lowerings: a ``fused_attention`` goes through the JAX-level
+    ``custom_vjp`` of its kernels (``flash_fwd`` in the span and again in
+    its replay, the backward kernels once) and a ``routed_experts`` through
+    ``_ladder`` (its forward conditional twice, its backward one once); the
+    op-level hand-overs (``LSE``, ``Kept``) stay inside the span.  A
+    persistable the span writes (``ExpertLoad``, batch norm's statistics)
+    is one of its outputs."""
     state = ctx.state
     sub = state.blocks[ctx.attr("sub_block")]
+    _m_recompute_lowered.inc(ops=len(sub.ops))
     in_names = ctx.attr("input_vars")
     out_names = ctx.attr("output_vars")
     # append_backward cuts grad flow at stop_gradient/no_grad vars; the

@@ -7,12 +7,15 @@ selection bias.  The reference predates all of them.
 (attributes ``first_expert`` and the leading dimension of its weights): it
 routes over all ``E`` experts of the model — float32 sigmoid scores, the
 ``top_k`` largest of score + selection bias, weights from the scores alone,
-normalised and scaled — and computes the part of ``sum_i w_i E_i(x)`` that
+normalised and scaled; or, with ``scoring_func="softmax"``, the softmax
+over the chosen logits; from the experts' input or from a second one, the
+op's ``RouterX`` — and computes the part of ``sum_i w_i E_i(x)`` that
 the experts held here give.  What the absent experts would add is left out:
 in an expert-parallel deployment it arrives through the exchange this
 single-chip lowering does not have, and nothing stands in for it.  There is
 no capacity and no dropped token: the assignments are sorted by expert, the
-rows of the held ones gathered, and three grouped matmuls (SwiGLU) run over
+rows of the held ones gathered, and three grouped matmuls (a gated unit:
+SwiGLU, or ReGLU with ``hidden_act="relu"``) run over
 the rows actually present — ``jax.lax.ragged_dot``, which XLA:TPU compiles
 to its own Mosaic grouped-matmul kernel that skips the tiles past the last
 group, and whose transpose rules give both backward products.  (On the
@@ -172,20 +175,31 @@ def _gated_short_conv(ctx, op):
 
 # -- the routed-expert layer ---------------------------------------------------
 
-def route(x, router_w, select_bias, top_k, scale):
-    """``(idx [T, k] int32, weight [T, k] float32, load [E] float32)``:
-    float32 sigmoid scores over all E experts; the ``top_k`` largest of
-    score + bias are chosen (ties: the lower index), the bias chooses and
-    does not weigh; weights are the chosen scores over their sum (+1e-20),
-    times ``scale``; ``load`` counts the tokens that chose each expert."""
+_SCORES = {"sigmoid": jax.nn.sigmoid, "softmax": lambda logits: logits}
+
+
+def route(x, router_w, select_bias, top_k, scale, scoring_func="sigmoid"):
+    """``(idx [T, k] int32, weight [T, k] float32, load [E] float32)`` from
+    the router's input ``x`` [T, H] (the experts' own input, or another:
+    the op's ``RouterX``): float32 scores over all E experts; the ``top_k``
+    largest of score + bias are chosen (ties: the lower index), the bias
+    chooses and does not weigh; ``load`` counts the tokens that chose each
+    expert.  ``scoring_func`` ``sigmoid``: the scores are the logits'
+    sigmoids and the weights the chosen scores over their sum (+1e-20),
+    times ``scale``.  ``softmax``: the scores are the logits themselves and
+    the weights the softmax over the CHOSEN logits, times ``scale``, which
+    is the softmax over all E, chosen and renormalised."""
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=_HIGHEST)
-    scores = jax.nn.sigmoid(logits)
+    scores = _SCORES[scoring_func](logits)
     _, idx = jax.lax.top_k(
         jax.lax.stop_gradient(scores) + select_bias.astype(jnp.float32),
         top_k)
     chosen = jnp.take_along_axis(scores, idx, axis=-1)
-    weight = chosen / (chosen.sum(axis=-1, keepdims=True) + 1e-20) * scale
+    if scoring_func == "sigmoid":
+        weight = chosen / (chosen.sum(axis=-1, keepdims=True) + 1e-20) * scale
+    else:
+        weight = jax.nn.softmax(chosen, axis=-1) * scale
     E = router_w.shape[-1]
     load = (idx[..., None] == jnp.arange(E)).sum(axis=(0, 1))
     return idx, weight, load.astype(jnp.float32)
@@ -304,8 +318,13 @@ def _gate_up(xs, wg, wu, group_sizes, acc):
         _grouped(xs, wu, group_sizes, acc)
 
 
-def _swiglu(gate, up, dtype):
-    return (jax.nn.silu(gate) * up).astype(dtype)
+_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _gated(act, gate, up, dtype):
+    """The gated unit's hidden rows, ``act(gate) * up``: SwiGLU with
+    ``silu``, ReGLU with ``relu``."""
+    return (_ACTIVATIONS[act](gate) * up).astype(dtype)
 
 
 def _every_row(dtypes, x, weight, wg, wu, wd, plan):
@@ -314,16 +333,17 @@ def _every_row(dtypes, x, weight, wg, wu, wd, plan):
     the whole layer where it has one.  Gather, grouped SwiGLU, weighted
     gather-sum; differentiable as it stands (``_dispatch`` and
     ``_combine`` carry their gathers' backward).  ``dtypes``: the one the
-    rows are computed in and the matmuls' accumulator (``amp_operands``)."""
+    rows are computed in, the matmuls' accumulator (``amp_operands``) and
+    the gate's activation (``_gated``)."""
     order, token_of, slot_of, held, group_sizes = plan
-    rows_dtype, acc = dtypes
+    rows_dtype, acc, act = dtypes
     R = order.shape[0]
     with _rows_scope("moe_dispatch", R):
         xs = _dispatch(x, token_of, slot_of, held)           # [T * k, H]
     with _rows_scope("moe_experts", R):
         xs = xs.astype(rows_dtype)
         gate, up = _gate_up(xs, wg, wu, group_sizes, acc)
-        ys = _grouped(_swiglu(gate, up, rows_dtype), wd, group_sizes,
+        ys = _grouped(_gated(act, gate, up, rows_dtype), wd, group_sizes,
                       acc).astype(x.dtype)
     with _rows_scope("moe_combine", R):
         return _combine(ys, weight, order, token_of, slot_of, held)
@@ -364,12 +384,13 @@ def _first_rung(R, dtypes, x, weight, wg, wu, wd, plan):
     ``x``'s is exact (the sum is float32 either way: half the bytes for
     the token side to read)."""
     _, token_of, slot_of, held, group_sizes = _first_rows(plan, R)
-    rows_dtype, acc = dtypes
+    rows_dtype, acc, act = dtypes
     with _rows_scope("moe_dispatch", R):
         xs = x[token_of].astype(rows_dtype)                  # [R, H]
     with _rows_scope("moe_experts", R):
         gate, up = _gate_up(xs, wg, wu, group_sizes, acc)
-        ys = _grouped(_swiglu(gate, up, rows_dtype), wd, group_sizes, acc)
+        ys = _grouped(_gated(act, gate, up, rows_dtype), wd, group_sizes,
+                      acc)
         if jnp.promote_types(ys.dtype, x.dtype) != x.dtype:
             ys = ys.astype(x.dtype)     # a narrowing, as ``_every_row``'s
     with _rows_scope("moe_combine", R):
@@ -386,7 +407,7 @@ def _first_rung_backward(R, dtypes, x, weight, wg, wu, wd, plan, kept, g):
     ``R`` rows and then a gather of scalars, where ``_combine``'s backward
     gathers ``[T, k, H]`` a second time."""
     order, token_of, slot_of, held, group_sizes = _first_rows(plan, R)
-    rows_dtype, acc = dtypes
+    rows_dtype, acc, act = dtypes
     xs, gate, up, ys = kept
     with _rows_scope("moe_combine", R):
         g_rows = g[token_of].astype(jnp.float32)             # [R, H]
@@ -395,14 +416,14 @@ def _first_rung_backward(R, dtypes, x, weight, wg, wu, wd, plan, kept, g):
         dw_row = (ys.astype(jnp.float32) * g_rows).sum(axis=-1)
         dweight = jnp.where(held, dw_row[slot_of], 0).astype(weight.dtype)
     with _rows_scope("moe_experts", R):
-        hidden, swiglu_vjp = jax.vjp(
-            lambda a, b: _swiglu(a, b, rows_dtype), gate, up)
+        hidden, gated_vjp = jax.vjp(
+            lambda a, b: _gated(act, a, b, rows_dtype), gate, up)
         dhidden, dwd = jax.vjp(
             lambda h, w: _grouped(h, w, group_sizes, acc).astype(ys.dtype),
             hidden, wd)[1](dy)
         dxs, dwg, dwu = jax.vjp(
             lambda a, b, c: _gate_up(a, b, c, group_sizes, acc),
-            xs, wg, wu)[1](swiglu_vjp(dhidden))
+            xs, wg, wu)[1](gated_vjp(dhidden))
     with _rows_scope("moe_dispatch", R):
         dx = _sum_by_token(dxs.astype(x.dtype), slot_of, held)
     return dx.astype(x.dtype), dweight, dwg, dwu, dwd
@@ -478,60 +499,86 @@ _ladder.defvjp(_ladder_fwd, _ladder_bwd)
 
 
 def _route_and_plan(x, router_w, select_bias, *, top_k, scale, first_expert,
-                    n_held):
-    """``weight`` [T, k] and ``(plan, load)``: everything of the layer
-    that does not depend on a rung."""
+                    n_held, scoring_func="sigmoid"):
+    """``weight`` [T, k] and ``(plan, load)`` from the router's input
+    ``x``: everything of the layer that does not depend on a rung."""
     with jax.named_scope("moe_route"):
-        idx, weight, load = route(x, router_w, select_bias, top_k, scale)
+        idx, weight, load = route(x, router_w, select_bias, top_k, scale,
+                                  scoring_func)
     with jax.named_scope("moe_dispatch"):
         plan = _plan(idx, first_expert, n_held)
     return weight, (plan, load)
 
 
 def _held_part(x, router_w, select_bias, w_gate, w_up, w_down, state, *,
-               top_k, scale, first_expert):
+               top_k, scale, first_expert, router_x=None,
+               scoring_func="sigmoid", hidden_act="silu"):
     """``(out, load, kept)``; ``kept`` is None where the layer has one
-    rung, whose backward needs nothing handed over."""
+    rung, whose backward needs nothing handed over.  ``router_x`` [T, H]:
+    what the router reads where that is not the experts' input ``x``."""
     weight, (plan, load) = _route_and_plan(
-        x, router_w, select_bias, top_k=top_k, scale=scale,
-        first_expert=first_expert, n_held=w_gate.shape[0])
-    operands, dtypes, rungs = _rows_operands(state, x, router_w, w_gate,
-                                             w_up, w_down, top_k)
+        x if router_x is None else router_x, router_w, select_bias,
+        top_k=top_k, scale=scale, first_expert=first_expert,
+        n_held=w_gate.shape[0], scoring_func=scoring_func)
+    operands, dtypes, rungs = _rows_operands(
+        state, x, router_w, w_gate, w_up, w_down, top_k, scoring_func,
+        hidden_act)
     if len(rungs) == 1:
         return _every_row(dtypes, x, weight, *operands, plan), load, None
     out, kept = _ladder(rungs, dtypes, x, weight, *operands, plan)
     return out, load, kept
 
 
-def _rows_operands(state, x, router_w, w_gate, w_up, w_down, top_k):
-    """The expert weights in the compute dtype, the rungs' ``dtypes`` and
-    the layer's rungs (counted: one lowering traced)."""
+def _rows_operands(state, x, router_w, w_gate, w_up, w_down, top_k,
+                   scoring_func="sigmoid", hidden_act="silu"):
+    """The expert weights in the compute dtype, the rungs' ``dtypes`` (with
+    the gate's activation) and the layer's rungs (counted: one lowering
+    traced)."""
     xc, wg, wu, wd, acc = amp_operands(state, x, w_gate, w_up, w_down)
     rungs = _rungs(x.shape[0], top_k, w_gate.shape[0], router_w.shape[-1])
     _m_experts_lowered.inc(path="ragged_dot",
-                           rows="|".join(str(R) for R in rungs))
-    return (wg, wu, wd), (xc.dtype, acc), rungs
+                           rows="|".join(str(R) for R in rungs),
+                           score=scoring_func, act=hidden_act)
+    return (wg, wu, wd), (xc.dtype, acc, hidden_act), rungs
 
 
 def routed_experts(x, router_w, select_bias, w_gate, w_up, w_down, *,
-                   top_k, scale, first_expert, state=None):
+                   top_k, scale, first_expert, state=None, router_x=None,
+                   scoring_func="sigmoid", hidden_act="silu"):
     """x [T, H]; router_w [H, E]; select_bias [E]; w_gate / w_up
     [held, H, I]; w_down [held, I, H] -> (out [T, H], load [E]): the part
     of ``sum_i w_i E_i(x)`` given by the experts ``first_expert ..
-    first_expert + held - 1``, ``E_i`` a SwiGLU."""
+    first_expert + held - 1``, ``E_i`` a gated unit (``hidden_act``), the
+    ``w_i`` from ``route`` on ``router_x`` (default: ``x``)."""
     return _held_part(x, router_w, select_bias, w_gate, w_up, w_down, state,
-                      top_k=top_k, scale=scale,
-                      first_expert=first_expert)[:2]
+                      top_k=top_k, scale=scale, first_expert=first_expert,
+                      router_x=router_x, scoring_func=scoring_func,
+                      hidden_act=hidden_act)[:2]
 
 
 def _op_operands(ctx):
+    """``(operands, keywords)`` of ``_held_part`` from the op (or its grad
+    op); ``router_x`` among the keywords where the op has a ``RouterX``."""
     x = ctx.i("X")
-    return (x.reshape(-1, x.shape[-1]), ctx.i("RouterW"),
-            ctx.i("SelectBias"), ctx.i("WGate"), ctx.i("WUp"),
-            ctx.i("WDown")), dict(
+    attrs = dict(
         top_k=int(ctx.attr("top_k")),
         scale=float(ctx.attr("routed_scaling_factor", 1.0)),
-        first_expert=int(ctx.attr("first_expert", 0)))
+        first_expert=int(ctx.attr("first_expert", 0)),
+        scoring_func=ctx.attr("scoring_func", "sigmoid") or "sigmoid",
+        hidden_act=ctx.attr("hidden_act", "silu") or "silu")
+    if attrs["scoring_func"] not in _SCORES or \
+            attrs["hidden_act"] not in _ACTIVATIONS:
+        raise ValueError("routed_experts: scoring_func %r, hidden_act %r"
+                         % (attrs["scoring_func"], attrs["hidden_act"]))
+    router_x = ctx.i_opt("RouterX")
+    if router_x is not None:
+        if router_x.shape != x.shape:
+            raise ValueError("routed_experts: RouterX %s beside X %s"
+                             % (router_x.shape, x.shape))
+        attrs["router_x"] = router_x.reshape(-1, x.shape[-1])
+    return (x.reshape(-1, x.shape[-1]), ctx.i("RouterW"),
+            ctx.i("SelectBias"), ctx.i("WGate"), ctx.i("WUp"),
+            ctx.i("WDown")), attrs
 
 
 @register_op("routed_experts", nondiff_inputs=("SelectBias",))
@@ -540,6 +587,13 @@ def _routed_experts(ctx, op):
     by ``moe_bias_update``); WGate / WUp [held, H, I]; WDown [held, I, H]
     -> Out [..., H] (the held experts' part of the routed sum) and
     ExpertLoad [E] float32 (tokens that chose each expert, over all E).
+
+    ``RouterX`` [..., H] (optional): what the router reads, where that is
+    not the experts' input (a router placed before the attention of its
+    layer); its gradient comes through the weights alone and is written
+    apart from X's.  Attributes ``scoring_func`` (``sigmoid``, the default,
+    or ``softmax``: ``route``) and ``hidden_act`` (``silu`` or ``relu``:
+    ``_gated``).
 
     ``Kept`` (four variables, optional): where the layer has two rungs,
     the first rung's rows ``(xs, gate, up, ys)`` as the forward
@@ -570,19 +624,26 @@ def _routed_experts_grad(ctx, op):
         return
     (x, router_w, select_bias, w_gate, w_up, w_down), attrs = \
         _op_operands(ctx)
+    router_x = attrs.pop("router_x", None)
+    hidden_act = attrs.pop("hidden_act")
     weight, route_vjp, (plan, _) = jax.vjp(
         lambda a, b: _route_and_plan(a, b, select_bias,
                                      n_held=w_gate.shape[0], **attrs),
-        x, router_w, has_aux=True)
+        x if router_x is None else router_x, router_w, has_aux=True)
     (wg, wu, wd), dtypes, rungs = _rows_operands(
-        ctx.state, x, router_w, w_gate, w_up, w_down, attrs["top_k"])
+        ctx.state, x, router_w, w_gate, w_up, w_down, attrs["top_k"],
+        attrs["scoring_func"], hidden_act)
     dx, dweight, dwg, dwu, dwd = _backward_by_rung(
         rungs, dtypes, x, weight, wg, wu, wd, plan, kept,
         g.reshape(x.shape).astype(x.dtype))
     dx_route, drouter = route_vjp(dweight)
-    grads = {"X": (dx + dx_route).reshape(ctx.i("X").shape),
-             "RouterW": drouter, "WGate": dwg.astype(w_gate.dtype),
-             "WUp": dwu.astype(w_up.dtype), "WDown": dwd.astype(w_down.dtype)}
+    shape = ctx.i("X").shape
+    if router_x is None:
+        grads = {"X": (dx + dx_route).reshape(shape)}
+    else:
+        grads = {"X": dx.reshape(shape), "RouterX": dx_route.reshape(shape)}
+    grads.update(RouterW=drouter, WGate=dwg.astype(w_gate.dtype),
+                 WUp=dwu.astype(w_up.dtype), WDown=dwd.astype(w_down.dtype))
     for slot, grad in grads.items():
         name = (op.output(slot + "@GRAD") or [""])[0]
         if name:

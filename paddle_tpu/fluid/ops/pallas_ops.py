@@ -165,8 +165,27 @@ here); that dQ pass is one sweep and takes delta from the kept ``out``.
 The rotary pair exists in that looped form alone (``_rope_runs_looped``):
 an op with a pair and a bias, or without the causal mask, composes one head
 size before the kernels (``_attention_route``).
+
+Sliding window (PR 39): with ``window`` = W under the causal mask query
+``i`` sees keys ``i - W < j <= i`` (``_in_band``).  The band bounds the
+looped sweeps on the side the diagonal leaves open: the forward and the dQ
+pass start at the k tile that holds the oldest key their block's FIRST row
+sees (``_first_k_block``), the dK/dV pass stops after the q tile of the
+youngest query that sees its block's LAST key (``_last_q_block``), and the
+tiles on either edge are masked by the ONE ``_band_mask``: at S=16384 with
+W=4096 a head visits 252 of the 528 tiles of 512.  A row that sees nothing
+of its first tile leaves ``exp(0)`` terms in its running sums, which the
+rescale multiplies by ``exp(-1e30 - m)`` = 0 when its own keys come (every
+row sees itself).  A windowed call is always the looped pair of passes
+(``_fused_backward`` is False: never ``flash_bwd``, never in place), has a
+plan of its own (``_shape_key``'s last element) and runs under the scope
+``attn_window``; what a cell HOLDS is the causal call's (the whole other
+side stays in VMEM).  ``W >= S_kv`` is the causal call itself (``_band``),
+traced as it was; beside a bias the op composes and masks the same band; a
+rotary pair and the sequence-parallel islands refuse a window by name.
 """
 
+import contextlib
 import functools
 
 import numpy as np
@@ -219,22 +238,31 @@ def _pallas_call(kernel, name, vmem_limit_bytes=None, **kwargs):
     return call
 
 
-def _reference_attention(q, k, v, bias, scale, causal=False):
+def _reference_attention(q, k, v, bias, scale, causal=False, window=0):
     """[BH, S, D] composition — the oracle and the vjp target.  A bias of
     fewer rows than ``q`` is one the heads of a sequence share
     (``_kernel_bias``: ``[B, S_q, S_kv]``), repeated here, so its
-    cotangent comes back summed over a sequence's heads."""
+    cotangent comes back summed over a sequence's heads.  ``window``: the
+    causal band (``_in_band``)."""
     s = jnp.einsum("bqd,bkd->bqk", q, k) * scale
     if bias is not None:
         if bias.shape[0] != q.shape[0]:
             bias = jnp.repeat(bias, q.shape[0] // bias.shape[0], axis=0)
         s = s + bias
     if causal:
-        S = q.shape[1]
-        allowed = jnp.arange(S)[:, None] >= jnp.arange(k.shape[1])[None, :]
+        allowed = _in_band(jnp.arange(q.shape[1])[:, None],
+                           jnp.arange(k.shape[1])[None, :], window)
         s = jnp.where(allowed[None], s, _NEG)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bqk,bkd->bqd", p, v)
+
+
+def _in_band(qpos, kpos, window=0):
+    """Which keys a query sees under the causal mask: ``kpos <= qpos``, and
+    with a sliding ``window`` W > 0 the last W of them alone, ``kpos > qpos
+    - W`` (the query's own position among them).  0: no window."""
+    allowed = qpos >= kpos
+    return allowed & (kpos > qpos - window) if window else allowed
 
 
 def _scores(q, ks, scale, qr=None, krs=None):
@@ -268,7 +296,7 @@ def _online_softmax(carry, s, vs, dtype):
 
 def _attention_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
                       scale, block_k, causal=False, qr_ref=None,
-                      kr_ref=None):
+                      kr_ref=None, window=0):
     # dots run in the INPUT dtype (bf16 under pure-bf16 AMP — a single
     # fast MXU pass) and accumulate fp32 via preferred_element_type;
     # casting inputs to fp32 first forces multi-pass fp32 MXU emulation
@@ -285,11 +313,15 @@ def _attention_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
             rows = _rows(kb, block_k)
             s = _scores(q, k_ref[0, rows, :], scale, qr,
                         None if kr_ref is None else kr_ref[0, rows, :])
-            s = _causal_mask(s, pid * bq, kb * block_k)
+            s = _band_mask(s, pid * bq, kb * block_k, window)
             return _online_softmax(carry, s, v_ref[0, rows, :], q.dtype)
-        # the k tiles that start at or before this q block's last row
+        # the k tiles that start at or before this q block's last row (and,
+        # under a window, from the one its first row's band reaches: a row
+        # that sees nothing of its first tile is flushed by the rescale
+        # when its own keys come)
         m, l, acc = jax.lax.fori_loop(
-            0, jnp.minimum(pl.cdiv((pid + 1) * bq, block_k), num_kb), step,
+            _first_k_block(pid, bq, block_k, window),
+            jnp.minimum(pl.cdiv((pid + 1) * bq, block_k), num_kb), step,
             (jnp.full((bq, 1), _NEG, jnp.float32),
              jnp.zeros((bq, 1), jnp.float32),
              jnp.zeros((bq, v_ref.shape[2]), jnp.float32)))
@@ -303,7 +335,7 @@ def _attention_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
                 s = _scores(q, ks, scale)
                 s = _add_bias(s, bias_ref, 0, bq, kb * block_k, block_k)
                 if causal:
-                    s = _causal_mask(s, pid * bq, kb * block_k)
+                    s = _band_mask(s, pid * bq, kb * block_k)
                 return _online_softmax(carry, s, vs, q.dtype)
 
             if causal and kb:
@@ -325,14 +357,19 @@ def _attention_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *,
         _stat_store(lse_ref, m + jnp.log(l))
 
 
-def _rope_runs_looped(rope, causal, bias):
+def _rope_runs_looped(rope, causal, bias, window=0):
     """A rotary pair exists in the kernels' looped sweeps alone — under the
     causal mask, without a bias: the decoder's case.  ``_attention_route``
-    composes one head size for every other op."""
+    composes one head size for every other op.  No model pairs one with a
+    sliding window: refused by name."""
     if rope is not None and not _loops_over_blocks(causal,
                                                    bias is not None):
         raise ValueError("the flash kernels take a rotary pair only under "
                          "the causal mask and without a bias")
+    if rope is not None and window:
+        raise NotImplementedError("the flash kernels take a rotary pair or "
+                                  "a sliding window, not both")
+
 
 
 def _rows(block, size):
@@ -352,19 +389,43 @@ def _add_bias(s, bias_ref, rows, row_len, cols, col_len, h=0):
                         cols:cols + col_len].astype(jnp.float32)
 
 
-def _causal_mask(s, q0, k0):
-    """Mask scores below the diagonal for a [bq, bk] block whose rows
-    start at absolute position q0 and columns at k0.  Rank-2 iota
-    (lax.broadcasted_iota) — Mosaic rejects rank-1 iota on TPU."""
+def _band_mask(s, q0, k0, window=0):
+    """Mask the scores outside the causal band (``_in_band``: above the
+    diagonal and, under a ``window``, more than ``window - 1`` keys behind
+    it) for a [bq, bk] block whose rows start at absolute position q0 and
+    columns at k0.  Rank-2 iota (lax.broadcasted_iota) — Mosaic rejects
+    rank-1 iota on TPU."""
     bq, bk = s.shape
     qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    return jnp.where(qpos >= kpos, s, _NEG)
+    return jnp.where(_in_band(qpos, kpos, window), s, _NEG)
+
+
+def _first_k_block(qb, block_q, block_k, window):
+    """The first k tile q block ``qb`` visits in the looped sweeps: 0, and
+    under a window the tile that holds the oldest key the block's FIRST row
+    sees, ``qb * block_q - window + 1`` (the tiles before it are outside
+    every row's band)."""
+    if not window:
+        return 0
+    return jnp.maximum(qb * block_q - (window - 1), 0) // block_k
+
+
+def _last_q_block(kb, block_k, block_q, num_qb, window):
+    """One past the last q tile that visits k block ``kb`` in the dK/dV
+    pass's looped sweep: every tile to the end, and under a window the tile
+    of the youngest query that still sees the block's LAST key, ``(kb + 1)
+    * block_k - 1 + window - 1``."""
+    if not window:
+        return num_qb
+    return jnp.minimum(num_qb,
+                       pl.cdiv((kb + 1) * block_k + window - 1, block_q))
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
                dq_ref, delta_out_ref, p_scr, dp_scr, *, scale, block_k,
-               causal=False, qr_ref=None, kr_ref=None, dqr_ref=None):
+               causal=False, qr_ref=None, kr_ref=None, dqr_ref=None,
+               window=0):
     """FlashAttention-2 backward, dQ pass: one q block vs all k tiles.
     p is recomputed from the saved LSE — no [S, S] materialization.
 
@@ -398,7 +459,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
         s = _scores(q, k_ref[0, cols, :], scale)
         s = _add_bias(s, bias_ref, 0, bq, kb * block_k, block_k)
         if causal:
-            s = _causal_mask(s, pid * bq, kb * block_k)
+            s = _band_mask(s, pid * bq, kb * block_k)
         p = jnp.exp(s - lse)
         dp = jnp.dot(do, v_ref[0, cols, :].T,
                      preferred_element_type=jnp.float32)
@@ -436,8 +497,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
             rows = _rows(kb, block_k)
             ks = k_ref[0, rows, :]
             krs = None if kr_ref is None else kr_ref[0, rows, :]
-            s = _causal_mask(_scores(q, ks, scale, qr, krs), pid * bq,
-                             kb * block_k)
+            s = _band_mask(_scores(q, ks, scale, qr, krs), pid * bq,
+                           kb * block_k, window)
             dp = jnp.dot(do, v_ref[0, rows, :].T,
                          preferred_element_type=jnp.float32)
             ds = (jnp.exp(s - lse) * (dp - delta) * scale).astype(q.dtype)
@@ -448,7 +509,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
                                         preferred_element_type=jnp.float32)
             return acc, acc_r
         acc, acc_r = jax.lax.fori_loop(
-            0, jnp.minimum(pl.cdiv((pid + 1) * bq, block_k), S // block_k),
+            _first_k_block(pid, bq, block_k, window),
+            jnp.minimum(pl.cdiv((pid + 1) * bq, block_k), S // block_k),
             step, (acc, acc_r))
         if qr_ref is not None:
             dqr_ref[0] = acc_r.astype(dqr_ref.dtype)
@@ -472,7 +534,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, *, scale, block_q, causal=False,
-                qr_ref=None, kr_ref=None, dkr_ref=None):
+                qr_ref=None, kr_ref=None, dkr_ref=None, window=0):
     """dK/dV pass: one k block vs all q tiles.  With a rotary part
     (``qr_ref`` [S_q, R], ``kr_ref`` [bk, R]; in the looped sweep only)
     ``dkr_ref`` takes THIS head's ``dS^T qr`` in float32; the caller sums
@@ -511,13 +573,16 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
             rows = _rows(qb, block_q)
             q = q_ref[0, rows, :]
             qr = None if qr_ref is None else qr_ref[0, rows, :]
-            s = _causal_mask(_scores(q, ks, scale, qr, krs), qb * block_q,
-                             pid * bk)
+            s = _band_mask(_scores(q, ks, scale, qr, krs), qb * block_q,
+                           pid * bk, window)
             return tile(carry, q, do_ref[0, rows, :], lse_ref[0, rows, :],
                         delta_ref[0, rows, :], s, qr)
-        # from the first q tile whose last row reaches this k block
-        dk, dv = jax.lax.fori_loop((pid * bk) // block_q, num_qb, step,
-                                   (dk, dv))
+        # from the first q tile whose last row reaches this k block (under
+        # a window: to the last whose first row still sees it)
+        dk, dv = jax.lax.fori_loop(
+            (pid * bk) // block_q,
+            _last_q_block(pid, bk, block_q, num_qb, window), step,
+            (dk, dv))
         if krs is not None:
             dk, dkr = dk
             dkr_ref[0] = dkr.astype(dkr_ref.dtype)
@@ -530,7 +595,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
                 s = _scores(q, ks, scale)
                 s = _add_bias(s, bias_ref, qb * block_q, block_q, 0, bk)
                 if causal:
-                    s = _causal_mask(s, qb * block_q, pid * bk)
+                    s = _band_mask(s, qb * block_q, pid * bk)
                 return tile(carry, q, do_ref[0, rows, :],
                             lse_ref[0, rows, :], delta_ref[0, rows, :], s)
 
@@ -568,7 +633,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
         s = _add_bias(_scores(q, ks, scale), bias_ref, 0, q.shape[0], 0,
                       ks.shape[0], h)
         if causal:
-            s = _causal_mask(s, 0, 0)
+            s = _band_mask(s, 0, 0)
         p = jnp.exp(s - _stat_column(lse_ref, h))
         dp = jnp.dot(do, vs.T, preferred_element_type=jnp.float32)
         delta = (p * dp).sum(axis=-1, keepdims=True) if delta_ref is None \
@@ -596,7 +661,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *, scale,
         s = _add_bias(_scores(q, _lanes(k_ref, h, heads), scale), bias_ref,
                       0, q.shape[0], 0, k_ref.shape[1], h)
         if causal:
-            s = _causal_mask(s, 0, 0)
+            s = _band_mask(s, 0, 0)
         m, l, acc = _online_softmax(None, s, _lanes(v_ref, h, heads),
                                     q.dtype)
         l = jnp.maximum(l, 1e-30)
@@ -646,7 +711,7 @@ def _dbias_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                         preferred_element_type=jnp.float32) * scale
             s = _add_bias(s, bias_ref, 0, bq, kb * block_k, block_k)
             if causal:
-                s = _causal_mask(s, pid * bq, kb * block_k)
+                s = _band_mask(s, pid * bq, kb * block_k)
             p = jnp.exp(s - lse)
             dp = jnp.dot(do.astype(q.dtype), vs.T,
                          preferred_element_type=jnp.float32)
@@ -802,7 +867,7 @@ def _whole_side(kernel, S_q, S_kv, D, D_v, R, itemsize):
 
 
 def _vmem_bytes(kernel, block_q, block_k, S_q, S_kv, D, D_v, R, has_bias,
-                causal, itemsize, group=1, rows=False, heads=1):
+                causal, itemsize, group=1, window=0, rows=False, heads=1):
     """VMEM one grid cell of ``kernel`` ('fwd', 'dq', 'dkv', 'bwd' or
     'dbias') asks for at tiles of ``block_q x block_k``, from shapes alone:
     what the chooser holds against ``_VMEM_BUDGET_BYTES`` and
@@ -817,7 +882,9 @@ def _vmem_bytes(kernel, block_q, block_k, S_q, S_kv, D, D_v, R, has_bias,
     D]`` in place, ``_in_place``) a cell's blocks hold that many heads
     side by side: every block of Q, K, V, dO and the outputs is ``heads``
     times as wide and each statistic has a row a head, while the score
-    tiles below stay one head's, the heads taking their turns.
+    tiles below stay one head's, the heads taking their turns.  A sliding
+    ``window`` bounds the sweeps, not what a cell holds: the whole other
+    side stays in VMEM, so the count is the causal call's.
 
     * the cell's own blocks, in and out, twice (the pipeline's two
       buffers): ``block_q`` rows of Q, its rotary part, dO, the row
@@ -903,7 +970,7 @@ def _vmem_limit(need):
 
 
 def _tiles(kernel, S_q, S_kv, D, D_v, R, has_bias, causal, itemsize,
-           group=1):
+           group=1, window=0):
     """``(ok, block_q, block_k)`` of one kernel ('fwd', 'dq', 'dkv',
     'bwd', 'dbias') at one shape: the largest tile, sides from
     ``_TILE_SIDES`` that divide the sequence, whose ``_vmem_bytes`` fits
@@ -915,10 +982,14 @@ def _tiles(kernel, S_q, S_kv, D, D_v, R, has_bias, causal, itemsize,
     divides a sequence (the caller composes), or nothing fits.
 
     'bwd' is the fused backward, whose one tile is the whole head where
-    ``_fused_backward`` lets it run."""
+    ``_fused_backward`` lets it run.  A sliding ``window`` exists in the
+    looped sweeps alone (under the causal mask, without a bias): with a
+    bias no kernel has a tile, and the caller composes."""
+    if window and not _loops_over_blocks(causal, has_bias):
+        return False, min(_TILE_SIDES[-1], S_q), min(_TILE_SIDES[-1], S_kv)
     if kernel == "bwd":
         return _fused_backward(S_q, S_kv, D, D_v, R, has_bias, causal,
-                               itemsize, group), S_q, S_kv
+                               itemsize, group, window), S_q, S_kv
 
     def sides(S):
         # a sequence shorter than the least side is one block
@@ -931,14 +1002,14 @@ def _tiles(kernel, S_q, S_kv, D, D_v, R, has_bias, causal, itemsize,
             ((bq, bk) for bq in sides(S_q) for bk in sides(S_kv)),
             key=size, reverse=True):
         if _vmem_bytes(kernel, block_q, block_k, S_q, S_kv, D, D_v, R,
-                       has_bias, causal, itemsize,
-                       group) <= _VMEM_BUDGET_BYTES:
+                       has_bias, causal, itemsize, group,
+                       window) <= _VMEM_BUDGET_BYTES:
             return True, block_q, block_k
     return False, min(_TILE_SIDES[-1], S_q), min(_TILE_SIDES[-1], S_kv)
 
 
 def _fused_backward(S_q, S_kv, D, D_v, R, has_bias, causal, itemsize,
-                    group=1):
+                    group=1, window=0):
     """Whether the backward at this shape (``_shape_key``) is ONE kernel
     (``_bwd_kernel``) and not the dQ pass followed by the dK/dV pass, from
     the shape alone: where the chooser gives BOTH passes a tile that covers
@@ -946,14 +1017,15 @@ def _fused_backward(S_q, S_kv, D, D_v, R, has_bias, causal, itemsize,
     gradient is accumulated across cells, so nothing forces two passes that
     each rebuild S, P, dP and dS), there is no rotary pair (it exists in
     the looped sweeps alone), no group of query heads over one key/value
-    head (dK and dV are then sums over the group: ``_flash_dkv``) and the
+    head (dK and dV are then sums over the group: ``_flash_dkv``), no
+    sliding window (the band's bounds live in the looped sweeps) and the
     kernel's own estimate fits the budget.  S <= 512 at the BERT widths,
     bias or none, causal or not (the mask on the one tile: there is no
     diagonal to skip).  These are also the shapes whose kernels can read
     ``[B, S, H * D]`` operands in place (``_in_place``): a cell that owns
     its heads' whole rows may as well own several heads' lanes."""
     shape = (S_q, S_kv, D, D_v, R, has_bias, causal, itemsize)
-    return not R and group == 1 and \
+    return not R and group == 1 and not window and \
         all(_tiles(kernel, *shape) == (True, S_q, S_kv)
             for kernel in ("dq", "dkv")) and \
         _vmem_bytes("bwd", S_q, S_kv, *shape) <= _VMEM_BUDGET_BYTES
@@ -992,7 +1064,7 @@ def _in_place_shape(q, k, bias, causal, heads):
     """``_shape_key`` of operands ``[B, S, heads * D]``."""
     D = q.shape[2] // heads
     return (q.shape[1], k.shape[1], D, D, 0, bias is not None, bool(causal),
-            q.dtype.itemsize, 1)
+            q.dtype.itemsize, 1, 0)
 
 
 def _cell_specs(q, k, bias, heads):
@@ -1031,13 +1103,37 @@ def _cell_specs(q, k, bias, heads):
             bias_spec)
 
 
-def _shape_key(q, k, v, bias, causal, rope):
+def _shape_key(q, k, v, bias, causal, rope, window=0):
     """What the chooser sees of a call: ``_tiles``'s arguments after the
-    kernel's name (the last: the query heads that share a key/value
-    head)."""
+    kernel's name (the last two: the query heads that share a key/value
+    head, and the sliding window, so a windowed call never shares a plan
+    with a causal one)."""
     return (q.shape[1], k.shape[1], q.shape[2], v.shape[2],
             0 if rope is None else rope[0].shape[2], bias is not None,
-            bool(causal), q.dtype.itemsize, q.shape[0] // k.shape[0])
+            bool(causal), q.dtype.itemsize, q.shape[0] // k.shape[0],
+            int(window))
+
+
+def _band(window, causal, S_kv):
+    """The sliding window a call runs with: ``window`` keys back from the
+    query, itself among them; 0 (none) where the window covers the whole
+    sequence, which IS the causal call.  A window needs the causal mask."""
+    window = int(window or 0)
+    if window < 0 or (window and not causal):
+        raise ValueError("attention: window=%d needs causal=True and a "
+                         "positive size" % window)
+    return 0 if window >= S_kv else window
+
+
+@contextlib.contextmanager
+def _window_scope(window):
+    """``attn_window`` around a windowed call's kernels, so a device trace
+    tells them from the full layers' (the same kernel names)."""
+    if not window:
+        yield
+        return
+    with jax.named_scope("attn_window"):
+        yield
 
 
 def _flash_fits(*shape):
@@ -1058,6 +1154,7 @@ def _plan(kernel, q, bias, *shape, heads=None):
     _, block_q, block_k = _tiles(kernel, *shape)
     rows = _row_stats(kernel, *shape)
     _m_tiles.inc(kernel=kernel, block_q=block_q, block_k=block_k,
+                 window=shape[9],
                  stats="row" if rows else "column",
                  bias="none" if bias is None
                  else "head" if bias.shape[0] == q.shape[0] * (heads or 1)
@@ -1085,7 +1182,8 @@ _m_tiles = telemetry.counter(
     "where the operands are [BH, S, D], a head a cell, 'bshd' where 'fwd' "
     "and 'bwd' read Q, K, V and dO as [B, S, H * D] where the projections "
     "left them and write O, dQ, dK and dV the same way, 128 // D heads a "
-    "cell")
+    "cell; window: the sliding window whose band bounds the call's sweeps "
+    "(0: none, the causal or the full call)")
 
 
 def _rope_specs(rope, q_block, k_block, whole_mode=None):
@@ -1156,7 +1254,7 @@ def _compose_rope(q, k, rope):
 
 
 def _flash_forward(q, k, v, bias, scale, *, with_lse=False,
-                   causal=False, rope=None):
+                   causal=False, rope=None, window=0):
     """q: [BH, S_q, D]; k: [BH, S_kv, D]; v: [BH, S_kv, D_v]
     (cross-attention supported; D_v may differ from D, the output has D_v);
     bias: [BH, S_q, S_kv], [B, S_q, S_kv] (the same for the H = BH / B
@@ -1164,7 +1262,8 @@ def _flash_forward(q, k, v, bias, scale, *, with_lse=False,
     kr [B, S_kv, R])`` or None — a second part of every head whose keys
     are shared by the heads of a sequence (``_scores``).  ``with_lse``:
     ``(out, logsumexp [BH, S_q] float32)``, whichever way the kernel wrote
-    it (``_row_stats``)."""
+    it (``_row_stats``).  ``window`` (``_band``): under the causal mask a
+    query sees its last ``window`` keys alone."""
     BH, S_q, D = q.shape
     S_kv = k.shape[1]
     D_v = v.shape[2]
@@ -1176,7 +1275,8 @@ def _flash_forward(q, k, v, bias, scale, *, with_lse=False,
             "causal=True needs S_q == S_kv (got %d vs %d); apply an "
             "explicit bias for cross-length causal masking"
             % (S_q, S_kv))
-    shape = _shape_key(q, k, v, bias, causal, rope)
+    window = _band(window, causal, S_kv)
+    shape = _shape_key(q, k, v, bias, causal, rope, window)
     kv = _kv_row(q, k)
     if not _flash_fits(*shape):
         if shape[8] != 1:
@@ -1185,14 +1285,14 @@ def _flash_forward(q, k, v, bias, scale, *, with_lse=False,
                 "kernels have no tile for; repeat K and V to the query "
                 "heads first (the fused_attention op does)")
         out = _reference_attention(*_compose_rope(q, k, rope), v, bias,
-                                   scale, causal=causal)
+                                   scale, causal=causal, window=window)
         if not with_lse:
             return out
         # (with_lse is only requested by _fa_fwd AFTER the same
         # tileability check, so this fallback never computes an LSE)
         raise AssertionError("with_lse requested for a non-tileable "
                              "shape — caller bug")
-    _rope_runs_looped(rope, causal, bias)
+    _rope_runs_looped(rope, causal, bias, window)
     if rope is not None and shape[8] != 1:
         raise ValueError("the flash kernels take a rotary pair or grouped "
                          "key/value heads, not both")
@@ -1224,7 +1324,7 @@ def _flash_forward(q, k, v, bias, scale, *, with_lse=False,
         lse_ref = refs[n_in + 1] if with_lse else None
         _attention_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                           scale=scale, block_k=block_k, causal=causal,
-                          qr_ref=qr_ref, kr_ref=kr_ref)
+                          qr_ref=qr_ref, kr_ref=kr_ref, window=window)
 
     out_specs = [pl.BlockSpec((1, block_q, D_v), lambda i, j: (i, j, 0))]
     out_shape = [jax.ShapeDtypeStruct((BH, S_q, D_v), q.dtype)]
@@ -1232,13 +1332,14 @@ def _flash_forward(q, k, v, bias, scale, *, with_lse=False,
         out_specs.append(_row_stat_spec(block_q, rows))
         out_shape.append(jax.ShapeDtypeStruct(
             (BH, 1, S_q) if rows else (BH, S_q, 1), jnp.float32))
-    res = _pallas_call(
-        kern, "flash_fwd", vmem_limit_bytes=vmem,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-    )(*args)
+    with _window_scope(window):
+        res = _pallas_call(
+            kern, "flash_fwd", vmem_limit_bytes=vmem,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+        )(*args)
     if not with_lse:
         return res[0]
     return res[0], (res[1][:, 0] if rows else res[1][..., 0])
@@ -1251,7 +1352,8 @@ def _row_delta(g, out):
     return jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
 
 
-def _flash_dq(q, k, v, bias, scale, lse, g, causal, delta, rope=None):
+def _flash_dq(q, k, v, bias, scale, lse, g, causal, delta, rope=None,
+              window=0):
     """The dQ pass (grid over q blocks): ``(dq, delta)``.  With
     ``delta=None`` the kernel forms it (``_dq_kernel``) and writes it as a
     further output; a passed delta comes back as it went in.  With a rotary
@@ -1260,8 +1362,8 @@ def _flash_dq(q, k, v, bias, scale, lse, g, causal, delta, rope=None):
     S_kv = k.shape[1]
     D_v = v.shape[2]
     block_q, block_k, whole, vmem = _plan(
-        "dq", q, bias, *_shape_key(q, k, v, bias, causal, rope))
-    _rope_runs_looped(rope, causal, bias)
+        "dq", q, bias, *_shape_key(q, k, v, bias, causal, rope, window))
+    _rope_runs_looped(rope, causal, bias, window)
     in_kernel = delta is None
     kv = _kv_row(q, k)
     q_block = pl.BlockSpec((1, block_q, D), lambda i, j: (i, j, 0))
@@ -1312,21 +1414,24 @@ def _flash_dq(q, k, v, bias, scale, lse, g, causal, delta, rope=None):
         _dq_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                    delta_ref, dq_ref, delta_out_ref, p_scr, dp_scr,
                    scale=scale, block_k=block_k, causal=causal,
-                   qr_ref=qr_ref, kr_ref=kr_ref, dqr_ref=dqr_ref)
+                   qr_ref=qr_ref, kr_ref=kr_ref, dqr_ref=dqr_ref,
+                   window=window)
 
-    res = _pallas_call(
-        kern, "flash_dq", vmem_limit_bytes=vmem,
-        grid=(BH, S_q // block_q),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-    )(*args)
+    with _window_scope(window):
+        res = _pallas_call(
+            kern, "flash_dq", vmem_limit_bytes=vmem,
+            grid=(BH, S_q // block_q),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=scratch,
+        )(*args)
     return (res[0] if rope is None else (res[0], res[1])), \
         (res[-1] if in_kernel else delta)
 
 
-def _flash_dkv(q, k, v, bias, scale, lse, g, causal, delta, rope=None):
+def _flash_dkv(q, k, v, bias, scale, lse, g, causal, delta, rope=None,
+               window=0):
     """The dK/dV pass (grid over k blocks, the whole Q side of a head in
     VMEM): ``(dk, dv)``.  With a rotary pair ``dk`` is the pair ``(dk,
     dkr)``, ``dkr`` summed over the heads that share the rotary keys.
@@ -1343,7 +1448,7 @@ def _flash_dkv(q, k, v, bias, scale, lse, g, causal, delta, rope=None):
     S_kv = k.shape[1]
     D_v = v.shape[2]
     block_q, block_k, whole, vmem = _plan(
-        "dkv", q, bias, *_shape_key(q, k, v, bias, causal, rope))
+        "dkv", q, bias, *_shape_key(q, k, v, bias, causal, rope, window))
     kv = _kv_row(q, k)
     group = BH // k.shape[0]
     in_specs = [
@@ -1369,7 +1474,8 @@ def _flash_dkv(q, k, v, bias, scale, lse, g, causal, delta, rope=None):
         _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, *refs[:5],
                     scale=scale, block_q=block_q, causal=causal,
                     qr_ref=qr_ref, kr_ref=kr_ref,
-                    dkr_ref=refs[5] if rope is not None else None)
+                    dkr_ref=refs[5] if rope is not None else None,
+                    window=window)
     in_specs += [
         pl.BlockSpec((1, S_q, D_v), lambda i, j: (i, 0, 0), **whole),  # dO
         pl.BlockSpec((1, S_q, 1), lambda i, j: (i, 0, 0), **whole),   # lse
@@ -1389,13 +1495,14 @@ def _flash_dkv(q, k, v, bias, scale, lse, g, causal, delta, rope=None):
             pl.BlockSpec((1, block_k, R), lambda i, j: (i, j, 0)))
         out_shape.append(
             jax.ShapeDtypeStruct((BH, S_kv, R), jnp.float32))
-    dk, dv, *dkr = _pallas_call(
-        kern, "flash_dkv", vmem_limit_bytes=vmem,
-        grid=(BH, S_kv // block_k),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-    )(*args, g, lse, delta)
+    with _window_scope(window):
+        dk, dv, *dkr = _pallas_call(
+            kern, "flash_dkv", vmem_limit_bytes=vmem,
+            grid=(BH, S_kv // block_k),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+        )(*args, g, lse, delta)
     if rope is not None:
         kr = rope[1]
         dk = (dk, dkr[0].reshape(kr.shape[0], -1, S_kv, kr.shape[2])
@@ -1508,7 +1615,7 @@ def _flash_fwd_in_place(q, k, v, bias, scale, heads, causal=False,
 
 
 def _flash_backward(q, k, v, bias, scale, lse, g, causal=False, delta=None,
-                    bias_grad=True, rope=None):
+                    bias_grad=True, rope=None, window=0):
     """Tiled dQ/dK/dV — recomputes p blockwise from the saved LSE
     (``[BH, S_q]`` float32, as ``_flash_forward`` hands it out; laid out
     here as the shape's kernels take it, ``_row_stats``); the [S, S]
@@ -1533,7 +1640,8 @@ def _flash_backward(q, k, v, bias, scale, lse, g, causal=False, delta=None,
     BH, S_q, D = q.shape
     S_kv = k.shape[1]
     D_v = v.shape[2]
-    shape = _shape_key(q, k, v, bias, causal, rope)
+    window = _band(window, causal, S_kv)
+    shape = _shape_key(q, k, v, bias, causal, rope, window)
     want_dbias = bias is not None and bias_grad
     rows = _row_stats("bwd", *shape)
     lse, delta = _kernel_stat(lse, rows), _kernel_stat(delta, rows)
@@ -1542,9 +1650,9 @@ def _flash_backward(q, k, v, bias, scale, lse, g, causal=False, delta=None,
                                        delta, delta_out=want_dbias)
     else:
         dq, delta = _flash_dq(q, k, v, bias, scale, lse, g, causal, delta,
-                              rope)
+                              rope, window)
         dk, dv = _flash_dkv(q, k, v, bias, scale, lse, g, causal, delta,
-                            rope)
+                            rope, window)
 
     dbias = None
     if want_dbias:
@@ -1577,7 +1685,7 @@ def _flash_backward(q, k, v, bias, scale, lse, g, causal=False, delta=None,
     return dq, dk, dv, dbias
 
 
-def _forward_keeping_lse(q, k, v, bias, scale, causal, rope=None):
+def _forward_keeping_lse(q, k, v, bias, scale, causal, rope=None, window=0):
     """Training forward on a tileable shape: (out, what the backward
     needs beside its inputs).  The row statistic leaves as ``[BH, S_q]``
     behind an ``optimization_barrier``.  Where the kernel wrote a column
@@ -1590,7 +1698,7 @@ def _forward_keeping_lse(q, k, v, bias, scale, causal, rope=None):
     for both layouts.  ``out`` is kept only where the dQ pass cannot form
     delta itself."""
     out, lse = _flash_forward(q, k, v, bias, scale, with_lse=True,
-                              causal=causal, rope=rope)
+                              causal=causal, rope=rope, window=window)
     lse = jax.lax.optimization_barrier(lse)
     return out, lse, (None if _delta_in_kernel(k.shape[1], causal,
                                                bias is not None)
@@ -1598,7 +1706,7 @@ def _forward_keeping_lse(q, k, v, bias, scale, causal, rope=None):
 
 
 def _backward_from_lse(q, k, v, bias, scale, causal, lse, out, g,
-                       bias_grad=True, rope=None):
+                       bias_grad=True, rope=None, window=0):
     """dq, dk, dv, dbias from the residuals of ``_forward_keeping_lse``.
     ``lse`` (``[BH, S_q]``) and ``g`` pass one barrier together, so
     ``_flash_backward``'s expansion to its kernels' layout (for a column
@@ -1608,35 +1716,41 @@ def _backward_from_lse(q, k, v, bias, scale, causal, lse, out, g,
     return _flash_backward(
         q, k, v, bias, scale, lse, g, causal=causal,
         delta=None if out is None else _row_delta(g, out),
-        bias_grad=bias_grad, rope=rope)
+        bias_grad=bias_grad, rope=rope, window=window)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def flash_attention(q, k, v, bias, scale, causal=False, rope=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 7))
+def flash_attention(q, k, v, bias, scale, causal=False, rope=None, window=0):
     """``rope``: a rotary pair ``(qr [BH, S_q, R], kr [B, S_kv, R])`` or
-    None (``_flash_forward``); its gradient is the pair ``(dqr, dkr)``."""
-    return _flash_forward(q, k, v, bias, scale, causal=causal, rope=rope)
+    None (``_flash_forward``); its gradient is the pair ``(dqr, dkr)``.
+    ``window``: a sliding window under the causal mask (``_band``)."""
+    return _flash_forward(q, k, v, bias, scale, causal=causal, rope=rope,
+                          window=window)
 
 
-def _fa_fwd(q, k, v, bias, scale, causal, rope=None):
-    if not _flash_fits(*_shape_key(q, k, v, bias, causal, rope)):
+def _fa_fwd(q, k, v, bias, scale, causal, rope=None, window=0):
+    window = _band(window, causal, k.shape[1])
+    if not _flash_fits(*_shape_key(q, k, v, bias, causal, rope, window)):
         # non-tileable shapes keep the exact-composition fallback
         return _flash_forward(q, k, v, bias, scale, causal=causal,
-                              rope=rope), (q, k, v, bias, rope, None, None)
-    out, lse, kept = _forward_keeping_lse(q, k, v, bias, scale, causal, rope)
+                              rope=rope, window=window), \
+            (q, k, v, bias, rope, None, None)
+    out, lse, kept = _forward_keeping_lse(q, k, v, bias, scale, causal, rope,
+                                          window)
     return out, (q, k, v, bias, rope, lse, kept)
 
 
-def _fa_bwd(scale, causal, res, g):
+def _fa_bwd(scale, causal, window, res, g):
     q, k, v, bias, rope, lse, out = res
     if lse is None:                        # composition fallback path
         def composed(q_, k_, v_, b_, r_):
             return _reference_attention(*_compose_rope(q_, k_, r_), v_, b_,
-                                        scale, causal=causal)
+                                        scale, causal=causal, window=window)
         _, vjp = jax.vjp(composed, q, k, v, bias, rope)
         return vjp(g)
     dq, dk, dv, dbias = _backward_from_lse(q, k, v, bias, scale, causal,
-                                           lse, out, g, rope=rope)
+                                           lse, out, g, rope=rope,
+                                           window=window)
     if rope is None:
         return dq, dk, dv, dbias, None
     return dq[0], dk[0], dv, dbias, (dq[1], dk[1])
@@ -1645,23 +1759,26 @@ def _fa_bwd(scale, causal, res, g):
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def flash_attention_lse(q, k, v, bias, scale, causal=False, rope=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 7))
+def flash_attention_lse(q, k, v, bias, scale, causal=False, rope=None,
+                        window=0):
     """``flash_attention`` on a tileable shape that also returns the
     logsumexp rows ``[BH, S_q]`` (float32): the training forward of the
     ``fused_attention`` op, whose grad op reads them back as its ``LSE``
     input.  The statistic is a residual, not a differentiable output: its
     cotangent is dropped."""
-    return _forward_keeping_lse(q, k, v, bias, scale, causal, rope)[:2]
+    return _forward_keeping_lse(q, k, v, bias, scale, causal, rope,
+                                window)[:2]
 
 
-def _fal_fwd(q, k, v, bias, scale, causal, rope=None):
-    out, lse, kept = _forward_keeping_lse(q, k, v, bias, scale, causal, rope)
+def _fal_fwd(q, k, v, bias, scale, causal, rope=None, window=0):
+    out, lse, kept = _forward_keeping_lse(q, k, v, bias, scale, causal, rope,
+                                          window)
     return (out, lse), (q, k, v, bias, rope, lse, kept)
 
 
-def _fal_bwd(scale, causal, res, gs):
-    return _fa_bwd(scale, causal, res, gs[0])
+def _fal_bwd(scale, causal, window, res, gs):
+    return _fa_bwd(scale, causal, window, res, gs[0])
 
 
 flash_attention_lse.defvjp(_fal_fwd, _fal_bwd)
@@ -1774,7 +1891,7 @@ def _axis_is_auto(mesh, name):
     return d.get(name, AxisType.Auto) == AxisType.Auto
 
 
-def _attn_core_remat(scale, causal, dropout, rng_axes=()):
+def _attn_core_remat(scale, causal, dropout, rng_axes=(), window=0):
     """jax.checkpoint-wrapped _attn_core with the static config bound.
 
     Without remat every attention layer's [B, H, S_q, S_kv] score and
@@ -1790,12 +1907,12 @@ def _attn_core_remat(scale, causal, dropout, rng_axes=()):
     the FLOP-budget test pins for RecomputeOptimizer.)"""
     def fn(qb, kb, vb, bb, q_offset, key):
         return _attn_core(qb, kb, vb, bb, scale, causal, q_offset,
-                          dropout, key, rng_axes)
+                          dropout, key, rng_axes, window)
     return jax.checkpoint(fn)
 
 
 def _attn_core(qb, kb, vb, bb, scale, causal, q_offset, dropout, key,
-               rng_axes=()):
+               rng_axes=(), window=0):
     """Exact attention composition on rank-4 blocks, with optional
     attention-probability dropout (upscale_in_train semantics, matching
     layers.dropout): qb [B, H, S_q, D], kb/vb [B, H, S_kv, D], bb
@@ -1803,7 +1920,8 @@ def _attn_core(qb, kb, vb, bb, scale, causal, q_offset, dropout, key,
     this block's first q row (non-zero inside the SP shard_map island, so
     the causal mask stays aligned); ``rng_axes`` are mesh axes whose
     index folds into the dropout key (decorrelates masks across shards —
-    the lowering.py rng contract)."""
+    the lowering.py rng contract); ``window``: the causal band
+    (``_in_band``)."""
     s = jnp.einsum("bhqd,bhkd->bhqk", qb, kb,
                    preferred_element_type=jnp.float32) * scale
     if bb is not None:
@@ -1811,7 +1929,7 @@ def _attn_core(qb, kb, vb, bb, scale, causal, q_offset, dropout, key,
     if causal:
         qi = q_offset + jnp.arange(qb.shape[2])[:, None]
         ki = jnp.arange(kb.shape[2])[None, :]
-        s = jnp.where(qi >= ki, s, _NEG)
+        s = jnp.where(_in_band(qi, ki, window), s, _NEG)
     p = jax.nn.softmax(s, axis=-1)
     if dropout:
         for ax in rng_axes:
@@ -1901,7 +2019,9 @@ def _attention_route(ctx, q, k, v, qr=None):
     included (``_kv_row``).  A rotary pair (``qr``: the op's ``QRope`` as
     ``[B, H, S_q, R]``) is among them only under the causal mask and
     without a bias (``_rope_runs_looped``); any other op with a pair
-    composes one head size first."""
+    composes one head size first.  A sliding window (``_op_window``) runs
+    in the kernels under the causal mask without a bias; beside a bias the
+    op composes."""
     dropout = 0.0 if _is_test(ctx) else \
         float(ctx.attr("attn_dropout", 0.0) or 0.0)
     sp_axis = ctx.attr("sp_axis", None)
@@ -1916,8 +2036,18 @@ def _attention_route(ctx, q, k, v, qr=None):
         not (qr is not None and (has_bias or not causal)) and \
         _flash_fits(S_q, k.shape[2], q.shape[3], v.shape[3],
                     0 if qr is None else qr.shape[3], has_bias, causal,
-                    q.dtype.itemsize, q.shape[1] // k.shape[1])
+                    q.dtype.itemsize, q.shape[1] // k.shape[1],
+                    _op_window(ctx, k.shape[2]))
     return sp_active, dropout, flash
+
+
+def _op_window(ctx, S_kv):
+    """The sliding window of a ``fused_attention`` op or its grad op
+    (attribute ``window``: a query sees its last ``window`` keys, itself
+    among them; 0 or absent: none), as the call runs it (``_band``: one
+    that covers the sequence IS the causal call)."""
+    return _band(ctx.attr("window", 0), bool(ctx.attr("causal", False)),
+                 S_kv)
 
 
 def _kv_group(q, k, v, qr=None):
@@ -2018,7 +2148,15 @@ def _fused_attention(ctx, op):
     attention: the rotary part of each head, its keys ONE head shared by
     all H) add ``QRope KRope^T`` to the scores.  The flash kernels read the
     shared head as it is (``_scores``); every other path composes one head
-    size first (``_compose_rope``)."""
+    size first (``_compose_rope``).
+
+    ``window`` = W > 0 (needs ``causal``): query ``i`` sees keys ``j`` with
+    ``i - W < j <= i``.  The looped kernels' sweeps are bounded by the band
+    on both sides (``_first_k_block``, ``_last_q_block``), so the tiles
+    outside it are never visited; ``W >= S_kv`` is the causal op.  Beside a
+    bias, under dropout or where no kernel has a tile the composition masks
+    the same band; the sequence-parallel islands and a rotary pair refuse
+    a window by name."""
     q = ctx.i("Q")
     k = ctx.i("K")
     v = ctx.i("V")
@@ -2029,7 +2167,7 @@ def _fused_attention(ctx, op):
     heads = int(ctx.attr("num_heads", 0) or 0)
     if heads:
         if _op_in_place(ctx, q, k, v, heads):
-            _m_lowered.inc(shape="mha", path="flash", layout="bshd")
+            _m_lowered.inc(shape="mha", path="flash", layout="bshd", window=0)
             B, S_q = q.shape[:2]
             out, lse = flash_attention_in_place(
                 q, k, v, _kernel_bias(bias, _heads_major_shape(q, heads),
@@ -2063,10 +2201,17 @@ def _fused_attention(ctx, op):
     sp_active, dropout, flash = _attention_route(ctx, q, k, v, qr)
     sp_axis = ctx.attr("sp_axis", None)
     mesh = getattr(ctx.state, "mesh", None)
+    window = _op_window(ctx, S_kv)
     _m_lowered.inc(shape="mla" if qr is not None
                    else "gqa" if group > 1 else "mha",
                    path="sequence_parallel" if sp_active
-                   else "flash" if flash else "composition", layout="bhsd")
+                   else "flash" if flash else "composition", layout="bhsd",
+                   window=window)
+    if window and (sp_active or qr is not None):
+        raise NotImplementedError(
+            "fused_attention: a sliding window %s"
+            % ("under sequence parallelism" if sp_active
+               else "with a rotary pair"))
     if group > 1 and not flash:
         k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     rope = None
@@ -2097,11 +2242,11 @@ def _fused_attention(ctx, op):
         # composition, per-op key (ctx.rng() already folds axis_env +
         # extra axes; replayed identically by the grad op: __op_seed__
         # rides the grad attrs)
-        put(_attn_core_remat(float(scale), causal, dropout)(
+        put(_attn_core_remat(float(scale), causal, dropout, window=window)(
             q, k, v, _norm_bias(bias, q, S_kv), 0, ctx.rng()))
         return
     args = (_flat(q), _flat(k), _flat(v), _kernel_bias(bias, q, S_kv),
-            float(scale), causal, rope)
+            float(scale), causal, rope, window)
     if flash and op.output("LSE") and not _is_test(ctx):
         out, lse = flash_attention_lse(*args)
         ctx.set("LSE", lse.reshape(B, H, S_q))
@@ -2141,7 +2286,8 @@ def _op_in_place(ctx, q, k, v, heads):
     shape ``_in_place`` takes, with as many key/value heads as query heads
     and no rotary pair."""
     if ctx.has_input("QRope") or k.shape[2] != q.shape[2] or \
-            v.shape[2] != q.shape[2] or q.shape[2] % heads:
+            v.shape[2] != q.shape[2] or q.shape[2] % heads or \
+            _op_window(ctx, k.shape[1]):
         return False
     major = [_heads_major_shape(x, heads) for x in (q, k, v)]
     return _attention_route(ctx, *major)[2] and _in_place(
@@ -2158,7 +2304,8 @@ _m_lowered = telemetry.counter(
     "'sequence_parallel': a shard_map island) and layout ('bshd': an op "
     "with num_heads whose [B, S, H * D] operands the kernels read in "
     "place, 128 // D heads a cell; 'bhsd': [B, H, S, D] operands, the "
-    "op's own or split from [B, S, H * D] inside the lowering)")
+    "op's own or split from [B, S, H * D] inside the lowering) and window "
+    "(the sliding window the call runs with; 0: none)")
 
 _m_grad_lowered = telemetry.counter(
     "fused_attention_grad_lowered_total",
@@ -2241,7 +2388,8 @@ def _fused_attention_grad(ctx, op):
         None if _delta_in_kernel(S_kv, bool(ctx.attr("causal", False)),
                                  bias is not None)
         else _flat(out),
-        _flat(g.astype(q.dtype)), bias_grad=bool(want["BiasQK"]), rope=rope)
+        _flat(g.astype(q.dtype)), bias_grad=bool(want["BiasQK"]), rope=rope,
+        window=_op_window(ctx, S_kv))
     grads = {}
     if rope is not None:
         (dq, dqr), (dk, dkr) = dq, dk

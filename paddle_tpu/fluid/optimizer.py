@@ -1151,8 +1151,12 @@ def _segment_for_recompute(program, checkpoints, loss_name, no_grad_set=()):
     ck = set(checkpoints)
     segments, cur = [], []
     for op in block.ops:
-        if op_sub_block_indices(op) or op.type in ("feed", "fetch"):
-            # control-flow/structural ops break (and are never wrapped)
+        if op_sub_block_indices(op) or op.type in ("feed", "fetch") or \
+                op.op_role & (OpRole.LRSched | OpRole.Optimize):
+            # control-flow/structural ops break (and are never wrapped);
+            # so do the learning-rate schedule's ops, built before the
+            # model: what they write is read by optimizer ops that do not
+            # exist yet, and a span would hide it from them
             if cur:
                 segments.append(("wrap", cur))
                 cur = []

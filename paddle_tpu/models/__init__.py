@@ -22,3 +22,5 @@ from . import ouro
 from . import ouro_reference
 from . import lfm2_moe
 from . import lfm2_moe_reference
+from . import smallthinker
+from . import smallthinker_reference

@@ -448,7 +448,7 @@ def test_the_two_rungs_agree(rows, compute, tol):
     p, x = _share_params(100, rows - 200)
     diff, plan = _rung_operands(p, x)
     diff = diff[:2] + tuple(w.astype(compute) for w in diff[2:])
-    dtypes = (compute, None)
+    dtypes = (compute, None, "silu")
     g = jnp.asarray(np.random.default_rng(5).normal(size=x.shape),
                     jnp.float32)
     first, kept = decoder_ops._first_rung(RUNG, dtypes, *diff, plan)

@@ -105,6 +105,40 @@ def test_recompute_with_dropout_in_span_is_deterministic():
     assert a[-1] < a[0]
 
 
+def test_recompute_leaves_a_learning_rate_schedule_outside_the_spans():
+    """A schedule built BEFORE the model (its ops lead the block, in the
+    LRSched role) is not packed into the first span: the optimizer ops that
+    read the rate are appended after the segmentation, so a span would not
+    hand it out.  The scheduled step equals the unsegmented one."""
+    def build(recompute):
+        main, startup = fluid.Program(), fluid.Program()
+        main.random_seed = startup.random_seed = 7
+        with fluid.program_guard(main, startup), fluid.unique_name.guard():
+            rate = fluid.layers.linear_lr_warmup(0.1, 4, 0.0, 0.1)
+            x = fluid.layers.data(name="x", shape=[16], dtype="float32")
+            y = fluid.layers.data(name="y", shape=[1], dtype="float32")
+            h1 = fluid.layers.fc(x, size=32, act="relu")
+            h2 = fluid.layers.fc(h1, size=32, act="relu")
+            loss = fluid.layers.mean(fluid.layers.square_error_cost(
+                fluid.layers.fc(h2, size=1), y))
+            opt = fluid.optimizer.SGDOptimizer(rate)
+            if recompute:
+                opt = fluid.optimizer.RecomputeOptimizer(opt)
+                opt._set_checkpoints([h2])
+            opt.minimize(loss)
+        return main, startup, loss
+    main, _, _ = build(True)
+    kinds = [op.type for op in main.global_block().ops]
+    assert "recompute" in kinds and kinds.index("recompute") > \
+        kinds.index("increment")
+    span, = [op for op in main.global_block().ops if op.type == "recompute"]
+    assert "increment" not in [
+        op.type for op in main.blocks[span.attr("sub_block")].ops]
+    remat, plain = _train(*build(True)), _train(*build(False))
+    np.testing.assert_allclose(remat, plain, rtol=0, atol=0)
+    assert remat[0] == remat[1] > remat[-1]      # the first step's rate is 0
+
+
 def test_recompute_requires_checkpoints():
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup):

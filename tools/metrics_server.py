@@ -229,11 +229,12 @@ def main(argv=None):
     args = ap.parse_args(argv)
     srv = start_metrics_server(port=args.port, host=args.host,
                                aggregate_dir=args.aggregate_dir)
-    print("serving metrics on %s (SIGTERM/SIGINT to stop)" % srv.url,
-          flush=True)
+    # the handlers stand before the line that tells a parent it may signal
     stop = threading.Event()
     for sig in (signal.SIGTERM, signal.SIGINT):
         signal.signal(sig, lambda *_: stop.set())
+    print("serving metrics on %s (SIGTERM/SIGINT to stop)" % srv.url,
+          flush=True)
     stop.wait()
     srv.close()
     print("metrics server stopped", flush=True)
