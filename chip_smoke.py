@@ -7,7 +7,7 @@ of ResNet-50 and BERT-base, with random weights made from a seed, and checks
 what comes out by the repo's own means.  ONE process, no child: a chip
 belongs to one process at a time.
 
-    python chip_smoke.py                 one chip: phases 1-4
+    python chip_smoke.py                 one chip: phases 1-5
     python chip_smoke.py --chips 4       one process driving four chips
     python chip_smoke.py --dry-run-cpu   the same phases at tiny sizes with
                                          interpreted kernels (pre-flight and
@@ -22,10 +22,16 @@ Phases (any failure raises through to a non-zero exit):
             pure-bf16 AMP), 8 steps fed as host numpy batches through a
             program-bound ``fluid.DataLoader.from_generator``.
 3. bert     BERT-base pretrain twice: (a) S=128, batch 64, dropout 0.1
-            (attention runs the XLA composition); (b) S=512, batch 16,
-            ``attn_dropout=0`` (the Pallas flash kernels, forward and
-            backward, are inside the step — proven from its compiled HLO).
+            (attention runs the XLA composition: at S=128 the kernels
+            would hold more than it, ``pallas_ops._drop_in_kernels``); (b)
+            S=512, batch 16, ``attn_dropout=0`` (the Pallas flash kernels,
+            forward and backward, are inside the step — proven from its
+            compiled HLO).
 4. kernels  every Pallas kernel alone against its ``jnp`` reference.
+5. flash_dropout  attention dropout drawn inside the in-place kernels from
+            the core's generator: its keep fraction over a [32, 512, 12 *
+            64] draw, forward and backward from one seed against the
+            composition fed the kernels' own mask, two seeds' masks apart.
 
 ``--chips 4`` runs phase 1 and then phase 2's program at global batch 1024
 twice: through ``CompiledProgram.with_data_parallel`` (GSPMD) and through
@@ -389,10 +395,11 @@ def _require_mosaic(hlo):
 
 
 def phase_bert(place, devices, dry_run):
-    """(a) the default config — attention is the XLA composition; (b)
-    ``attn_dropout=0`` — the Pallas flash kernels are inside the step.
-    The dry run takes (b) only: on the CPU the two differ by one attribute
-    of one op."""
+    """(a) the default config at S=128 — attention is the XLA composition
+    (with dropout the kernels draw it only where a head's scores outnumber
+    what they keep: S=512, ``phase_flash_dropout``); (b) ``attn_dropout=0``
+    — the Pallas flash kernels are inside the step.  The dry run takes (b)
+    only: on the CPU the two differ by one attribute of one op."""
     from paddle_tpu import models
 
     if dry_run:
@@ -637,6 +644,88 @@ def phase_kernels(device, dry_run):
             "layer_norm_err": round(e_ln, 5)}
 
 
+def phase_flash_dropout(device, dry_run):
+    """Attention dropout drawn inside ``flash_fwd`` / ``flash_bwd`` in
+    place (PR 40), at the BERT cells' widths: the keep fraction of the
+    core's generator over a ``[32, 512, 12 * 64]`` draw (read back by
+    ``_drawn_mask``, a kernel of the same grid drawing as they do) within
+    5 sigma of 1 - rate; forward and backward from one seed against the
+    composition (``_attn_core``) handed that mask as its ``bernoulli``, in
+    float32 and bfloat16; and the seeds of two steps drawing masks that
+    agree on (1 - rate)^2 + rate^2 of their elements, as two independent
+    ones do.  On the CPU the kernels draw the interpreter's counter hash."""
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.fluid.ops import pallas_ops as po
+
+    rate, D = 0.1, 64
+    scale = 1.0 / np.sqrt(D)
+    rng = np.random.RandomState(1)
+
+    def put(x):
+        return jax.device_put(x, device)
+    seeds = [put(jnp.array([s], jnp.int32)) for s in (1234567, 1234568)]
+    B, heads, S = (2, 2, 128) if dry_run else (32, 12, 512)
+    drawn = jax.jit(po._drawn_mask, static_argnums=(1, 2, 3, 4, 5, 6))
+    first = drawn(seeds[0], B, heads, D, S, S, rate)
+    keep = float(jnp.mean(first.astype(jnp.float32)))
+    sigma = np.sqrt(rate * (1 - rate) / first.size)
+    require(abs(keep - (1 - rate)) <= 5 * sigma,
+            "keep fraction %.6f over %d draws, wanted %.2f +- %.6f", keep,
+            first.size, 1 - rate, 5 * sigma)
+    agree = float(jnp.mean((first == drawn(seeds[1], B, heads, D, S, S,
+                                           rate)).astype(jnp.float32)))
+    want = (1 - rate) ** 2 + rate ** 2
+    require(abs(agree - want) <= 0.01, "two seeds' masks agree on %.4f, "
+            "wanted %.4f", agree, want)
+    log("flash dropout [%d, %d, %d x %d]: keep %.6f (5 sigma %.6f); two "
+        "seeds agree on %.4f" % (B, S, heads, D, keep, 5 * sigma, agree))
+    del first
+
+    B_, heads, S = (2, 2, 128) if dry_run else (2, 12, 512)
+    kept = drawn(seeds[0], B_, heads, D, S, S, rate).reshape(
+        B_, heads, S, S).astype(bool)
+    err = 0.0
+    for dtype in (jnp.float32, jnp.bfloat16):
+        q, k, v, g = (put(jnp.asarray(rng.normal(0, 1, (B_, S, heads * D)),
+                                      dtype)) for _ in range(4))
+        mask = put(jnp.asarray(rng.normal(0, 1, (B_, S, S)), dtype))
+
+        def kernels(q, k, v, b, g):
+            out, lse = po._flash_fwd_in_place(q, k, v, b, scale, heads,
+                                              False, True, rate, seeds[0])
+            return (out,) + po._backward_in_place(
+                q, k, v, b, scale, False, heads, lse, g, rate, seeds[0])
+
+        def composition(q, k, v, b, g):
+            def core(q, k, v):
+                split = [po._heads_major(x.astype(jnp.float32), heads)
+                         for x in (q, k, v)]
+                with mock.patch.object(jax.random, "bernoulli",
+                                       lambda key, p, shape: kept):
+                    out = po._attn_core(*split, b[:, None].astype(
+                        jnp.float32), scale, False, 0, rate,
+                        jax.random.PRNGKey(0))
+                return po._heads_minor(out)
+            out, vjp = jax.vjp(core, q, k, v)
+            return (out,) + vjp(g.astype(jnp.float32))
+        got = jax.jit(kernels)(q, k, v, mask, g)
+        want = jax.jit(composition)(q, k, v, mask, g)
+        for nm, a, w, tol in zip(("out", "dq", "dk", "dv"), got, want,
+                                 (FWD_TOL,) + (BWD_TOL,) * 3):
+            e = _rel_err(a, w)
+            require(e <= tol, "flash dropout %s %s err %.4f",
+                    jnp.dtype(dtype).name, nm, e)
+            err = max(err, e)
+    log("flash dropout in place [B=%d, S=%d, %d x %d]: fwd + dq/dk/dv match "
+        "the composition fed the kernels' mask (worst rel err %.4f)"
+        % (B_, S, heads, D, err))
+    return {"flash_dropout_err": round(err, 5),
+            "flash_dropout_keep": round(keep, 7)}
+
+
 # ---------------------------------------------------------------------------
 # --chips 4: one process driving every local chip, both data-parallel paths
 # ---------------------------------------------------------------------------
@@ -708,7 +797,9 @@ def main(argv=None):
     else:
         todo = [("resnet50", lambda: phase_resnet(place, devices, dry)),
                 ("bert", lambda: phase_bert(place, devices, dry)),
-                ("kernels", lambda: phase_kernels(devices[0], dry))]
+                ("kernels", lambda: phase_kernels(devices[0], dry)),
+                ("flash_dropout",
+                 lambda: phase_flash_dropout(devices[0], dry))]
     for name, run in todo:
         log("== phase %s" % name)
         t0 = time.perf_counter()

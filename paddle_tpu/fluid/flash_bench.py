@@ -24,7 +24,15 @@ cells, a pair of heads of 64 a cell, ``pallas_ops._in_place``) beside the
 same calls on ``[BH, S, D]`` (``bhsd``), in float32 as the step hands them
 over (``fused_attention`` is on no AMP list) and in bfloat16.
 
-Run: python -m paddle_tpu.fluid.flash_bench [bert|moonlight ...]
+``dropout`` (PR 40) times ``flash_fwd`` and ``flash_bwd`` in place at the
+two dropout cells' attention shapes, 12 heads of 64, float32 with the
+padding mask: (B=16, S=512) of ``bert_base_s512_dropout`` and (B=128,
+S=128) of ``bert_base_s128_dropout``, at rate 0 and at rate 0.1 with the
+keep mask drawn in the kernel from the core's generator (``bits`` ``core``)
+or from the counter hash the interpreter draws (``hash``,
+``pallas_ops._CHIP_BITS``).
+
+Run: python -m paddle_tpu.fluid.flash_bench [bert|moonlight|dropout ...]
 Prints one JSON line per shape, kernel and tile; ``form`` says which form
 of the backward the lowering takes at the shape (``fused``: ``bwd`` alone
 runs in a step, ``dq`` and ``dkv`` are what it replaced; ``two_pass``: no
@@ -193,6 +201,58 @@ def layouts(steps=30):
                        ms=round(dt / steps * 1e3, 4))
 
 
+DROPOUT_SHAPES = ((16, 512), (128, 128))
+
+
+def dropout(steps=30):
+    """Yield one record per shape, rate, source of bits and kernel of the
+    in-place pair with dropout drawn inside it (module docstring)."""
+    import functools
+    import jax
+    import jax.numpy as jnp
+    from .mesh_utils import local_devices
+    from .ops import pallas_ops as po
+    from .timing import timed_steps
+
+    _require_tpu()
+    H, D = 12, 64
+    rng = np.random.default_rng(0)
+    dev = local_devices()[0]
+    seed = jax.device_put(jnp.array([20240], jnp.int32), dev)
+    chip_bits = po._CHIP_BITS
+    try:
+        for B, S in DROPOUT_SHAPES:
+            a = {n: jax.device_put(rng.standard_normal(
+                (B, S, H * D), dtype=np.float32), dev)
+                for n in ("q", "k", "v", "g")}
+            bias = jax.device_put(rng.standard_normal(
+                (B, S, S), dtype=np.float32) * 0.1, dev)
+            for rate, bits in ((0.0, "none"), (0.1, "core"), (0.1, "hash")):
+                po._CHIP_BITS = "core" if bits == "none" else bits
+                drawn = seed if rate else None
+
+                def fwd(a, rate=rate, drawn=drawn):
+                    return po._flash_fwd_in_place(a["q"], a["k"], a["v"],
+                                                  bias, D ** -0.5, H, False,
+                                                  True, rate, drawn)
+                lse = jax.jit(fwd)(a)[1]
+
+                def bwd(a, rate=rate, drawn=drawn):
+                    return po._backward_in_place(a["q"], a["k"], a["v"],
+                                                 bias, D ** -0.5, False, H,
+                                                 lse, a["g"], rate, drawn)
+                for kernel, fn in (("fwd", fwd), ("bwd", bwd)):
+                    call = functools.partial(_corner(fn), a)
+                    dt, _ = timed_steps(lambda i: call(), steps, warmup=3,
+                                        fetch=lambda out: float(out))
+                    yield dict(shape="dropout", kernel=kernel, batch=B,
+                               seq=S, layout="bshd", dtype="float32",
+                               rate=rate, bits=bits,
+                               ms=round(dt / steps * 1e3, 4))
+    finally:
+        po._CHIP_BITS = chip_bits
+
+
 def sweep(shape, steps=30):
     """Yield one record per kernel and tile of ``shape`` (and per sliding
     window, where the shape has layers of several kinds: ``WINDOWS``); the
@@ -235,7 +295,7 @@ def _sweep_window(shape, ops, window, steps):
 
 def main():
     for shape in sys.argv[1:] or ("bert", "moonlight"):
-        for rec in sweep(shape):
+        for rec in dropout() if shape == "dropout" else sweep(shape):
             print(json.dumps(rec))
             sys.stdout.flush()
         for rec in layouts() if shape == "bert" else ():

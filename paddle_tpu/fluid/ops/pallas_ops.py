@@ -140,10 +140,27 @@ one-tile body on each head's lanes in turn (``_lanes``, a static slice at
 lane 64 that Mosaic takes as written), storing into its lanes, so every
 output leaves as full rows; the mask ``[B, S_q, S_kv]`` is the cell's one
 block at row ``b`` and the statistics stay ``[B * H, 1, S_q]``, a row a
-head.  Every other op in that layout (dropout, a sequence-parallel mesh,
-several tiles a head, heads that do not pack, grouped heads, a rotary
-pair, a wanted bias gradient) is split to ``[B, H, S, D]`` inside the
-lowering and takes the path it took.
+head.  Every other op in that layout (a sequence-parallel mesh, several
+tiles a head, heads that do not pack, grouped heads, a rotary pair, a
+wanted bias gradient, dropout the kernels do not draw) is split to ``[B,
+H, S, D]`` inside the lowering and takes the path it took.
+
+Dropout in the kernels (PR 40): an op in that layout with attention-
+probability dropout (BERT as published, rate 0.1) runs the same two
+kernels, which draw the keep mask themselves, per head a ``[S_q, S_kv]``
+tile of bits from the TensorCore's generator seeded with the op's seed
+and the head (``_keep_mask``; the seed is 32 bits of the op's own key,
+``_dropout_seed``, which the grad op remakes, so ``flash_bwd`` draws what
+``flash_fwd`` drew): O = (P M) V / ((1 - rate) l) with the undropped
+logsumexp, and in the backward dV = (P M / (1 - rate))^T dO, dP = (dO
+V^T) M / (1 - rate).  No mask exists in HBM.  The rate is static: at 0
+nothing is drawn and the program is the one it was (the sha1 pin of
+``tests/test_fused_attention_grad.py``).  It does so where a head's scores
+outnumber the Q, K, V the kernels keep for it (``_drop_in_kernels``: S=512
+at D=64, not S=128, where the step would hold 1.18 GB more than the
+composition's) and the bias wants no gradient; every other dropout op
+composes (``_attn_core``).  Interpreted, the bits are a counter hash
+(``_hash_bits``): the core's generator has no interpreter.
 
 Latent attention (PR 28): V's head size may differ from Q's and K's, and
 a head may have a second, rotary part whose keys are ONE head shared by
@@ -200,12 +217,25 @@ from ..registry import register_grad_lower, register_op
 _NEG = -1e30
 
 
-def _pallas_call(kernel, name, vmem_limit_bytes=None, **kwargs):
+# The two branches of each one-tile call ``_pallas_call`` was handed a
+# ``cache_key`` for, by that key and the operands' avals: a step traces a
+# kernel once for all its layers of one shape (PR 40: twelve layers' fresh
+# ``flash_fwd`` / ``flash_bwd`` traces were most of what the kernels add to
+# a step's set-up).
+_CALLS = {}
+_CALLS_MAX = 256
+
+
+def _pallas_call(kernel, name, vmem_limit_bytes=None, platform_bound=False,
+                 cache_key=None, **kwargs):
     """``pl.pallas_call`` whose mode follows the platform the computation
     is LOWERED for, not the process's default backend: compiled by Mosaic
     for a TPU, interpreted for anything else (a ``CPUPlace`` executor on a
     TPU host included).  ``lax.platform_dependent`` lowers only the chosen
     branch, so a TPU executable never holds an interpreted kernel.
+    ``platform_bound``: the kernel body takes ``interpret=`` and is bound
+    with the branch it runs in (``_keep_bits``: the core's generator has
+    no interpreter).
 
     ``name`` is the kernel's stable name: XLA:TPU names the custom call's
     instruction after it (``%flash_dq.3`` in a device trace, where an
@@ -217,24 +247,47 @@ def _pallas_call(kernel, name, vmem_limit_bytes=None, **kwargs):
 
     Inside a ``shard_map`` the outputs vary over every mesh axis an input
     varies over; saying so in ``out_shape`` lets the kernels run under
-    ``check_vma=True``."""
+    ``check_vma=True``.
+
+    ``cache_key``: everything the kernel body, its grid and its blocks
+    depend on beside the operands' avals (a caller that gives one keeps
+    no operand in the body's closure): the branches are built once for it
+    and reused (``_CALLS``), so ``pl.pallas_call``'s own jit traces the
+    body once, and every call traces the same program as a fresh one."""
     out_shape = kwargs.pop("out_shape")
     mosaic = {} if vmem_limit_bytes is None else {
         "compiler_params": pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes)}
 
-    def call(*args):
-        vma = frozenset().union(*(jax.typeof(a).vma for a in args))
+    def body(interpret):
+        return functools.partial(kernel, interpret=interpret) \
+            if platform_bound else kernel
+
+    def branches(vma):
         shapes = jax.tree.map(
             lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, vma=vma),
             out_shape)
+        return (pl.pallas_call(body(False), out_shape=shapes, name=name,
+                               **mosaic, **kwargs),
+                pl.pallas_call(body(True), out_shape=shapes, name=name,
+                               interpret=True, **kwargs))
+
+    def call(*args):
+        vma = frozenset().union(*(jax.typeof(a).vma for a in args))
+        if cache_key is None:
+            tpu, default = branches(vma)
+        else:
+            key = (cache_key, name, vmem_limit_bytes,
+                   platform_bound and _CHIP_BITS, vma,
+                   tuple((a.shape, a.dtype) for a in args))
+            if key not in _CALLS:
+                if len(_CALLS) >= _CALLS_MAX:
+                    _CALLS.clear()
+                _CALLS[key] = branches(vma)
+            tpu, default = _CALLS[key]
         with jax.named_scope(name):
-            return jax.lax.platform_dependent(
-                *args,
-                tpu=pl.pallas_call(kernel, out_shape=shapes, name=name,
-                                   **mosaic, **kwargs),
-                default=pl.pallas_call(kernel, out_shape=shapes, name=name,
-                                       interpret=True, **kwargs))
+            return jax.lax.platform_dependent(*args, tpu=tpu,
+                                              default=default)
     return call
 
 
@@ -611,7 +664,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
                 dq_ref, dk_ref, dv_ref, delta_out_ref, *, scale, causal=False,
-                heads=1):
+                heads=1, rate=0.0, seed_ref=None, interpret=False):
     """The whole backward of a head that is ONE tile (``_fused_backward``):
     S, P, dP and dS are formed once and feed all three gradients, where
     the dQ and dK/dV passes each rebuild them.  The same five products in
@@ -625,7 +678,13 @@ def _bwd_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
 
     ``heads``: the heads a cell holds side by side on its blocks' lanes
     (``_in_place``: operands ``[B, S, H * D]``, a block 128 lanes wide);
-    each runs this body on its lanes and stores into its lanes."""
+    each runs this body on its lanes and stores into its lanes.
+
+    ``rate`` (in place only): the forward's keep mask M, drawn again from
+    the same seed, cell and head (``_keep_mask``).  dV = (P M / (1 -
+    rate))^T dO and dP = (dO V^T) M / (1 - rate), the gradient of the
+    undropped P; delta = rowsum(P dP) is still rowsum(dO O), and dS, dQ,
+    dK follow as without it."""
     for h in range(heads):
         q, ks, vs = (_lanes(ref, h, heads)         # [S_q, D], [S_kv, D | D_v]
                      for ref in (q_ref, k_ref, v_ref))
@@ -636,6 +695,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
             s = _band_mask(s, 0, 0)
         p = jnp.exp(s - _stat_column(lse_ref, h))
         dp = jnp.dot(do, vs.T, preferred_element_type=jnp.float32)
+        pv = p
+        if rate:
+            keep = _keep_mask(seed_ref, h, heads, s.shape, rate, interpret)
+            dp = jnp.where(keep, dp * (1.0 / (1.0 - rate)), 0.0)
+            pv = jnp.where(keep, p * (1.0 / (1.0 - rate)), 0.0)
         delta = (p * dp).sum(axis=-1, keepdims=True) if delta_ref is None \
             else _stat_column(delta_ref, h)
         if delta_out_ref is not None:
@@ -646,28 +710,142 @@ def _bwd_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
         _store_lanes(dk_ref, h, heads,
                      jnp.dot(ds.T, q, preferred_element_type=jnp.float32))
         _store_lanes(dv_ref, h, heads,
-                     jnp.dot(p.astype(q.dtype).T, do,
+                     jnp.dot(pv.astype(q.dtype).T, do,
                              preferred_element_type=jnp.float32))
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *, scale,
-                causal=False, heads=1):
+                causal=False, heads=1, rate=0.0, seed_ref=None,
+                interpret=False):
     """The forward of heads that are ONE tile each and lie side by side on
     their blocks' lanes (``_in_place``): what ``_attention_kernel`` does
     at one tile, a plain softmax with nothing to rescale, on each head's
-    lanes in turn, so the output leaves as full rows."""
+    lanes in turn, so the output leaves as full rows.
+
+    ``rate``: attention-probability dropout with the keep mask M drawn
+    here (``_keep_mask``), O = (P M) V / ((1 - rate) l); ``l`` and the
+    logsumexp are the undropped P's, as the composition's (``_attn_core``)
+    softmax is taken before its mask."""
     for h in range(heads):
         q = _lanes(q_ref, h, heads)
         s = _add_bias(_scores(q, _lanes(k_ref, h, heads), scale), bias_ref,
                       0, q.shape[0], 0, k_ref.shape[1], h)
         if causal:
             s = _band_mask(s, 0, 0)
-        m, l, acc = _online_softmax(None, s, _lanes(v_ref, h, heads),
-                                    q.dtype)
-        l = jnp.maximum(l, 1e-30)
-        _store_lanes(o_ref, h, heads, acc / l)
+        if rate:
+            m = s.max(axis=-1, keepdims=True)
+            p = jnp.exp(s - m)
+            l = p.sum(axis=-1, keepdims=True)
+            keep = _keep_mask(seed_ref, h, heads, s.shape, rate, interpret)
+            acc = jnp.dot(jnp.where(keep, p, 0.0).astype(q.dtype),
+                          _lanes(v_ref, h, heads),
+                          preferred_element_type=jnp.float32)
+            l = jnp.maximum(l, 1e-30)
+            _store_lanes(o_ref, h, heads, acc / (l * (1.0 - rate)))
+        else:
+            m, l, acc = _online_softmax(None, s, _lanes(v_ref, h, heads),
+                                        q.dtype)
+            l = jnp.maximum(l, 1e-30)
+            _store_lanes(o_ref, h, heads, acc / l)
         if lse_ref is not None:
             _stat_store(lse_ref, m + jnp.log(l), h)
+
+
+# Where the flash kernels' dropout bits come from on the chip: "core", the
+# TensorCore's own generator (``pltpu.prng_seed`` / ``prng_random_bits``),
+# or "hash", the counter hash the interpreter draws (``_hash_bits``).
+# ``flash_bench``'s ``dropout`` row times both (PERF.md section 6, PR 40).
+_CHIP_BITS = "core"
+
+
+def _keep_bits(seed, head, shape, interpret):
+    """32 random bits (int32) for each score of one head's ``shape`` tile:
+    ``head`` (its row of ``[B * H, ...]``, ``_keep_mask``) of a call
+    seeded with ``seed`` (a scalar).  The forward and the backward call it
+    with the same two and the same shape, so they draw the same bits.  On
+    the chip the core's generator, seeded with both (Mosaic's
+    ``prng_set_seed_32`` takes at most two values: the head is one number,
+    not its grid cell and place in the cell); interpreted
+    (``_pallas_call``'s ``platform_bound``: ``prng_seed`` has no CPU
+    lowering, and the interpreter's ``prng_random_bits`` is all zeros) a
+    counter hash of both and the element's row and column, which a test
+    can rebuild outside the kernel."""
+    if interpret or _CHIP_BITS == "hash":
+        return _hash_bits(seed, head, shape)
+    pltpu.prng_seed(seed, head)
+    return pltpu.prng_random_bits(shape)
+
+
+def _mix32(x):
+    """A bijective avalanche of int32 lanes (Wellons' ``lowbias32``), with
+    wrapping int32 products and logical shifts, as Mosaic has them; lax
+    ops on numpy constants, which cost the least to trace."""
+    lax = jax.lax
+    for shift, factor in ((16, 0x7FEB352D), (15, 0x846CA68B), (16, None)):
+        x = lax.bitwise_xor(x, lax.shift_right_logical(x, np.int32(shift)))
+        if factor is not None:
+            x = lax.mul(x, np.uint32(factor).view(np.int32))
+    return x
+
+
+def _hash_bits(seed, head, shape):
+    """``_keep_bits``' counter hash: the seed and the head mixed in turn,
+    then each element's index ``row * cols + col`` mixed with the result."""
+    lax = jax.lax
+    key = _mix32(lax.add(_mix32(lax.convert_element_type(seed, np.int32)),
+                         lax.convert_element_type(head, np.int32)))
+    index = lax.add(
+        lax.mul(lax.broadcasted_iota(np.int32, shape, 0), np.int32(shape[1])),
+        lax.broadcasted_iota(np.int32, shape, 1))
+    return _mix32(lax.bitwise_xor(index, key))
+
+
+def _keep_threshold(rate):
+    """The keep rule's bound, ``round((1 - rate) * 2**32)``, moved by
+    ``-2**31`` into int32: unsigned ``bits < t`` is signed ``bits ^ -2**31 <
+    t - 2**31``, so a keep is drawn with probability ``1 - rate`` to
+    ``2**-32``."""
+    t = min(int(round((1.0 - rate) * 2.0 ** 32)), 2 ** 32 - 1)
+    return t - 2 ** 31
+
+
+def _keep_mask(seed_ref, h, heads, shape, rate, interpret):
+    """Which scores of head ``h`` of the ``heads`` a grid cell ``(b, g)``
+    holds (``_in_place``) survive dropout at ``rate`` (bool ``shape``):
+    the head is row ``(b * cells + g) * heads + h`` of ``[B * H, ...]``,
+    its bits (``_keep_bits``) are kept below the threshold, compared
+    without a sign (``_keep_threshold``)."""
+    head = (pl.program_id(0) * pl.num_programs(1) + pl.program_id(1)) * \
+        heads + h
+    bits = _keep_bits(seed_ref[0], head, shape, interpret)
+    return jax.lax.lt(jax.lax.bitwise_xor(bits, np.int32(-2 ** 31)),
+                      np.int32(_keep_threshold(rate)))
+
+
+def _drawn_mask(seed, batch, heads, D, S_q, S_kv, rate):
+    """The keep masks ``flash_fwd`` / ``flash_bwd`` in place draw for
+    operands ``[batch, S, heads * D]`` from ``seed`` (int32 ``[1]``), read
+    back as int32 ``[batch * heads, S_q, S_kv]`` (1 kept) by a kernel of
+    the same grid that draws them as the attention kernels do, on the chip
+    from the core's generator: the oracle of ``chip_smoke.py`` and the
+    tests."""
+    pack = _LANES // D
+    cells = heads // pack
+
+    def kern(seed_ref, mask_ref, interpret):
+        for h in range(pack):
+            mask_ref[h] = _keep_mask(seed_ref, h, pack, (S_q, S_kv), rate,
+                                     interpret).astype(jnp.int32)
+
+    return _pallas_call(
+        kern, "flash_keep_mask", platform_bound=True,
+        grid=(batch, cells),
+        in_specs=[_seed_spec()],
+        out_specs=pl.BlockSpec((pack, S_q, S_kv),
+                               lambda b, g: (b * cells + g, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((batch * heads, S_q, S_kv),
+                                       jnp.int32),
+    )(seed)
 
 
 def _lanes(ref, h, heads):
@@ -867,7 +1045,8 @@ def _whole_side(kernel, S_q, S_kv, D, D_v, R, itemsize):
 
 
 def _vmem_bytes(kernel, block_q, block_k, S_q, S_kv, D, D_v, R, has_bias,
-                causal, itemsize, group=1, window=0, rows=False, heads=1):
+                causal, itemsize, group=1, window=0, rows=False, heads=1,
+                bits=False):
     """VMEM one grid cell of ``kernel`` ('fwd', 'dq', 'dkv', 'bwd' or
     'dbias') asks for at tiles of ``block_q x block_k``, from shapes alone:
     what the chooser holds against ``_VMEM_BUDGET_BYTES`` and
@@ -904,7 +1083,9 @@ def _vmem_bytes(kernel, block_q, block_k, S_q, S_kv, D, D_v, R, has_bias,
       the dK/dV pass and the fused backward their transposes);
     * the float32 accumulators, and the forward's two running statistics;
     * the dQ pass's two ``[block_q, S_kv]`` float32 scratches, where it
-      forms delta over more than one tile (``_delta_in_kernel``)."""
+      forms delta over more than one tile (``_delta_in_kernel``);
+    * with ``bits`` (dropout drawn in the kernel, ``_keep_mask``) the int32
+      bits of a tile and the float32 tile the mask leaves."""
     def stat(b):
         return (heads, b, 4) if rows else (b, 1, 4)
     D, D_v = D * heads, D_v * heads
@@ -956,6 +1137,8 @@ def _vmem_bytes(kernel, block_q, block_k, S_q, S_kv, D, D_v, R, has_bias,
     if kernel == "dq" and S_kv > block_k and \
             _delta_in_kernel(S_kv, causal, has_bias):
         need += 2 * block_q * S_kv * 4
+    if bits:
+        need += block_q * block_k * (4 + 4)
     return need
 
 
@@ -1103,6 +1286,12 @@ def _cell_specs(q, k, bias, heads):
             bias_spec)
 
 
+def _seed_spec():
+    """The block of a kernel's dropout seed (int32 ``[1]``,
+    ``_dropout_seed``): the whole array in SMEM, where a scalar is read."""
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
 def _shape_key(q, k, v, bias, causal, rope, window=0):
     """What the chooser sees of a call: ``_tiles``'s arguments after the
     kernel's name (the last two: the query heads that share a key/value
@@ -1143,14 +1332,14 @@ def _flash_fits(*shape):
                for kernel in ("fwd", "dq", "dkv") + ("dbias",) * shape[5])
 
 
-def _plan(kernel, q, bias, *shape, heads=None):
+def _plan(kernel, q, bias, *shape, heads=None, rate=0.0):
     """One kernel call's ``(block_q, block_k, keywords for the whole-
     sequence BlockSpecs, vmem_limit_bytes)`` at this shape
     (``_shape_key``), counted in ``flash_tiles_total`` with the layout of
     its operands (``heads``: the H of ``[B, S, H * D]`` operands read in
     place, ``_in_place``; None for ``[BH, S, D]``), of its row statistics
-    (``_row_stats``) and which block row its bias is read at
-    (``_bias_row``)."""
+    (``_row_stats``), which block row its bias is read at (``_bias_row``)
+    and whether it draws a dropout mask (``rate``, in place only)."""
     _, block_q, block_k = _tiles(kernel, *shape)
     rows = _row_stats(kernel, *shape)
     _m_tiles.inc(kernel=kernel, block_q=block_q, block_k=block_k,
@@ -1159,13 +1348,14 @@ def _plan(kernel, q, bias, *shape, heads=None):
                  bias="none" if bias is None
                  else "head" if bias.shape[0] == q.shape[0] * (heads or 1)
                  else "sequence",
-                 layout="bhsd" if heads is None else "bshd")
+                 layout="bhsd" if heads is None else "bshd",
+                 dropout="in_kernel" if rate else "none")
     S_q, S_kv, D, D_v, R, _, _, itemsize = shape[:8]
     return (block_q, block_k,
             _whole_seq(_whole_side(kernel, S_q, S_kv, D, D_v, R, itemsize)),
             _vmem_limit(_vmem_bytes(
                 kernel, block_q, block_k, *shape, rows=rows,
-                heads=1 if heads is None else _LANES // D)))
+                heads=1 if heads is None else _LANES // D, bits=bool(rate))))
 
 
 _m_tiles = telemetry.counter(
@@ -1183,7 +1373,9 @@ _m_tiles = telemetry.counter(
     "and 'bwd' read Q, K, V and dO as [B, S, H * D] where the projections "
     "left them and write O, dQ, dK and dV the same way, 128 // D heads a "
     "cell; window: the sliding window whose band bounds the call's sweeps "
-    "(0: none, the causal or the full call)")
+    "(0: none, the causal or the full call); dropout: 'in_kernel' where "
+    "'fwd' or 'bwd' in place draws the attention-probability keep mask "
+    "itself, 'none' everywhere else")
 
 
 def _rope_specs(rope, q_block, k_block, whole_mode=None):
@@ -1515,7 +1707,7 @@ def _flash_dkv(q, k, v, bias, scale, lse, g, causal, delta, rope=None,
 
 
 def _flash_bwd(q, k, v, bias, scale, lse, g, causal, delta,
-               delta_out=False, heads=None):
+               delta_out=False, heads=None, rate=0.0, seed=None):
     """The fused backward (``_bwd_kernel``; where ``_fused_backward`` says a
     head is one tile): ``(dq, dk, dv, delta)`` from one call, a grid cell a
     head.  ``lse`` and delta are rows, ``[BH, 1, S_q]`` (``_row_stats``).
@@ -1525,13 +1717,14 @@ def _flash_bwd(q, k, v, bias, scale, lse, g, causal, delta,
 
     With ``heads`` (``_in_place``) Q, K, V and dO are ``[B, S, heads * D]``
     and so are dQ, dK and dV; a cell runs ``128 // D`` heads
-    (``_cell_specs``)."""
+    (``_cell_specs``), and with ``rate`` draws the forward's keep mask
+    again from ``seed`` (``_seed_spec``)."""
     S_q, S_kv = q.shape[1], k.shape[1]
     D, D_v = (q.shape[2], v.shape[2]) if heads is None \
         else (q.shape[2] // heads,) * 2
     shape = _shape_key(q, k, v, bias, causal, None) if heads is None \
         else _in_place_shape(q, k, bias, causal, heads)
-    vmem = _plan("bwd", q, bias, *shape, heads=heads)[3]
+    vmem = _plan("bwd", q, bias, *shape, heads=heads, rate=rate)[3]
     delta_out = delta_out and delta is None
     grid, pack, rows, stat, bias_spec = _cell_specs(q, k, bias, heads)
     in_specs = [rows(S_q, D), rows(S_kv, D), rows(S_kv, D_v)]
@@ -1544,6 +1737,9 @@ def _flash_bwd(q, k, v, bias, scale, lse, g, causal, delta,
     if delta is not None:
         in_specs.append(stat)
         args.append(delta)
+    if rate:
+        in_specs.append(_seed_spec())
+        args.append(seed)
     out_specs = [rows(S_q, D), rows(S_kv, D), rows(S_kv, D_v)]
     out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype),
                  jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -1552,17 +1748,22 @@ def _flash_bwd(q, k, v, bias, scale, lse, g, causal, delta,
         out_specs.append(stat)
         out_shape.append(jax.ShapeDtypeStruct(lse.shape, jnp.float32))
 
-    def kern(q_ref, k_ref, v_ref, *refs):
+    has_bias, has_delta = bias is not None, delta is not None
+
+    def kern(q_ref, k_ref, v_ref, *refs, **drawn):
         refs = list(refs)
-        bias_ref = refs.pop(0) if bias is not None else None
+        bias_ref = refs.pop(0) if has_bias else None
         do_ref, lse_ref = refs.pop(0), refs.pop(0)
-        delta_ref = None if delta is None else refs.pop(0)
+        delta_ref = refs.pop(0) if has_delta else None
+        if rate:
+            drawn.update(rate=rate, seed_ref=refs.pop(0))
         _bwd_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
                     delta_ref, *refs[:3], refs[3] if delta_out else None,
-                    scale=scale, causal=causal, heads=pack)
+                    scale=scale, causal=causal, heads=pack, **drawn)
 
     dq, dk, dv, *formed = _pallas_call(
-        kern, "flash_bwd", vmem_limit_bytes=vmem,
+        kern, "flash_bwd", vmem_limit_bytes=vmem, platform_bound=bool(rate),
+        cache_key=("bwd", scale, causal, heads, rate, delta_out),
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
@@ -1572,31 +1773,39 @@ def _flash_bwd(q, k, v, bias, scale, lse, g, causal, delta,
 
 
 def _flash_fwd_in_place(q, k, v, bias, scale, heads, causal=False,
-                        with_lse=True):
+                        with_lse=True, rate=0.0, seed=None):
     """``flash_fwd`` on operands as the projections left them
     (``_in_place``): q ``[B, S_q, heads * D]``, k and v ``[B, S_kv, heads *
     D]``, bias ``[B, S_q, S_kv]`` (the heads of a sequence share it),
     ``[B * heads, S_q, S_kv]`` or None -> ``(out [B, S_q, heads * D],
     logsumexp [B * heads, S_q] float32 or None)``: what ``_flash_forward``
     gives for the same heads as ``[B * heads, S, D]``, with no copy of any
-    of them."""
+    of them.  ``rate``: attention-probability dropout, its keep mask drawn
+    in the kernel from ``seed`` (int32 ``[1]``, ``_dropout_seed``)."""
     B, S_q, _ = q.shape
     D = q.shape[2] // heads
-    vmem = _plan("fwd", q, bias,
-                 *_in_place_shape(q, k, bias, causal, heads), heads=heads)[3]
+    vmem = _plan("fwd", q, bias, *_in_place_shape(q, k, bias, causal, heads),
+                 heads=heads, rate=rate)[3]
     grid, pack, rows, stat, bias_spec = _cell_specs(q, k, bias, heads)
     in_specs = [rows(S_q, D), rows(k.shape[1], D), rows(k.shape[1], D)]
     args = [q, k, v]
     if bias is not None:
         in_specs.append(bias_spec)
         args.append(bias)
+    if rate:
+        in_specs.append(_seed_spec())
+        args.append(seed)
 
-    def kern(q_ref, k_ref, v_ref, *refs):
+    has_bias = bias is not None
+
+    def kern(q_ref, k_ref, v_ref, *refs, **drawn):
         refs = list(refs)
-        bias_ref = refs.pop(0) if bias is not None else None
+        bias_ref = refs.pop(0) if has_bias else None
+        if rate:
+            drawn.update(rate=rate, seed_ref=refs.pop(0))
         _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, refs[0],
                     refs[1] if with_lse else None, scale=scale,
-                    causal=causal, heads=pack)
+                    causal=causal, heads=pack, **drawn)
 
     out_specs = [rows(S_q, D)]
     out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype)]
@@ -1605,7 +1814,8 @@ def _flash_fwd_in_place(q, k, v, bias, scale, heads, causal=False,
         out_shape.append(jax.ShapeDtypeStruct((B * heads, 1, S_q),
                                               jnp.float32))
     res = _pallas_call(
-        kern, "flash_fwd", vmem_limit_bytes=vmem,
+        kern, "flash_fwd", vmem_limit_bytes=vmem, platform_bound=bool(rate),
+        cache_key=("fwd_in_place", scale, causal, heads, with_lse, rate),
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
@@ -1797,26 +2007,31 @@ def _heads_minor(x):
     return x.transpose(0, 2, 1, 3).reshape(B, S, H * D)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def flash_attention_in_place(q, k, v, bias, scale, causal, heads,
-                             with_lse=True):
+                             with_lse=True, rate=0.0, seed=None):
     """``flash_attention_lse`` on operands ``[B, S, heads * D]`` at a shape
     the kernels read in place (``_in_place``): ``(out [B, S_q, heads * D],
     logsumexp [B * heads, S_q] or None)``.  The statistic is a residual,
-    not a differentiable output."""
-    return _flash_fwd_in_place(q, k, v, bias, scale, heads, causal, with_lse)
+    not a differentiable output.  ``rate``: attention-probability dropout
+    drawn in the kernels from ``seed`` (``_dropout_seed``); the bias is
+    then a mask that wants no gradient (``_op_in_place``), and none is
+    formed."""
+    return _flash_fwd_in_place(q, k, v, bias, scale, heads, causal, with_lse,
+                               rate, seed)
 
 
-def _fip_fwd(q, k, v, bias, scale, causal, heads, with_lse):
-    out, lse = _flash_fwd_in_place(q, k, v, bias, scale, heads, causal)
-    return (out, lse if with_lse else None), (q, k, v, bias, lse)
+def _fip_fwd(q, k, v, bias, scale, causal, heads, with_lse, rate, seed):
+    out, lse = _flash_fwd_in_place(q, k, v, bias, scale, heads, causal,
+                                   rate=rate, seed=seed)
+    return (out, lse if with_lse else None), (q, k, v, bias, lse, seed)
 
 
-def _fip_bwd(scale, causal, heads, with_lse, res, gs):
-    q, k, v, bias, lse = res
-    if bias is None:
-        return _backward_in_place(q, k, v, None, scale, causal, heads, lse,
-                                  gs[0]) + (None,)
+def _fip_bwd(scale, causal, heads, with_lse, rate, res, gs):
+    q, k, v, bias, lse, seed = res
+    if bias is None or rate:
+        return _backward_in_place(q, k, v, bias, scale, causal, heads, lse,
+                                  gs[0], rate, seed) + (None, None)
     # a bias under jax's own differentiation may want its gradient, and
     # the dbias pass writes a head's ``[BH, S_q, S_kv]``: the heads are
     # split for it, and XLA drops what nothing reads
@@ -1824,19 +2039,29 @@ def _fip_bwd(scale, causal, heads, with_lse, res, gs):
     dq, dk, dv, dbias = _backward_from_lse(q, k, v, bias, scale, causal, lse,
                                            None, g)
     return tuple(_heads_minor(d.reshape(-1, heads, *d.shape[1:]))
-                 for d in (dq, dk, dv)) + (dbias,)
+                 for d in (dq, dk, dv)) + (dbias, None)
 
 
 flash_attention_in_place.defvjp(_fip_fwd, _fip_bwd)
 
 
-def _backward_in_place(q, k, v, bias, scale, causal, heads, lse, g):
+def _backward_in_place(q, k, v, bias, scale, causal, heads, lse, g,
+                       rate=0.0, seed=None):
     """``(dq, dk, dv)`` as ``[B, S, heads * D]`` from operands and ``g`` in
     that layout and the forward's logsumexp ``[B * heads, S_q]``: ONE
     ``flash_bwd`` call that forms delta itself (the one tile is the whole
-    row), for a bias whose gradient nobody wants."""
+    row), for a bias whose gradient nobody wants; with ``rate`` it draws
+    the forward's keep mask again from the forward's ``seed``."""
     return _flash_bwd(q, k, v, bias, scale, lse[:, None], g.astype(q.dtype),
-                      causal, None, heads=heads)[:3]
+                      causal, None, heads=heads, rate=rate, seed=seed)[:3]
+
+
+def _dropout_seed(ctx):
+    """The dropout seed of a ``fused_attention`` op's in-kernel mask, int32
+    ``[1]``: 32 bits of the op's own key (``ctx.rng()``: per op, per step),
+    which its grad op remakes from the same key (``__op_seed__``)."""
+    return jax.lax.bitcast_convert_type(
+        jax.random.bits(ctx.rng(), (1,), jnp.uint32), jnp.int32)
 
 
 def _sp_attention(q, k, v, mesh, axis, mode, scale, causal, bias=None):
@@ -2007,23 +2232,32 @@ def _is_test(ctx):
     return bool(ctx.attr("is_test", False) or ctx.state.is_test)
 
 
+def _op_dropout(ctx):
+    """The attention-probability dropout rate an op runs with: its
+    ``attn_dropout``, 0 under ``is_test``."""
+    return 0.0 if _is_test(ctx) else \
+        float(ctx.attr("attn_dropout", 0.0) or 0.0)
+
+
 def _attention_route(ctx, q, k, v, qr=None):
     """Which path a ``fused_attention`` op — or its grad op, which
     carries the same attributes — takes, from what it can observe:
-    ``(sp_active, dropout, flash)``.  ``sp_active``: the sequence-parallel
-    transpiler stamped the op and the step compiles over a mesh carrying
-    that axis; ``dropout``: the attention-probability rate in effect;
-    ``flash``: neither, and every kernel has a tile at the shape
-    (``_flash_fits``), so the Pallas kernels run on the op's operands (Q,
-    K, V ``[B, H | H_kv, S, D]``) as they are, grouped key/value heads
-    included (``_kv_row``).  A rotary pair (``qr``: the op's ``QRope`` as
+    ``(sp_active, dropout, flash, kernels)``.  ``sp_active``: the
+    sequence-parallel transpiler stamped the op and the step compiles over
+    a mesh carrying that axis; ``dropout``: the attention-probability rate
+    in effect; ``kernels``: not ``sp_active``, and every kernel has a tile
+    at the shape (``_flash_fits``); ``flash``: that, without dropout, so
+    the Pallas kernels run on the op's operands (Q, K, V ``[B, H | H_kv,
+    S, D]``) as they are, grouped key/value heads included (``_kv_row``).
+    Dropout is drawn inside the kernels only where they read ``[B, S, H *
+    D]`` in place (``_op_in_place``, which reads ``kernels``); every other
+    op with dropout composes.  A rotary pair (``qr``: the op's ``QRope`` as
     ``[B, H, S_q, R]``) is among them only under the causal mask and
     without a bias (``_rope_runs_looped``); any other op with a pair
     composes one head size first.  A sliding window (``_op_window``) runs
     in the kernels under the causal mask without a bias; beside a bias the
     op composes."""
-    dropout = 0.0 if _is_test(ctx) else \
-        float(ctx.attr("attn_dropout", 0.0) or 0.0)
+    dropout = _op_dropout(ctx)
     sp_axis = ctx.attr("sp_axis", None)
     mesh = getattr(ctx.state, "mesh", None)
     sp = dict(mesh.shape).get(sp_axis, 1) if (sp_axis and mesh is not None) \
@@ -2032,13 +2266,13 @@ def _attention_route(ctx, q, k, v, qr=None):
     sp_active = sp > 1 and S_q % sp == 0 and _axis_is_auto(mesh, sp_axis)
     causal, has_bias = bool(ctx.attr("causal", False)), \
         ctx.has_input("BiasQK")
-    flash = not sp_active and not dropout and \
+    kernels = not sp_active and \
         not (qr is not None and (has_bias or not causal)) and \
         _flash_fits(S_q, k.shape[2], q.shape[3], v.shape[3],
                     0 if qr is None else qr.shape[3], has_bias, causal,
                     q.dtype.itemsize, q.shape[1] // k.shape[1],
                     _op_window(ctx, k.shape[2]))
-    return sp_active, dropout, flash
+    return sp_active, dropout, kernels and not dropout, kernels
 
 
 def _op_window(ctx, S_kv):
@@ -2125,8 +2359,10 @@ def _fused_attention(ctx, op):
     bias/padding mask) routes through ring/Ulysses attention under
     shard_map (transpiler/sequence_parallel.py); cross-length attention
     and attention dropout route through the q-row-sharded gather island
-    (``_sp_gather_attention`` — r5).  Off-mesh, dropout runs the exact
-    composition and everything else the flash kernel.
+    (``_sp_gather_attention`` — r5).  Off-mesh, dropout is drawn inside
+    the kernels where they read the operands in place and
+    ``_drop_in_kernels`` holds (``_op_in_place``), every other dropout op
+    runs the exact composition, and everything else the flash kernels.
 
     ``LSE`` [B, H, S_q] float32 (an intermediate output, like
     ``batch_norm``'s ``SavedMean``) is written where the flash kernels run
@@ -2169,11 +2405,13 @@ def _fused_attention(ctx, op):
         if _op_in_place(ctx, q, k, v, heads):
             _m_lowered.inc(shape="mha", path="flash", layout="bshd", window=0)
             B, S_q = q.shape[:2]
+            rate = _op_dropout(ctx)
             out, lse = flash_attention_in_place(
                 q, k, v, _kernel_bias(bias, _heads_major_shape(q, heads),
                                       k.shape[1]),
                 float(scale), causal, heads,
-                bool(op.output("LSE")) and not _is_test(ctx))
+                bool(op.output("LSE")) and not _is_test(ctx), rate,
+                _dropout_seed(ctx) if rate else None)
             if lse is not None:
                 ctx.set("LSE", lse.reshape(B, heads, S_q))
             ctx.set("Out", out)
@@ -2198,7 +2436,7 @@ def _fused_attention(ctx, op):
             "ambiguous; pass an explicit additive bias instead"
             % (S_q, S_kv))
     group = _kv_group(q, k, v, qr)
-    sp_active, dropout, flash = _attention_route(ctx, q, k, v, qr)
+    sp_active, dropout, flash, _ = _attention_route(ctx, q, k, v, qr)
     sp_axis = ctx.attr("sp_axis", None)
     mesh = getattr(ctx.state, "mesh", None)
     window = _op_window(ctx, S_kv)
@@ -2281,18 +2519,56 @@ def _op_heads_major(q, k, v, qr, kr, heads):
 
 def _op_in_place(ctx, q, k, v, heads):
     """Whether an op (or its grad op) whose operands are ``[B, S, heads *
-    D]`` runs the kernels on them as they lie: the flash route of
-    ``_attention_route`` (no sequence-parallel mesh, no dropout) at a
-    shape ``_in_place`` takes, with as many key/value heads as query heads
-    and no rotary pair."""
+    D]`` runs the kernels on them as they lie: where the kernels have a
+    tile (``_attention_route``'s ``kernels``: no sequence-parallel mesh)
+    at a shape ``_in_place`` takes, with as many key/value heads as query
+    heads and no rotary pair.  With attention dropout the kernels draw the
+    mask themselves where they hold less than the composition
+    (``_drop_in_kernels``) and the bias wants no gradient
+    (``_bias_may_want_grad``: the dbias pass has no mask); every other op
+    with dropout composes."""
     if ctx.has_input("QRope") or k.shape[2] != q.shape[2] or \
             v.shape[2] != q.shape[2] or q.shape[2] % heads or \
             _op_window(ctx, k.shape[1]):
         return False
     major = [_heads_major_shape(x, heads) for x in (q, k, v)]
-    return _attention_route(ctx, *major)[2] and _in_place(
+    _, dropout, _, kernels = _attention_route(ctx, *major)
+    if dropout and (_bias_may_want_grad(ctx) or not _drop_in_kernels(
+            q.shape[1], k.shape[1], q.shape[2] // heads)):
+        return False
+    return kernels and _in_place(
         heads, *_in_place_shape(q, k, ctx.i_opt("BiasQK"),
                                 ctx.attr("causal", False), heads))
+
+
+def _drop_in_kernels(S_q, S_kv, D):
+    """Whether an op with attention dropout holds less in the kernels than
+    in the composition, from a head's shape: where its scores outnumber
+    the operands the kernels keep for it from the forward to the backward,
+    ``S_q * S_kv > (S_q + 2 * S_kv) * D``.  A Mosaic call reads Q, K and V
+    as they lie in HBM, and the step keeps them whole, float32 under the
+    BERT cells' pure-bf16 AMP, until the backward's call; the composition
+    instead forms a layer's ``[B, H, S_q, S_kv]`` scores, probabilities
+    and mask, one layer at a time, and XLA keeps less of the projections
+    for its replay.  Compiled here for a v5e (PERF.md section 6, PR 40):
+    at S=512, D=64, batch 16 the kernels' step reserves 183 MB LESS than
+    the composition's; at S=128, batch 128 1.18 GB MORE, the projections'
+    outputs (``role_fwd/fluid_mul``) 3.36 GB of it against 1.67.  Without
+    dropout the kernels have no composition to weigh against: the rule is
+    dropout's alone."""
+    return S_q * S_kv > (S_q + 2 * S_kv) * D
+
+
+def _bias_may_want_grad(ctx):
+    """Whether the op's bias is a variable that a backward may
+    differentiate: one that is not ``stop_gradient`` (``append_backward``
+    gives the grad op a ``BiasQK@GRAD`` exactly then).  Read from the
+    variable, so a forward op and its grad op agree."""
+    names = ctx.op.input("BiasQK")
+    if not names or not names[0]:
+        return False
+    var = ctx.block._find_var_recursive(names[0])
+    return var is None or not var.stop_gradient
 
 
 _m_lowered = telemetry.counter(
@@ -2327,7 +2603,8 @@ def _fused_attention_grad(ctx, op):
     op's (it folds a replay of plain HLO, never a custom call).
     Everywhere else — sequence-parallel islands, the dropout composition,
     non-tileable shapes, a program built without the ``LSE`` slot — the
-    replay stands.
+    replay stands.  An op whose kernels drew a dropout mask draws it again
+    from the same key (``_dropout_seed``).
 
     An op with ``num_heads`` (operands ``[B, S, H * D]``,
     ``_fused_attention``) whose shape the kernels read in place runs ONE
@@ -2348,12 +2625,14 @@ def _fused_attention_grad(ctx, op):
         if lse is not None and g is not None and not want["BiasQK"] and \
                 _op_in_place(ctx, q, k, v, heads):
             _m_grad_lowered.inc(path="residual", kv_sum="none")
+            rate = _op_dropout(ctx)
             grads = _backward_in_place(
                 q, k, v, _kernel_bias(ctx.i_opt("BiasQK"),
                                       _heads_major_shape(q, heads),
                                       k.shape[1]),
                 float(ctx.attr("scale", 1.0)),
-                bool(ctx.attr("causal", False)), heads, _flat(lse), g)
+                bool(ctx.attr("causal", False)), heads, _flat(lse), g,
+                rate, _dropout_seed(ctx) if rate else None)
             for slot, grad in zip(("Q", "K", "V"), grads):
                 if want[slot]:
                     ctx.env[want[slot]] = grad

@@ -33,9 +33,10 @@ class BertConfig:
         self.attn_dropout = attn_dropout
         self.max_seq_len = max_seq_len
         # pallas flash-attention core; with attention dropout on, the
-        # op routes through its exact-composition path (flash has no
-        # in-kernel RNG) but keeps the fused_attention program surface,
-        # so sequence parallelism still engages
+        # kernels draw the mask themselves where a head's scores outnumber
+        # what they keep (S=512), else the op composes; either way it keeps
+        # the fused_attention program surface, so sequence parallelism
+        # still engages
         self.use_fused_attention = use_fused_attention
 
 

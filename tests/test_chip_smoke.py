@@ -44,7 +44,8 @@ def test_smoke_dry_run_passes_at_tiny_sizes(capsys):
     doc = json.loads(last[len("dry-run summary: "):])
     assert doc["device"]["platform"] == "cpu" and doc["native"] is True
     assert doc["phases"] == {"device": "pass", "resnet50": "pass",
-                             "bert": "pass", "kernels": "pass"}
+                             "bert": "pass", "kernels": "pass",
+                             "flash_dropout": "pass"}
     # a program-bound loader moving from host batches to staged ones
     # compiles nothing again, in the executor or under it in jit
     assert doc["informational"]["resnet50"][
@@ -60,7 +61,8 @@ def test_smoke_result_line_is_exactly_ok_and_device(monkeypatch, capsys):
     device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
     monkeypatch.setattr(chip_smoke, "phase_device",
                         lambda dry_run, chips: (device, [None]))
-    for name in ("phase_resnet", "phase_bert", "phase_kernels"):
+    for name in ("phase_resnet", "phase_bert", "phase_kernels",
+                 "phase_flash_dropout"):
         monkeypatch.setattr(chip_smoke, name, lambda *a: {})
     chip_smoke.main([])
     lines = capsys.readouterr().out.strip().splitlines()
@@ -68,7 +70,7 @@ def test_smoke_result_line_is_exactly_ok_and_device(monkeypatch, capsys):
     assert lines[-2].startswith("summary: ")
     assert json.loads(lines[-2][len("summary: "):])["phases"] == {
         "device": "pass", "resnet50": "pass", "bert": "pass",
-        "kernels": "pass"}
+        "kernels": "pass", "flash_dropout": "pass"}
 
 
 def test_explicit_tpu_place_never_resolves_to_a_cpu():

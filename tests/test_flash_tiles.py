@@ -402,3 +402,94 @@ def test_flash_tiles_total_says_which_operand_layout_a_call_took(heads,
     assert counter.value() - total == 2
     assert counter.value(layout="bshd") + counter.value(layout="bhsd") == \
         counter.value()
+
+
+# -- attention dropout drawn inside the in-place kernels (PR 40) -------------
+
+def _rebuilt_mask(seed, n_heads, S_q, S_kv, rate):
+    """The keep masks the interpreted kernels draw, rebuilt outside any
+    kernel from ``_keep_bits``' interpreted branch (``_hash_bits``): bool
+    ``[n_heads, S_q, S_kv]``, head ``n`` the row of ``[B * H, ...]``."""
+    t = pallas_ops._keep_threshold(rate)
+    with jax.ensure_compile_time_eval():      # also while a step is traced
+        return np.stack([
+            np.asarray((pallas_ops._hash_bits(seed, n, (S_q, S_kv))
+                        ^ jnp.int32(-2 ** 31)) < t)
+            for n in range(n_heads)])
+
+
+def test_the_kernels_mask_is_the_rebuilt_one_and_keeps_nine_in_ten():
+    """The mask read back by a kernel of the attention kernels' grid
+    (``_drawn_mask``: two sequences, four heads of 64, two cells of two a
+    sequence) is the one rebuilt outside it; over 128 x 128 x 8 draws it
+    keeps within 5 sigma of 0.9."""
+    got = np.asarray(pallas_ops._drawn_mask(jnp.array([7], jnp.int32), 2, 4,
+                                            64, 128, 128, 0.1))
+    want = _rebuilt_mask(7, 8, 128, 128, 0.1)
+    assert got.shape == want.shape and (got.astype(bool) == want).all()
+    assert abs(want.mean() - 0.9) < 5 * np.sqrt(0.9 * 0.1 / want.size)
+
+
+@pytest.mark.parametrize("other", ["seed", "head_in_cell", "cell", "sequence"])
+def test_seeds_heads_and_cells_draw_masks_of_their_own(other):
+    """Head 0 of sequence 0 under seed 7 against one that differs in one
+    of them: two independent masks agree on 0.9^2 + 0.1^2 = 0.82 of their
+    elements, the same one on all."""
+    seed, head = {"seed": (8, 0), "head_in_cell": (7, 1), "cell": (7, 2),
+                  "sequence": (7, 4)}[other]
+    mask = {s: np.asarray(pallas_ops._drawn_mask(jnp.array([s], jnp.int32),
+                                                 2, 4, 64, 128, 128, 0.1))
+            for s in {7, seed}}
+    agree = (mask[7][0] == mask[seed][head]).mean()
+    assert abs(agree - 0.82) < 0.02, agree
+
+
+@pytest.mark.parametrize("rate,dropout", [(0.0, "none"), (0.1, "in_kernel")])
+def test_flash_tiles_total_says_whether_a_call_draws_its_dropout(rate,
+                                                                  dropout):
+    """``dropout``: ``in_kernel`` for ``fwd`` and ``bwd`` in place that draw
+    the keep mask themselves, ``none`` for every other call."""
+    counter = telemetry.registry().get("flash_tiles_total")
+    x = jax.ShapeDtypeStruct((2, 128, 2 * 64), jnp.float32)
+    seed = jax.ShapeDtypeStruct((1,), jnp.int32) if rate else None
+    labels = [dict(kernel=k, layout="bshd", dropout=dropout)
+              for k in ("fwd", "bwd")]
+    before, total = [counter.value(**lb) for lb in labels], counter.value()
+
+    def loss(q, k, v, seed):
+        return pallas_ops.flash_attention_in_place(
+            q, k, v, None, 0.125, False, 2, True, rate, seed)[0].sum()
+    jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x, seed)
+    assert [counter.value(**lb) - n for lb, n in zip(labels, before)] == \
+        [1, 1]
+    assert counter.value() - total == 2
+    assert counter.value(dropout="none") + \
+        counter.value(dropout="in_kernel") == counter.value()
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "bwd"])
+def test_estimate_counts_the_bits_a_dropout_tile_draws(kernel):
+    """With dropout a cell also holds a tile's int32 bits and the float32
+    tile the mask leaves, and the flash cell's pair of heads in float32
+    still fits the budget (the route does not read the rate)."""
+    key = RULE_SHAPES["s512_f32_bias"]
+    plain, drawn = (pallas_ops._vmem_bytes(kernel, 512, 512, *key, rows=True,
+                                           heads=2, bits=bits)
+                    for bits in (False, True))
+    assert drawn - plain == 512 * 512 * 8
+    assert drawn <= pallas_ops._VMEM_BUDGET_BYTES
+
+
+@pytest.mark.parametrize("S_q,S_kv,D,want", [
+    (512, 512, 64, True),        # bert_base_s512_dropout: 262144 > 98304
+    (256, 256, 64, True),
+    (128, 128, 64, False),       # bert_base_s128_dropout: 16384 < 24576
+    (512, 512, 128, True),
+    (256, 256, 128, False),
+    (512, 128, 64, True),        # 65536 > 49152
+    (128, 512, 64, False)])      # 65536 < 73728
+def test_dropout_is_drawn_in_the_kernels_where_scores_outnumber_operands(
+        S_q, S_kv, D, want):
+    """An op with dropout takes the kernels in place where a head's scores
+    outnumber the Q, K and V the kernels keep for it, else it composes."""
+    assert pallas_ops._drop_in_kernels(S_q, S_kv, D) is want

@@ -20,6 +20,7 @@ import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import layers, telemetry
 from paddle_tpu.fluid.ops import pallas_ops
 from paddle_tpu.fluid.ops.pallas_ops import _reference_attention
+from tests.test_flash_tiles import _rebuilt_mask
 
 B, H, D = 2, 2, 16
 
@@ -347,22 +348,25 @@ def test_long_kv_keeps_out_and_passes_delta(monkeypatch, kernel_calls, S_kv,
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4, err_msg=name)
 
 
-def test_dropout_and_untileable_shapes_keep_the_replay(kernel_calls):
+@pytest.mark.parametrize("case", ["dropout_4d", "dropout_s1024", "s192"])
+def test_dropout_off_the_in_place_route_and_untileable_shapes_keep_the_replay(
+        kernel_calls, case):
+    """A 4-D op (no ``num_heads``) with attention dropout, at one tile or
+    several, is the exact composition replayed with its key: only the
+    kernels that read ``[B, S, H * D]`` in place draw a mask; S = 192 does
+    not tile into 128-row blocks, and takes the reference composition."""
+    S = {"dropout_4d": 128, "dropout_s1024": 1024, "s192": 192}[case]
     before = _grad_paths()
-    feed = _feed()
-    # attention dropout: the exact composition, replayed with its key
-    _run(*_program(dropout=0.1), feed)
+    feed = _feed(S, S, seed=2)
+    got = _run(*_program(S_q=S, S_kv=S, dropout=0.0 if case == "s192"
+                         else 0.1), feed)
     assert _paths_taken(before) == (0, 1)
     assert not kernel_calls
-    # S = 192 does not tile into 128-row blocks: the reference composition
-    before = _grad_paths()
-    feed = _feed(192, 192, seed=2)
-    got = _run(*_program(S_q=192, S_kv=192), feed)
-    assert _paths_taken(before) == (0, 1)
-    assert not kernel_calls
-    for name, a, b in zip(("loss", "dq", "dk", "dv"), got,
-                          _reference_grads(feed, False)):
-        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4, err_msg=name)
+    if case == "s192":
+        for name, a, b in zip(("loss", "dq", "dk", "dv"), got,
+                              _reference_grads(feed, False)):
+            np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4,
+                                       err_msg=name)
 
 
 def test_sequence_parallel_island_keeps_the_replay():
@@ -792,11 +796,48 @@ def _layout_feed(heads=2, kv_heads=None, head=64, S=128, bias_shape=None,
     return major, minor
 
 
-def _tiles_by_layout():
+def _tiles_by_layout(dropout=False):
+    """``flash_tiles_total`` by (layout, kernel), and with ``dropout`` by
+    (layout, kernel, dropout)."""
     c = telemetry.counter("flash_tiles_total")
+    if dropout:
+        return {(layout, kernel, d): c.value(layout=layout, kernel=kernel,
+                                             dropout=d)
+                for layout in ("bshd", "bhsd")
+                for kernel in ("fwd", "bwd", "dq", "dkv", "dbias")
+                for d in ("none", "in_kernel")}
     return {(layout, kernel): c.value(layout=layout, kernel=kernel)
             for layout in ("bshd", "bhsd")
             for kernel in ("fwd", "bwd", "dq", "dkv", "dbias")}
+
+
+# the seed every dropout op of a test draws from, where a test fixes it
+SEED = 20240
+_HEAD_MASKS = {}
+
+
+def _the_kernels_mask(shape, rate):
+    """bool ``[B, H, S_q, S_kv]``: what the in-place kernels draw from
+    ``SEED`` for heads laid out so (``_rebuilt_mask``)."""
+    key = (tuple(shape), rate)
+    if key not in _HEAD_MASKS:
+        b, h, S_q, S_kv = shape
+        _HEAD_MASKS[key] = _rebuilt_mask(SEED, b * h, S_q, S_kv,
+                                         rate).reshape(shape)
+    return _HEAD_MASKS[key]
+
+
+@pytest.fixture
+def one_mask(monkeypatch):
+    """Every dropout op draws what the in-place kernels draw from ``SEED``:
+    the kernels' seed is fixed, and the composition's ``bernoulli`` is
+    handed the same mask, so a 4-D op and the in-place kernels drop the
+    same probabilities."""
+    monkeypatch.setattr(pallas_ops, "_dropout_seed",
+                        lambda ctx: jnp.array([SEED], jnp.int32))
+    monkeypatch.setattr(jax.random, "bernoulli",
+                        lambda key, p, shape: jnp.asarray(
+                            _the_kernels_mask(shape, 1.0 - p)))
 
 
 def _lowered_by_layout():
@@ -866,9 +907,37 @@ ROUTES = {
         {"flash_fwd": [2], "flash_dq": [2], "flash_dkv": [3]},
         {("bhsd", "fwd"): 1, ("bhsd", "dq"): 1, ("bhsd", "dkv"): 1},
         {("bhsd", "flash"): 1}),
+    # attention dropout at a shape the kernels read in place and whose
+    # scores outnumber what they keep (``_drop_in_kernels``: S=256 at D=64):
+    # they draw the mask themselves (``dropout="in_kernel"``), with a mask
+    # that wants no gradient or without one (the 4-D op composes and
+    # writes no ``LSE``)
     "dropout": (
-        dict(dropout=0.1, bias_shape=(B, 1, 128, 128), lse=False),
-        {}, {}, {("bhsd", "composition"): 2}),      # forward and its replay
+        dict(dropout=0.1, S=256, bias_shape=(B, 1, 256, 256), lse=False),
+        {"flash_fwd": [2], "flash_bwd": [3]},
+        {("bshd", "fwd", "in_kernel"): 1, ("bshd", "bwd", "in_kernel"): 1},
+        {("bshd", "flash"): 1}),
+    "dropout_causal": (
+        dict(dropout=0.1, S=256, causal=True, lse=False),
+        {"flash_fwd": [2], "flash_bwd": [3]},
+        {("bshd", "fwd", "in_kernel"): 1, ("bshd", "bwd", "in_kernel"): 1},
+        {("bshd", "flash"): 1}),
+    # every other op with dropout composes, forward and replay: one tile
+    # whose scores are fewer than the operands the kernels would keep
+    # (S=128 at D=64), several tiles a head, no tile, a bias whose
+    # gradient is wanted (the dbias pass has no mask)
+    "dropout_s128": (
+        dict(dropout=0.1, bias_shape=(B, 1, 128, 128), lse=False), {}, {},
+        {("bhsd", "composition"): 2}),
+    "dropout_s1024": (
+        dict(dropout=0.1, S=1024, lse=False), {}, {},
+        {("bhsd", "composition"): 2}),
+    "dropout_s192": (
+        dict(dropout=0.1, S=192, lse=False), {}, {},
+        {("bhsd", "composition"): 2}),
+    "dropout_bias_grad": (
+        dict(dropout=0.1, bias_shape=(B, 1, 128, 128), bias_grad=True,
+             lse=False), {}, {}, {("bhsd", "composition"): 2}),
     "no_tile_s192": (
         dict(S=192, lse=False), {}, {}, {("bhsd", "composition"): 2}),
 }
@@ -876,20 +945,22 @@ ROUTES = {
 
 @pytest.mark.parametrize("case", sorted(ROUTES))
 def test_heads_minor_op_takes_its_route_and_gives_the_4d_ops_numbers(
-        kernel_calls, case):
+        kernel_calls, one_mask, case):
     """An op with ``num_heads`` runs the kernels on its operands in place
     where a head is one tile and the heads pack whole into 128 lanes, and
     is the 4-D op between a split and a merge everywhere else; either way
     the loss, ``LSE`` and every gradient are the 4-D op's on the same
-    numbers (with dropout: the same key, so the same mask)."""
+    numbers (with dropout: the same mask, ``one_mask``, whether the
+    kernels draw it or the composition is handed it)."""
     kw, kernels, tiles, lowered = ROUTES[case]
+    by_dropout = bool(kw.get("dropout")) and bool(tiles)
     major, minor = _layout_feed(**kw)
     want = _run(*_layout_program(False, **kw), major)
     kernel_calls.clear()
-    before = _tiles_by_layout(), _lowered_by_layout()
+    before = _tiles_by_layout(by_dropout), _lowered_by_layout()
     got = _run(*_layout_program(True, **kw), minor)
     assert dict(kernel_calls) == kernels
-    assert _moved(before[0], _tiles_by_layout()) == tiles
+    assert _moved(before[0], _tiles_by_layout(by_dropout)) == tiles
     assert _moved(before[1], _lowered_by_layout()) == lowered
     names = ["loss"] + ["lse"] * kw.get("lse", True) + ["dq", "dk", "dv"] + \
         (["dqr", "dkr"] if kw.get("rope") else []) + \
@@ -1013,13 +1084,183 @@ def test_tiny_fused_bert_step_holds_no_head_split_or_merge(kernel_calls):
     assert losses[-1] < losses[0]
 
 
-def test_tiny_fused_bert_with_attention_dropout_splits_inside_the_op():
-    """Attention dropout takes the composition: the op splits the heads
-    itself, and the program still spells no transpose."""
-    main, startup, loss, feed, _ = _tiny_fused_bert(attn_dropout=0.1)
+def test_tiny_fused_bert_with_attention_dropout_draws_it_in_the_kernels():
+    """BERT as published (attention dropout 0.1) at a shape a head is one
+    tile (S=256): the kernels read Q, K, V in place and draw the mask
+    themselves, one ``flash_fwd`` and one ``flash_bwd`` a layer at
+    ``dropout="in_kernel"``, with no composition and no transpose; the
+    loss falls."""
+    main, startup, loss, feed, _ = _tiny_fused_bert(attn_dropout=0.1, S=256)
     kinds = [op.type for op in main.global_block().ops]
     assert "transpose2" not in kinds
-    before = _lowered_by_layout()
-    assert np.isfinite(_run(main, startup, [loss], feed)[0]).all()
-    assert _moved(before, _lowered_by_layout()) == {
-        ("bhsd", "composition"): 4}
+    before = _lowered_by_layout(), _tiles_by_layout(dropout=True)
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        losses = [float(np.asarray(exe.run(main, feed=feed,
+                                           fetch_list=[loss])[0]).reshape(()))
+                  for _ in range(3)]
+    assert np.isfinite(losses).all() and losses[-1] < losses[0]
+    assert _moved(before[0], _lowered_by_layout()) == {("bshd", "flash"): 2}
+    assert _moved(before[1], _tiles_by_layout(dropout=True)) == {
+        ("bshd", "fwd", "in_kernel"): 2, ("bshd", "bwd", "in_kernel"): 2}
+
+
+# -- attention dropout inside the in-place kernels (PR 40) -------------------
+
+# B=2, S=128, four heads of 64 (two cells of a pair a sequence)
+DROP_HEADS, DROP_S, RATE = 4, 128, 0.1
+_drop_runs = {}
+
+
+def _dropout_case(bias, causal):
+    """``(got, want)`` by name (out, lse, dq, dk, dv): the in-place kernels
+    with dropout from ``SEED``, and ``_attn_core`` (the composition the
+    4-D op runs) handed their mask as its ``bernoulli``, its logsumexp
+    that of the undropped scores; cached."""
+    key = (bias, causal)
+    if key in _drop_runs:
+        return _drop_runs[key]
+    rng = np.random.RandomState(17 + 2 * bias + causal)
+    shape = (B, DROP_S, DROP_HEADS * 64)
+    q, k, v, g = (jnp.asarray(rng.randn(*shape).astype(np.float32) * 0.5)
+                  for _ in range(4))
+    mask = jnp.asarray(rng.randn(B, DROP_S, DROP_S).astype(np.float32)
+                       * 0.3) if bias else None
+    seed = jnp.array([SEED], jnp.int32)
+    out, lse = pallas_ops._flash_fwd_in_place(q, k, v, mask, 0.125,
+                                              DROP_HEADS, causal, True,
+                                              RATE, seed)
+    got = dict(zip(("out", "lse", "dq", "dk", "dv"), (out, lse) + tuple(
+        pallas_ops._backward_in_place(q, k, v, mask, 0.125, causal,
+                                      DROP_HEADS, lse, g, RATE, seed))))
+    kept = jnp.asarray(_the_kernels_mask((B, DROP_HEADS, DROP_S, DROP_S),
+                                         RATE))
+
+    def split(x):
+        return pallas_ops._heads_major(x, DROP_HEADS)
+
+    def core(q, k, v):
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(jax.random, "bernoulli", lambda key, p, sh: kept)
+            o = pallas_ops._attn_core(
+                split(q), split(k), split(v),
+                None if mask is None else mask[:, None], 0.125, causal, 0,
+                RATE, jax.random.PRNGKey(0))
+        return pallas_ops._heads_minor(o)
+    ref_out, vjp = jax.vjp(core, q, k, v)
+    s = jnp.einsum("bhqd,bhkd->bhqk", split(q), split(k)) * 0.125
+    if mask is not None:
+        s = s + mask[:, None]
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones((DROP_S, DROP_S), bool)), s,
+                      pallas_ops._NEG)
+    want = dict(zip(("out", "lse", "dq", "dk", "dv"),
+                    (ref_out, jax.nn.logsumexp(s, -1).reshape(-1, DROP_S))
+                    + tuple(vjp(g))))
+    _drop_runs[key] = got, want
+    return got, want
+
+
+@pytest.mark.parametrize("what", ["out", "lse", "dq", "dk", "dv"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("bias", [False, True], ids=["no_bias", "mask"])
+def test_in_kernel_dropout_is_the_composition_fed_the_kernels_mask(
+        bias, causal, what):
+    """``flash_fwd`` and ``flash_bwd`` in place with the keep mask drawn
+    inside them: Out, ``LSE``, dQ, dK and dV are ``_attn_core``'s (the
+    4-D op's composition) fed the same mask, rebuilt outside the kernels
+    from ``_keep_bits``' interpreted branch; ``LSE`` is the undropped
+    scores'."""
+    got, want = _dropout_case(bias, causal)
+    assert got[what].shape == want[what].shape
+    np.testing.assert_allclose(np.asarray(got[what]), np.asarray(want[what]),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_forward_and_grad_op_draw_one_mask_and_two_steps_two(monkeypatch):
+    """The op's seed comes from its own key (``ctx.rng()``): the grad op
+    remakes the forward's, so its gradients are those of the mask the
+    forward drew (the composition fed that mask agrees), and the next step
+    draws another."""
+    seen = []
+    real = pallas_ops._dropout_seed
+
+    def spy(ctx):
+        seed = real(ctx)
+        jax.debug.callback(lambda s: seen.append(int(np.asarray(s)[0])),
+                           seed)
+        return seed
+    monkeypatch.setattr(pallas_ops, "_dropout_seed", spy)
+    kw = dict(dropout=RATE, S=256, bias_shape=(B, 1, 256, 256), lse=False)
+    major, minor = _layout_feed(**kw)
+    main, startup, fetches = _layout_program(True, **kw)
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        steps = [[np.asarray(x) for x in exe.run(main, feed=minor,
+                                                  fetch_list=fetches)]
+                 for _ in range(2)]
+    assert len(seen) == 4 and seen[0] == seen[1] and seen[2] == seen[3]
+    assert seen[0] != seen[2]
+    assert not np.allclose(steps[0][0], steps[1][0])
+    # the first step against the 4-D composition fed that step's mask
+    monkeypatch.setattr(pallas_ops, "_dropout_seed", real)
+    kept = jnp.asarray(_rebuilt_mask(seen[0], B * 2, 256, 256, RATE)
+                       .reshape(B, 2, 256, 256))
+    monkeypatch.setattr(jax.random, "bernoulli", lambda key, p, sh: kept)
+    want = _run(*_layout_program(False, **kw), major)
+    for name, a, b in zip(("loss", "dq", "dk", "dv"), steps[0], want):
+        b = b if name == "loss" else _minor(b)
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5, err_msg=name)
+
+
+def test_for_test_clone_of_a_dropout_op_runs_rate_0(kernel_calls):
+    """Under ``is_test`` the rate is 0: the clone reads in place, draws no
+    mask (``dropout="none"``) and gives what the op without dropout
+    gives."""
+    kw = dict(dropout=RATE, bias_shape=(B, 1, 128, 128), backward=False,
+              lse=False)
+    major, minor = _layout_feed(**kw)
+    main, startup, fetches = _layout_program(True, **kw)
+    before = _tiles_by_layout(dropout=True)
+    infer, = _run(main.clone(for_test=True), startup, fetches, minor)
+    assert _moved(before, _tiles_by_layout(dropout=True)) == {
+        ("bshd", "fwd", "none"): 1}
+    assert dict(kernel_calls) == {"flash_fwd": [1]}
+    plain, = _run(*_layout_program(True, **dict(kw, dropout=0.0)), minor)
+    np.testing.assert_allclose(infer, plain, rtol=1e-6)
+
+
+# (B, heads, S, D, rows of bias) of the op in place -> sha1 of the jaxpr of
+# what its lowering and its grad op's trace: ``flash_attention_in_place``
+# and ``_backward_in_place``, float32 as the BERT cells hand them over
+ONE_TILE_PROGRAMS = {
+    # bert_base_s512_flash: 12 heads of 64, S=512, the padding mask a
+    # sequence, no dropout; the hash of the tree before in-kernel dropout
+    "flash_cell": ((32, 12, 512, 64, 32),
+                   "3a8cc238167f4204c304c65d854896f9d3ca770f"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(ONE_TILE_PROGRAMS))
+def test_one_tile_programs_at_rate_0_are_the_ones_pinned(name):
+    """THE PIN for the flash cell: at rate 0 the in-place program, kernel
+    bodies, grids, index maps and VMEM limits included, is the text it was
+    before dropout could be drawn in the kernels (the rate is static: no
+    seed operand, no draw, no other limit)."""
+    import hashlib
+    (batch, heads, S, D, bias_rows), want = ONE_TILE_PROGRAMS[name]
+
+    def arr(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+    def step(q, k, v, bias, g):
+        out, lse = pallas_ops.flash_attention_in_place(q, k, v, bias, 0.125,
+                                                       False, heads, True)
+        return (out,) + tuple(pallas_ops._backward_in_place(
+            q, k, v, bias, 0.125, False, heads, lse, g))
+    x = arr(batch, S, heads * D)
+    text = str(jax.make_jaxpr(step)(x, x, x, arr(bias_rows, S, S), x))
+    assert "prng" not in text
+    assert hashlib.sha1(text.encode()).hexdigest() == want
