@@ -42,6 +42,11 @@ merges a replayed forward's plain HLO with the forward op's, never two
 conditionals).  A layer whose first rung would be half the last or more —
 every layer that holds all ``E`` experts, and small shapes — has one rung
 and traces no conditional.  PERF.md section 6, PR 31.
+
+The token side's sums (the weighted sum back to the tokens, and the
+dispatch's backward) are ``pallas_ops.row_sum``: the row buffer read once
+up to its last live row, each row added into its token's float32 sum;
+the gathers of rows stay XLA's.  PERF.md section 6.
 """
 
 import contextlib
@@ -52,6 +57,7 @@ import jax.numpy as jnp
 
 from .. import telemetry
 from ..lowering import amp_operands
+from . import pallas_ops
 from ..registry import register_grad_lower, register_op
 
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -61,6 +67,11 @@ _m_experts_lowered = telemetry.counter(
     "routed_experts lowerings traced, by the path of its grouped matmuls "
     "(a training step traces each op twice: the forward op and its replay "
     "inside the grad op)")
+_m_token_rows = telemetry.counter(
+    "moe_token_rows_lowered_total",
+    "routed_experts sums by token traced (Pallas call moe_row_sum), by form "
+    "(combine: the weighted sum of the rows back to the tokens; dx: the "
+    "dispatch's backward) and the row buffer's rows")
 
 
 @register_op("rms_norm")
@@ -205,57 +216,86 @@ def route(x, router_w, select_bias, top_k, scale, scoring_func="sigmoid"):
     return idx, weight, load.astype(jnp.float32)
 
 
-@jax.custom_vjp
-def _dispatch(x, token_of, slot_of, held):
-    """Rows of ``x`` [T, H] in sorted-assignment order, [T * k, H]:
-    row r is token ``token_of[r]``.  Backward is a gather too (each token
-    sums the rows of its own held assignments, found through ``slot_of``
-    [T, k], the row of each assignment), never a scatter-add."""
-    return x[token_of]
+def _narrowed(rows, dtype):
+    """``rows`` as the grouped matmul gave them, or in ``dtype`` where that
+    is narrower: the token side sums in float32 either way, and a row
+    widened only to be summed is the same numbers in twice the bytes."""
+    if jnp.promote_types(rows.dtype, dtype) != dtype:
+        return rows.astype(dtype)
+    return rows
 
 
-def _dispatch_fwd(x, token_of, slot_of, held):
-    return x[token_of], (slot_of, held)
+def _sum_rows(rows, token_of, n_live, tokens, dtype, w_row=None):
+    """``pallas_ops.row_sum`` in ``dtype``: each token's float32 sum of
+    its live rows (times ``w_row``), a row wider than ``dtype`` rounded to
+    it first (counted: one lowering traced)."""
+    _m_token_rows.inc(form="dx" if w_row is None else "combine",
+                      rows=str(rows.shape[0]))
+    return pallas_ops.row_sum(_narrowed(rows, dtype), token_of, n_live,
+                              tokens, w_row).astype(dtype)
 
 
-def _dispatch_bwd(res, g):
-    slot_of, held = res
-    rows = jnp.where(held[..., None], g[slot_of], 0)        # [T, k, H]
-    return rows.astype(jnp.float32).sum(axis=1).astype(g.dtype), \
+def _row_weights(weight, order, held):
+    """``[R]`` float32: the weight of each sorted row's assignment, zero
+    for the assignments held elsewhere."""
+    return jnp.where(held, weight, 0).reshape(-1)[order]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _dispatch(dtypes, x, token_of, slot_of, n_live):
+    """Rows of ``x`` [T, H] in sorted-assignment order, [T * k, H] in the
+    rows' dtype (``dtypes`` = (rows', x's)): row r is token
+    ``token_of[r]``.  Backward: each token sums its live rows
+    (``_sum_rows``), never a scatter-add; ``slot_of`` [T, k] gives the
+    tokens' count."""
+    return x[token_of].astype(dtypes[0])
+
+
+def _dispatch_fwd(dtypes, x, token_of, slot_of, n_live):
+    return _dispatch(dtypes, x, token_of, slot_of, n_live), \
+        (token_of, slot_of, n_live)
+
+
+def _dispatch_bwd(dtypes, res, g):
+    token_of, slot_of, n_live = res
+    return _sum_rows(g, token_of, n_live, slot_of.shape[0], dtypes[1]), \
         None, None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-def _held_rows(y, slot_of, held):
-    """[T, k, H] float32: the row of each held assignment, zero for the
-    others (their slots lie past the last group, where nothing is
-    computed)."""
-    return jnp.where(held[..., None], y[slot_of], 0).astype(jnp.float32)
+def _combine_backward(ys, weight, order, token_of, slot_of, held, g):
+    """``(dys [R, H], dweight [T, k])`` of the weighted sum by token for
+    the cotangent ``g`` [T, H]: ``g`` gathered by row as the forward
+    gathered ``x``, and the weights' gradient formed on the row side,
+    ``<ys[r], g[token_of[r]]>`` over the rows and then a gather of
+    scalars.  A row past the live ones has a zero weight; its ``ys`` may
+    be anything a grouped matmul left there, and its product is read
+    nowhere."""
+    g_rows = g[token_of].astype(jnp.float32)                  # [R, H]
+    dys = (g_rows * _row_weights(weight, order, held)[:, None]) \
+        .astype(ys.dtype)
+    dw_row = (ys.astype(jnp.float32) * g_rows).sum(axis=-1)
+    return dys, jnp.where(held, dw_row[slot_of], 0).astype(weight.dtype)
 
 
-@jax.custom_vjp
-def _combine(y, weight, order, token_of, slot_of, held):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _combine(y, weight, order, token_of, slot_of, held, n_live, dtype):
     """out[t] = sum_k weight[t, k] * y[slot_of[t, k]] over the held
-    assignments (float32 sum), [T, H] in ``y``'s dtype; the backward
-    gathers ``d out`` by row, as the forward gathered ``x``."""
-    return (_held_rows(y, slot_of, held) * weight[..., None]).sum(axis=1) \
-        .astype(y.dtype)
+    assignments (float32 sum), [T, H] in ``dtype``; the backward gathers
+    ``d out`` by row, as the forward gathered ``x``."""
+    return _sum_rows(y, token_of, n_live, weight.shape[0], dtype,
+                     _row_weights(weight, order, held))
 
 
-def _combine_fwd(y, weight, order, token_of, slot_of, held):
-    return _combine(y, weight, order, token_of, slot_of, held), \
-        (y, weight, order, token_of, slot_of, held)
+def _combine_fwd(y, weight, order, token_of, slot_of, held, n_live, dtype):
+    return _combine(y, weight, order, token_of, slot_of, held, n_live,
+                    dtype), (y, weight, order, token_of, slot_of, held)
 
 
-def _combine_bwd(res, g):
-    y, weight, order, token_of, slot_of, held = res
-    w_row = jnp.where(held, weight, 0).reshape(-1)[order]   # [T * k]
-    dy = (g[token_of].astype(jnp.float32) * w_row[:, None]).astype(y.dtype)
-    dw = (_held_rows(y, slot_of, held) *
-          g[:, None, :].astype(jnp.float32)).sum(axis=-1)
-    return dy, dw.astype(weight.dtype), None, None, None, None
+def _combine_bwd(dtype, res, g):
+    return _combine_backward(*res, g) + (None,) * 5
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -331,38 +371,24 @@ def _every_row(dtypes, x, weight, wg, wu, wd, plan):
     """The held experts' part of the routed sum through row buffers of
     every assignment (``T * k`` rows, whatever came): the last rung, and
     the whole layer where it has one.  Gather, grouped SwiGLU, weighted
-    gather-sum; differentiable as it stands (``_dispatch`` and
-    ``_combine`` carry their gathers' backward).  ``dtypes``: the one the
-    rows are computed in, the matmuls' accumulator (``amp_operands``) and
-    the gate's activation (``_gated``)."""
+    sum by token; differentiable as it stands (``_dispatch`` and
+    ``_combine`` carry their backward).  ``dtypes``: the one the rows are
+    computed in, the matmuls' accumulator (``amp_operands``) and the
+    gate's activation (``_gated``)."""
     order, token_of, slot_of, held, group_sizes = plan
     rows_dtype, acc, act = dtypes
     R = order.shape[0]
+    n_live = group_sizes.sum()
     with _rows_scope("moe_dispatch", R):
-        xs = _dispatch(x, token_of, slot_of, held)           # [T * k, H]
+        xs = _dispatch((rows_dtype, x.dtype), x, token_of, slot_of,
+                       n_live)                               # [T * k, H]
     with _rows_scope("moe_experts", R):
-        xs = xs.astype(rows_dtype)
         gate, up = _gate_up(xs, wg, wu, group_sizes, acc)
-        ys = _grouped(_gated(act, gate, up, rows_dtype), wd, group_sizes,
-                      acc).astype(x.dtype)
+        ys = _narrowed(_grouped(_gated(act, gate, up, rows_dtype), wd,
+                                group_sizes, acc), x.dtype)
     with _rows_scope("moe_combine", R):
-        return _combine(ys, weight, order, token_of, slot_of, held)
-
-
-def _sum_by_token(rows, slot_of, held, weight=None):
-    """[T, H] float32: each token's sum over its k assignments of the row
-    ``rows[slot_of[t, j]]`` of every HELD one (times ``weight[t, j]``).
-    One gather of ``[T, H]`` a choice: a single ``[T, k, H]`` gather costs
-    a copy into the layout of a k that is no multiple of 8 before it can
-    be summed.  The rows of the others are masked, not trusted: past the
-    last group a grouped matmul leaves what was there."""
-    total = 0
-    for j in range(slot_of.shape[1]):
-        chosen = jnp.where(held[:, j, None], rows[slot_of[:, j]], 0) \
-            .astype(jnp.float32)
-        total = total + (chosen if weight is None
-                         else chosen * weight[:, j, None])
-    return total
+        return _combine(ys, weight, order, token_of, slot_of, held, n_live,
+                        x.dtype)
 
 
 def _first_rows(plan, R):
@@ -379,42 +405,36 @@ def _first_rung(R, dtypes, x, weight, wg, wu, wd, plan):
     """``(out [T, H], kept)``: what ``_every_row`` gives, through row
     buffers of ``R`` rows, for a step whose rows fit (``group_sizes.sum()
     <= R``), and the rows its backward reads: ``(xs [R, H], gate, up
-    [R, I], ys [R, H])``.  The same rows, the same float32 sum over ``k``;
+    [R, I], ys [R, H])``.  The same rows, the same float32 sums;
     ``ys`` stays in the dtype the matmul gives where widening it to
     ``x``'s is exact (the sum is float32 either way: half the bytes for
     the token side to read)."""
-    _, token_of, slot_of, held, group_sizes = _first_rows(plan, R)
+    order, token_of, slot_of, held, group_sizes = _first_rows(plan, R)
     rows_dtype, acc, act = dtypes
     with _rows_scope("moe_dispatch", R):
         xs = x[token_of].astype(rows_dtype)                  # [R, H]
     with _rows_scope("moe_experts", R):
         gate, up = _gate_up(xs, wg, wu, group_sizes, acc)
-        ys = _grouped(_gated(act, gate, up, rows_dtype), wd, group_sizes,
-                      acc)
-        if jnp.promote_types(ys.dtype, x.dtype) != x.dtype:
-            ys = ys.astype(x.dtype)     # a narrowing, as ``_every_row``'s
+        ys = _narrowed(_grouped(_gated(act, gate, up, rows_dtype), wd,
+                                group_sizes, acc), x.dtype)
     with _rows_scope("moe_combine", R):
-        out = _sum_by_token(ys, slot_of, held, weight)
-    return out.astype(x.dtype), (xs, gate, up, ys)
+        out = _sum_rows(ys, token_of, group_sizes.sum(), x.shape[0],
+                        x.dtype, _row_weights(weight, order, held))
+    return out, (xs, gate, up, ys)
 
 
 def _first_rung_backward(R, dtypes, x, weight, wg, wu, wd, plan, kept, g):
     """``(dx, dweight, dwg, dwu, dwd)`` of ``_first_rung(R, ...)[0]`` for
     the cotangent ``g`` [T, H], from the rows its forward ``kept``.  No
     matmul of the forward runs again: the grouped products are linear, and
-    the primal side of their ``jax.vjp`` is dead code.  The weights'
-    gradient is formed on the row side, ``<ys[r], g[token_of[r]]>`` over
-    ``R`` rows and then a gather of scalars, where ``_combine``'s backward
-    gathers ``[T, k, H]`` a second time."""
+    the primal side of their ``jax.vjp`` is dead code.  The combine's
+    backward is ``_combine``'s (``_combine_backward``)."""
     order, token_of, slot_of, held, group_sizes = _first_rows(plan, R)
     rows_dtype, acc, act = dtypes
     xs, gate, up, ys = kept
     with _rows_scope("moe_combine", R):
-        g_rows = g[token_of].astype(jnp.float32)             # [R, H]
-        w_row = jnp.where(held, weight, 0).reshape(-1)[order]
-        dy = (g_rows * w_row[:, None]).astype(ys.dtype)
-        dw_row = (ys.astype(jnp.float32) * g_rows).sum(axis=-1)
-        dweight = jnp.where(held, dw_row[slot_of], 0).astype(weight.dtype)
+        dy, dweight = _combine_backward(ys, weight, order, token_of, slot_of,
+                                        held, g)
     with _rows_scope("moe_experts", R):
         hidden, gated_vjp = jax.vjp(
             lambda a, b: _gated(act, a, b, rows_dtype), gate, up)
@@ -425,8 +445,9 @@ def _first_rung_backward(R, dtypes, x, weight, wg, wu, wd, plan, kept, g):
             lambda a, b, c: _gate_up(a, b, c, group_sizes, acc),
             xs, wg, wu)[1](gated_vjp(dhidden))
     with _rows_scope("moe_dispatch", R):
-        dx = _sum_by_token(dxs.astype(x.dtype), slot_of, held)
-    return dx.astype(x.dtype), dweight, dwg, dwu, dwd
+        dx = _sum_rows(dxs, token_of, group_sizes.sum(), x.shape[0],
+                       x.dtype)
+    return dx, dweight, dwg, dwu, dwd
 
 
 def _cond_on_rows(rungs, plan, first_rung, last_rung, operands):

@@ -2687,6 +2687,125 @@ def _fused_attention_grad(ctx, op):
 
 
 # ---------------------------------------------------------------------------
+# the routed experts' sums by token
+# ---------------------------------------------------------------------------
+#
+# ``row_sum``: each token's float32 sum of the live rows of a row buffer
+# that belong to it (the combine, weighted; the dispatch's backward), the
+# buffer streamed once and only its live rows added, in their order.  A
+# grid cell reads ``block`` rows of a column chunk and adds each live one
+# into its token's row of the chunk's output, which stays in VMEM while the
+# whole buffer passes (grid: chunk, then row block): an output row is read
+# and written in VMEM, and the buffer's rows need no copy of their own.
+# The composition it replaces gathered ``[T, H]`` once a choice, every
+# token whether that choice is held here or not: ``k`` gathers of ``T``
+# rows for ``T * k * held / E`` live ones, and XLA:TPU's row gather costs
+# about 45 ns a row on a v5e at these widths, whatever the row's bytes.  A
+# single row is no slice a DMA of a tiled HBM array takes (a tile holds 8
+# rows), so the sum streams the buffer rather than fetching its rows one by
+# one (PERF.md section 6).
+
+# rows of the buffer a grid cell reads, at most
+_SUM_BLOCK = 512
+# VMEM for a column chunk's float32 output, both of its buffers
+_SUM_OUT_BYTES = 32 << 20
+
+
+def _sum_block(R):
+    """Rows a grid cell reads: the largest power of two from
+    ``_SUM_BLOCK`` down to 8 that divides ``R`` (``R`` itself below 8)."""
+    block = _SUM_BLOCK
+    while block > 8 and R % block:
+        block //= 2
+    return block if R % block == 0 else R
+
+
+def _sum_columns(T, H):
+    """Lanes of a column chunk: the widest multiple of 128 that divides
+    ``H`` and whose ``[T, W]`` float32 output, double-buffered, fits
+    ``_SUM_OUT_BYTES``; ``H`` itself where it is no multiple of 128."""
+    if H % 128:
+        return H
+    for W in range(H, 128, -128):
+        if H % W == 0 and 2 * T * W * 4 <= _SUM_OUT_BYTES:
+            return W
+    return 128
+
+
+def _row_sum_kernel(n_ref, token_ref, *refs, block, weighted):
+    """One grid cell (chunk ``c``, rows ``i * block ..``): ``n_ref`` [1]
+    the live rows; ``token_ref`` / ``w_ref`` [1, 1, block] (SMEM) each
+    row's token and weight; ``out_ref`` [T, W] float32, the chunk's sums,
+    zeroed by the chunk's first cell."""
+    if weighted:
+        w_ref, rows_ref, out_ref, rows32 = refs
+    else:
+        rows_ref, out_ref, rows32 = refs
+    i = pl.program_id(1)
+
+    @pl.when(i == 0)
+    def _():
+        out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
+
+    live = jnp.clip(n_ref[0] - i * block, 0, block)
+
+    @pl.when(live > 0)
+    def _():
+        rows32[...] = rows_ref[...].astype(jnp.float32)
+
+        def add(r, carry):
+            row = rows32[pl.ds(r, 1), :]
+            if weighted:
+                row = row * w_ref[0, 0, r]
+            t = token_ref[0, 0, r]
+            out_ref[pl.ds(t, 1), :] = out_ref[pl.ds(t, 1), :] + row
+            return carry
+        jax.lax.fori_loop(0, live, add, 0)
+
+
+def row_sum(rows, token_of, n_live, tokens, weight=None):
+    """``[tokens, H]`` float32: ``out[t]`` is the sum over the live rows
+    ``r < n_live`` with ``token_of[r] == t`` of ``rows[r]`` in float32
+    (times ``weight[r]``), added in the order of the rows.  ``rows`` [R, H]
+    (a row past ``n_live`` is never read: it may hold anything),
+    ``token_of`` [R] int32, ``weight`` [R] float32.  Pallas call
+    ``moe_row_sum``."""
+    R, H = rows.shape
+    pad = -R % 8 if R > 8 else 0
+    if pad:
+        rows = jnp.pad(rows, ((0, pad), (0, 0)))
+        token_of = jnp.pad(token_of, (0, pad))
+        if weight is not None:
+            weight = jnp.pad(weight, (0, pad))
+    block = _sum_block(R + pad)
+    W = _sum_columns(tokens, H)
+    cells = (R + pad) // block
+    scalars = functools.partial(pl.BlockSpec, (1, 1, block),
+                                lambda c, i: (i, 0, 0),
+                                memory_space=pltpu.SMEM)
+    in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM), scalars()]
+    operands = [jnp.reshape(n_live, (1,)).astype(jnp.int32),
+                token_of.astype(jnp.int32).reshape(cells, 1, block)]
+    if weight is not None:
+        in_specs.append(scalars())
+        operands.append(weight.astype(jnp.float32).reshape(cells, 1, block))
+    in_specs.append(pl.BlockSpec((block, W), lambda c, i: (i, c)))
+    operands.append(rows)
+    need = 2 * tokens * W * 4 + 2 * block * W * rows.dtype.itemsize + \
+        block * W * 4
+    return _pallas_call(
+        functools.partial(_row_sum_kernel, block=block,
+                          weighted=weight is not None),
+        "moe_row_sum", vmem_limit_bytes=_vmem_limit(need),
+        cache_key=(block, weight is not None, tokens, W),
+        grid=(H // W, cells), in_specs=in_specs,
+        out_specs=pl.BlockSpec((tokens, W), lambda c, i: (0, c)),
+        out_shape=jax.ShapeDtypeStruct((tokens, H), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block, W), jnp.float32)],
+    )(*operands)
+
+
+# ---------------------------------------------------------------------------
 # fused layer norm
 # ---------------------------------------------------------------------------
 

@@ -11,6 +11,7 @@ gives (a dropped expert or a bias that weighs moves the output by percents).
 """
 
 import re
+import types
 
 import numpy as np
 import pytest
@@ -468,7 +469,29 @@ def test_the_two_rungs_agree(rows, compute, tol):
 
 
 def _conditionals(fn, *args):
-    return str(jax.make_jaxpr(fn)(*args)).count(" cond[")
+    """The ``cond`` equations of the traced program, the Pallas calls
+    left out: a call is a ``cond`` on ``platform_index`` (Mosaic or the
+    interpreter: ``pallas_ops._pallas_call``) and a kernel's ``pl.when``
+    a ``cond`` of its own."""
+    def count(jaxpr):
+        found, platform = 0, set()
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "platform_index":
+                platform.update(eqn.outvars)
+                continue
+            if eqn.primitive.name == "pallas_call" or (
+                    eqn.primitive.name == "cond" and
+                    eqn.invars[0] in platform):
+                continue
+            found += eqn.primitive.name == "cond"
+            for param in eqn.params.values():
+                for sub in param if isinstance(param, (list, tuple)) \
+                        else (param,):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        found += count(sub)
+        return found
+    return count(jax.make_jaxpr(fn)(*args).jaxpr)
 
 
 @pytest.mark.parametrize("tokens,experts,held,conds,rows", [
@@ -488,6 +511,178 @@ def test_one_rung_traces_no_conditional(tokens, experts, held, conds, rows):
     counter = telemetry.registry().get("moe_experts_lowered_total")
     assert counter.value(path="ragged_dot", rows=rows) == 1
     assert counter.value(path="ragged_dot") == 1
+
+
+# -- the token side: the sums by token, a Pallas kernel (interpreted here) -------
+
+def _composed_sum(rows, slot_of, held, weight=None):
+    """The composition ``row_sum`` replaces: one gather of ``[T, H]`` a
+    choice, masked, summed over ``k`` in order in float32."""
+    total = 0
+    for j in range(slot_of.shape[1]):
+        chosen = jnp.where(held[:, j, None], rows[slot_of[:, j]], 0) \
+            .astype(jnp.float32)
+        total = total + (chosen if weight is None
+                         else chosen * weight[:, j, None])
+    return total
+
+
+def _sum_in_row_order(rows, token_of, n_live, tokens, weight=None):
+    """``row_sum`` as a loop: float32, the rows added in their order."""
+    rows = np.asarray(rows, np.float32)
+    out = np.zeros((tokens, rows.shape[1]), np.float32)
+    for r in range(n_live):
+        row = rows[r] if weight is None else \
+            rows[r] * np.float32(weight[r])
+        out[token_of[r]] = out[token_of[r]] + row
+    return out
+
+
+def _nan_past(rows, n_live):
+    return jnp.where(jnp.arange(rows.shape[0])[:, None] < n_live, rows,
+                     jnp.nan).astype(rows.dtype)
+
+
+@pytest.mark.parametrize("weighted", [True, False])
+@pytest.mark.parametrize("held_share", [0.0, 0.4, 1.0],
+                         ids=["no_token_held", "mixed", "every_assignment"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_row_sum_is_the_masked_sum_by_token(weighted, held_share, dtype):
+    """``row_sum`` over a row buffer sorted as ``_plan`` sorts it (the
+    held assignments first) is the loop that adds the live rows in their
+    order (to the bit unweighted; weighted, a multiply-add may round once
+    where the loop rounds twice), and the composition it replaces
+    (``_composed_sum``, a choice at a time) to float32 round-off: the same
+    products, added in another order.  Tokens with no held assignment read zeros, tokens with
+    all ``k`` held sum them all, the rows past the live ones are NaN (never
+    read), and a buffer of no whole number of 8-row groups is padded."""
+    rng = np.random.default_rng(2)
+    tokens, k = 40, 3
+    held = rng.random((tokens, k)) < held_share
+    held[:3] = False                   # tokens with none held
+    if held_share:
+        held[3:6] = True               # ... and with all k
+    idx = np.where(held, rng.integers(0, 2, (tokens, k)), 2)
+    order, token_of, slot_of, _, sizes = decoder_ops._plan(
+        jnp.asarray(idx, jnp.int32), 0, 2)
+    n_live = int(sizes.sum())
+    R = tokens * k - 3                 # the live rows are a prefix
+    order, token_of = order[:R], token_of[:R]
+    slot_of = jnp.minimum(slot_of, R - 1)
+    rows = _nan_past(jnp.asarray(rng.normal(size=(R, HID)), dtype), n_live)
+    weight = jnp.asarray(rng.random((tokens, k)), jnp.float32) \
+        if weighted else None
+    w_row = None if weight is None else \
+        decoder_ops._row_weights(weight, order, jnp.asarray(held))
+    got = jax.jit(pallas_ops.row_sum, static_argnums=3)(
+        rows, token_of, jnp.int32(n_live), tokens, w_row)
+    assert got.dtype == jnp.float32 and got.shape == (tokens, HID)
+    assert np.isfinite(np.asarray(got)).all()
+    in_order = _sum_in_row_order(rows, np.asarray(token_of), n_live, tokens,
+                                 None if w_row is None else np.asarray(w_row))
+    if weighted:        # a multiply-add may be fused where the loop rounds
+        close(got, in_order, tol=1e-6, what="against the loop")
+    else:
+        np.testing.assert_array_equal(np.asarray(got), in_order)
+    want = jax.jit(_composed_sum)(rows, slot_of, jnp.asarray(held), weight)
+    close(got, want, tol=1e-6, what="against the composition")
+    assert not np.asarray(got)[~held.any(axis=1)].any()
+
+
+def _leaves_nan_past_the_groups(monkeypatch):
+    """A grouped matmul that writes NaN past its last group, as a chip's
+    may leave anything there: the token side must never read those rows."""
+    real = decoder_ops._grouped
+
+    def grouped(a, w, group_sizes, acc):
+        return _nan_past(real(a, w, group_sizes, acc), group_sizes.sum())
+    monkeypatch.setattr(decoder_ops, "_grouped", grouped)
+
+
+def _composed_token_side(monkeypatch):
+    """The sums by token as XLA's segment sum of the live rows."""
+    def sums(rows, token_of, n_live, tokens, dtype, w_row=None):
+        rows = decoder_ops._narrowed(rows, dtype).astype(jnp.float32)
+        if w_row is not None:
+            rows = rows * w_row[:, None]
+        live = jnp.arange(rows.shape[0])[:, None] < n_live
+        return jax.ops.segment_sum(jnp.where(live, rows, 0), token_of,
+                                   tokens).astype(dtype)
+    monkeypatch.setattr(decoder_ops, "_sum_rows", sums)
+
+
+@pytest.mark.parametrize("path,both,one,bias", [
+    ("first_rung", 100, 50, None), ("every_row", 200, 113, None),
+    ("every_row", 0, 0, ALL_HELD)],
+    ids=["first_rung", "every_row", "every_assignment_held"])
+def test_the_sums_by_token_are_the_composition_they_replace(
+        monkeypatch, path, both, one, bias):
+    """The layer with two rungs, forward and every gradient, with the
+    kernel's sums and with XLA's segment sum of the same rows, to float32
+    round-off: on a step that fits the first rung, one that takes the last
+    (``_every_row``) and one where every assignment is held.  The grouped
+    matmul leaves NaN past its last group, so no sum reads there."""
+    p, x = _share_params(both, one, bias)
+    _leaves_nan_past_the_groups(monkeypatch)
+    g = jnp.asarray(np.random.default_rng(6).normal(size=x.shape),
+                    jnp.float32)
+
+    def layer():
+        (out, load), vjp = jax.vjp(
+            lambda x_, p_: _op(p_, x_, held=RHELD), x, p)
+        return jax.tree.map(np.asarray, (out, load, vjp((g, jnp.zeros(RE)))))
+    telemetry.reset_metrics()
+    got = layer()
+    counter = telemetry.registry().get("moe_token_rows_lowered_total")
+    rung = {"first_rung": RUNG, "every_row": RT * K}[path]
+    assert (float(got[1][:RHELD].sum()) > RUNG) == (path == "every_row")
+    for form in ("combine", "dx"):
+        assert counter.value(form=form, rows=str(rung)) >= 1
+    _composed_token_side(monkeypatch)
+    want = layer()
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.isfinite(a).all()
+        close(a, b, tol=1e-6)
+
+
+# cell -> (T, H, E, top_k, held, I, scoring, act, the rungs' rows)
+CELL_LAYERS = {
+    "moonlight_ep8share_s4096_train":
+        (4096, 2048, 64, 6, 8, 1408, "sigmoid", "silu", ("6144", "24576")),
+    "lfm2_ep4share_s8192_train":
+        (8192, 2048, 32, 4, 8, 1792, "sigmoid", "silu", ("32768",)),
+    "smallthinker_ep8share_s16384_train":
+        (16384, 2560, 64, 6, 8, 768, "softmax", "relu", ("24576", "98304")),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_LAYERS))
+def test_every_rung_of_the_cells_layers_lowers_both_sums(cell):
+    """At each expert cell's shapes (traced, not run) every rung of the
+    layer lowers the combine's sum and the dispatch's backward:
+    ``moe_token_rows_lowered_total{form, rows}``."""
+    T, H, E, k, held, inner, scoring, act, rungs = CELL_LAYERS[cell]
+    assert tuple(str(R) for R in decoder_ops._rungs(T, k, held, E)) == rungs
+    state = types.SimpleNamespace(amp_dtype="bfloat16", amp_keep=True)
+
+    def loss(x, rw, wg, wu, wd, bias):
+        out, load = decoder_ops.routed_experts(
+            x, rw, bias, wg, wu, wd, top_k=k, scale=1.0, first_expert=0,
+            state=state, scoring_func=scoring, hidden_act=act)
+        return out.astype(jnp.float32).sum() + load.sum() * 0
+
+    def arr(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32)
+    telemetry.reset_metrics()
+    jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), arr(T, H),
+                   arr(H, E), arr(held, H, inner), arr(held, H, inner),
+                   arr(held, inner, H), arr(E))
+    counter = telemetry.registry().get("moe_token_rows_lowered_total")
+    for rows in rungs:
+        for form in ("combine", "dx"):
+            assert counter.value(form=form, rows=rows) >= 1, (form, rows)
+    assert counter.value() == counter.value(form="combine") + \
+        counter.value(form="dx")
 
 
 # -- the whole model -------------------------------------------------------------
@@ -561,7 +756,9 @@ def test_model_loss_and_every_gradient_through_executor(
     counter = telemetry.registry().get("moe_experts_lowered_total")
     assert counter.value(path="ragged_dot", rows=rows) == \
         counter.value(path="ragged_dot") >= 2
-    assert len(re.findall(r" conditional\(", hlo)) == 2 * ("|" in rows)
+    # the interpreted sums by token hold conditionals of their own
+    assert len(re.findall(r" conditional\((?![^\n]*moe_row_)", hlo)) == \
+        2 * ("|" in rows)
     want_loss, want_grads, want_loads = ref.loss_and_grads(
         params, *_squeeze(feed), _reference_cfg(cfg))
     assert abs(float(got[0][0]) - float(want_loss)) < 2e-5 * float(want_loss)

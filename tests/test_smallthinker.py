@@ -428,24 +428,27 @@ def test_the_shares_add_up_to_the_uncut_layer():
 
 # -- what must not move ---------------------------------------------------------------
 
-# cell -> ((T, H, E, top_k, held, I, scale), sha1 of the traced text at the
-# parent of the PR that gave routed_experts its second input)
+# cell -> ((T, H, E, top_k, held, I, scale), sha1 of the traced text since
+# the sums by token became a Pallas kernel; before, at the parent of the PR
+# that gave routed_experts its second input: 7c1c9b0b..., d939de24...)
 EXPERT_LAYERS = {
     "moonlight": ((4096, 2048, 64, 6, 8, 1408, 2.446),
-                  "7c1c9b0b907735e20f0d9b90c8a038fb7b99e034"),
+                  "ea760f321542b6b160b824b08fd7dcd1550d77a9"),
     "lfm2": ((8192, 2048, 32, 4, 8, 1792, 1.0),
-             "d939de248980a16bb1468a00d6fb4a693faf1e39"),
+             "240b37768144fc91fb1b545564e7d3759db7ee26"),
 }
 
 
 @pytest.mark.parametrize("name", sorted(EXPERT_LAYERS))
 def test_expert_layers_at_their_defaults_are_the_ones_pinned(name):
-    """THE PIN for the two cells whose steps hold ``routed_experts``: at
-    the defaults (no ``router_x``, sigmoid scores, SiLU gates) a layer's
-    whole traced program, forward and backward, conditionals included, is
-    the text it was before the op had a second input: those steps compile
-    to what they compiled to (``tools/step_memory.py`` says so for the
-    whole step: 4,880,808,448 bytes of temporaries in Moonlight's)."""
+    """THE PIN for the two cells whose steps hold ``routed_experts`` at
+    the defaults (no ``router_x``, sigmoid scores, SiLU gates): a layer's
+    whole traced program, forward and backward, conditionals and the row
+    copies included, is the text pinned, so those steps compile to what
+    they compiled to (``tools/step_memory.py`` says so for the whole
+    step).  Re-pinned once, when the sums by token became a Pallas kernel
+    (PERF.md section 6); the op's second input had left the text
+    as it was."""
     (T, H, E, k, held, I, scale), want = EXPERT_LAYERS[name]
     state = types.SimpleNamespace(amp_dtype="bfloat16", amp_keep=True)
 
