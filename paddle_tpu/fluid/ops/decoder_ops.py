@@ -47,6 +47,16 @@ The token side's sums (the weighted sum back to the tokens, and the
 dispatch's backward) are ``pallas_ops.row_sum``: the row buffer read once
 up to its last live row, each row added into its token's float32 sum;
 the gathers of rows stay XLA's.  PERF.md section 6.
+
+The rotary embedding (``rotary``) is one pass over its operand each way:
+the rotate-half and the de-interleave are products by constant signed
+permutations, exact on the MXU, and the backward (a ``custom_vjp``) is the
+same pass with the matrices transposed.  The slices it replaced,
+``[-x[D/2:], x[:D/2]]`` of a float32 copy, cut a 128-lane tile at lane 64,
+which XLA:TPU does not fuse: it wrote each float32 half to HBM at 128
+lanes, 17.8 ms of a SmallThinker step and 18.7 of Ouro's on the chip
+where reading Q and K and writing them once costs about 3 and 6.  PERF.md
+section 6.
 """
 
 import contextlib
@@ -54,6 +64,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .. import telemetry
 from ..lowering import amp_operands
@@ -88,6 +99,61 @@ def _rms_norm(ctx, op):
     ctx.set("Y", y.astype(x.dtype))
 
 
+_m_rotary_lowered = telemetry.counter(
+    "rotary_lowered_total",
+    "rotary_embedding lowerings traced, by the lanes' pairing "
+    "(interleaved) and head_dim (a training step traces each op twice: "
+    "the forward op and its replay inside the grad op)")
+
+
+def _lane_matrices(D, interleaved):
+    """``(A, AR)``, numpy ``[D, D]``: ``x @ A`` is ``x`` de-interleaved
+    (evens first, then odds; None where the lanes pair as they are) and
+    ``x @ AR`` the rotate-half of that, ``[-x_a[D/2:], x_a[:D/2]]``.  One
+    entry of +-1 a column: a product by either is exact."""
+    h = D // 2
+    rot = np.zeros((D, D), np.float32)
+    rot[np.arange(h) + h, np.arange(h)] = -1.0
+    rot[np.arange(h), np.arange(h) + h] = 1.0
+    if not interleaved:
+        return None, rot
+    deint = np.zeros((D, D), np.float32)
+    deint[2 * np.arange(h), np.arange(h)] = 1.0
+    deint[2 * np.arange(h) + 1, np.arange(h) + h] = 1.0
+    return deint, deint @ rot
+
+
+def _turn(x, first, second, angles):
+    """``(x @ first) * cos + (x @ second) * sin`` in float32, cast to
+    ``x``'s dtype: each element of ``x`` read once, each of the result
+    written once.  ``first`` None is the identity; the products are
+    exact (bf16 operands on the MXU with a float32 sum; float32 at
+    ``HIGHEST``); ``angles`` [S, D] are broadcast over batch and heads."""
+    def lanes(m):
+        return jnp.einsum(
+            "bsnd,de->bsne", x, jnp.asarray(m, x.dtype),
+            preferred_element_type=jnp.float32,
+            precision=_HIGHEST if x.dtype == jnp.float32 else None)
+
+    a = x.astype(jnp.float32) if first is None else lanes(first)
+    angles = angles[None, :, None, :]
+    return (a * jnp.cos(angles) + lanes(second) * jnp.sin(angles)) \
+        .astype(x.dtype)
+
+
+def _angles(S, D, theta, adjacent):
+    """Position times frequency, [S, D] float32: frequency ``i`` on lanes
+    ``i`` and ``i + D/2`` (the rotate-half's order), or with ``adjacent``
+    on lanes ``2i`` and ``2i + 1`` (an interleaved input's own order)."""
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
+    if adjacent:
+        inv_freq = jnp.repeat(inv_freq, 2)
+    else:
+        inv_freq = jnp.concatenate([inv_freq, inv_freq])
+    return jnp.arange(S, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
 def rotary(x, theta, interleaved=True):
     """Rotary position embedding over the last axis of ``x`` [B, S, heads,
     D], positions 0..S-1 (full sequences, packed from 0).
@@ -99,17 +165,39 @@ def rotary(x, theta, interleaved=True):
     de-interleaved order, for Q and K alike, so the scores are unchanged.
     Not ``interleaved`` (the Llama layout): the weights pair lane i with
     lane i + D/2 already, and the rotate-half runs on the lanes as they
-    are."""
+    are.
+
+    ONE pass over ``x`` each way: the de-interleave and the rotate-half
+    are products by constant ``[D, D]`` signed permutations
+    (``_lane_matrices``), and the backward is the same pass with the
+    matrices transposed and the angles in the input's lane order, ``dx =
+    (g A^T) cos + (g (AR)^T) sin``: ``sin`` is equal on the two lanes of
+    a pair, so ``R^T (g sin) = (R^T g) sin``, and the backward forms the
+    products differentiation forms, in float32, and sums them once.
+    Written as slices, ``[-x[D/2:], x[:D/2]]`` of a float32 copy, the
+    half at lane 64 of a 128-lane tile is no fusion XLA:TPU makes: it
+    wrote each float32 half to HBM at 128 lanes, half of them padding,
+    17.8 ms of a SmallThinker step and 18.7 of Ouro's on the chip against
+    a 3.0 ms HBM floor for reading Q and K and writing them once in each
+    of SmallThinker's 9 passes (PERF.md section 6)."""
     B, S, N, D = x.shape
-    xf = x.astype(jnp.float32)
-    if interleaved:
-        xf = xf.reshape(B, S, N, D // 2, 2).swapaxes(-1, -2) \
-            .reshape(B, S, N, D)
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
-    angles = jnp.arange(S, dtype=jnp.float32)[:, None] * inv_freq[None, :]
-    angles = jnp.concatenate([angles, angles], axis=-1)[None, :, None, :]
-    half = jnp.concatenate([-xf[..., D // 2:], xf[..., :D // 2]], axis=-1)
-    return (xf * jnp.cos(angles) + half * jnp.sin(angles)).astype(x.dtype)
+    first, second = _lane_matrices(D, interleaved)
+    return _turn(x, first, second,
+                 _angles(S, D, theta, adjacent=False))
+
+
+def _rotary_fwd(x, theta, interleaved):
+    return rotary(x, theta, interleaved), None
+
+
+def _rotary_bwd(theta, interleaved, _, g):
+    B, S, N, D = g.shape
+    first, second = _lane_matrices(D, interleaved)
+    return (_turn(g, None if first is None else first.T, second.T,
+                  _angles(S, D, theta, adjacent=interleaved)),)
+
+
+rotary.defvjp(_rotary_fwd, _rotary_bwd)
 
 
 @register_op("rotary_embedding")
@@ -117,8 +205,10 @@ def _rotary_embedding(ctx, op):
     """X [B, S, heads, D] -> Out, the same shape: rotary embedding with
     base ``theta`` on the whole last axis (the caller hands over the
     rotary slice of the head); ``interleaved`` says how the lanes pair."""
-    ctx.set("Out", rotary(ctx.i("X"), float(ctx.attr("theta", 10000.0)),
-                          bool(ctx.attr("interleaved", True))))
+    x, interleaved = ctx.i("X"), bool(ctx.attr("interleaved", True))
+    _m_rotary_lowered.inc(interleaved=interleaved, head_dim=int(x.shape[-1]))
+    ctx.set("Out", rotary(x, float(ctx.attr("theta", 10000.0)),
+                          interleaved))
 
 
 # -- the gated short convolution -------------------------------------------------
